@@ -250,8 +250,8 @@ def export_bundle(engine, out: str, node: str = "",
     }]
     programs: List[Dict[str, str]] = []
     total = len(payload)
-    blobs = passthrough \
-        + trainer.programs.serialize_programs(monitor=monitor)
+    blobs = passthrough + trainer.programs.serialize_programs(
+        list(trainer.mesh.devices.flat))
     for i, (key, blob) in enumerate(sorted(blobs, key=lambda e:
                                            repr(e[0]))):
         name = "prog-%04d.pkl" % i

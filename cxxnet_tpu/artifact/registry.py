@@ -46,6 +46,17 @@ class ResidencyBudgetError(RuntimeError):
     is rejected whole; whatever was serving keeps serving."""
 
 
+class ArtifactLoadError(RuntimeError):
+    """A sealed executable failed to deserialize where it had to
+    succeed: at export (the round-trip check on the sealing runtime)
+    or at boot on a runtime whose fingerprint MATCHED the bundle's.
+    That is a fault in the bundle or the runtime, not a portability
+    miss: it surfaces as this error instead of a warning and a silent
+    recompile, so "zero-compile boot" cannot degrade unseen. (A
+    mismatched fingerprint stays the documented path: one warning,
+    every key re-lowers.)"""
+
+
 class WeightResidency:
     """The device-resident serve weight tree and its accounting.
 
@@ -232,17 +243,23 @@ class ProgramRegistry:
                 warn_code: str, monitor=None) -> int:
         """AOT-compile ``(key, lower-thunk)`` pairs, skipping keys
         already present (including keys a bundle install satisfied —
-        that skip IS the near-zero cold start). A failed compile warns
-        once and leaves that key on the jit fallback path; per-program
-        telemetry rides on ``monitor`` when one is attached. Returns
-        the number of programs newly compiled."""
+        that skip IS the near-zero cold start). On the CPU backend a
+        failed compile warns once and leaves that key on the jit
+        fallback path. On an accelerator it propagates: there a
+        refusal is the compiler saying the program does not fit
+        (VMEM, tiling, HBM), and a run that asked for precompile must
+        not turn that into a slower run that still exits 0.
+        Per-program telemetry rides on ``monitor`` when one is
+        attached. Returns the number of programs newly compiled."""
+        import warnings
+
+        import jax
         compiled = 0
         for key, thunk in programs:
             if key in self.aot:
                 continue
+            t0 = time.perf_counter()
             try:
-                import warnings
-                t0 = time.perf_counter()
                 with warnings.catch_warnings():
                     # donated pred buffers that XLA cannot alias into
                     # the (differently shaped) outputs warn per
@@ -251,6 +268,8 @@ class ProgramRegistry:
                         "ignore", message=".*[Dd]onat")
                     self.aot[key] = thunk().compile()
             except Exception as e:
+                if jax.default_backend() != "cpu":
+                    raise
                 from ..monitor import warn_once
                 warn_once(warn_code,
                           "precompile of %r failed (falling back to "
@@ -269,52 +288,53 @@ class ProgramRegistry:
 
     # -- sealed-artifact serialization -----------------------------------
 
-    def serialize_programs(self, monitor=None
-                           ) -> List[Tuple[tuple, bytes]]:
+    def serialize_programs(self, devices) -> List[Tuple[tuple, bytes]]:
         """Serialize every freshly COMPILED executable into portable
         blobs (``jax.experimental.serialize_executable`` payload +
         arg pytrees, pickled together), round-trip-checked: each blob
-        is
-        deserialized once right here, because a blob that only fails
-        at boot would silently degrade zero-compile to
-        rebuild-everything (observed with re-serialized *Loaded*
-        executables: the payload comes back without its compiled
-        symbols). Keys in ``installed`` are excluded — the exporter
-        copies their original bundle blobs byte-for-byte instead.
-        Unserializable executables are skipped with one warning — a
-        bundle with fewer programs still boots, it just re-lowers the
-        missing keys."""
+        is deserialized once right here onto ``devices`` — the devices
+        of the mesh the programs were compiled for, which is where a
+        boot will load them — because a blob that only fails at boot
+        would degrade zero-compile to rebuild-everything (observed
+        with re-serialized *Loaded* executables: the payload comes
+        back without its compiled symbols). A blob that does not load
+        back raises :class:`ArtifactLoadError`: an export that cannot
+        seal its programs has not made the artifact it was asked for.
+        Keys in ``installed`` are excluded — the exporter copies their
+        original bundle blobs byte-for-byte instead."""
         from jax.experimental import serialize_executable as se
         out: List[Tuple[tuple, bytes]] = []
         for key in sorted(self.aot, key=repr):
             if key in self.installed:
                 continue
+            payload, in_tree, out_tree = se.serialize(self.aot[key])
+            blob = pickle.dumps((payload, in_tree, out_tree),
+                                protocol=pickle.HIGHEST_PROTOCOL)
             try:
-                payload, in_tree, out_tree = se.serialize(self.aot[key])
-                blob = pickle.dumps((payload, in_tree, out_tree),
-                                    protocol=pickle.HIGHEST_PROTOCOL)
-                se.deserialize_and_load(*pickle.loads(blob))
+                _load_executable(blob, devices)
             except Exception as e:
-                _warn(monitor, "artifact_serialize_failed",
-                      "executable %r does not serialize round-trip "
-                      "(%s); the bundle ships without it and boot "
-                      "re-lowers that key" % (key[0], e))
-                continue
+                raise ArtifactLoadError(
+                    "executable %r does not load back from its own "
+                    "serialization: %s" % (key[0], e)) from e
             out.append((key, blob))
         return out
 
     def install_serialized(self, programs: Sequence[Tuple[tuple, bytes]],
-                           path: str, fingerprint_ok: bool,
+                           path: str, fingerprint_ok: bool, devices,
                            monitor=None) -> Dict[str, Any]:
-        """Deserialize bundle executables into the store.
+        """Deserialize bundle executables into the store, onto
+        ``devices`` (the owning trainer's mesh devices — jax would
+        otherwise load every program for ALL devices of the backend,
+        and a one-device serve program then refuses its arguments on
+        any host with more than one).
 
-        With a matching runtime fingerprint every loadable program
-        becomes a resident executable (a *hit*: that key will never
-        lower or compile this boot). A mismatched fingerprint installs
+        With a matching runtime fingerprint every program becomes a
+        resident executable (a *hit*: that key will never lower or
+        compile this boot), and a blob that fails to load raises
+        :class:`ArtifactLoadError`. A mismatched fingerprint installs
         NOTHING — one warning, and every key re-lowers on demand (a
-        *rebuild*). Per-blob deserialization failures also fall back
-        per-key. Returns the ``artifact_load`` record fields; honesty
-        rule: ``hits + rebuilds == len(programs)``, always.
+        *rebuild*). Returns the ``artifact_load`` record fields;
+        honesty rule: ``hits + rebuilds == len(programs)``, always.
         """
         t0 = time.perf_counter()
         hits = rebuilds = 0
@@ -329,19 +349,14 @@ class ProgramRegistry:
                   "recompiles (results are unaffected)"
                   % (path, len(programs)))
         else:
-            from jax.experimental import serialize_executable as se
             for key, blob in programs:
                 try:
-                    payload, in_tree, out_tree = pickle.loads(blob)
-                    exe = se.deserialize_and_load(payload, in_tree,
-                                                  out_tree)
+                    exe = _load_executable(blob, devices)
                 except Exception as e:
-                    rebuilds += 1
-                    _warn(monitor, "artifact_deserialize_failed",
-                          "bundle executable %r failed to load (%s); "
-                          "that key re-lowers and recompiles"
-                          % (key[0], e))
-                    continue
+                    raise ArtifactLoadError(
+                        "bundle %s matches this runtime's fingerprint "
+                        "but its executable %r failed to load: %s"
+                        % (path, key[0], e)) from e
                 self.aot[key] = exe
                 # a bundle-installed program is not a compile event:
                 # the first dispatch of this signature runs a sealed
@@ -354,6 +369,14 @@ class ProgramRegistry:
                 "fingerprint_match": bool(fingerprint_ok),
                 "hits": hits, "rebuilds": rebuilds,
                 "wall_ms": (time.perf_counter() - t0) * 1e3}
+
+
+def _load_executable(blob: bytes, devices: Sequence):
+    """One sealed blob -> a loaded executable bound to ``devices``."""
+    from jax.experimental import serialize_executable as se
+    payload, in_tree, out_tree = pickle.loads(blob)
+    return se.deserialize_and_load(payload, in_tree, out_tree,
+                                   execution_devices=devices)
 
 
 def _warn(monitor, code: str, message: str) -> None:

@@ -7,9 +7,9 @@ validated hand kernels against library implementations via pairtest
 validated with ``pairtest-pallas_fullc-fullc`` (tests/test_pallas.py),
 runnable in interpret mode on CPU test meshes.
 
-Kernel: tiled matmul on the MXU — block rows of x and block columns of
-w meet in VMEM, ``jnp.dot`` drives the systolic array with f32
-accumulation. The backward pass reuses the same kernel for both
+Kernel: tiled matmul on the MXU — (bm, bk) blocks of x and (bk, bn)
+blocks of w meet in VMEM, ``jnp.dot`` drives the systolic array and
+the f32 output block accumulates over the K grid axis. The backward pass reuses the same kernel for both
 gradient GEMMs (dx = dy·wᵀ, dw = xᵀ·dy), exactly the two products the
 reference's hand-written fullc backprop computed
 (fullc_layer-inl.hpp:108-130).
@@ -18,7 +18,7 @@ reference's hand-written fullc backprop computed
 from __future__ import annotations
 
 from functools import partial
-from typing import Dict, List
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -28,40 +28,95 @@ from .base import Shape3
 from .common import FullConnectLayer
 
 
-def _interpret() -> bool:
+# The explicit interpret-mode choice (None = none made). The CPU tests
+# choose True in conftest.py; chip_smoke.py chooses False.
+_INTERPRET: Optional[bool] = None
+
+
+def set_interpret(value: Optional[bool]) -> None:
+    global _INTERPRET
+    _INTERPRET = value
+
+
+def interpret() -> bool:
+    """Whether kernels built in this process go through the Pallas
+    interpreter: the explicit choice when one was made, else decided
+    by the backend — compiled by Mosaic on ``tpu``, interpreted on
+    anything else (no kernel compiles for a CPU). The trainer's
+    ``layout`` record reports it as ``pallas_interpret``, so a record
+    stream says which of the two a run's kernels were."""
+    if _INTERPRET is not None:
+        return _INTERPRET
     return jax.default_backend() != "tpu"
 
 
+def _build_interpret() -> bool:
+    """:func:`interpret` for a kernel that is being built now. A
+    kernel going interpreted by the backend's say-so alone is said
+    once, out loud: interpret mode checks a kernel's arithmetic, never
+    that it compiles, fits VMEM or runs on a chip."""
+    mode = interpret()
+    if mode and _INTERPRET is None:
+        from ..monitor import warn_once
+        warn_once("pallas_interpret",
+                  "Pallas kernels run in INTERPRET mode on the %s "
+                  "backend: results are checked, but nothing here "
+                  "shows the kernels compile or run on a TPU"
+                  % jax.default_backend())
+    return mode
+
+
 def _matmul_kernel(x_ref, w_ref, o_ref):
-    o_ref[:] = jnp.dot(x_ref[:], w_ref[:],
-                       preferred_element_type=jnp.float32)
+    """One (i, j, k) grid step: the f32 output block stays resident in
+    VMEM across the K axis (its index map ignores k) and accumulates
+    one (bm, bk) x (bk, bn) MXU product per step."""
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    o_ref[...] += jnp.dot(x_ref[...], w_ref[...],
+                          preferred_element_type=jnp.float32)
 
 
 def _pad_to(v: int, m: int) -> int:
     return (v + m - 1) // m * m
 
 
-@partial(jax.jit, static_argnames=("bm", "bn"))
+@partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def _matmul_pallas_raw(x: jnp.ndarray, w: jnp.ndarray,
-                       bm: int = 256, bn: int = 256) -> jnp.ndarray:
+                       bm: int = 256, bn: int = 256, bk: int = 512,
+                       interpret: bool = False) -> jnp.ndarray:
+    """Tiled x @ w with f32 accumulation. K is a grid axis: a whole-K
+    block (the first version) ran out of VMEM at AlexNet's fc6 width
+    (256x9216 . 9216x4096) on the v5e; with (bm, bk) / (bk, bn) blocks
+    the working set is three 256 KB-class tiles, double-buffered,
+    whatever K is. A K that fits one block keeps the single-step
+    shape (block = full, 8-aligned K)."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     m, k = x.shape
     k2, n = w.shape
     assert k == k2
-    mp, np_, kp = _pad_to(m, bm), _pad_to(n, bn), _pad_to(k, 8)
+    if k <= bk:
+        bk = _pad_to(k, 8)
+    mp, np_, kp = _pad_to(m, bm), _pad_to(n, bn), _pad_to(k, bk)
     xp = jnp.pad(x, ((0, mp - m), (0, kp - k)))
     wp = jnp.pad(w, ((0, kp - k), (0, np_ - n)))
     out = pl.pallas_call(
         _matmul_kernel,
-        grid=(mp // bm, np_ // bn),
+        grid=(mp // bm, np_ // bn, kp // bk),
         in_specs=[
-            pl.BlockSpec((bm, kp), lambda i, j: (i, 0)),
-            pl.BlockSpec((kp, bn), lambda i, j: (0, j)),
+            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
+            pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
-        interpret=_interpret(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
     )(xp, wp)
     return out[:m, :n]
 
@@ -69,18 +124,18 @@ def _matmul_pallas_raw(x: jnp.ndarray, w: jnp.ndarray,
 @jax.custom_vjp
 def matmul(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
     """x @ w through the Pallas kernel, differentiable."""
-    return _matmul_pallas_raw(x, w)
+    return _matmul_pallas_raw(x, w, interpret=_build_interpret())
 
 
 def _matmul_fwd(x, w):
-    return _matmul_pallas_raw(x, w), (x, w)
+    return _matmul_pallas_raw(x, w, interpret=_build_interpret()), (x, w)
 
 
 def _matmul_bwd(res, dy):
     x, w = res
-    dx = _matmul_pallas_raw(dy, w.T).astype(x.dtype)
-    dw = _matmul_pallas_raw(x.T, dy).astype(w.dtype)
-    return dx, dw
+    dx = _matmul_pallas_raw(dy, w.T, interpret=_build_interpret())
+    dw = _matmul_pallas_raw(x.T, dy, interpret=_build_interpret())
+    return dx.astype(x.dtype), dw.astype(w.dtype)
 
 
 matmul.defvjp(_matmul_fwd, _matmul_bwd)
@@ -149,7 +204,7 @@ def _relu_pool_call_fwd(x: jnp.ndarray, k: int) -> jnp.ndarray:
         in_specs=[pl.BlockSpec((1, h, w, c), lambda i: (i, 0, 0, 0))],
         out_specs=pl.BlockSpec((1, oh, ow, c), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, oh, ow, c), x.dtype),
-        interpret=_interpret(),
+        interpret=_build_interpret(),
     )(x)
 
 
@@ -185,7 +240,7 @@ def _relu_pool_call_bwd(x: jnp.ndarray, y: jnp.ndarray,
         out_specs=pl.BlockSpec((1, h, w, c), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, w, c), x.dtype),
         scratch_shapes=[pltpu.VMEM((h, w, c), jnp.float32)],
-        interpret=_interpret(),
+        interpret=_build_interpret(),
     )(x, y, dy)
 
 
@@ -286,7 +341,7 @@ def _bn_apply_call(x: jnp.ndarray, scale: jnp.ndarray,
         out_specs=pl.BlockSpec((1, rows, w, c),
                                lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, w, c), x4.dtype),
-        interpret=_interpret(),
+        interpret=_build_interpret(),
     )(x4, scale[None, :], shift[None, :])
     return y[:, 0, 0, :] if mat else y
 
@@ -358,7 +413,7 @@ def _conv_epilogue_call(x: jnp.ndarray, scale: jnp.ndarray,
         out_specs=pl.BlockSpec((1, rows, w, c),
                                lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, w, c), out_dtype),
-        interpret=_interpret(),
+        interpret=_build_interpret(),
     )(x4, scale.astype(jnp.float32)[None, :],
       shift.astype(jnp.float32)[None, :])
     return y[:, 0, 0, :] if mat else y
@@ -459,7 +514,7 @@ def _pool_concat_call(branches, pool_pos: int, k: int,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, h, w, off), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, w, off), dtype),
-        interpret=_interpret(),
+        interpret=_build_interpret(),
     )(*[x.astype(dtype) for x in xs])
 
 

@@ -57,6 +57,14 @@ PROGRAM_BUILDERS = {
     "cxxnet_tpu/retrieval/engine.py": (
         "RetrievalEngine._lower_search",
     ),
+    # the relayout program behind input_layout = rowmajor: what
+    # jax.device_put(x, Format) builds internally, rebuilt here with a
+    # per-process salt so it is never READ from the persistent cache
+    # (jax 0.9.0 loses output layouts there) — one tiny identity per
+    # pinned format, never a model program
+    "cxxnet_tpu/utils/compile_cache.py": (
+        "put_with_layout",
+    ),
 }
 
 # -- CXL003: hot-path roots -----------------------------------------------

@@ -478,22 +478,30 @@ def create_monitor(cfg, root: Optional[bool] = None) -> Monitor:
                    trace_end=trace_end)
 
 
-def run_metadata(task: str, cfg, mesh=None) -> Dict[str, Any]:
+def run_metadata(task: str, cfg, mesh=None,
+                 device: bool = True) -> Dict[str, Any]:
     """Run-level metadata for the ``run_start`` record: mesh shape,
-    process topology, backend and versions, config digest."""
+    process topology, backend and versions, config digest.
+
+    ``device=False`` is for tasks that must never initialize a jax
+    backend (the ``fleet`` / ``fleet_balancer`` parents, whose replica
+    children own the chips): the record then says ``platform: none``
+    with zero devices instead of asking jax what it could attach to."""
     import platform as _platform
 
     import jax
+
+    from ..parallel import rank, world_size
     meta: Dict[str, Any] = {
         "task": task,
         "config_hash": config_hash(cfg),
         "jax_version": jax.__version__,
         "python_version": _platform.python_version(),
-        "platform": jax.default_backend(),
-        "process_count": jax.process_count(),
-        "process_index": jax.process_index(),
-        "device_count": len(jax.devices()),
-        "device_kind": jax.devices()[0].device_kind,
+        "platform": jax.default_backend() if device else "none",
+        "process_count": world_size(),
+        "process_index": rank(),
+        "device_count": len(jax.devices()) if device else 0,
+        "device_kind": jax.devices()[0].device_kind if device else "",
         "mesh": dict(mesh.shape) if mesh is not None else None,
     }
     return meta

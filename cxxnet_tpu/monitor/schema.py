@@ -28,7 +28,7 @@ REQUIRED: Dict[str, tuple] = {
     "round_start": ("round",),
     "step": ("step", "round", "dispatch", "n_batches", "examples",
              "wall_ms", "data_wait_ms", "examples_per_sec",
-             "update_counter", "lr", "compile"),
+             "update_counter", "lr", "loss", "compile"),
     "compile": ("kind", "wall_ms", "signature"),
     "memory": ("round", "available", "devices"),
     "io_wait": ("round", "count", "total_ms", "max_ms", "p50_ms",
@@ -43,8 +43,12 @@ REQUIRED: Dict[str, tuple] = {
     # analytic FLOPs for MFU math + the layout/fusion pass decisions
     "model_info": ("flops_per_example", "train_flops_per_example",
                    "params", "layers"),
+    # input_layout is the pin that took hold (not the one asked for);
+    # pallas_interpret says whether this process builds its Pallas
+    # kernels interpreted (never true on the tpu backend unless chosen)
     "layout": ("channel_pad", "layers_padded", "input_layout",
-               "bn_fuse_relu", "bn_fold_eval_pairs"),
+               "bn_fuse_relu", "bn_fold_eval_pairs",
+               "pallas_interpret"),
     "eval": ("round", "name", "metrics"),
     "round_end": ("round", "examples", "wall_s", "examples_per_sec"),
     "trace_start": ("dir",),

@@ -27,11 +27,11 @@ snapshot and a servable quantized graph:
   into the conv epilogue (``layers/pallas_kernels.conv_epilogue``).
   Training forwards never consult the spec.
 
-Fallbacks are part of the contract: a backend without native int8/fp8
-contraction support still *computes the quantized numbers* (operands
+One fallback is part of the contract: a backend that rejects native
+int8/fp8 contractions still *computes the quantized numbers* (operands
 round through the quantized grid but contract in f32 — bit-identical
-values, no speedup), and ``serve_dtype = fp8`` on a backend without an
-fp8 dtype falls back to int8 scales with one warning. Parity against
+values, no speedup), and the ``quantized_model`` record says so
+(``native: false``). Parity against
 the f32 eval output is gated by ``task = quantize``
 (doc/perf_profile.md "Low-precision inference").
 """
@@ -72,9 +72,7 @@ def normalize_serve_dtype(val: str) -> str:
     return v
 
 
-def fp8_dtype():
-    """The fp8 storage dtype, or None when this jax build has none."""
-    return getattr(jnp, "float8_e4m3fn", None)
+FP8 = jnp.float8_e4m3fn                  # the fp8 storage dtype
 
 
 _NATIVE_CACHE: Dict[tuple, bool] = {}
@@ -83,34 +81,30 @@ _NATIVE_CACHE: Dict[tuple, bool] = {}
 def backend_native(dtype: str, op: str) -> bool:
     """True when the backend contracts ``dtype`` operands natively
     (``op`` = 'dot' | 'conv'). Probed once with a tiny op; a backend
-    that rejects the dtype falls back to the f32-simulated contraction
-    — same values, no speedup."""
+    whose compiler or runtime REJECTS the op (a ``JaxRuntimeError``)
+    falls back to the f32-simulated contraction — same values, no
+    speedup — and the ``quantized_model`` record says ``native:
+    false``. Any other failure of the probe is a bug and propagates."""
     key = (dtype, op, jax.default_backend())
     if key in _NATIVE_CACHE:
         return _NATIVE_CACHE[key]
-    ok = False
+    qt, acc = (jnp.int8, jnp.int32) if dtype == "int8" \
+        else (FP8, jnp.float32)
     try:
-        if dtype == "int8":
-            qt = jnp.int8
-            acc = jnp.int32
+        if op == "dot":
+            a = jnp.ones((8, 8), qt)
+            out = jnp.dot(a, a, preferred_element_type=acc)
         else:
-            qt = fp8_dtype()
-            acc = jnp.float32
-        if qt is not None:
-            if op == "dot":
-                a = jnp.ones((8, 8), qt)
-                out = jnp.dot(a, a, preferred_element_type=acc)
-            else:
-                x = jnp.ones((1, 4, 4, 8), qt)
-                w = jnp.ones((3, 3, 8, 8), qt)
-                out = jax.lax.conv_general_dilated(
-                    x, w, window_strides=(1, 1), padding="VALID",
-                    dimension_numbers=("NHWC", "HWIO", "NHWC"),
-                    preferred_element_type=acc)
-            jax.block_until_ready(out)   # one-time capability probe
-            ok = True
-    except Exception:
-        ok = False                       # unsupported: simulate in f32
+            x = jnp.ones((1, 4, 4, 8), qt)
+            w = jnp.ones((3, 3, 8, 8), qt)
+            out = jax.lax.conv_general_dilated(
+                x, w, window_strides=(1, 1), padding="VALID",
+                dimension_numbers=("NHWC", "HWIO", "NHWC"),
+                preferred_element_type=acc)
+        jax.block_until_ready(out)       # one-time capability probe
+        ok = True
+    except jax.errors.JaxRuntimeError:
+        ok = False                       # rejected: simulate in f32
     _NATIVE_CACHE[key] = ok
     return ok
 
@@ -169,8 +163,7 @@ def quantize_tensor(v: jnp.ndarray, scale, dtype: str,
         q = jnp.clip(jnp.round(vf), -qmax, qmax)
         return q.astype(jnp.int8) if native else q
     q = jnp.clip(vf, -qmax, qmax)
-    f8 = fp8_dtype()
-    q = q.astype(f8)                     # e4m3 mantissa rounding
+    q = q.astype(FP8)                    # e4m3 mantissa rounding
     return q if native else q.astype(jnp.float32)
 
 
@@ -328,15 +321,7 @@ def attach(trainer) -> Dict[str, Any]:
             "serve_dtype=%s needs a calibrated snapshot: run "
             "task=quantize over this model first (doc/perf_profile.md "
             "\"Low-precision inference\")" % dtype)
-    eff = dtype
-    if dtype == "fp8" and fp8_dtype() is None:
-        from ..monitor import warn_once
-        warn_once("fp8_unsupported",
-                  "serve_dtype=fp8: this jax build has no fp8 dtype; "
-                  "falling back to int8 scales")
-        eff = "int8"
-    report["dtype"] = eff
-    qmax = QMAX[eff]
+    qmax = QMAX[dtype]
     meta_fold = trainer.quant_meta.get("bn_fold_eval")
     if meta_fold is not None and bool(meta_fold) != net._bn_fold_eval:
         from ..monitor import warn_once
@@ -355,7 +340,7 @@ def attach(trainer) -> Dict[str, Any]:
                             _AMAX_FLOOR) / qmax)
         w_scale = np.maximum(tab["w_amax"].astype(np.float32),
                              _AMAX_FLOOR) / qmax
-        native = backend_native(eff, tgt.kind)
+        native = backend_native(dtype, tgt.kind)
         if (tgt.kind == "conv"
                 and net.layer_objs[tgt.li].param.num_group > 1):
             # the capability probe runs ungrouped; grouped low-dtype
@@ -363,7 +348,7 @@ def attach(trainer) -> Dict[str, Any]:
             native = False
         natives.append(native)
         net.layer_objs[tgt.li]._quant = QuantSpec(
-            eff, x_scale=x_scale, w_scale=jnp.asarray(w_scale),
+            dtype, x_scale=x_scale, w_scale=jnp.asarray(w_scale),
             native=native)
         report["layers"] += 1
     report["native"] = bool(natives) and all(natives)

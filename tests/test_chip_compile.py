@@ -1,0 +1,178 @@
+"""Real-size compiles for the chip, without the chip.
+
+The TPU's compiler is installed here and compiles for a chip that is
+described, not attached (``v5e:2x2``). Interpret mode and the CPU
+backend cannot see what it refuses: a block that does not fit VMEM (the
+first ``pallas_fullc`` matmul at AlexNet's fc6 width), a slice off the
+tiling, a program too large for HBM. So the kernels of
+``layers/pallas_kernels.py`` at the widths ``chip_smoke.py`` runs them
+at, and AlexNet's batch-256 train step, are compiled here on every run
+of the suite — a couple of seconds a kernel, ten a step. A compile that
+passes is not a chip run and is never reported as one.
+
+Only one process at a time may hold the TPU library, so the topology is
+described inside a module-scoped fixture and nowhere else: never at
+import, in a ``skipif``, in ``parametrize`` arguments or in conftest.
+All of it stays in this one file, and every compile runs in the test's
+own process with the persistent compilation cache off (an entry written
+for a described chip cannot be read back without one).
+"""
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+import chip_smoke as cs
+from test_chip_smoke import KERNELS
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from cxxnet_tpu.layers import pallas_kernels
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    pallas_kernels.set_interpret(False)      # compile, as on the chip
+    yield desc
+    pallas_kernels.set_interpret(True)       # conftest's choice
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def real_cases():
+    """The chip's kernel cases as shapes only: built under eval_shape,
+    so no real-size array is ever made on this host."""
+    import jax
+    held = {}
+
+    def build():
+        held["cases"] = cs.kernel_cases(real=True)
+        return [c.args for c in held["cases"]]
+
+    shapes = jax.eval_shape(build)
+    return {c.name: (c, s) for c, s in zip(held["cases"], shapes)}
+
+
+def _on(sharding):
+    import jax
+    return lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                          sharding=sharding)
+
+
+@pytest.mark.parametrize("name", KERNELS)
+def test_kernel_compiles_for_v5e_at_real_width(topo, real_cases, name):
+    import jax
+    from jax.sharding import SingleDeviceSharding
+    case, args = real_cases[name]
+    on_chip = _on(SingleDeviceSharding(topo.devices[0]))
+    fn, _ = cs.kernel_programs(case)
+    dy = jax.eval_shape(case.ref, *args)
+    compiled = fn.lower(on_chip(dy),
+                        *jax.tree.map(on_chip, args)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _described_trainer(topo, ndev, batch, extra):
+    """An AlexNet NetTrainer whose mesh is ``ndev`` DESCRIBED devices.
+    Nothing can be placed on them, so placement is skipped and every
+    array becomes a ShapeDtypeStruct carrying the sharding the trainer
+    chose; the jitted step functions are the trainer's own."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh
+    from cxxnet_tpu.models import alexnet
+    from cxxnet_tpu.nnet.trainer import NetTrainer
+    from cxxnet_tpu.utils.config import parse_config
+    mesh = Mesh(np.array(topo.devices[:ndev]).reshape(ndev, 1),
+                ("data", "model"))
+    t = NetTrainer(parse_config(alexnet(nclass=1000, batch_size=batch,
+                                        image_size=227))
+                   + [("dtype", "bfloat16"), ("eval_train", "0"),
+                      ("silent", "1")] + extra, mesh=mesh)
+
+    def no_placement():
+        t.grad_acc = jax.tree.map(jnp.zeros_like, t.params) \
+            if t.update_period > 1 else None
+
+    t._put_all = no_placement
+    # the probe places an array too; the pin itself is what is compiled
+    t._probe_input_layout = lambda: setattr(
+        t, "input_layout_effective", t.input_layout)
+    t.init_model()
+
+    def sds(x, sharding):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    t.params = jax.tree.map(sds, t.params, t._p_shard)
+    t.opt_state = jax.tree.map(sds, t.opt_state, t._o_shard)
+    t.net_state = jax.tree.map(_on(t._repl), t.net_state)
+    if t.grad_acc is not None:
+        t.grad_acc = jax.tree.map(sds, t.grad_acc, t._p_shard)
+    t._base_key = _on(t._repl)(t._base_key)
+    return t
+
+
+def test_alexnet_batch256_train_step_compiles_for_one_v5e(topo):
+    import jax
+    t = _described_trainer(topo, 1, 256, [])
+    sds = jax.ShapeDtypeStruct
+    u32 = sds((), np.uint32)
+    compiled = t._train_step.lower(
+        t.params, t.opt_state, t.net_state, None,
+        sds((256, 227, 227, 3), np.float32, sharding=t._b_shard),
+        sds((256, 1), np.float32, sharding=t._b_shard), None, (),
+        sds((len(t._hyper_index), 3), np.float32), u32, u32,
+        t._base_key, do_update=True).compile()
+    mem = compiled.memory_analysis()
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert 0 < live < 16e9           # fits one v5e's HBM, with room
+
+
+def test_alexnet_up2_scanned_step_pins_the_batch_row_major(topo):
+    """The alexnet_up2 bench cell: run_steps + update_period 2 +
+    input_layout = rowmajor in one program, whose batch input the
+    compiler must hold to the row-major layout (left alone, it picks a
+    batch-minor one for this shape)."""
+    t = _described_trainer(topo, 1, 128, [
+        ("grad_dtype", "bfloat16"), ("momentum_dtype", "bfloat16"),
+        ("update_period", "2"), ("input_layout", "rowmajor")])
+    assert t.precompile(n_steps=20, per_batch=False) == 1
+    (key,) = t.programs.aot
+    layout = t.programs.aot[key].input_formats[0][4].layout
+    assert tuple(layout.major_to_minor) == (0, 1, 2, 3)
+
+
+def test_alexnet_data_parallel_step_compiles_for_four_v5e(topo):
+    """chip_smoke.py --chips 4, ZeRO-1 on: the K-window step over a
+    data=4 mesh compiles, reduces gradients across the chips and
+    gathers the sharded update."""
+    import jax
+    t = _described_trainer(topo, 4, 256, [("grad_sync", "fused"),
+                                          ("optim_shard", "1")])
+    sds = jax.ShapeDtypeStruct
+    k = 2
+    compiled = t._many_step.lower(
+        t.params, t.opt_state, t.net_state, None,
+        sds((k, 256, 227, 227, 3), np.float32, sharding=t._kb_shard),
+        sds((k, 256, 1), np.float32, sharding=t._kb_shard), None, (),
+        sds((k, len(t._hyper_index), 3), np.float32),
+        sds((k,), np.uint32), sds((k,), np.bool_), sds((), np.uint32),
+        t._base_key, collect=False).compile()
+    text = compiled.as_text()
+    assert "all-reduce" in text or "reduce-scatter" in text
+    assert "all-gather" in text

@@ -916,3 +916,60 @@ monitor_path = %s
     events = [r["event"] for r in records]
     assert "run_start" in events and "task_end" in events
     assert "fleet_scale" in events     # replica_ready at least
+
+
+# -- the fleet parents never take a device ---------------------------------
+
+_PARENT_PROBE = """
+import json, sys
+import jax
+from jax._src import xla_bridge
+from cxxnet_tpu.main import main
+rc = main([sys.argv[1]])
+print("PROBE " + json.dumps(
+    {"rc": rc, "backends": xla_bridge.backends_are_initialized()}))
+"""
+
+
+@pytest.mark.parametrize("task", ["fleet", "fleet_balancer"])
+def test_fleet_parent_never_initializes_a_backend(tmp_path, task):
+    """A chip belongs to one process at a time, and the replica
+    children need it: the ``task = fleet`` / ``task = fleet_balancer``
+    parent must run its whole path — config, monitor, run_start,
+    spawn, serve, drain — without ever initializing a jax backend
+    (root/world come from the process-group state, not from
+    ``jax.process_index()``). A fresh interpreter, because this test
+    process initialized its backend in conftest."""
+    import subprocess
+    import sys
+    snap = tmp_path / "models" / "0001.model.npz"
+    snap.parent.mkdir()
+    _save_mlp_snapshot(snap)
+    conf = tmp_path / "parent.conf"
+    conf.write_text(FLEET_MLP_CONF + """
+task = %s
+model_in = %s
+fleet_replicas = 1
+fleet_http_port = 0
+fleet_binary_port = -1
+fleet_duration_s = 0.3
+fleet_dir = %s
+fleet_registry = %s
+monitor = jsonl
+monitor_path = %s
+""" % (task, snap, tmp_path / "run", tmp_path / "registry.json",
+       tmp_path / "parent.jsonl"))
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, "-c", _PARENT_PROBE, str(conf)], cwd=repo,
+        capture_output=True, text=True, timeout=240)
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+    (line,) = [ln for ln in proc.stdout.splitlines()
+               if ln.startswith("PROBE ")]
+    probe = json.loads(line[len("PROBE "):])
+    assert probe == {"rc": 0, "backends": False}, proc.stdout
+    from cxxnet_tpu.monitor.schema import read_jsonl
+    records = read_jsonl(str(tmp_path / "parent.jsonl"))
+    assert validate_records(records, strict=False) == []
+    (start,) = [r for r in records if r["event"] == "run_start"]
+    assert start["platform"] == "none" and start["device_count"] == 0

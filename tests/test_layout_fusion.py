@@ -299,6 +299,76 @@ def test_run_steps_update_period_matches_per_batch():
     _assert_params(ta, tb, exact=False, rtol=1e-6, atol=1e-7)
 
 
+def _layout_records(t):
+    from cxxnet_tpu.monitor import MemorySink, Monitor
+    sink = MemorySink()
+    t.set_monitor(Monitor(sink))
+    return [r for r in sink.records if r["event"] == "layout"]
+
+
+def test_input_layout_rowmajor_pins_the_compiled_batch_input():
+    """input_layout = rowmajor under the installed jax (Format/Layout):
+    the probe takes hold, the layout record reports what took hold,
+    the AOT program's batch input carries the row-major layout — read
+    back from the executable — and a dispatch through it runs and
+    matches the unpinned run."""
+    data, label = _data(3)
+    extra = [("eval_train", "0")]
+    pinned = NetTrainer(parse_config(CHAIN_CONF) + extra
+                        + [("input_layout", "rowmajor")])
+    plain = NetTrainer(parse_config(CHAIN_CONF) + extra)
+    pinned.init_model()
+    plain.init_model()
+    assert pinned.input_layout_effective == "rowmajor"
+    assert plain.input_layout_effective == "none"
+    assert _layout_records(pinned)[-1]["input_layout"] == "rowmajor"
+    assert _layout_records(plain)[-1]["input_layout"] == "none"
+    assert pinned.precompile(n_steps=3, per_batch=False) == 1
+    (key,) = pinned.programs.aot
+    fmt = pinned.programs.aot[key].input_formats[0][4]
+    assert tuple(fmt.layout.major_to_minor) == (0, 1, 2, 3)
+    placed = pinned._put_batch_array(data)
+    assert tuple(placed.format.layout.major_to_minor) == (0, 1, 2, 3)
+    for t in (pinned, plain):
+        t.run_steps(DataBatch(data=t._put_batch_array(data),
+                              label=t._put_batch_array(label)), 3)
+    _assert_params(pinned, plain, exact=True)
+
+
+def test_input_layout_rowmajor_raises_when_it_cannot_be_honoured(
+        monkeypatch):
+    """Asked for and impossible is an error on a single-process run —
+    never a warning and an unpinned run whose records say rowmajor."""
+    def refuse(*a, **k):
+        raise NotImplementedError("no layouts on this backend")
+    monkeypatch.setattr(NetTrainer, "_rowmajor",
+                        staticmethod(refuse))
+    t = NetTrainer(parse_config(CHAIN_CONF)
+                   + [("input_layout", "rowmajor")])
+    with pytest.raises(RuntimeError,
+                       match="input_layout = rowmajor was asked for"):
+        t.init_model()
+    # not asked for: nothing probes, nothing raises
+    t = NetTrainer(parse_config(CHAIN_CONF))
+    t.init_model()
+    assert t.input_layout_effective == "none"
+
+
+def test_input_layout_record_reports_unpinned_under_multiprocess(
+        monkeypatch):
+    """Multi-process batches cannot carry the pin: the run goes on
+    unpinned, and the record says ``none`` — what took hold — not the
+    ``rowmajor`` that was asked for."""
+    t = NetTrainer(parse_config(CHAIN_CONF)
+                   + [("input_layout", "rowmajor")])
+    t.init_model()
+    monkeypatch.setattr(jax, "process_count", lambda: 2)
+    t._probe_input_layout()
+    assert t.input_layout == "rowmajor"
+    assert t.input_layout_effective == "none"
+    assert t._pin_layout(t._b_shard, 4) is t._b_shard
+
+
 def test_epoch_rides_exact_uint32():
     """The applied-update counter reaches the device exactly: a float32
     hyper slot rounds 2^24+1 to 2^24 (the old bug); the uint32 scalar

@@ -229,7 +229,7 @@ def test_validate_records_monotonic_step():
                 "dispatch": "update", "n_batches": 1, "examples": 8,
                 "wall_ms": 1.0, "data_wait_ms": 0.0,
                 "examples_per_sec": 8.0, "update_counter": i,
-                "lr": 0.1, "compile": False}
+                "lr": 0.1, "loss": 2.3, "compile": False}
     assert validate_records([step(1), step(2), step(3)]) == []
     with pytest.raises(ValueError, match="not monotonic"):
         validate_records([step(2), step(2)])

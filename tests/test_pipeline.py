@@ -638,3 +638,118 @@ def test_compile_cache_dir_writes_entries(tmp_path):
         jax.config.update("jax_compilation_cache_dir", prev)
         from jax._src import compilation_cache as _cc
         _cc.reset_cache()
+
+
+# -- where the compile cache lives (utils/compile_cache.py) ----------------
+
+
+@pytest.fixture
+def cache_config():
+    """Restore jax's cache settings whatever a test sets."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    prev = {n: getattr(jax.config, n) for n in names}
+    yield jax.config
+    for n, v in prev.items():
+        jax.config.update(n, v)
+    compilation_cache.reset_cache()
+
+
+def test_cache_dir_environment_wins_and_code_sets_none(
+        tmp_path, monkeypatch, cache_config):
+    from cxxnet_tpu.utils import compile_cache as cc
+    env_dir = str(tmp_path / "from_env")
+    monkeypatch.setenv(cc.ENV_VAR, env_dir)
+    before = cache_config.jax_compilation_cache_dir
+    got = cc.enable_compile_cache(default_dir=str(tmp_path / "default"))
+    assert got == env_dir
+    # jax reads the variable itself at import; the program sets no
+    # directory in code when it is there
+    assert cache_config.jax_compilation_cache_dir == before
+    assert cache_config.jax_persistent_cache_min_compile_time_secs == 0.0
+    # nothing asked for beyond the environment: nothing touched at all
+    assert cc.enable_compile_cache() == env_dir
+
+
+def test_cache_dir_unset_is_one_fixed_path_in_the_checkout(
+        monkeypatch, cache_config):
+    import os
+    from cxxnet_tpu.utils import compile_cache as cc
+    monkeypatch.delenv(cc.ENV_VAR, raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert cc.REPO_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    assert cc.enable_compile_cache(default_dir=cc.REPO_CACHE_DIR) \
+        == cc.REPO_CACHE_DIR
+    assert cache_config.jax_compilation_cache_dir == cc.REPO_CACHE_DIR
+    # no key, no default: a plain run caches nothing, as before
+    assert cc.enable_compile_cache() == ""
+
+
+def test_cache_dir_key_is_ignored_with_one_warning_under_the_environment(
+        tmp_path, monkeypatch, cache_config):
+    from cxxnet_tpu.monitor import MemorySink, Monitor, set_global
+    from cxxnet_tpu.nnet.trainer import NetTrainer
+    from cxxnet_tpu.utils import compile_cache as cc
+    from cxxnet_tpu.utils.config import parse_config
+    env_dir, key_dir = str(tmp_path / "env"), str(tmp_path / "key")
+    monkeypatch.setenv(cc.ENV_VAR, env_dir)
+    before = cache_config.jax_compilation_cache_dir
+    sink = MemorySink()
+    set_global(Monitor(sink))
+    try:
+        t = NetTrainer(parse_config(_NET)
+                       + [("compile_cache_dir", key_dir)])
+        t.init_model()                   # calls the helper twice over
+        assert cc.enable_compile_cache(key_dir) == env_dir
+    finally:
+        set_global(None)
+    assert cache_config.jax_compilation_cache_dir == before
+    warns = [r for r in sink.records if r["event"] == "warning"
+             and r["code"] == "compile_cache_dir_ignored"]
+    assert len(warns) == 1 and key_dir in warns[0]["message"]
+    # a key that AGREES with the environment is no conflict
+    sink.clear()
+    assert cc.enable_compile_cache(env_dir) == env_dir
+    assert sink.records == []
+
+
+_PINNED_PUT = """
+import sys
+import jax, numpy as np
+from jax.experimental.layout import Format, Layout
+from jax.sharding import SingleDeviceSharding
+from cxxnet_tpu.utils.compile_cache import (enable_compile_cache,
+                                            put_with_layout)
+enable_compile_cache(sys.argv[1])
+x = np.arange(120, dtype=np.float32).reshape(2, 3, 4, 5)
+fmt = Format(Layout(major_to_minor=(3, 2, 1, 0)),
+             SingleDeviceSharding(jax.devices()[0]))
+a = put_with_layout(x, fmt)
+assert np.array_equal(np.asarray(a), x)
+print("LAYOUT", tuple(a.format.layout.major_to_minor))
+"""
+
+
+def test_pinned_layout_survives_a_warm_compile_cache(tmp_path):
+    """An executable jax 0.9.0 reads back from its persistent cache
+    has lost its output layouts, and ``device_put(x, Format)`` is such
+    an executable: the first process got the pinned layout, every
+    later one the default (seen on the v5e as a warm chip_smoke.py
+    refusing its own batch). ``put_with_layout`` salts the relayout
+    program so it is never read from the cache: a second and third
+    process over the same cache directory still get the pin."""
+    import os
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for _ in range(3):
+        p = subprocess.run(
+            [sys.executable, "-c", _PINNED_PUT, str(tmp_path / "cache")],
+            cwd=repo, capture_output=True, text=True, timeout=240,
+            env=dict(os.environ, JAX_PLATFORMS="cpu"))
+        assert p.returncode == 0, p.stderr[-2000:]
+        assert "LAYOUT (3, 2, 1, 0)" in p.stdout
+    assert os.listdir(tmp_path / "cache")      # the cache was in use
