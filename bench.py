@@ -492,11 +492,9 @@ def measure_pipeline(batch: int = 256, rec_path: str = "/tmp/bench.rec",
         "pure": pure,
         "eval_ips": eval_ips,
         "buffer_reuse_rate": telemetry.get("buffer_reuse_rate", 0.0),
-        "h2d_overlap_ratio": telemetry.get("h2d_overlap_ratio", 0.0),
         "io_wait_p50_ms": io_snap.get("p50_ms", 0.0),
         "io_wait_p99_ms": io_snap.get("p99_ms", 0.0),
         "io_wait_count": io_snap.get("count", 0),
-        "precompile_wall_ms": round(t.precompile_wall_s * 1e3, 1),
         "precompile_programs": t.precompile_programs,
     }
 
@@ -645,10 +643,8 @@ def main():
             "pure_compute_images_per_sec": round(cap["pure"], 1),
             "eval_images_per_sec": round(cap["eval_ips"], 1),
             "buffer_reuse_rate": round(cap["buffer_reuse_rate"], 4),
-            "h2d_overlap_ratio": round(cap["h2d_overlap_ratio"], 4),
             "io_wait_p50_ms": cap["io_wait_p50_ms"],
             "io_wait_p99_ms": cap["io_wait_p99_ms"],
-            "precompile_wall_ms": cap["precompile_wall_ms"],
         }))
         return
     if args.model is not None:
@@ -763,10 +759,8 @@ def main():
         out["pipeline"] = {
             "e2e_images_per_sec": round(pcap["e2e"], 1),
             "buffer_reuse_rate": round(pcap["buffer_reuse_rate"], 4),
-            "h2d_overlap_ratio": round(pcap["h2d_overlap_ratio"], 4),
             "io_wait_p50_ms": pcap["io_wait_p50_ms"],
             "io_wait_p99_ms": pcap["io_wait_p99_ms"],
-            "precompile_wall_ms": pcap["precompile_wall_ms"],
         }
     except Exception as e:               # telemetry must never sink the
         out["pipeline"] = {"error": str(e)}   # headline capture

@@ -23,6 +23,8 @@ from typing import Callable, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..monitor.spans import no_span
+
 
 @dataclass
 class DataInst:
@@ -58,7 +60,14 @@ class DataBatch:
 
 class IIterator:
     """Iterator interface (data.h:20-39): init / before_first / next /
-    value, plus set_param for config plumbing."""
+    value, plus set_param for config plumbing.
+
+    ``span`` times one chunk's or batch's work: ``Monitor.span`` when
+    an enabled monitor was attached to the chain
+    (``iter_batch.attach_chain_spans``), else the shared no-op, so an
+    unmonitored chain reads no clock."""
+
+    span = staticmethod(no_span)
 
     def set_param(self, name: str, val: str) -> None:
         pass

@@ -452,11 +452,12 @@ class AugmentAdapter(IIterator):
             if self._pool is None and self.nthread > 1:
                 from concurrent.futures import ThreadPoolExecutor
                 self._pool = ThreadPoolExecutor(max_workers=self.nthread)
-            if self._pool is not None and len(chunk) > 1:
-                self._buf = list(self._pool.map(self._transform_inst,
-                                                chunk))
-            else:
-                self._buf = [self._transform_inst(i) for i in chunk]
+            with self.span("io.augment", n=len(chunk)):
+                if self._pool is not None and len(chunk) > 1:
+                    self._buf = list(self._pool.map(self._transform_inst,
+                                                    chunk))
+                else:
+                    self._buf = [self._transform_inst(i) for i in chunk]
             self._bufpos = 0
         self._out = self._buf[self._bufpos]
         self._bufpos += 1

@@ -250,7 +250,8 @@ class BatchAdapter(IIterator):
                             np.asarray(pad_inst.label, np.float32)),
                         extra_data=[np.zeros_like(e)
                                     for e in pad_inst.extra_data]))
-        self._out = self._assemble(insts, npadd)
+        with self.span("io.assemble", n=len(insts)):
+            self._out = self._assemble(insts, npadd)
         if nzero and self._aug is not None:
             # parity with the per-instance path, which pads with zeros
             # AFTER the transform: the deferred whole-batch mean/scale
@@ -425,8 +426,9 @@ class PrefetchIterator(IIterator):
         # False on backends whose device_put aliases host memory
         # (CPU zero-copy) — releasing there would corrupt queued batches
         self._release_safe: Optional[bool] = None
-        # per-round H2D / wait counters (pipeline telemetry)
-        self._h2d_s = 0.0
+        # per-round H2D / wait counters (pipeline telemetry); the H2D
+        # time is the io.h2d_* spans', so 0 on an unmonitored chain
+        self._h2d_ns = 0
         self._h2d_batches = 0
         self._consumer_wait_s = 0.0
 
@@ -467,11 +469,16 @@ class PrefetchIterator(IIterator):
         return self._stop.is_set() or self._restart.is_set()
 
     def _put(self, item) -> bool:
-        return self._q.put(item, self._cancelled)
+        # the producer blocked on a full queue: the pipeline is ahead
+        with self.span("io.queue_full"):
+            return self._q.put(item, self._cancelled)
 
     def _producer(self) -> None:
         while not self._stop.is_set():
-            self._restart.wait()
+            # an epoch is done and the consumer has not asked for the
+            # next one yet: like a full queue, the pipeline is ahead
+            with self.span("io.epoch_wait"):
+                self._restart.wait()
             if self._stop.is_set():
                 return
             self._restart.clear()
@@ -486,17 +493,17 @@ class PrefetchIterator(IIterator):
                 return
 
     def _run_epoch(self, epoch: int) -> None:
-        pending = None                  # (raw, staged, issue_seconds)
+        pending = None                  # (raw, staged, issue_ns)
         while not self._cancelled():
             has_next = self.base.next()
             raw = staged = None
-            issue_s = 0.0
+            issue_ns = 0
             if has_next:
                 raw = self.base.value()
                 if self._transform is not None:
-                    t0 = time.perf_counter()
-                    staged = self._transform(raw)   # async H2D issue
-                    issue_s = time.perf_counter() - t0
+                    with self.span("io.h2d_issue") as sp:
+                        staged = self._transform(raw)   # async H2D issue
+                    issue_ns = sp.dur_ns
                 else:
                     staged = raw
             # deliver the PREVIOUS batch now that the next transfer is
@@ -509,7 +516,7 @@ class PrefetchIterator(IIterator):
                 self._put((epoch, None))            # epoch end sentinel
                 return
             if self._transform is not None:
-                pending = (raw, staged, issue_s)
+                pending = (raw, staged, issue_ns)
             else:
                 if not self._put((epoch, staged)):
                     return
@@ -524,15 +531,14 @@ class PrefetchIterator(IIterator):
     def _finish(self, pending, epoch: int) -> bool:
         """Wait for a staged batch's H2D, hand its host ring buffer
         back for refill, and enqueue the device batch."""
-        raw, staged, issue_s = pending
-        t0 = time.perf_counter()
-        _block_batch_ready(staged)
+        raw, staged, issue_ns = pending
+        with self.span("io.h2d_wait") as sp:
+            _block_batch_ready(staged)
         # only the issue call + the readiness wait count as H2D time:
         # the decode of the NEXT batch and queue-full waits happen in
-        # between and must not inflate the overlap ratio
-        dt = issue_s + (time.perf_counter() - t0)
+        # between
         with self._lock:
-            self._h2d_s += dt
+            self._h2d_ns += issue_ns + sp.dur_ns
             self._h2d_batches += 1
         self._release_raw(raw, staged)  # transfer done: buffer reusable
         return self._put((epoch, staged))
@@ -610,11 +616,10 @@ class PrefetchIterator(IIterator):
     def h2d_snapshot(self) -> dict:
         """Per-round H2D/wait counters (reset on read)."""
         with self._lock:
-            out = {"h2d_ms": self._h2d_s * 1e3,
+            out = {"h2d_ms": self._h2d_ns / 1e6,
                    "h2d_batches": self._h2d_batches,
-                   "consumer_wait_ms": self._consumer_wait_s * 1e3,
-                   "wait_measured": self.wait_hist is not None}
-            self._h2d_s, self._h2d_batches = 0.0, 0
+                   "consumer_wait_ms": self._consumer_wait_s * 1e3}
+            self._h2d_ns, self._h2d_batches = 0, 0
             self._consumer_wait_s = 0.0
         return out
 
@@ -642,22 +647,28 @@ def enable_chain_wait_stats(it):
     return None
 
 
+def attach_chain_spans(it, span) -> None:
+    """Give every iterator of a chain (walking ``.base``) the monitor's
+    ``span``: its per-chunk and per-batch work is then timed on the
+    profiler's clock (monitor/spans.py). Attached like the wait
+    histogram, only under an enabled monitor."""
+    node = it
+    while node is not None:
+        node.span = span
+        node = getattr(node, "base", None)
+
+
 def pipeline_snapshot(it) -> Optional[dict]:
     """Collect (and reset) per-round pipeline counters from an iterator
-    chain: buffer reuse from BatchAdapter rings, H2D staging time and
-    consumer waits from PrefetchIterators. Returns None when the chain
-    has neither (nothing to report).
-
-    ``h2d_overlap_ratio`` is the share of H2D staging time hidden
-    behind device compute, measured conservatively: any time the
-    consumer spent blocked on the prefetch queue counts as unhidden
-    (even when the real bottleneck was decode, not transfer)."""
+    chain: buffer reuse from BatchAdapter rings, H2D staging time (the
+    ``io.h2d_issue`` + ``io.h2d_wait`` spans; 0 on a chain with no
+    monitor attached) and consumer waits from PrefetchIterators.
+    Returns None when the chain has neither (nothing to report)."""
     found = False
     alloc = reuse = batches = 0
     h2d_ms = 0.0
     h2d_batches = 0
     wait_ms = 0.0
-    wait_measured = False
     node = it
     while node is not None:
         if isinstance(node, BatchAdapter):
@@ -672,22 +683,14 @@ def pipeline_snapshot(it) -> Optional[dict]:
             h2d_ms += s["h2d_ms"]
             h2d_batches += s["h2d_batches"]
             wait_ms += s["consumer_wait_ms"]
-            wait_measured = wait_measured or s["wait_measured"]
         node = getattr(node, "base", None)
     if not found:
         return None
     total = alloc + reuse
-    if h2d_ms <= 0:
-        overlap = 1.0                   # nothing to hide
-    elif not wait_measured:
-        overlap = 0.0                   # no wait evidence: claim nothing
-    else:
-        overlap = max(0.0, min(1.0, 1.0 - wait_ms / h2d_ms))
     return {"batches": batches,
             "buffers_allocated": alloc,
             "buffers_reused": reuse,
             "buffer_reuse_rate": (reuse / total) if total else 0.0,
             "h2d_ms": round(h2d_ms, 3),
             "h2d_batches": h2d_batches,
-            "consumer_wait_ms": round(wait_ms, 3),
-            "h2d_overlap_ratio": round(overlap, 4)}
+            "consumer_wait_ms": round(wait_ms, 3)}

@@ -240,21 +240,23 @@ class ImageRecordIterator(IIterator):
 
     def _fill(self) -> bool:
         recs: List[bytes] = []
-        while len(recs) < self._chunk and \
-                self._cur_reader < len(self._readers):
-            r = self._readers[self._cur_reader].next_record()
-            if r is None:
-                self._cur_reader += 1
-                continue
-            if self._shard_plan is not None:
-                owned = self._shard_plan.owns(self._rec_seq)
-                self._rec_seq += 1
-                if not owned:
-                    continue             # another host's record: no decode
-            recs.append(r)
+        with self.span("io.read"):
+            while len(recs) < self._chunk and \
+                    self._cur_reader < len(self._readers):
+                r = self._readers[self._cur_reader].next_record()
+                if r is None:
+                    self._cur_reader += 1
+                    continue
+                if self._shard_plan is not None:
+                    owned = self._shard_plan.owns(self._rec_seq)
+                    self._rec_seq += 1
+                    if not owned:
+                        continue         # another host's record: no decode
+                recs.append(r)
         if not recs:
             return False
-        insts = list(self._pool.map(self._decode, recs))
+        with self.span("io.decode", n=len(recs)):
+            insts = list(self._pool.map(self._decode, recs))
         insts = [i for i in insts if i is not None]
         if self.shuffle:
             self._rng.shuffle(insts)

@@ -26,7 +26,8 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from .io import create_iterator
-from .io.iter_batch import enable_chain_wait_stats, pipeline_snapshot
+from .io.iter_batch import (attach_chain_spans, enable_chain_wait_stats,
+                            pipeline_snapshot)
 from .monitor import (Monitor, create_monitor, device_memory_snapshot,
                       run_metadata, set_global)
 from .nnet.checkpoint import CheckpointManager, find_latest_valid
@@ -448,8 +449,9 @@ class LearnTask:
                     all_iters.append(it)
                     itr_train = it
                     continue
-                it = create_iterator(_localize(b["cfg"]), batch_cfg)
-                it.init()
+                with self._mon.span("setup.iterator"):
+                    it = create_iterator(_localize(b["cfg"]), batch_cfg)
+                    it.init()
                 all_iters.append(it)
                 if b["kind"] == "data":
                     itr_train = it
@@ -488,21 +490,23 @@ class LearnTask:
                     # finetune = remap-aware bootstrap
                     from .continual import ContinualConfig
                     mode = ContinualConfig(cfg).task
-                if self.model_in and (mode == "train"
-                                      or self._resume_found):
-                    # plain verified load — including a resumed
-                    # (continue = 1) finetune/continual run: its own
-                    # snapshots already carry the remapped structure,
-                    # so resume must NOT re-remap a freshly
-                    # initialized head over the trained one
-                    trainer.load_model(self.model_in)
-                else:
-                    trainer.init_model()
-                    if mode == "finetune":
-                        assert self.model_in, "finetune requires model_in"
-                        trainer.finetune_from(
-                            self.model_in, remap=self.finetune_remap,
-                            strict=bool(self.finetune_strict))
+                with self._mon.span("setup.init_model"):
+                    if self.model_in and (mode == "train"
+                                          or self._resume_found):
+                        # plain verified load — including a resumed
+                        # (continue = 1) finetune/continual run: its
+                        # own snapshots already carry the remapped
+                        # structure, so resume must NOT re-remap a
+                        # freshly initialized head over the trained one
+                        trainer.load_model(self.model_in)
+                    else:
+                        trainer.init_model()
+                        if mode == "finetune":
+                            assert self.model_in, \
+                                "finetune requires model_in"
+                            trainer.finetune_from(
+                                self.model_in, remap=self.finetune_remap,
+                                strict=bool(self.finetune_strict))
                 if self.task == "continual":
                     return self._task_continual(cfg, trainer,
                                                 itr_train, eval_iters)
@@ -671,6 +675,7 @@ class LearnTask:
             # attached only under an active monitor so the default
             # path never pays the per-batch clock reads
             io_hist = enable_chain_wait_stats(itr_train)
+            attach_chain_spans(itr_train, mon.span)
         k = self.dispatch_period
         # checkpoints go through the manager: atomic commit + digest,
         # background writer (checkpoint_async), retention GC
@@ -699,7 +704,7 @@ class LearnTask:
         # process handlers (a long-lived library caller must get its
         # Ctrl-C back even when the loop below raises)
         handlers = []
-        ndisp = 0
+        self._ndisp = 0
         try:
             handlers = self._install_preempt_handlers()
             for r in range(self.start_counter - 1, self.num_round):
@@ -707,103 +712,11 @@ class LearnTask:
                 # multi-process): r rounds have fully completed
                 if self._preempt_now():
                     return self._preempt_exit(ckpt, r, mon)
-                trainer.start_round(r)
-                if monitored:
-                    mon.emit("round_start", round=r)
-                # trace hooks are NOT gated on an enabled sink: a
-                # profiler trace is one config line (monitor_trace_dir)
-                # away even with monitor = none (doc/debug_perf.md)
-                mon.maybe_start_trace(r)
-                nbatch = 0
-                window = []
-                t_wait = time.perf_counter() if monitored else 0.0
-                # lockstep across ranks: unequal per-rank batch counts
-                # would deadlock the SPMD collectives (see
-                # parallel.synced_batches)
-                for batch in synced_batches(itr_train, window=k):
-                    if monitored:
-                        # data-wait half of the step-time split: time
-                        # this loop spent blocked on the iterator since
-                        # the last dispatch
-                        trainer.note_data_wait(
-                            time.perf_counter() - t_wait)
-                    if k == 1:
-                        trainer.update(batch)
-                        nbatch += 1
-                    else:
-                        window.append(batch)
-                        if len(window) < k:
-                            if monitored:
-                                t_wait = time.perf_counter()
-                            continue
-                        trainer.update_many(window)
-                        nbatch += len(window)
-                        window = []
-                    _progress(r, nbatch)
-                    # every rank reaches each dispatch boundary the
-                    # same number of times (synced_batches), so the
-                    # collective preemption check stays in lockstep.
-                    # Multi-process, the check is a blocking host
-                    # allgather — throttle it to every 8th dispatch
-                    # (the shared ndisp counter keeps ranks agreeing
-                    # on WHICH dispatches check) so the hot path does
-                    # not grow a second per-dispatch host collective
-                    ndisp += 1
-                    if (world_size() == 1 or ndisp % 8 == 0) \
-                            and self._preempt_now():
-                        return self._preempt_exit(ckpt, r, mon)
-                    if monitored:
-                        t_wait = time.perf_counter()
-                for batch in window:    # round tail: per-batch (a short
-                    trainer.update(batch)  # window would recompile)
-                    nbatch += 1
-                trainer.end_round()     # close the throughput window
-                #                         before evals start
-                line = "[%d]" % (r + 1)
-                if self.task_eval_train:
-                    line += trainer.train_metric_str("train")
-                for name, it in eval_iters:
-                    line += trainer.evaluate(it, name)
-                if self.silent == 0 and is_root():
-                    mon.line(line)
-                mon.maybe_stop_trace(r)
-                if monitored:
-                    mon.emit("round_end", round=r,
-                             examples=trainer.last_round_examples,
-                             wall_s=trainer.last_round_wall_s,
-                             examples_per_sec=trainer
-                             .last_round_examples_per_sec)
-                    mon.emit("memory", round=r,
-                             **device_memory_snapshot())
-                    if io_hist is not None:
-                        mon.emit("io_wait", round=r,
-                                 **io_hist.snapshot())
-                        io_hist.reset()
-                    ps = pipeline_snapshot(itr_train)
-                    if ps is not None:
-                        # per-round input-pipeline health: buffer-reuse
-                        # rate of the zero-copy assembly, H2D overlap
-                        # of the prefetch staging (doc/observability.md)
-                        mon.emit("pipeline", round=r, **ps)
-                    if isinstance(itr_train, DryrunFeed):
-                        # per-round per-host input-shard accounting:
-                        # rows_per_host sums exactly to the round's
-                        # real rows (the exactly-once invariant,
-                        # counted per round)
-                        mon.emit("dist_shard", round=r,
-                                 **itr_train.accounting())
-                        itr_train.reset_accounting()
-                if self.test_on_server:
-                    # per-round weight consistency audit (the
-                    # reference's test_on_server CheckWeight_,
-                    # async_updater-inl.hpp:149-154): every device
-                    # replica must hold identical weights
-                    trainer.check_weight_consistency()
-                if self.save_period and (r + 1) % self.save_period == 0:
-                    # all ranks call (ZeRO-state gathers are
-                    # collective); only root commits, on the background
-                    # writer when checkpoint_async
-                    ckpt.save(r + 1)
+                with mon.span("train.round", round=r):
+                    rc = self._train_round(trainer, itr_train, eval_iters,
+                                           ckpt, r, io_hist, _progress)
+                if rc is not None:
+                    return rc
             # drain the writer before run_end: every checkpoint record
             # lands in the stream, and the last commit is durable
             # before the exit code says success
@@ -819,6 +732,116 @@ class LearnTask:
             mon.emit("run_end", wall_s=time.time() - start,
                      steps=int(c["steps"]), examples=int(c["examples"]))
         return 0
+
+    def _train_round(self, trainer, itr_train, eval_iters, ckpt, r: int,
+                     io_hist, progress) -> Optional[int]:
+        """One round of ``task = train``: the dispatch loop, then the
+        round's evals, records and snapshot. Returns the preemption
+        exit code when a signal ended the round, else None. The loop's
+        three phases are spans (wait for a batch, dispatch, end of
+        round): ``step.data_wait_ms`` IS the ``train.data_wait`` spans'
+        time since the last dispatch, one measurement."""
+        mon = self._mon
+        monitored = mon.enabled
+        k = self.dispatch_period
+        trainer.start_round(r)
+        if monitored:
+            mon.emit("round_start", round=r)
+        # trace hooks are NOT gated on an enabled sink: a profiler
+        # trace is one config line (monitor_trace_dir) away even with
+        # monitor = none (doc/debug_perf.md)
+        mon.maybe_start_trace(r)
+        nbatch = 0
+        window = []
+        # lockstep across ranks: unequal per-rank batch counts would
+        # deadlock the SPMD collectives (see parallel.synced_batches)
+        batches = synced_batches(itr_train, window=k)
+        while True:
+            # data-wait half of the step-time split: time this loop
+            # spends blocked on the iterator (its restart included)
+            with mon.span("train.data_wait", round=r) as wait:
+                batch = next(batches, None)
+            if monitored:
+                trainer.note_data_wait(wait.dur_ns / 1e9)
+            if batch is None:
+                break
+            if k > 1:
+                window.append(batch)
+                if len(window) < k:
+                    continue
+            with mon.span("train.dispatch", round=r):
+                if k == 1:
+                    trainer.update(batch)
+                    nbatch += 1
+                else:
+                    trainer.update_many(window)
+                    nbatch += len(window)
+                    window = []
+            progress(r, nbatch)
+            # every rank reaches each dispatch boundary the same
+            # number of times (synced_batches), so the collective
+            # preemption check stays in lockstep. Multi-process, the
+            # check is a blocking host allgather — throttle it to
+            # every 8th dispatch (the shared counter keeps ranks
+            # agreeing on WHICH dispatches check) so the hot path does
+            # not grow a second per-dispatch host collective
+            self._ndisp += 1
+            if (world_size() == 1 or self._ndisp % 8 == 0) \
+                    and self._preempt_now():
+                return self._preempt_exit(ckpt, r, mon)
+        if window:
+            with mon.span("train.dispatch", round=r, n=len(window)):
+                for batch in window:    # round tail: per-batch (a short
+                    trainer.update(batch)  # window would recompile)
+        with mon.span("train.round_end", round=r):
+            trainer.end_round()         # close the throughput window
+            #                             before evals start
+            line = "[%d]" % (r + 1)
+            if self.task_eval_train:
+                line += trainer.train_metric_str("train")
+            for name, it in eval_iters:
+                line += trainer.evaluate(it, name)
+            if self.silent == 0 and is_root():
+                mon.line(line)
+            mon.maybe_stop_trace(r)
+            if monitored:
+                self._emit_round_records(trainer, itr_train, r, io_hist)
+            if self.test_on_server:
+                # per-round weight consistency audit (the reference's
+                # test_on_server CheckWeight_,
+                # async_updater-inl.hpp:149-154): every device replica
+                # must hold identical weights
+                trainer.check_weight_consistency()
+            if self.save_period and (r + 1) % self.save_period == 0:
+                # all ranks call (ZeRO-state gathers are collective);
+                # only root commits, on the background writer when
+                # checkpoint_async
+                ckpt.save(r + 1)
+        return None
+
+    def _emit_round_records(self, trainer, itr_train, r: int,
+                            io_hist) -> None:
+        mon = self._mon
+        mon.emit("round_end", round=r,
+                 examples=trainer.last_round_examples,
+                 wall_s=trainer.last_round_wall_s,
+                 examples_per_sec=trainer.last_round_examples_per_sec)
+        mon.emit("memory", round=r, **device_memory_snapshot())
+        if io_hist is not None:
+            mon.emit("io_wait", round=r, **io_hist.snapshot())
+            io_hist.reset()
+        ps = pipeline_snapshot(itr_train)
+        if ps is not None:
+            # per-round input-pipeline health: buffer-reuse rate of the
+            # zero-copy assembly, H2D staging time of the prefetch
+            # (doc/observability.md)
+            mon.emit("pipeline", round=r, **ps)
+        if isinstance(itr_train, DryrunFeed):
+            # per-round per-host input-shard accounting: rows_per_host
+            # sums exactly to the round's real rows (the exactly-once
+            # invariant, counted per round)
+            mon.emit("dist_shard", round=r, **itr_train.accounting())
+            itr_train.reset_accounting()
 
     def _task_continual(self, cfg, trainer, itr_train,
                         eval_iters) -> int:

@@ -19,11 +19,15 @@ them, configured through the same ``key = value`` config grammar:
   long-lived ``task = continual`` process cannot grow one unbounded
   stream.
 - ``monitor_trace_dir`` — when set, a ``jax.profiler`` trace is
-  captured into this directory over a round window, so a perf trace is
-  one config line away.
+  captured into this directory over ONE round window (Python tracer
+  off), so a perf trace is one config line away.
 - ``monitor_trace_begin`` / ``monitor_trace_end`` — first/last round
   (0-based) of the trace window; both default to round 1 (skipping the
   compile-heavy round 0).
+
+``Monitor.span(name, **attrs)`` times one interval of host work on the
+profiler's clock (``monitor/spans.py``); over a null sink it is one
+shared no-op object.
 
 Multi-process runs gate emission on process 0 (the rabit
 ``IsRoot``-style gating main.py already applies to prints,
@@ -42,11 +46,20 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+from .spans import NULL_SPAN, SpanRecorder, no_span
+
 __all__ = [
     "Monitor", "NullSink", "StdoutSink", "JsonlSink", "MemorySink",
     "LatencyHistogram", "create_monitor", "config_hash",
     "device_memory_snapshot", "get_global", "set_global", "warn_once",
+    "NULL_SPAN", "no_span",
 ]
+
+# records just ahead of which the closed spans are written out: the
+# ones a reader waits for, so a sink that is read without a close (a
+# MemorySink after a library caller's loop) holds their spans too, and
+# ``run_end`` stays a stream's last record
+_FLUSH_SPANS_AT = frozenset(("step", "precompile", "round_end", "run_end"))
 
 
 # -- sinks ---------------------------------------------------------------
@@ -304,7 +317,9 @@ class Monitor:
     exactly as the unmonitored code did (callers keep their own
     silent/is_root gating), and enabled sinks additionally record it as
     a ``log`` event. ``emit(event, **fields)`` is the structured
-    channel; it is a no-op over a null sink.
+    channel; it is a no-op over a null sink. ``span(name, **attrs)``
+    is the timing channel: a context manager around one interval of
+    host work, the shared ``NULL_SPAN`` over a null sink.
     """
 
     def __init__(self, sink=None, trace_dir: str = "",
@@ -320,6 +335,7 @@ class Monitor:
         # checkpoint writer, prefetch) as well as the main thread
         self._warn_lock = threading.Lock()
         self._warned = set()
+        self.spans = SpanRecorder() if self.sink.enabled else None
 
     @property
     def enabled(self) -> bool:
@@ -328,9 +344,30 @@ class Monitor:
     def emit(self, event: str, **fields: Any) -> None:
         if not self.sink.enabled:
             return
+        if event in _FLUSH_SPANS_AT:
+            self.flush_spans()
         record = {"event": event, "t": time.time()}
         record.update(fields)
         self.sink.write(record)
+
+    def span(self, name: str, **attrs: int):
+        """Time one interval of host work: ``with mon.span("io.decode",
+        n=256) as sp: ...``; ``sp.dur_ns`` afterwards. ``attrs`` are
+        small integers (round, step, batch, n)."""
+        if self.spans is None:
+            return NULL_SPAN
+        return self.spans.span(name, **attrs)
+
+    def flush_spans(self) -> None:
+        """Write the closed spans out as ``span`` records."""
+        if self.spans is None:
+            return
+        for sp in self.spans.drain():
+            self.emit("span", **sp.record())
+        if self.spans.dropped:
+            self.warn_once("spans_dropped",
+                           "the span ring wrapped before a flush: %d "
+                           "span(s) lost" % self.spans.dropped)
 
     def line(self, text: str) -> None:
         """Print a parity stdout line; record it when enabled."""
@@ -365,12 +402,17 @@ class Monitor:
         """Start at the first observed round >= trace_begin (not only
         on exact equality: a resumed run may begin past the window,
         and a silent no-trace would be worse than a late one)."""
-        if (not self.trace_dir or self._tracing
+        if (not self.trace_dir or self._trace_started
                 or round_idx < self.trace_begin):
-            return
+            return                       # one window a run
         try:
             import jax
-            jax.profiler.start_trace(self.trace_dir)
+            # the Python tracer's events of a training loop ran a
+            # 40 GiB host out of memory (PERF.md, PR 24)
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(self.trace_dir,
+                                     profiler_options=opts)
         except Exception as e:  # profiler backend is best-effort
             self.warn_once("trace_start_failed",
                            "jax.profiler.start_trace failed: %s" % e)
@@ -407,6 +449,7 @@ class Monitor:
 
     def close(self) -> None:
         self.maybe_stop_trace(0, force=True)
+        self.flush_spans()
         if self.trace_dir and not self._trace_started:
             # trace requested but the run never reached trace_begin —
             # say so instead of leaving an empty dir with no diagnostic

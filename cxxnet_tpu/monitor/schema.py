@@ -33,10 +33,21 @@ REQUIRED: Dict[str, tuple] = {
     "memory": ("round", "available", "devices"),
     "io_wait": ("round", "count", "total_ms", "max_ms", "p50_ms",
                 "p99_ms", "buckets"),
-    # per-round input-pipeline health: zero-copy assembly reuse +
-    # prefetch H2D overlap (doc/observability.md)
-    "pipeline": ("round", "buffer_reuse_rate", "h2d_overlap_ratio",
-                 "batches", "h2d_ms", "consumer_wait_ms"),
+    # per-round input-pipeline health: zero-copy assembly reuse, H2D
+    # staging time (the io.h2d_* spans' sum) and the consumer's waits
+    # (doc/observability.md)
+    "pipeline": ("round", "buffer_reuse_rate", "batches", "h2d_ms",
+                 "consumer_wait_ms"),
+    # one interval of host work on the profiler's clock
+    # (monitor/spans.py): t is the END in seconds, t0_ns/dur_ns are
+    # time.time_ns() integers, parent is the enclosing span's id on
+    # that thread (0 = none), attrs small integers
+    "span": ("name", "t0_ns", "dur_ns", "tid", "id", "parent", "attrs"),
+    # once per dispatched AOT program: {HLO instruction -> scope path}
+    # read from the loaded executable, so a device trace's ops can be
+    # grouped by layer (doc/observability.md)
+    "program_scopes": ("program", "module", "scopes", "fusions",
+                       "fusions_mapped", "wall_ms"),
     # one-time AOT compile window (precompile = 1)
     "precompile": ("wall_ms", "programs"),
     # static per-model records (emitted once per init/monitor attach):
@@ -199,12 +210,12 @@ _TIMING_KEYS = ("wall_ms", "data_wait_ms", "total_ms", "max_ms",
                 "device_ms", "latency_p50_ms", "latency_p99_ms",
                 "rows_per_sec", "gather_ms", "serialize_ms",
                 "write_ms", "fsync_ms", "quantize_ms",
-                "backprop_ms", "reduce_ms", "step_ms", "window_s")
+                "backprop_ms", "reduce_ms", "step_ms", "window_s",
+                "dur_ns")
 
 # ratio fields must sit in [0, 1]
-_RATIO_KEYS = ("buffer_reuse_rate", "h2d_overlap_ratio", "fill_rate",
-               "pad_fraction", "agree_rate", "data_wait_share",
-               "overlap_ratio", "recall")
+_RATIO_KEYS = ("buffer_reuse_rate", "fill_rate", "pad_fraction",
+               "agree_rate", "data_wait_share", "overlap_ratio", "recall")
 
 
 def validate_record(rec: Dict[str, Any]) -> List[str]:

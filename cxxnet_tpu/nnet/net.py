@@ -262,6 +262,18 @@ class FuncNet:
 
     # -- forward ---------------------------------------------------------
 
+    def layer_scope(self, li: int) -> str:
+        """``<type>.<layer_key>``: the ``jax.named_scope`` of one
+        layer's ops (a shared layer under its primary's type)."""
+        g = self.graph
+        return "%s.%s" % (g.effective_type(li),
+                          g.layer_key(li).replace("/", "_"))
+
+    @property
+    def scope_names(self) -> Tuple[str, ...]:
+        return tuple(self.layer_scope(li)
+                     for li in range(len(self.graph.layers)))
+
     def forward(self, params: Params, state: NetState,
                 data: jnp.ndarray,
                 extra: Sequence[jnp.ndarray] = (),
@@ -322,11 +334,16 @@ class FuncNet:
                     if rng is not None else None)
             if collect_logits and layer.is_loss:
                 loss_inputs[li] = ins[0]
-            if layer.needs_mask:
-                outs, s2 = layer.forward(p, s, ins, is_train, lrng,
-                                         mask=mask)
-            else:
-                outs, s2 = layer.forward(p, s, ins, is_train, lrng)
+            # metadata only: names the layer in every op it lowers to
+            # (op_name), forward and, under jax.grad, backward as
+            # transpose(jvp(<type>.<key>)); a layer whose epilogue ran
+            # fused in its producer (above) opens none
+            with jax.named_scope(self.layer_scope(li)):
+                if layer.needs_mask:
+                    outs, s2 = layer.forward(p, s, ins, is_train, lrng,
+                                             mask=mask)
+                else:
+                    outs, s2 = layer.forward(p, s, ins, is_train, lrng)
             if s2:
                 new_state[pkey] = s2
             for ni, v in zip(info.nindex_out, outs):
@@ -361,7 +378,9 @@ class FuncNet:
                 raise ValueError("loss layer: unknown target=%s"
                                  % layer.target)
             a, b = slices[layer.target]
-            total = total + layer.loss_value(logit, labels[:, a:b], mask)
+            with jax.named_scope("loss"):
+                total = total + layer.loss_value(logit, labels[:, a:b],
+                                                 mask)
         collected = [self.depad_node(ni, nodes[ni])
                      for ni in collect_nodes]
         return total, (new_state, collected)

@@ -45,6 +45,7 @@ from ..artifact import registry as _areg
 from ..graph import NetGraph
 from ..io.data import DataBatch
 from ..layers import pallas_kernels as _pallas
+from ..monitor.spans import NULL_SPAN, STEP_SCOPES, scope_map
 from ..parallel import (batch_sharding, make_mesh, opt_state_sharding,
                         param_sharding, replicated)
 from ..updater import create_updater
@@ -188,6 +189,8 @@ class NetTrainer:
         # the monitor so monitor=none adds NO host<->device syncs to
         # the step path.
         self._mon = None                 # monitor.Monitor or None
+        self._scoped: set = set()        # program keys whose
+        #                                  program_scopes went out
         self._steps_total = 0            # dispatches (telemetry step id)
         self._examples_total = 0         # real (non-padded) local rows
         self._round_examples = 0
@@ -392,6 +395,7 @@ class NetTrainer:
         mesh = self.mesh
         self.programs.reset()            # rebuilt programs orphan any
         #                                  earlier AOT executables
+        self._scoped = set()
         self._b_shard = batch_sharding(mesh)
         self._probe_input_layout()
         self._repl = replicated(mesh)
@@ -563,25 +567,30 @@ class NetTrainer:
             streams (step) and skewing Adam's bias correction (epoch)
             on long runs."""
             rng = jax.random.fold_in(base_key, step)
+            with jax.named_scope("grad_cast"):
+                shadow = _grad_cast(params)
             (loss, (new_state, preds)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(
-                    _grad_cast(params), net_state, data, labels, mask,
-                    extra, rng)
+                    shadow, net_state, data, labels, mask, extra, rng)
             preds = [p.astype(jnp.float32) for p in preds] if collect \
                 else []
+            with jax.named_scope("grad_cast"):
+                grads = _grad_f32(grads)
             if update_period == 1:
-                params, opt_state = apply_updates(
-                    params, opt_state, _grad_f32(grads), hyper_row,
-                    epoch)
+                with jax.named_scope("update"):
+                    params, opt_state = apply_updates(
+                        params, opt_state, grads, hyper_row, epoch)
                 return (params, opt_state, new_state, grad_acc, loss,
                         preds)
             # accumulate in f32 regardless of gradient dtype
-            grad_acc = _tree_add(grad_acc, _grad_f32(grads))
+            with jax.named_scope("update"):
+                grad_acc = _tree_add(grad_acc, grads)
 
             def do_apply(args):
                 p, o, acc = args
-                p2, o2 = apply_updates(p, o, acc, hyper_row, epoch)
-                return p2, o2, _tree_zeros_like(acc)
+                with jax.named_scope("update"):
+                    p2, o2 = apply_updates(p, o, acc, hyper_row, epoch)
+                    return p2, o2, _tree_zeros_like(acc)
 
             params, opt_state, grad_acc = jax.lax.cond(
                 do_up, do_apply, lambda a: a,
@@ -627,10 +636,13 @@ class NetTrainer:
                     epoch_i, do_up, step + i, base_key, False)
                 return (p, o, s, acc), loss
             n = hyper_k.shape[0]
-            carry, losses = jax.lax.scan(
-                body, (params, opt_state, net_state, grad_acc),
-                (hyper_k, epoch_k, do_up_k,
-                 jnp.arange(n, dtype=jnp.uint32)))
+            # the scan's own ops (slicing each step's rows out of the
+            # stacked operands) get a name too; layers lie deeper
+            with jax.named_scope("window"):
+                carry, losses = jax.lax.scan(
+                    body, (params, opt_state, net_state, grad_acc),
+                    (hyper_k, epoch_k, do_up_k,
+                     jnp.arange(n, dtype=jnp.uint32)))
             params, opt_state, net_state, grad_acc = carry
             return params, opt_state, net_state, grad_acc, losses[-1]
 
@@ -661,10 +673,11 @@ class NetTrainer:
                     epoch_i, do_up, step + i, base_key, collect)
                 return (p, o, s, acc), (loss, preds)
             K = hyper_k.shape[0]
-            carry, (losses, preds_k) = jax.lax.scan(
-                body, (params, opt_state, net_state, grad_acc),
-                (data_k, labels_k, mask_k, extra_k, hyper_k, epoch_k,
-                 do_up_k, jnp.arange(K, dtype=jnp.uint32)))
+            with jax.named_scope("window"):
+                carry, (losses, preds_k) = jax.lax.scan(
+                    body, (params, opt_state, net_state, grad_acc),
+                    (data_k, labels_k, mask_k, extra_k, hyper_k, epoch_k,
+                     do_up_k, jnp.arange(K, dtype=jnp.uint32)))
             params, opt_state, net_state, grad_acc = carry
             return (params, opt_state, net_state, grad_acc, losses[-1],
                     preds_k)
@@ -973,10 +986,41 @@ class NetTrainer:
         artifact — static args baked in either way), the jit function
         otherwise. One code path so a key-scheme change cannot
         silently strand a dispatch site on jit fallback."""
-        aot = self.programs.get((kind,) + sig)
-        if aot is not None:
-            return aot(*args)
-        return jit_fn(*args, **static_kw)
+        key = (kind,) + sig
+        aot = self.programs.get(key)
+        if aot is None:
+            return jit_fn(*args, **static_kw)
+        out = aot(*args)
+        if (kind != "pred" and key not in self._scoped
+                and self._mon_on()):
+            # after the enqueue: the device works while the host reads
+            self._scoped.add(key)
+            self._emit_program_scopes(kind, aot)
+        return out
+
+    def _emit_program_scopes(self, kind: str, executable) -> None:
+        """One ``program_scopes`` record at a registry program's first
+        dispatch: {HLO instruction -> scope path}, read from the text
+        of the executable that was LOADED (jax's persistent-cache key
+        ignores metadata, so an executable read from a cache written
+        by an earlier build carries that build's scopes, or none: the
+        record says what the chip is running, not what was traced).
+        Programs on the jit fallback path give no record, and neither
+        do ``pred`` programs: serving gets its spans and scopes with
+        its benchmark cell (PERF.md)."""
+        with self._span("setup.program_scopes") as span:
+            try:
+                module, scopes, fusions, mapped = scope_map(
+                    executable.as_text(),
+                    self.net.scope_names + STEP_SCOPES)
+            except Exception as e:  # an executable that cannot print
+                self._mon.warn_once(
+                    "program_scopes_failed",
+                    "no scope map for program %r: %s" % (kind, e))
+                return
+        self._mon.emit("program_scopes", program=kind, module=module,
+                       scopes=scopes, fusions=fusions,
+                       fusions_mapped=mapped, wall_ms=span.dur_ns / 1e6)
 
     # the pred dispatch signature (sans the leading "pred" kind): the
     # single definition — cxxnet_tpu.artifact.registry.pred_sig —
@@ -1035,116 +1079,118 @@ class NetTrainer:
         input's device layout channels-minor. Returns the number of
         programs compiled."""
         assert self._initialized, "call init_model/load_model first"
-        from ..io.data import inst_array_shape
-        t_start = time.perf_counter()
-        self._enable_persistent_cache()
-        dtype = np.dtype(np.uint8 if self.precompile_dtype == "uint8"
-                         else np.float32)
-        # GLOBAL batch shapes: multi-process dispatch arrays come out of
-        # make_array_from_process_local_data with the global leading dim
-        # (each rank contributes batch_size/world rows), and the runtime
-        # signature keys use those global shapes
-        n = self.batch_size
-        data_shape = (n,) + inst_array_shape(
-            tuple(self.graph.input_shape))
-        lw = max((b for _, _a, b in self._label_slices), default=1)
-        label_shape = (n, lw)
+        with self._span("setup.precompile") as span:
+            from ..io.data import inst_array_shape
+            self._enable_persistent_cache()
+            dtype = np.dtype(np.uint8 if self.precompile_dtype == "uint8"
+                             else np.float32)
+            # GLOBAL batch shapes: multi-process dispatch arrays come out of
+            # make_array_from_process_local_data with the global leading dim
+            # (each rank contributes batch_size/world rows), and the runtime
+            # signature keys use those global shapes
+            n = self.batch_size
+            data_shape = (n,) + inst_array_shape(
+                tuple(self.graph.input_shape))
+            lw = max((b for _, _a, b in self._label_slices), default=1)
+            label_shape = (n, lw)
 
-        def sds(shape, dt, sharding=None):
-            if sharding is None:
-                return jax.ShapeDtypeStruct(shape, dt)
-            return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+            def sds(shape, dt, sharding=None):
+                if sharding is None:
+                    return jax.ShapeDtypeStruct(shape, dt)
+                return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
 
-        data_s = sds(data_shape, dtype,
-                     self._pin_layout(self._b_shard, len(data_shape)))
-        labels_s = sds(label_shape, np.float32, self._b_shard)
-        hyper_s = sds((len(self._hyper_index), 3), np.float32)
-        step_s = sds((), np.uint32)
-        epoch_s = sds((), np.uint32)
-        # the None-mask specialization only exists single-process
-        # (multi-process dp always materializes the mask — see _mask)
-        mask_variants = [None, sds((n,), np.float32, self._b_shard)]
-        if jax.process_count() > 1:
-            mask_variants = [sds((n,), np.float32, self._b_shard)]
-        do_up_variants = [True] if self.update_period == 1 \
-            else [True, False]
-        programs = []                    # (key, lower_thunk)
+            data_s = sds(data_shape, dtype,
+                         self._pin_layout(self._b_shard, len(data_shape)))
+            labels_s = sds(label_shape, np.float32, self._b_shard)
+            hyper_s = sds((len(self._hyper_index), 3), np.float32)
+            step_s = sds((), np.uint32)
+            epoch_s = sds((), np.uint32)
+            # the None-mask specialization only exists single-process
+            # (multi-process dp always materializes the mask — see _mask)
+            mask_variants = [None, sds((n,), np.float32, self._b_shard)]
+            if jax.process_count() > 1:
+                mask_variants = [sds((n,), np.float32, self._b_shard)]
+            do_up_variants = [True] if self.update_period == 1 \
+                else [True, False]
+            programs = []                    # (key, lower_thunk)
 
-        for mask_v in (mask_variants if per_batch else []):
-            for du in do_up_variants:
-                key = ("update",) + _areg.update_sig(
-                    data_shape, dtype, label_shape, mask_v is None, 0,
-                    bool(du))
-                programs.append((key, lambda m=mask_v, d=du:
-                                 self._train_step.lower(
-                                     self.params, self.opt_state,
-                                     self.net_state, self.grad_acc,
-                                     data_s, labels_s, m, (), hyper_s,
-                                     epoch_s, step_s, self._base_key,
-                                     do_update=d)))
-            if window > 1:
-                K = int(window)
-                data_k_s = sds((K,) + data_shape, dtype, self._kb_shard)
-                labels_k_s = sds((K,) + label_shape, np.float32,
-                                 self._kb_shard)
-                mask_k = None if mask_v is None \
-                    else sds((K, n), np.float32, self._kb_shard)
-                hyper_k_s = sds((K, len(self._hyper_index), 3),
+            for mask_v in (mask_variants if per_batch else []):
+                for du in do_up_variants:
+                    key = ("update",) + _areg.update_sig(
+                        data_shape, dtype, label_shape, mask_v is None, 0,
+                        bool(du))
+                    programs.append((key, lambda m=mask_v, d=du:
+                                     self._train_step.lower(
+                                         self.params, self.opt_state,
+                                         self.net_state, self.grad_acc,
+                                         data_s, labels_s, m, (), hyper_s,
+                                         epoch_s, step_s, self._base_key,
+                                         do_update=d)))
+                if window > 1:
+                    K = int(window)
+                    data_k_s = sds((K,) + data_shape, dtype, self._kb_shard)
+                    labels_k_s = sds((K,) + label_shape, np.float32,
+                                     self._kb_shard)
+                    mask_k = None if mask_v is None \
+                        else sds((K, n), np.float32, self._kb_shard)
+                    hyper_k_s = sds((K, len(self._hyper_index), 3),
+                                    np.float32)
+                    epoch_k_s = sds((K,), np.uint32)
+                    do_up_s = sds((K,), np.bool_)
+                    collect = bool(self.eval_train and self._metrics.evals)
+                    key = ("update_many",) + _areg.update_many_sig(
+                        (K,) + data_shape, dtype, (K,) + label_shape,
+                        mask_k is None, 0, K, collect)
+                    programs.append((key, lambda mk=mask_k, c=collect,
+                                     ds=data_k_s, ls=labels_k_s,
+                                     hs=hyper_k_s, es=epoch_k_s,
+                                     us=do_up_s:
+                                     self._many_step.lower(
+                                         self.params, self.opt_state,
+                                         self.net_state, self.grad_acc,
+                                         ds, ls, mk, (), hs, es, us,
+                                         step_s, self._base_key,
+                                         collect=c)))
+                if self._metric_nodes:
+                    nodes = tuple(self._metric_nodes)
+                    key = ("pred",) + self.pred_sig(
+                        data_shape, dtype, mask_v is None, 0, nodes)
+                    # operands resolved at lower time: under weight
+                    # residency the eval dispatches pass the frozen serve
+                    # tree, so the precompiled program must take the same
+                    # pytree (one calling convention per trainer)
+                    programs.append((key, lambda m=mask_v, nw=nodes:
+                                     self._pred_step.lower(
+                                         *self._pred_operands(),
+                                         data_s, m, (),
+                                         nodes_wanted=nw)))
+
+            if n_steps > 0:
+                # run_steps is the bench/test_skipread mode: its mask
+                # variant is known up front (None single-process, the
+                # materialized mask under multi-process dp), so exactly ONE
+                # program compiles — no wasted minutes on the other variant
+                mask_rs = None if jax.process_count() == 1 \
+                    else mask_variants[0]
+                ns = int(n_steps)
+                hyper_k_s = sds((ns, len(self._hyper_index), 3),
                                 np.float32)
-                epoch_k_s = sds((K,), np.uint32)
-                do_up_s = sds((K,), np.bool_)
-                collect = bool(self.eval_train and self._metrics.evals)
-                key = ("update_many",) + _areg.update_many_sig(
-                    (K,) + data_shape, dtype, (K,) + label_shape,
-                    mask_k is None, 0, K, collect)
-                programs.append((key, lambda mk=mask_k, c=collect,
-                                 ds=data_k_s, ls=labels_k_s,
-                                 hs=hyper_k_s, es=epoch_k_s,
-                                 us=do_up_s:
-                                 self._many_step.lower(
+                epoch_k_s = sds((ns,), np.uint32)
+                do_up_k_s = sds((ns,), np.bool_)
+                key = ("run_steps",) + _areg.run_steps_sig(
+                    data_shape, dtype, label_shape, mask_rs is None, 0, ns)
+                programs.append((key, lambda m=mask_rs, hs=hyper_k_s,
+                                 es=epoch_k_s, us=do_up_k_s:
+                                 self._multi_step.lower(
                                      self.params, self.opt_state,
                                      self.net_state, self.grad_acc,
-                                     ds, ls, mk, (), hs, es, us,
-                                     step_s, self._base_key,
-                                     collect=c)))
-            if self._metric_nodes:
-                nodes = tuple(self._metric_nodes)
-                key = ("pred",) + self.pred_sig(
-                    data_shape, dtype, mask_v is None, 0, nodes)
-                # operands resolved at lower time: under weight
-                # residency the eval dispatches pass the frozen serve
-                # tree, so the precompiled program must take the same
-                # pytree (one calling convention per trainer)
-                programs.append((key, lambda m=mask_v, nw=nodes:
-                                 self._pred_step.lower(
-                                     *self._pred_operands(),
-                                     data_s, m, (),
-                                     nodes_wanted=nw)))
+                                     data_s, labels_s, m, (), hs, es,
+                                     us, step_s, self._base_key)))
 
-        if n_steps > 0:
-            # run_steps is the bench/test_skipread mode: its mask
-            # variant is known up front (None single-process, the
-            # materialized mask under multi-process dp), so exactly ONE
-            # program compiles — no wasted minutes on the other variant
-            mask_rs = None if jax.process_count() == 1 \
-                else mask_variants[0]
-            ns = int(n_steps)
-            hyper_k_s = sds((ns, len(self._hyper_index), 3),
-                            np.float32)
-            epoch_k_s = sds((ns,), np.uint32)
-            do_up_k_s = sds((ns,), np.bool_)
-            key = ("run_steps",) + _areg.run_steps_sig(
-                data_shape, dtype, label_shape, mask_rs is None, 0, ns)
-            programs.append((key, lambda m=mask_rs, hs=hyper_k_s,
-                             es=epoch_k_s, us=do_up_k_s:
-                             self._multi_step.lower(
-                                 self.params, self.opt_state,
-                                 self.net_state, self.grad_acc,
-                                 data_s, labels_s, m, (), hs, es,
-                                 us, step_s, self._base_key)))
-
-        compiled = self._compile_programs(programs, "precompile_failed")
-        self.precompile_wall_s = time.perf_counter() - t_start
+            compiled = self._compile_programs(programs,
+                                              "precompile_failed")
+        # the span's duration: 0.0 without an enabled monitor
+        self.precompile_wall_s = span.dur_ns / 1e9
         self.precompile_programs = compiled
         if self._mon_on():
             self._mon.emit("precompile",
@@ -1406,6 +1452,7 @@ class NetTrainer:
         documented in doc/observability.md). A None/disabled monitor
         leaves the step path untouched."""
         self._mon = mon
+        self._scoped = set()             # a new stream gets the maps
         if self._initialized:
             self._emit_model_records()
 
@@ -1446,6 +1493,13 @@ class NetTrainer:
     def _mon_on(self) -> bool:
         return self._mon is not None and self._mon.enabled
 
+    def _span(self, name: str, **attrs: int):
+        """``Monitor.span`` of the attached monitor; the shared no-op
+        without one (monitor/spans.py)."""
+        if self._mon is None:
+            return NULL_SPAN
+        return self._mon.span(name, **attrs)
+
     def note_data_wait(self, seconds: float) -> None:
         """The drive loop reports time it spent blocked on the data
         iterator since the last dispatch; the next step record carries
@@ -1467,6 +1521,14 @@ class NetTrainer:
                        kind="first" if first else "recompile",
                        wall_ms=wall * 1e3, signature=repr(key))
         return True
+
+    def _loss_wait(self, loss, staged, sid: int) -> float:
+        """Block on a dispatch's loss (monitored runs only) and return
+        the dispatch's wall time in seconds: the extent of its three
+        spans, ``trainer.stage`` start to ``trainer.loss_wait`` end."""
+        with self._span("trainer.loss_wait", step=sid) as waited:
+            jax.block_until_ready(loss)  # cxxlint: disable=CXL003 -- monitor-gated: wall_ms must cover device compute; unmonitored runs never sync
+        return (waited.t1_ns - staged.t0_ns) / 1e9
 
     def _emit_step(self, kind: str, n_batches: int, examples: int,
                    wall: float, sig: tuple, lr: float) -> None:
@@ -1525,25 +1587,28 @@ class NetTrainer:
 
     def update(self, batch: DataBatch) -> None:
         assert self._initialized, "call init_model/load_model first"
-        t0 = time.perf_counter() if self._mon_on() else 0.0
-        data, labels, mask, extra = self._device_batch(batch)
-        hyper = self._hyper()
-        # step BEFORE the counter bump: batch i of the run folds RNG
-        # with step U*period+S (0-based), the same index scan_step uses
-        # as step0+i — so dropout/insanity masks are identical whether
-        # batches go through update(), update_many, or run_steps
-        step = self._step_scalar()
-        self.sample_counter += 1
-        do_update = self.sample_counter >= self.update_period
-        sig = _areg.update_sig(data.shape, data.dtype, labels.shape,
-                               mask is None, len(extra),
-                               bool(do_update))
-        out = self._call_step(
-            "update", sig, self._train_step,
-            (self.params, self.opt_state, self.net_state, self.grad_acc,
-             data, labels, mask, extra, hyper, self._epoch_u32(), step,
-             self._base_key),
-            do_update=bool(do_update))
+        sid = self._steps_total + 1      # this dispatch's step id
+        with self._span("trainer.stage", step=sid) as staged:
+            data, labels, mask, extra = self._device_batch(batch)
+            hyper = self._hyper()
+            # step BEFORE the counter bump: batch i of the run folds
+            # RNG with step U*period+S (0-based), the same index
+            # scan_step uses as step0+i — so dropout/insanity masks are
+            # identical whether batches go through update(),
+            # update_many, or run_steps
+            step = self._step_scalar()
+            self.sample_counter += 1
+            do_update = self.sample_counter >= self.update_period
+            sig = _areg.update_sig(data.shape, data.dtype, labels.shape,
+                                   mask is None, len(extra),
+                                   bool(do_update))
+        with self._span("trainer.enqueue", step=sid):
+            out = self._call_step(
+                "update", sig, self._train_step,
+                (self.params, self.opt_state, self.net_state,
+                 self.grad_acc, data, labels, mask, extra, hyper,
+                 self._epoch_u32(), step, self._base_key),
+                do_update=bool(do_update))
         (self.params, self.opt_state, self.net_state,
          self.grad_acc, loss, preds) = out
         self.programs.residency = None   # weights moved: the frozen
@@ -1552,9 +1617,8 @@ class NetTrainer:
         ex = self._local_batch_size(batch) - batch.num_batch_padd
         self._count_examples(ex)
         if self._mon_on():
-            jax.block_until_ready(loss)  # cxxlint: disable=CXL003 -- monitor-gated: wall_ms must cover device compute; unmonitored runs never sync
-            wall = time.perf_counter() - t0
-            self._emit_step("update", 1, ex, wall, sig,
+            self._emit_step("update", 1, ex,
+                            self._loss_wait(loss, staged, sid), sig,
                             float(hyper[0, 0]) if len(hyper) else 0.0)
         if do_update:
             self.sample_counter = 0
@@ -1577,23 +1641,26 @@ class NetTrainer:
         update_period=2 configs benchmark in this fused mode, equality-
         tested against the per-batch dispatch path."""
         assert self._initialized, "call init_model/load_model first"
-        t0 = time.perf_counter() if self._mon_on() else 0.0
-        data, labels, mask, extra = self._device_batch(batch)
-        n = int(n_steps)
-        period = self.update_period
-        S, U = self.sample_counter, self.update_counter
-        epochs = [U + (S + i) // period for i in range(n)]
-        hyper_k = np.stack([self._hyper(e) for e in epochs])
-        epoch_k = np.asarray(epochs, np.uint32)  # cxxlint: disable=CXL003 -- host python list of schedule epochs
-        do_up_k = np.asarray([((S + i + 1) % period) == 0  # cxxlint: disable=CXL003 -- host python list of apply flags
-                              for i in range(n)])
-        sig = _areg.run_steps_sig(data.shape, data.dtype, labels.shape,
-                                  mask is None, len(extra), n)
-        out = self._call_step(
-            "run_steps", sig, self._multi_step,
-            (self.params, self.opt_state, self.net_state, self.grad_acc,
-             data, labels, mask, extra, hyper_k, epoch_k, do_up_k,
-             self._step_scalar(), self._base_key))
+        sid = self._steps_total + 1      # this dispatch's step id
+        with self._span("trainer.stage", step=sid) as staged:
+            data, labels, mask, extra = self._device_batch(batch)
+            n = int(n_steps)
+            period = self.update_period
+            S, U = self.sample_counter, self.update_counter
+            epochs = [U + (S + i) // period for i in range(n)]
+            hyper_k = np.stack([self._hyper(e) for e in epochs])
+            epoch_k = np.asarray(epochs, np.uint32)  # cxxlint: disable=CXL003 -- host python list of schedule epochs
+            do_up_k = np.asarray([((S + i + 1) % period) == 0  # cxxlint: disable=CXL003 -- host python list of apply flags
+                                  for i in range(n)])
+            sig = _areg.run_steps_sig(data.shape, data.dtype,
+                                      labels.shape, mask is None,
+                                      len(extra), n)
+        with self._span("trainer.enqueue", step=sid):
+            out = self._call_step(
+                "run_steps", sig, self._multi_step,
+                (self.params, self.opt_state, self.net_state,
+                 self.grad_acc, data, labels, mask, extra, hyper_k,
+                 epoch_k, do_up_k, self._step_scalar(), self._base_key))
         (self.params, self.opt_state, self.net_state, self.grad_acc,
          loss) = out
         self.programs.residency = None
@@ -1601,9 +1668,8 @@ class NetTrainer:
         ex = (self._local_batch_size(batch) - batch.num_batch_padd) * n
         self._count_examples(ex)
         if self._mon_on():
-            jax.block_until_ready(loss)  # cxxlint: disable=CXL003 -- monitor-gated: wall_ms must cover device compute; unmonitored runs never sync
-            wall = time.perf_counter() - t0
-            self._emit_step("run_steps", n, ex, wall, sig,
+            self._emit_step("run_steps", n, ex,
+                            self._loss_wait(loss, staged, sid), sig,
                             float(hyper_k[0, 0, 0]) if hyper_k.size
                             else 0.0)
         self.update_counter = U + (S + n) // period
@@ -1625,39 +1691,41 @@ class NetTrainer:
         K = len(batches)
         if K == 1:
             return self.update(batches[0])
-        t0 = time.perf_counter() if self._mon_on() else 0.0
-        period = self.update_period
-        S, U = self.sample_counter, self.update_counter
-        epochs = [U + (S + i) // period for i in range(K)]
-        hyper_k = np.stack([self._hyper(e) for e in epochs])
-        epoch_k = np.asarray(epochs, np.uint32)  # cxxlint: disable=CXL003 -- host python list of schedule epochs
-        do_up = np.asarray([((S + i + 1) % period) == 0  # cxxlint: disable=CXL003 -- host python list of apply flags
-                            for i in range(K)])
-        step0 = self._step_scalar()
-        data_k = self._put_window([b.data for b in batches])
-        labels_k = self._put_window([b.label for b in batches])
-        masks = [self._mask(b) for b in batches]
-        if all(m is None for m in masks):
-            mask_k = None
-        else:       # mixed window: materialize ones for unpadded rows
-            mask_k = self._put_window(
-                [np.ones((self._local_batch_size(b),), np.float32)
-                 if m is None else m
-                 for m, b in zip(masks, batches)])
-        n_extra = len(batches[0].extra_data)
-        extra_k = tuple(
-            self._put_window([b.extra_data[j] for b in batches])
-            for j in range(n_extra))
-        collect = bool(self.eval_train and self._metrics.evals)
-        sig = _areg.update_many_sig(data_k.shape, data_k.dtype,
-                                    labels_k.shape, mask_k is None,
-                                    n_extra, K, collect)
-        out = self._call_step(
-            "update_many", sig, self._many_step,
-            (self.params, self.opt_state, self.net_state, self.grad_acc,
-             data_k, labels_k, mask_k, extra_k, hyper_k, epoch_k, do_up,
-             step0, self._base_key),
-            collect=collect)
+        sid = self._steps_total + 1      # this dispatch's step id
+        with self._span("trainer.stage", step=sid) as staged:
+            period = self.update_period
+            S, U = self.sample_counter, self.update_counter
+            epochs = [U + (S + i) // period for i in range(K)]
+            hyper_k = np.stack([self._hyper(e) for e in epochs])
+            epoch_k = np.asarray(epochs, np.uint32)  # cxxlint: disable=CXL003 -- host python list of schedule epochs
+            do_up = np.asarray([((S + i + 1) % period) == 0  # cxxlint: disable=CXL003 -- host python list of apply flags
+                                for i in range(K)])
+            step0 = self._step_scalar()
+            data_k = self._put_window([b.data for b in batches])
+            labels_k = self._put_window([b.label for b in batches])
+            masks = [self._mask(b) for b in batches]
+            if all(m is None for m in masks):
+                mask_k = None
+            else:   # mixed window: materialize ones for unpadded rows
+                mask_k = self._put_window(
+                    [np.ones((self._local_batch_size(b),), np.float32)
+                     if m is None else m
+                     for m, b in zip(masks, batches)])
+            n_extra = len(batches[0].extra_data)
+            extra_k = tuple(
+                self._put_window([b.extra_data[j] for b in batches])
+                for j in range(n_extra))
+            collect = bool(self.eval_train and self._metrics.evals)
+            sig = _areg.update_many_sig(data_k.shape, data_k.dtype,
+                                        labels_k.shape, mask_k is None,
+                                        n_extra, K, collect)
+        with self._span("trainer.enqueue", step=sid):
+            out = self._call_step(
+                "update_many", sig, self._many_step,
+                (self.params, self.opt_state, self.net_state,
+                 self.grad_acc, data_k, labels_k, mask_k, extra_k,
+                 hyper_k, epoch_k, do_up, step0, self._base_key),
+                collect=collect)
         (self.params, self.opt_state, self.net_state, self.grad_acc,
          loss, preds_k) = out
         self.programs.residency = None
@@ -1666,9 +1734,8 @@ class NetTrainer:
                  for b in batches)
         self._count_examples(ex)
         if self._mon_on():
-            jax.block_until_ready(loss)  # cxxlint: disable=CXL003 -- monitor-gated: wall_ms must cover device compute; unmonitored runs never sync
-            wall = time.perf_counter() - t0
-            self._emit_step("update_many", K, ex, wall, sig,
+            self._emit_step("update_many", K, ex,
+                            self._loss_wait(loss, staged, sid), sig,
                             float(hyper_k[0, 0, 0]) if hyper_k.size
                             else 0.0)
         self.update_counter = U + (S + K) // period

@@ -137,7 +137,8 @@ def _group_boundary_bwd(_, cts):
     # one atomic bundle the scheduler places as a unit, and the
     # SPMD-inserted all-reduce that consumes them hangs off the bundle
     # as an independently issuable collective. Identity numerics.
-    return (jax.lax.optimization_barrier(cts),)
+    with jax.named_scope("grad_sync"):
+        return (jax.lax.optimization_barrier(cts),)
 
 
 _group_boundary.defvjp(_group_boundary_fwd, _group_boundary_bwd)
@@ -155,7 +156,9 @@ def apply_group_boundaries(params, groups: Sequence[ReductionGroup]):
                 if lk in out and tag in out[lk]]
         if not keys:
             continue
-        marked = _group_boundary(tuple(out[lk][tag] for lk, tag in keys))
+        with jax.named_scope("grad_sync"):
+            marked = _group_boundary(
+                tuple(out[lk][tag] for lk, tag in keys))
         for (lk, tag), v in zip(keys, marked):
             out[lk][tag] = v
     return out

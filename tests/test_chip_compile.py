@@ -141,6 +141,16 @@ def test_alexnet_batch256_train_step_compiles_for_one_v5e(topo):
     live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert 0 < live < 16e9           # fits one v5e's HBM, with room
+    # the TPU's compiler keeps the layer scopes: what a program_scopes
+    # record would map of this step (the CPU backend drops some)
+    from cxxnet_tpu.monitor.spans import STEP_SCOPES, scope_map
+    module, scopes, fusions, mapped = scope_map(
+        compiled.as_text(), t.net.scope_names + STEP_SCOPES)
+    assert module == "jit_train_step"
+    assert mapped >= 0.9 * fusions > 0
+    paths = set(scopes.values())
+    assert {"jvp(conv.conv1)", "transpose(jvp(conv.conv1))",
+            "transpose(jvp(max_pooling.pool1))", "update"} <= paths, paths
 
 
 def test_alexnet_up2_scanned_step_pins_the_batch_row_major(topo):

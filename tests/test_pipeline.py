@@ -346,7 +346,9 @@ def test_prefetch_transform_releases_host_buffer():
     assert snap["buffers_reused"] > 0
     assert snap["buffer_reuse_rate"] > 0.5
     assert snap["h2d_batches"] >= 40
-    assert 0.0 <= snap["h2d_overlap_ratio"] <= 1.0
+    # H2D time is the io.h2d_* spans': a chain with no monitor attached
+    # reads no clock
+    assert snap["h2d_ms"] == 0.0
 
 
 def test_prefetch_never_releases_aliasing_transform():
@@ -608,7 +610,7 @@ def test_precompile_cli_stream_criterion(tmp_path, capsys):
     assert [p["round"] for p in pipes] == [0, 1]
     for p in pipes:
         assert 0.0 <= p["buffer_reuse_rate"] <= 1.0
-        assert 0.0 <= p["h2d_overlap_ratio"] <= 1.0
+        assert p["h2d_ms"] > 0.0          # the io.h2d_* spans' sum
         assert p["h2d_batches"] == 6      # one per delivered batch
     waits = [r for r in recs if r["event"] == "io_wait"]
     assert all(0 <= w["p50_ms"] <= w["p99_ms"] <= w["max_ms"]
