@@ -69,8 +69,29 @@ class IIterator:
 
     span = staticmethod(no_span)
 
+    # an image source that can hand out contiguous uint8 RGB pixels
+    # says so here; ``emit_uint8`` is switched on by the augmenter above
+    # it once a consumer took the normalisation over (defer_normalize)
+    can_emit_uint8 = False
+    emit_uint8 = False
+
     def set_param(self, name: str, val: str) -> None:
         pass
+
+    def defer_normalize(self, accept=None):
+        """The consumer of this chain offers to run the input
+        normalisation itself (a ``NetTrainer`` does it as the first ops
+        of the compiled step). Returns the spec handed over, ``(mean,
+        scale)`` with ``mean`` a float32 ``(C,)`` / ``(H, W, C)`` array
+        or None, or None when the chain keeps the work (it then delivers
+        normalised float32, as without the question). ``accept(spec)``
+        lets the consumer turn a spec down before anything changes.
+        After a yes, from the chain's next ``before_first`` on, image
+        batches are raw uint8 pixels, cropped and mirrored only: a
+        batch's dtype says who normalises it. Adapters forward the
+        question to their base; ``AugmentAdapter`` answers it."""
+        base = getattr(self, "base", None)
+        return None if base is None else base.defer_normalize(accept)
 
     def init(self) -> None:
         pass
@@ -96,6 +117,16 @@ class IIterator:
         self.before_first()
         while self.next():
             yield self.value()
+
+
+def rgb_pixels(bgr: np.ndarray, uint8: bool) -> np.ndarray:
+    """OpenCV's decoded BGR image as RGB: float32 (the host path, which
+    normalises next) or contiguous uint8 (``emit_uint8``: one cvtColor,
+    GIL released, instead of a float cast of a negative-stride view)."""
+    if uint8:
+        import cv2
+        return cv2.cvtColor(bgr, cv2.COLOR_BGR2RGB)
+    return bgr[:, :, ::-1].astype(np.float32)
 
 
 def shape_from_conf(val: str) -> Tuple[int, int, int]:

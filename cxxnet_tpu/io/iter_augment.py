@@ -13,8 +13,10 @@ and ``image_augmenter-inl.hpp:13-222``:
 - affine warp (rotation / shear / aspect / random scale) through
   cv2.warpAffine when any of those knobs are set
 
-All work happens host-side on NumPy instances, feeding the device
-pipeline — the TPU analogue of the reference's OpenCV host augmentation.
+Crop, mirror, warps and jitter always run host-side on NumPy
+instances, feeding the device pipeline — the TPU analogue of the
+reference's OpenCV host augmentation. Mean and scale run here too unless
+the chain's consumer took them over (below).
 
 Two execution modes:
 
@@ -31,6 +33,18 @@ Two execution modes:
   serializes. Output is bit-identical (each row draws from the same
   ``_inst_rng(index)`` stream); ``augment_vectorize = 0`` forces the
   per-instance path.
+
+Device-side normalisation (``defer_normalize``, io/data.py): a consumer
+that runs ``(float32(x) - mean) * scale`` itself (``NetTrainer``, as the
+first ops of the compiled step) asks the chain for the spec. This
+adapter answers ``(mean, scale)`` only in the deferred mode, and only
+over a source that can decode to uint8 (``can_emit_uint8``); any other
+chain answers None and keeps every byte of the float32 host path. After
+a yes, from the next ``before_first`` on, the source decodes to
+contiguous uint8 RGB, the ring buffer is uint8 (a quarter of the bytes
+to fill and to ship) and ``assemble_deferred`` only crops and mirrors:
+same crop, same mirror, no whole-batch float pass. Nobody asked = the
+chain delivers normalised float32, as ever.
 """
 
 from __future__ import annotations
@@ -77,6 +91,10 @@ class AugmentAdapter(IIterator):
         self.rng = np.random.RandomState(self.kRandMagic)
         self.meanimg: Optional[np.ndarray] = None
         self._seed_base = self.kRandMagic
+        # assemble_deferred's own generator, re-seeded per row: one
+        # thread assembles, and constructing a RandomState costs ~20x
+        # its seeding
+        self._row_rng = np.random.RandomState(0)
         self.nthread = min(8, os.cpu_count() or 4)
         self._pool = None
         self._buf: List[DataInst] = []
@@ -86,6 +104,10 @@ class AugmentAdapter(IIterator):
         # BatchAdapter when the knob set allows deferral)
         self.vectorize = 1
         self._deferred = False
+        # mean/scale handed to the consumer (defer_normalize): asked
+        # by the consumer's thread, in force (base.emit_uint8) from the
+        # next before_first of the thread that runs the chain
+        self._norm_asked = False
 
     def set_param(self, name: str, val: str) -> None:
         self.base.set_param(name, val)
@@ -210,6 +232,8 @@ class AugmentAdapter(IIterator):
         self.base.before_first()
 
     def before_first(self) -> None:
+        if self._norm_asked:
+            self.base.emit_uint8 = True
         self.base.before_first()
         self._buf, self._bufpos = [], 0
 
@@ -220,8 +244,10 @@ class AugmentAdapter(IIterator):
         deterministic regardless of decode/augment thread interleaving
         (the serial rand_r of the reference cannot survive a parallel
         pipeline)."""
-        return np.random.RandomState(
-            (self._seed_base * 2654435761 + index * 97 + 13) % (2**31))
+        return np.random.RandomState(self._inst_seed(index))
+
+    def _inst_seed(self, index: int) -> int:
+        return (self._seed_base * 2654435761 + index * 97 + 13) % (2**31)
 
     def _need_affine(self) -> bool:
         return (self.max_rotate_angle > 0 or self.max_shear_ratio > 0
@@ -321,10 +347,13 @@ class AugmentAdapter(IIterator):
         return img[ys:ys + ty, xs:xs + tx]
 
     def _is_float_work(self) -> bool:
-        """True when any knob forces float math (mean/scale/jitter);
+        """True when any knob forces float math HERE (mean/scale/
+        jitter, unless the consumer took mean and scale over);
         otherwise uint8 input stays uint8 through crop/mirror/warp so
         the batch ships to the device at 1/4 the bytes (device-side
         normalization is the TPU-idiomatic input path)."""
+        if self.base.emit_uint8:
+            return False                 # can_defer() excludes jitter
         return (self.scale != 1.0 or self.meanimg is not None
                 or self.mean_value is not None
                 or self.max_random_contrast > 0
@@ -388,6 +417,30 @@ class AugmentAdapter(IIterator):
         self._deferred = self.can_defer()
         return self._deferred
 
+    def defer_normalize(self, accept=None):
+        """Hand ``(mean, scale)`` to the consumer (io/data.py) when
+        this chain's float work is exactly ``(x - mean) * scale``: the
+        deferred mode (plain crop / mirror, no warp, crop-resize or
+        jitter: ``can_defer``) over a source that decodes to uint8.
+        ``mean`` follows ``_transform``'s precedence: the mean image
+        when its shape is the crop's, else ``mean_value``, else None."""
+        if not (self._deferred and self.base.can_emit_uint8):
+            return None
+        ch, ty, tx = self.shape
+        mean = None
+        if self.meanimg is not None and self.meanimg.shape == (ty, tx, ch):
+            if self.meanimg.dtype != np.float32:
+                return None              # the host subtracts a float64
+                #                          image in float64: other bits
+            mean = self.meanimg
+        elif self.mean_value is not None:
+            mean = self.mean_value
+        spec = (mean, np.float32(self.scale))
+        if accept is not None and not accept(spec):
+            return None
+        self._norm_asked = True
+        return spec
+
     def deferred_row_spec(self, inst: DataInst):
         """(row_shape, dtype) a deferred batch buffer needs for this
         instance stream — the post-crop shape and the same dtype rule
@@ -407,20 +460,32 @@ class AugmentAdapter(IIterator):
         float work (mean/scale) as whole-batch array ops. Bit-identical
         to the per-instance path: each row draws from the same
         _inst_rng(index) stream in the same order, and the elementwise
-        float ops run in the same sequence."""
+        float ops run in the same sequence. A uint8 buffer has no float
+        work (none configured, or the consumer's: defer_normalize); its
+        mirrored rows go through cv2.flip straight into the row, a
+        tenth of the cost of numpy's negative-stride copy."""
         _, ty, tx = self.shape
+        rng = self._row_rng              # .seed(s) == RandomState(s)
+        flip = None
+        if buf.dtype == np.uint8:
+            import cv2
+            flip = cv2.flip
         for i, inst in enumerate(insts):
             data = np.asarray(inst.data)
             if data.ndim != 3:
                 buf[i] = data
                 continue
-            rng = self._inst_rng(inst.index)
+            rng.seed(self._inst_seed(inst.index))
             h, w = data.shape[:2]
             ys, xs = self._crop_start(rng, h, w, ty, tx)
             view = data[ys:ys + ty, xs:xs + tx]
-            if self._mirror_draw(rng):
-                view = view[:, ::-1]
-            buf[i] = view
+            if not self._mirror_draw(rng):
+                buf[i] = view
+            elif flip is not None and data.dtype == np.uint8 \
+                    and data.flags.c_contiguous:
+                flip(view, 1, dst=buf[i])
+            else:
+                buf[i] = view[:, ::-1]
         if buf.dtype == np.uint8 or buf.ndim < 2:
             return
         if buf.ndim == 4:

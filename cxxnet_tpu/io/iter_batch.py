@@ -135,6 +135,7 @@ class BatchAdapter(IIterator):
         self._epoch_done = False
         self._ring = _BufferRing()
         self._aug: Optional[AugmentAdapter] = None
+        self.data_dtype = ""             # of the last assembled batch
 
     def set_param(self, name: str, val: str) -> None:
         self.base.set_param(name, val)
@@ -199,6 +200,7 @@ class BatchAdapter(IIterator):
     def _assemble(self, insts: List[DataInst], npadd: int) -> DataBatch:
         buf = self._ring.acquire(self._buf_spec(insts[0]))
         data, label, index = buf.data, buf.label, buf.index
+        self.data_dtype = data.dtype.name
         if self._aug is not None:
             self._aug.assemble_deferred(data, insts)
         else:
@@ -255,7 +257,9 @@ class BatchAdapter(IIterator):
         if nzero and self._aug is not None:
             # parity with the per-instance path, which pads with zeros
             # AFTER the transform: the deferred whole-batch mean/scale
-            # must not leak (-mean*scale) into the filler rows
+            # must not leak (-mean*scale) into the filler rows. (A
+            # consumer that normalises uint8 rows itself zeroes the
+            # rows its pad mask excludes: Net.forward.)
             self._out.data[self.batch_size - nzero:] = 0
         if self.test_skipread and self._head is None:
             self._head = self._out
@@ -662,9 +666,14 @@ def pipeline_snapshot(it) -> Optional[dict]:
     """Collect (and reset) per-round pipeline counters from an iterator
     chain: buffer reuse from BatchAdapter rings, H2D staging time (the
     ``io.h2d_issue`` + ``io.h2d_wait`` spans; 0 on a chain with no
-    monitor attached) and consumer waits from PrefetchIterators.
+    monitor attached) and consumer waits from PrefetchIterators, and
+    what the chain delivers: ``input_dtype`` of the last batch
+    assembled and ``norm_on_device`` (1 when mean/scale were handed to
+    the consumer, ``defer_normalize``).
     Returns None when the chain has neither (nothing to report)."""
     found = False
+    input_dtype = ""
+    norm_on_device = 0
     alloc = reuse = batches = 0
     h2d_ms = 0.0
     h2d_batches = 0
@@ -677,6 +686,9 @@ def pipeline_snapshot(it) -> Optional[dict]:
             alloc += s["allocated"]
             reuse += s["reused"]
             batches += s["batches"]
+            input_dtype = input_dtype or node.data_dtype
+        if isinstance(node, AugmentAdapter):
+            norm_on_device = int(node.base.emit_uint8)
         if isinstance(node, PrefetchIterator):
             found = True
             s = node.h2d_snapshot()
@@ -693,4 +705,6 @@ def pipeline_snapshot(it) -> Optional[dict]:
             "buffer_reuse_rate": (reuse / total) if total else 0.0,
             "h2d_ms": round(h2d_ms, 3),
             "h2d_batches": h2d_batches,
-            "consumer_wait_ms": round(wait_ms, 3)}
+            "consumer_wait_ms": round(wait_ms, 3),
+            "input_dtype": input_dtype,
+            "norm_on_device": norm_on_device}

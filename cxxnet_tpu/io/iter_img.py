@@ -3,7 +3,8 @@
 Parity with ``/root/reference/src/io/iter_img-inl.hpp:17-138``: each row
 of ``image_list`` is ``<index> <label...> <path>``; images are decoded
 (OpenCV) relative to ``image_root``, emitted as float32 NHWC in [0,255]
-(scaling such as ``divideby`` is the augmenter's job), optional
+(scaling such as ``divideby`` is the augmenter's job; uint8 RGB once
+``emit_uint8`` is on, io/data.py ``defer_normalize``), optional
 per-epoch shuffle, ``label_width`` labels per row.
 """
 
@@ -14,11 +15,13 @@ from typing import List, Optional
 
 import numpy as np
 
-from .data import DataInst, IIterator, resolve_data_shard
+from .data import DataInst, IIterator, resolve_data_shard, rgb_pixels
 from ..utils.stream import open_stream
 
 
 class ImageIterator(IIterator):
+    can_emit_uint8 = True
+
     def __init__(self):
         self.image_list = ""
         self.image_root = ""
@@ -89,7 +92,7 @@ class ImageIterator(IIterator):
         if img is None:
             raise IOError("cannot decode image %r" % full)
         # BGR->RGB to match the reference's channel order convention
-        return img[:, :, ::-1].astype(np.float32)
+        return rgb_pixels(img, self.emit_uint8)
 
     def next(self) -> bool:
         if self.idx >= len(self.rows):

@@ -26,21 +26,13 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 from .binpage import iter_objects
-from .data import DataInst, IIterator
+from .data import DataInst, IIterator, rgb_pixels
 from ..utils.stream import open_stream
 
 
-def _decode(args: Tuple[int, np.ndarray, bytes]) -> Optional[DataInst]:
-    import cv2
-    index, label, raw = args
-    img = cv2.imdecode(np.frombuffer(raw, np.uint8), cv2.IMREAD_COLOR)
-    if img is None:
-        return None
-    return DataInst(index=index, data=img[:, :, ::-1].astype(np.float32),
-                    label=label)
-
-
 class ImageBinIterator(IIterator):
+    can_emit_uint8 = True
+
     def __init__(self):
         self.image_list: List[str] = []
         self.image_bin: List[str] = []
@@ -177,6 +169,16 @@ class ImageBinIterator(IIterator):
                         % (binf, lst))
                 yield (rows[i][0], rows[i][1], raw)
 
+    def _decode(self, args: Tuple[int, np.ndarray, bytes]
+                ) -> Optional[DataInst]:
+        import cv2
+        index, label, raw = args
+        img = cv2.imdecode(np.frombuffer(raw, np.uint8), cv2.IMREAD_COLOR)
+        if img is None:
+            return None
+        return DataInst(index=index,
+                        data=rgb_pixels(img, self.emit_uint8), label=label)
+
     def before_first(self) -> None:
         self._gen = self._records()
         self._buf, self._bufpos = [], 0
@@ -189,7 +191,7 @@ class ImageBinIterator(IIterator):
                 break
         if not chunk:
             return False
-        insts = [i for i in self._pool.map(_decode, chunk)
+        insts = [i for i in self._pool.map(self._decode, chunk)
                  if i is not None]
         self._buf, self._bufpos = insts, 0
         return True

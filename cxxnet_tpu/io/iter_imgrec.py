@@ -12,8 +12,10 @@ reads image records from a .rec archive, decodes JPEG in a thread pool
   (label_width > 1 support, :120-147) without repacking
 - ``shuffle_chunk``: shuffles decode chunks within a window
 
-Emits DataInst (float32 NHWC in [0,255]); stack augment/batch adapters
-on top (the factory wires this like the reference's chained iterators).
+Emits DataInst (float32 NHWC in [0,255], or contiguous uint8 RGB once
+the augmenter above switched ``emit_uint8`` on: ``defer_normalize``,
+io/data.py); stack augment/batch adapters on top (the factory wires
+this like the reference's chained iterators).
 """
 
 from __future__ import annotations
@@ -24,7 +26,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from .data import DataInst, IIterator
+from .data import DataInst, IIterator, rgb_pixels
 from .recordio import (RAW_TENSOR_FLAG, RecordIOReader,
                        parse_image_record, record_flag,
                        unpack_raw_tensor_record)
@@ -32,6 +34,8 @@ from ..utils.stream import open_stream
 
 
 class ImageRecordIterator(IIterator):
+    can_emit_uint8 = True                # JPEG and raw-tensor records
+
     def __init__(self):
         self.path_imgrec = ""
         self.path_imglist = ""
@@ -54,7 +58,6 @@ class ImageRecordIterator(IIterator):
         self.nthread = max(4, os.cpu_count() or 4)
         self.shuffle = 0
         self.seed = 0
-        self.decode_uint8 = 0
         self._label_map: Optional[Dict[int, np.ndarray]] = None
         self._readers: List = []
         self._pool: Optional[ThreadPoolExecutor] = None
@@ -90,10 +93,6 @@ class ImageRecordIterator(IIterator):
             self.shuffle = int(val)
         if name == "seed_data":
             self.seed = int(val)
-        if name == "decode_uint8":
-            # keep pixels uint8 through the host pipeline; the device
-            # casts to compute dtype (4x less host->device traffic)
-            self.decode_uint8 = int(val)
 
     # -- init ------------------------------------------------------------
 
@@ -206,7 +205,7 @@ class ImageRecordIterator(IIterator):
         if record_flag(rec) == RAW_TENSOR_FLAG:
             # pre-decoded uint8 tensor record: no jpeg in the loop
             index, label, data = unpack_raw_tensor_record(rec)
-            if not self.decode_uint8:
+            if not self.emit_uint8:
                 data = data.astype(np.float32)
             return self._with_label(index, label, data)
         import cv2
@@ -215,10 +214,8 @@ class ImageRecordIterator(IIterator):
                            cv2.IMREAD_COLOR)
         if img is None:
             return None
-        data = img[:, :, ::-1]                        # BGR -> RGB
-        if not self.decode_uint8:
-            data = data.astype(np.float32)
-        return self._with_label(index, label, data, labels)
+        return self._with_label(index, label,
+                                rgb_pixels(img, self.emit_uint8), labels)
 
     def _with_label(self, index: int, label: float,
                     data: np.ndarray,

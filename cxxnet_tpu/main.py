@@ -507,6 +507,8 @@ class LearnTask:
                             trainer.finetune_from(
                                 self.model_in, remap=self.finetune_remap,
                                 strict=bool(self.finetune_strict))
+                self._defer_normalize(
+                    trainer, [itr_train] + [it for _, it in eval_iters])
                 if self.task == "continual":
                     return self._task_continual(cfg, trainer,
                                                 itr_train, eval_iters)
@@ -517,6 +519,7 @@ class LearnTask:
             # artifact_load accounting during load_model
             trainer.set_monitor(self._mon)
             trainer.load_model(self.model_in)
+            self._defer_normalize(trainer, [pred_iter or itr_train])
             if self.task == "pred":
                 return self._task_predict(trainer, pred_iter or itr_train)
             if self.task in ("extract_feature", "extract",
@@ -636,6 +639,20 @@ class LearnTask:
                      exit_code=EXIT_PREEMPTED)
         return EXIT_PREEMPTED
 
+    @staticmethod
+    def _defer_normalize(trainer, iters) -> None:
+        """The task runner hands a trainer its iterators, so it asks
+        each chain whether the trainer may run the normalisation
+        (``IIterator.defer_normalize``): a plain crop / mirror image
+        chain then delivers uint8 pixels and ``(x - mean) * scale``
+        runs as the first ops of the step. The first chain in the
+        list (the training data) sets the trainer's spec; a chain that
+        offers another keeps the host path. Before ``precompile``,
+        which lowers for the dtype the chains will deliver."""
+        for it in iters:
+            if it is not None:
+                it.defer_normalize(trainer.adopt_input_norm)
+
     def _task_train(self, trainer, itr_train, eval_iters) -> int:
         assert itr_train is not None, "train requires a data block"
         mon = self._mon
@@ -650,7 +667,8 @@ class LearnTask:
         io_hist = None
         if monitored:
             mon.emit("run_start", **run_metadata(
-                self.task, self._cfg_stream, trainer.mesh))
+                self.task, self._cfg_stream, trainer.mesh),
+                input_norm=trainer.input_norm_record())
             topo = current_topology()
             if topo.num_hosts > 1:
                 # the input/mesh topology this dist (or dryrun) run
@@ -864,7 +882,8 @@ class LearnTask:
             itr_train.set_transform(trainer.device_put_batch)
         if mon.enabled:
             mon.emit("run_start", **run_metadata(
-                "continual", self._cfg_stream, trainer.mesh))
+                "continual", self._cfg_stream, trainer.mesh),
+                input_norm=trainer.input_norm_record())
         handlers = []
         try:
             handlers = self._install_preempt_handlers()

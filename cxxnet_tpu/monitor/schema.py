@@ -35,9 +35,11 @@ REQUIRED: Dict[str, tuple] = {
                 "p99_ms", "buckets"),
     # per-round input-pipeline health: zero-copy assembly reuse, H2D
     # staging time (the io.h2d_* spans' sum) and the consumer's waits
-    # (doc/observability.md)
+    # (doc/observability.md); input_dtype is what the chain's batches
+    # hold (uint8 = raw pixels), norm_on_device whether mean/scale run
+    # in the compiled step (1) or on the host (0)
     "pipeline": ("round", "buffer_reuse_rate", "batches", "h2d_ms",
-                 "consumer_wait_ms"),
+                 "consumer_wait_ms", "input_dtype", "norm_on_device"),
     # one interval of host work on the profiler's clock
     # (monitor/spans.py): t is the END in seconds, t0_ns/dur_ns are
     # time.time_ns() integers, parent is the enclosing span's id on

@@ -140,7 +140,8 @@ class SpanRecorder:
 # -- device scopes ---------------------------------------------------------
 
 # step-level scopes the trainer opens beside the net's per-layer ones
-STEP_SCOPES = ("window", "loss", "grad_cast", "update", "grad_sync")
+STEP_SCOPES = ("window", "input_norm", "loss", "grad_cast", "update",
+               "grad_sync")
 
 _MODULE_RE = re.compile(r"^HloModule\s+([^\s,]+)")
 _COMPUTATION_RE = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$")

@@ -37,6 +37,10 @@ class FuncNet:
         self.layer_objs: List[Layer] = []
         self.node_shapes: List[Optional[Shape3]] = \
             [None] * graph.num_nodes
+        # (mean, scale) a non-floating input is normalised with at the
+        # top of forward; None = cast only. Set by the trainer that
+        # adopted it from an iterator chain (NetTrainer.set_input_norm)
+        self.input_norm = None
         self._build()
 
     # -- construction ----------------------------------------------------
@@ -291,8 +295,13 @@ class FuncNet:
         nodes: List[Optional[jnp.ndarray]] = [None] * g.num_nodes
         if not jnp.issubdtype(data.dtype, jnp.floating):
             # uint8 pipeline: pixels ship to the device raw and are
-            # normalized here (4x less host->device traffic)
-            data = data.astype(jnp.float32)
+            # normalized here (4x less host->device traffic), in
+            # float32 and in the host augmenter's order (buf -= mean;
+            # buf *= scale), so the first layer sees the host path's
+            # values to the bit. A floating input is some host's
+            # finished work and passes untouched
+            with jax.named_scope("input_norm"):
+                data = self._normalize_raw(data, mask)
         nodes[0] = data
         for i in range(g.extra_data_num):
             nodes[1 + i] = extra[i]
@@ -349,6 +358,24 @@ class FuncNet:
             for ni, v in zip(info.nindex_out, outs):
                 nodes[ni] = v
         return nodes, new_state, loss_inputs
+
+    def _normalize_raw(self, data, mask):
+        data = data.astype(jnp.float32)
+        if self.input_norm is None:
+            return data
+        mean, scale = self.input_norm
+        if mean is not None:
+            data = data - mean
+        if scale != 1:
+            data = data * scale
+        if mask is not None:
+            # the host path zero-fills a short batch's tail AFTER it
+            # normalised; here the filler is raw zeros, and the rows
+            # the pad mask excludes are set to zero instead (no layer
+            # or loss reads them: that is what the mask says)
+            keep = mask.reshape((-1,) + (1,) * (data.ndim - 1)) > 0
+            data = jnp.where(keep, data, 0.0)
+        return data
 
     # -- loss ------------------------------------------------------------
 

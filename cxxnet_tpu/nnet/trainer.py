@@ -130,9 +130,12 @@ class NetTrainer:
         self.compile_cache_dir = ""      # persistent XLA compilation
         #                                  cache (compile once per
         #                                  machine, not per run)
-        self.precompile_dtype = "float32"  # input dtype precompile()
-        #                                  lowers for (uint8 pipelines
-        #                                  set precompile_dtype=uint8)
+        self.input_norm = None           # (mean, scale) adopted from
+        #                                  an iterator chain whose
+        #                                  uint8 batches this trainer
+        #                                  normalises in the step
+        #                                  (set_input_norm); None =
+        #                                  batches arrive normalised
         self.serve_dtype = "float32"     # eval/pred/serve compute
         #                                  dtype: float32 | bfloat16 |
         #                                  int8 | fp8 — int8/fp8 need a
@@ -245,11 +248,6 @@ class NetTrainer:
                 self.dispatch_period = max(1, int(val))
             if name == "compile_cache_dir":
                 self.compile_cache_dir = val
-            if name == "precompile_dtype":
-                if val not in ("float32", "uint8"):
-                    raise ValueError(
-                        "precompile_dtype must be float32 or uint8")
-                self.precompile_dtype = val
             if name == "input_layout":
                 if val not in ("none", "rowmajor"):
                     raise ValueError(
@@ -396,6 +394,7 @@ class NetTrainer:
         self.programs.reset()            # rebuilt programs orphan any
         #                                  earlier AOT executables
         self._scoped = set()
+        self._bind_input_norm()          # a rebuilt net keeps the spec
         self._b_shard = batch_sharding(mesh)
         self._probe_input_layout()
         self._repl = replicated(mesh)
@@ -709,6 +708,78 @@ class NetTrainer:
                                          static_argnames=("nodes_wanted",),
                                          donate_argnums=(2, 3))
         self._build_resident_prep()
+
+    # -- device-side input normalisation ---------------------------------
+
+    def set_input_norm(self, mean, scale=1.0) -> None:
+        """Adopt an iterator chain's normalisation (``IIterator.
+        defer_normalize``): from now on a NON-FLOATING batch is raw
+        pixels and every program of this trainer starts with
+        ``(float32(x) - mean) * scale`` (``Net.forward``, scope
+        ``input_norm``); a floating batch lowers to the program it
+        always did. ``mean`` is None, ``(C,)`` or ``(H, W, C)``. The
+        identity spec (no mean, scale 1) adopts too: it says the
+        chain's batches are uint8, which ``precompile`` lowers for."""
+        assert self._initialized, "call init_model/load_model first"
+        spec = (None if mean is None else np.asarray(mean, np.float32),
+                np.float32(scale))
+        if self.input_norm is not None and self._same_norm(spec):
+            return
+        self.input_norm = spec
+        self._bind_input_norm()
+        # a trace of a non-floating signature made before this call
+        # has the old constants baked in; registry keys carry the
+        # spec's digest (_dtype_tag), the jit fallback forgets
+        for fn in (self._train_step, self._multi_step, self._many_step,
+                   self._pred_step, self._pred_step_donate):
+            fn.clear_cache()
+
+    def adopt_input_norm(self, spec) -> bool:
+        """The ``accept`` of ``IIterator.defer_normalize``: the first
+        spec offered is adopted, a later chain is taken over only when
+        it offers the same one (one trainer, one normalisation; a
+        chain turned down keeps normalising on the host)."""
+        if self.input_norm is None:
+            self.set_input_norm(*spec)
+        return self._same_norm(spec)
+
+    def _same_norm(self, spec) -> bool:
+        (m0, s0), (m1, s1) = self.input_norm, spec
+        return bool(s0 == s1 and (
+            m1 is None if m0 is None
+            else m1 is not None and np.array_equal(m0, m1)))
+
+    def _bind_input_norm(self) -> None:
+        mean, scale = self.input_norm or (None, 1.0)
+        identity = mean is None and scale == 1
+        self.net.input_norm = None if identity else self.input_norm
+        self._norm_digest = ""
+        if not identity:
+            import hashlib
+            h = hashlib.sha1(scale.tobytes())
+            if mean is not None:
+                h.update(repr(mean.shape).encode() + mean.tobytes())
+            self._norm_digest = h.hexdigest()[:12]
+
+    def _dtype_tag(self, dtype) -> str:
+        """The dtype element of a dispatch signature. A non-floating
+        input under an adopted normalisation runs a program with that
+        mean and scale baked in, so its key carries their digest: a
+        sealed program of another spec can never answer for it."""
+        if not self._norm_digest or jnp.issubdtype(dtype, jnp.floating):
+            return str(dtype)
+        return "%s/norm:%s" % (dtype, self._norm_digest)
+
+    def input_norm_record(self) -> Dict[str, Any]:
+        """The adopted spec for ``run_start``: ``mean`` the per-channel
+        values, or the mean image's shape."""
+        if self.input_norm is None:
+            return {"adopted": False, "mean": None, "scale": 1.0}
+        mean, scale = self.input_norm
+        if mean is not None:
+            mean = [float(v) for v in mean] if mean.ndim == 1 \
+                else "image%r" % (tuple(mean.shape),)
+        return {"adopted": True, "mean": mean, "scale": float(scale)}
 
     def _probe_input_layout(self) -> None:
         """input_layout = rowmajor: decide ONCE whether the batch
@@ -1031,8 +1102,8 @@ class NetTrainer:
 
     def _call_pred(self, data, mask, extra, nodes_wanted):
         params, net_state = self._pred_operands()
-        sig = self.pred_sig(data.shape, data.dtype, mask is None,
-                            len(extra), nodes_wanted)
+        sig = self.pred_sig(data.shape, self._dtype_tag(data.dtype),
+                            mask is None, len(extra), nodes_wanted)
         return self._call_step(
             "pred", sig, self._pred_step,
             (params, net_state, data, mask, extra),
@@ -1067,8 +1138,10 @@ class NetTrainer:
         — and with ``compile_cache_dir`` set they amortize across runs.
 
         Shapes must be fully known: batch_size from the config, the
-        instance shape from ``input_shape``, input dtype from
-        ``precompile_dtype`` (uint8 for raw-pixel pipelines). Nets with
+        instance shape from ``input_shape``, input dtype from what the
+        run's iterator handed over: uint8 once a normalisation was
+        adopted (``set_input_norm``, before this call), else float32.
+        Nets with
         ``extra_data`` inputs and eval iterators with a different
         batch_size fall back to the jit path for those dispatches —
         precompile never changes results, only when compilation
@@ -1082,8 +1155,9 @@ class NetTrainer:
         with self._span("setup.precompile") as span:
             from ..io.data import inst_array_shape
             self._enable_persistent_cache()
-            dtype = np.dtype(np.uint8 if self.precompile_dtype == "uint8"
-                             else np.float32)
+            dtype = np.dtype(np.float32 if self.input_norm is None
+                             else np.uint8)
+            tag = self._dtype_tag(dtype)
             # GLOBAL batch shapes: multi-process dispatch arrays come out of
             # make_array_from_process_local_data with the global leading dim
             # (each rank contributes batch_size/world rows), and the runtime
@@ -1117,7 +1191,7 @@ class NetTrainer:
             for mask_v in (mask_variants if per_batch else []):
                 for du in do_up_variants:
                     key = ("update",) + _areg.update_sig(
-                        data_shape, dtype, label_shape, mask_v is None, 0,
+                        data_shape, tag, label_shape, mask_v is None, 0,
                         bool(du))
                     programs.append((key, lambda m=mask_v, d=du:
                                      self._train_step.lower(
@@ -1139,7 +1213,7 @@ class NetTrainer:
                     do_up_s = sds((K,), np.bool_)
                     collect = bool(self.eval_train and self._metrics.evals)
                     key = ("update_many",) + _areg.update_many_sig(
-                        (K,) + data_shape, dtype, (K,) + label_shape,
+                        (K,) + data_shape, tag, (K,) + label_shape,
                         mask_k is None, 0, K, collect)
                     programs.append((key, lambda mk=mask_k, c=collect,
                                      ds=data_k_s, ls=labels_k_s,
@@ -1154,7 +1228,7 @@ class NetTrainer:
                 if self._metric_nodes:
                     nodes = tuple(self._metric_nodes)
                     key = ("pred",) + self.pred_sig(
-                        data_shape, dtype, mask_v is None, 0, nodes)
+                        data_shape, tag, mask_v is None, 0, nodes)
                     # operands resolved at lower time: under weight
                     # residency the eval dispatches pass the frozen serve
                     # tree, so the precompiled program must take the same
@@ -1178,7 +1252,7 @@ class NetTrainer:
                 epoch_k_s = sds((ns,), np.uint32)
                 do_up_k_s = sds((ns,), np.bool_)
                 key = ("run_steps",) + _areg.run_steps_sig(
-                    data_shape, dtype, label_shape, mask_rs is None, 0, ns)
+                    data_shape, tag, label_shape, mask_rs is None, 0, ns)
                 programs.append((key, lambda m=mask_rs, hs=hyper_k_s,
                                  es=epoch_k_s, us=do_up_k_s:
                                  self._multi_step.lower(
@@ -1262,7 +1336,7 @@ class NetTrainer:
             mask_s = None if rows == n else jax.ShapeDtypeStruct(
                 (n,), np.float32, sharding=self._b_shard)
             key = ("pred",) + self.pred_sig(
-                data_shape, dt, mask_s is None, 0, nodes)
+                data_shape, self._dtype_tag(dt), mask_s is None, 0, nodes)
             programs.append((key, lambda ds=data_structs[n], m=mask_s,
                              pj=pred_jit:
                              pj.lower(params_t, state_t, ds,
@@ -1599,9 +1673,10 @@ class NetTrainer:
             step = self._step_scalar()
             self.sample_counter += 1
             do_update = self.sample_counter >= self.update_period
-            sig = _areg.update_sig(data.shape, data.dtype, labels.shape,
-                                   mask is None, len(extra),
-                                   bool(do_update))
+            sig = _areg.update_sig(data.shape,
+                                   self._dtype_tag(data.dtype),
+                                   labels.shape, mask is None,
+                                   len(extra), bool(do_update))
         with self._span("trainer.enqueue", step=sid):
             out = self._call_step(
                 "update", sig, self._train_step,
@@ -1652,7 +1727,8 @@ class NetTrainer:
             epoch_k = np.asarray(epochs, np.uint32)  # cxxlint: disable=CXL003 -- host python list of schedule epochs
             do_up_k = np.asarray([((S + i + 1) % period) == 0  # cxxlint: disable=CXL003 -- host python list of apply flags
                                   for i in range(n)])
-            sig = _areg.run_steps_sig(data.shape, data.dtype,
+            sig = _areg.run_steps_sig(data.shape,
+                                      self._dtype_tag(data.dtype),
                                       labels.shape, mask is None,
                                       len(extra), n)
         with self._span("trainer.enqueue", step=sid):
@@ -1716,7 +1792,8 @@ class NetTrainer:
                 self._put_window([b.extra_data[j] for b in batches])
                 for j in range(n_extra))
             collect = bool(self.eval_train and self._metrics.evals)
-            sig = _areg.update_many_sig(data_k.shape, data_k.dtype,
+            sig = _areg.update_many_sig(data_k.shape,
+                                        self._dtype_tag(data_k.dtype),
                                         labels_k.shape, mask_k is None,
                                         n_extra, K, collect)
         with self._span("trainer.enqueue", step=sid):

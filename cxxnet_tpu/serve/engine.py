@@ -323,7 +323,7 @@ class InferenceEngine:
         t = self.trainer
         with self._lock:
             sig = ("pred",) + t.pred_sig(
-                staged.data.shape, staged.data.dtype,
+                staged.data.shape, t._dtype_tag(staged.data.dtype),
                 staged.mask is None, 0, staged.nodes)
             if sig in t._aot:
                 self.counters["aot_hits"] += 1

@@ -261,12 +261,11 @@ def test_imgrec_distributed_parts(tmp_path):
 
 def test_raw_tensor_records(tmp_path):
     """Decode-free raw uint8 tensor records round-trip through the
-    imgrec iterator (the --pipeline-raw input path)."""
+    writer and the reader (the --pipeline-raw input path)."""
     import numpy as np
     from cxxnet_tpu.io.recordio import (RecordIOWriter,
                                         pack_raw_tensor_record,
                                         unpack_raw_tensor_record)
-    from cxxnet_tpu.io.iter_imgrec import ImageRecordIterator
 
     rng = np.random.RandomState(0)
     imgs = [rng.randint(0, 255, (8, 6, 3), np.uint8) for _ in range(5)]
@@ -284,22 +283,39 @@ def test_raw_tensor_records(tmp_path):
     np.testing.assert_array_equal(arr, imgs[0])
     r.close()
 
-    # through the iterator: float32 path and uint8 path
-    for u8 in (0, 1):
-        it = ImageRecordIterator()
-        it.set_param("path_imgrec", p)
-        it.set_param("silent", "1")
-        it.set_param("decode_uint8", str(u8))
-        it.init()
-        got = []
-        while it.next():
-            got.append(it.value())
-        assert len(got) == 5
-        want_dtype = np.uint8 if u8 else np.float32
-        assert got[0].data.dtype == want_dtype
-        np.testing.assert_array_equal(
-            np.asarray(got[2].data, np.uint8), imgs[2])
-        it.close()
+
+@pytest.mark.parametrize("asked", [False, True],
+                         ids=["not_asked", "asked"])
+def test_raw_tensor_records_through_the_chain(tmp_path, asked):
+    """Raw-tensor records through an imgrec chain: float32 pixels when
+    nobody asked, uint8 once a consumer took the normalisation over
+    (``IIterator.defer_normalize``) — the same pixels either way."""
+    import numpy as np
+    from cxxnet_tpu.io import create_iterator
+    from cxxnet_tpu.io.recordio import (RecordIOWriter,
+                                        pack_raw_tensor_record)
+
+    rng = np.random.RandomState(0)
+    imgs = [rng.randint(0, 255, (8, 6, 3), np.uint8) for _ in range(5)]
+    p = str(tmp_path / "raw.rec")
+    w = RecordIOWriter(p, force_python=True)
+    for i, img in enumerate(imgs):
+        w.write_record(pack_raw_tensor_record(i, float(i % 2), img))
+    w.close()
+
+    it = create_iterator(
+        [("iter", "imgrec"), ("path_imgrec", p), ("silent", "1"),
+         ("round_batch", "0")],
+        [("batch_size", "5"), ("input_shape", "3,8,6")])
+    it.init()
+    if asked:
+        mean, scale = it.defer_normalize()
+        assert mean is None and scale == 1     # the identity spec
+    (b,) = list(it)
+    assert b.data.dtype == (np.uint8 if asked else np.float32)
+    np.testing.assert_array_equal(np.asarray(b.data, np.uint8),
+                                  np.stack(imgs))
+    it.close()
 
 
 @pytest.mark.skipif(not _HAVE_TOOLS, reason="im2rec not built")
