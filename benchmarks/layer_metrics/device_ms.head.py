@@ -1,0 +1,13 @@
+"""Layers / XLA fusions: device time of the ops the program's
+``program_scopes`` records place in the embedding, output head and loss (scope types embed, fullc, softmax and the step-level loss),
+by the op's OUTERMOST layer scope, in ms a trained batch over the whole
+dispatches the trace holds, mean over the chips. Left out where under
+90 % of the scoped programs' op time maps to a scope, or where the
+program writes no such record (scope_groups.py). Moves train_img_per_s.
+"""
+
+import scope_groups
+
+
+def read(run):
+    return scope_groups.device_ms(run, ("embed", "fullc", "softmax", "loss"))

@@ -5,7 +5,7 @@ Parity with ``/root/reference/src/io/data.cpp:27-94``: the first
 (``threadbuffer``, ``membuffer``); parameters apply to every iterator in
 the chain (the reference calls SetParam down the chain).
 
-Sources: mnist (batch-level); csv / img / imgrec / imgbin (instance
+Sources: mnist, tokens (batch-level); csv / img / imgrec / imgbin (instance
 level, auto-wrapped in a BatchAdapter like the reference's
 CreateBatchIter). Adapters: augment, batch, threadbuffer, membuffer,
 attachtxt.
@@ -26,6 +26,7 @@ from .iter_imgrec import ImageRecordIterator
 from .iter_augment import AugmentAdapter
 from .iter_attach import AttachTxtIterator
 from .iter_imgbin import ImageBinIterator
+from .iter_tokens import TokenIterator
 
 
 
@@ -57,6 +58,10 @@ def create_iterator(cfg: Sequence[Tuple[str, str]],
                 assert it is None, "csv must be the base iterator"
                 it = CSVIterator()
                 is_instance_level = True
+            elif val == "tokens":
+                assert it is None, "tokens must be the base iterator"
+                it = TokenIterator()
+                is_instance_level = False
             elif val == "libsvm":
                 assert it is None, "libsvm must be the base iterator"
                 it = LibSVMIterator()

@@ -18,7 +18,8 @@ from __future__ import annotations
 
 from typing import Callable, Dict, Sequence, Tuple
 
-from .base import Layer, LayerParam, Shape3, array_shape, as_mat
+from .base import (Layer, LayerParam, SeqShape, Shape3, array_shape, as_mat,
+                   seq_shape)
 from .common import (ActivationLayer, BiasLayer, ConcatLayer, DropoutLayer,
                      FixConnectLayer, FlattenLayer, FullConnectLayer,
                      InsanityLayer, PReluLayer, SplitLayer, XeluLayer)
@@ -27,6 +28,8 @@ from .conv import (BatchNormLayer, ConvolutionLayer, InsanityPoolingLayer,
 from .loss import LossLayer, LpLossLayer, MultiLogisticLayer, SoftmaxLayer
 from .pairtest import PairTestLayer
 from .pallas_kernels import PallasFullConnectLayer
+from .sequence import (AddLayer, EmbedLayer, MLAAttentionLayer, MoELayer,
+                       RMSNormLayer, SwiGLULayer)
 from .torch_adapter import TorchLayer
 
 _FACTORY: Dict[str, Callable[..., Layer]] = {
@@ -71,6 +74,14 @@ _FACTORY: Dict[str, Callable[..., Layer]] = {
     # cross-framework oracle (the caffe adapter equivalent): a torch-
     # backed fullc/conv for pairtest-conv-torch style in-net A/B checks
     "torch": lambda cfg, **kw: TorchLayer(cfg),
+    # the sequence node (batch, time, features) and the decoder block
+    # over it (layers/sequence.py, doc/sequence.md)
+    "embed": lambda cfg, **kw: EmbedLayer(cfg),
+    "rmsnorm": lambda cfg, **kw: RMSNormLayer(cfg),
+    "add": lambda cfg, **kw: AddLayer(cfg),
+    "swiglu": lambda cfg, **kw: SwiGLULayer(cfg),
+    "mla_attention": lambda cfg, **kw: MLAAttentionLayer(cfg),
+    "moe": lambda cfg, **kw: MoELayer(cfg),
 }
 
 # registered in the reference enum but rejected by its factory
@@ -111,6 +122,7 @@ def create_layer(type_str: str, cfg: Sequence[Tuple[str, str]] = (),
 
 
 __all__ = [
-    "Layer", "LayerParam", "Shape3", "array_shape", "as_mat",
+    "Layer", "LayerParam", "SeqShape", "Shape3", "array_shape", "as_mat",
+    "seq_shape",
     "create_layer", "known_layer_type", "LossLayer",
 ]

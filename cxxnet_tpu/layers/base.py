@@ -13,6 +13,10 @@ so convolutions feed the MXU without transposes; flattened nodes are 2-D
 node shapes keep the reference's ``(ch, y, x)`` convention
 (``layer.h:32-72``) so config files and shape messages stay compatible:
 a logical shape with ch==1 and y==1 is a "matrix" node stored 2-D.
+A third kind is the sequence node ``(batch, time, features)``
+(``SeqShape``): ``y`` counts the positions, ``x`` the features of each,
+``ch`` is 1. An ``embed`` layer makes one from a matrix of integer ids;
+``doc/sequence.md`` lists the layers that read and write it.
 """
 
 from __future__ import annotations
@@ -37,14 +41,39 @@ class Shape3(NamedTuple):
         return self.ch == 1 and self.y == 1
 
     @property
+    def is_seq(self) -> bool:
+        return False
+
+    @property
     def flat_size(self) -> int:
         return self.ch * self.y * self.x
+
+
+class SeqShape(Shape3):
+    """Logical shape of a sequence node: ``(1, time, features)``, stored
+    ``(batch, time, features)``. Never a matrix, whatever its length;
+    compares equal to the ``Shape3`` of the same numbers."""
+    __slots__ = ()
+
+    @property
+    def is_mat(self) -> bool:
+        return False
+
+    @property
+    def is_seq(self) -> bool:
+        return True
+
+
+def seq_shape(time: int, features: int) -> SeqShape:
+    return SeqShape(1, time, features)
 
 
 def array_shape(batch: int, s: Shape3) -> Tuple[int, ...]:
     """Concrete array shape for a logical node shape."""
     if s.is_mat:
         return (batch, s.x)
+    if s.is_seq:
+        return (batch, s.y, s.x)
     return (batch, s.y, s.x, s.ch)
 
 
@@ -58,6 +87,8 @@ def as_mat(x: jnp.ndarray) -> jnp.ndarray:
     if x.ndim == 2:
         return x
     b = x.shape[0]
+    if x.ndim == 3:             # sequence node: time major, as stored
+        return x.reshape(b, -1)
     return jnp.transpose(x, (0, 3, 1, 2)).reshape(b, -1)
 
 

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .base import Layer, LayerParam, Shape3, as_mat
+from .base import Layer, LayerParam, Shape3, as_mat, seq_shape
 from ..utils.stream import open_stream
 
 
@@ -40,7 +40,7 @@ class FullConnectLayer(Layer):
 
     def infer_shape(self, in_shapes: List[Shape3]) -> List[Shape3]:
         s = self._expect_one(in_shapes)
-        if not s.is_mat:
+        if not (s.is_mat or s.is_seq):
             raise ValueError("fullc: input must be a matrix (flatten first)")
         if self.param.num_hidden <= 0:
             raise ValueError("fullc: must set nhidden correctly")
@@ -49,7 +49,9 @@ class FullConnectLayer(Layer):
         elif self.param.num_input_node != s.x:
             raise ValueError("fullc: input hidden nodes not consistent")
         self.in_shapes = [s]
-        self.out_shapes = [Shape3(1, 1, self.param.num_hidden)]
+        # a sequence node is projected position by position
+        self.out_shapes = [seq_shape(s.y, self.param.num_hidden) if s.is_seq
+                           else Shape3(1, 1, self.param.num_hidden)]
         return self.out_shapes
 
     def init_params(self, key: jax.Array) -> Dict[str, jnp.ndarray]:

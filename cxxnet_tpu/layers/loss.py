@@ -69,16 +69,51 @@ class SoftmaxLayer(LossLayer):
 
     Logits are upcast to f32 at this boundary: in mixed-precision nets
     the activations ride bf16 and the loss is where precision returns.
+
+    On a sequence node ``(batch, time, classes)`` the label field holds
+    one class a position (``label_vec[0,time) = label``) and a row's
+    loss is the mean over its positions, so the total is the mean
+    cross-entropy over all positions of the batch. ``loss_chunk = n``
+    takes the positions ``n`` at a time, each chunk's float32
+    log-softmax recomputed in the backward pass, so that the float32
+    copy of the logits and of their gradient is a chunk's, not the
+    sequence's (8k positions x 20k classes: 1.3 GB each).
     """
+
+    def __init__(self, cfg=()):
+        self.loss_chunk = 0
+        super().__init__(cfg)
+
+    def set_param(self, name, val):
+        super().set_param(name, val)
+        if name == "loss_chunk":
+            self.loss_chunk = int(val)
+
+    @staticmethod
+    def _ce_sum(logit, lab):
+        """Summed cross-entropy of each row's positions: (b, t, classes),
+        (b, t) -> (b,)."""
+        logp = jax.nn.log_softmax(logit.astype(jnp.float32), axis=-1)
+        return jnp.sum(-jnp.take_along_axis(
+            logp, lab.astype(jnp.int32)[..., None], axis=-1)[..., 0], axis=-1)
 
     def forward(self, params, state, inputs, is_train, rng):
         return [jax.nn.softmax(inputs[0].astype(jnp.float32),
                                axis=-1)], state
 
     def loss_value(self, logit, label, mask):
-        lab = label[:, 0].astype(jnp.int32)
-        logp = jax.nn.log_softmax(logit.astype(jnp.float32), axis=-1)
-        ce = -jnp.take_along_axis(logp, lab[:, None], axis=-1)[:, 0]
+        if logit.ndim == 3:
+            t, c = logit.shape[1], self.loss_chunk
+            if 0 < c < t and t % c == 0:
+                part = jax.checkpoint(self._ce_sum)
+                ce = sum(part(logit[:, i:i + c], label[:, i:i + c])
+                         for i in range(0, t, c)) / t
+            else:
+                ce = self._ce_sum(logit, label) / t
+        else:
+            lab = label[:, 0].astype(jnp.int32)
+            logp = jax.nn.log_softmax(logit.astype(jnp.float32), axis=-1)
+            ce = -jnp.take_along_axis(logp, lab[:, None], axis=-1)[:, 0]
         if mask is not None:
             ce = ce * mask
         return self._scale() * jnp.sum(ce)

@@ -1,0 +1,612 @@
+"""Layers over the sequence node ``(batch, time, features)``.
+
+``embed`` turns a matrix of integer ids into a sequence node; ``rmsnorm``,
+``add``, ``swiglu``, ``mla_attention`` and ``moe`` read and write one
+(``fullc`` and ``softmax`` take one too, see common.py and loss.py).
+Together they are the decoder block of DeepSeek-V3's family: pre-norm
+residual, multi-head latent attention, and a sigmoid-routed expert layer
+with shared experts. ``doc/sequence.md`` lists the config keys.
+
+Mixed precision follows the rest of the zoo: ``dtype = bfloat16`` casts
+matmul operands to bf16 (float32 accumulation on the MXU), masters stay
+float32. Normalisations, the rotary embedding, attention's softmax and
+the router's scores are computed in float32 whatever the dtype.
+
+An expert layer is told which experts it holds (``expert_first``,
+``expert_count``): it routes over all ``nexpert``, computes the part of
+the result its own experts give plus the shared experts, and leaves out
+what absent experts would add. Nothing stands in for other chips.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Dict, List, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from .base import Layer, Shape3, seq_shape
+
+_F32 = jnp.float32
+
+
+def _expect_seq(name: str, s: Shape3) -> Shape3:
+    if not s.is_seq:
+        raise ValueError("%s: input must be a sequence node "
+                         "(batch, time, features); put an embed layer first"
+                         % name)
+    return s
+
+
+def _dot(x, w, cd):
+    """``x @ w`` with both operands in the compute dtype; the result is
+    in it too (float32 accumulation inside the MXU either way)."""
+    return jnp.dot(x.astype(cd), w.astype(cd))
+
+
+def rms_norm(x, weight, eps: float):
+    """``x / sqrt(mean(x^2) + eps) * weight`` over the last axis, in
+    float32, returned in ``x``'s dtype."""
+    x32 = x.astype(_F32)
+    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    return (x32 * jax.lax.rsqrt(var + eps) * weight).astype(x.dtype)
+
+
+def swiglu(x, wgate, wup, wdown, cd):
+    """``(silu(x Wgate) * (x Wup)) Wdown``; the gate's product in
+    float32."""
+    a = _dot(x, wgate, cd).astype(_F32)
+    u = _dot(x, wup, cd).astype(_F32)
+    return _dot((jax.nn.silu(a) * u).astype(cd), wdown, cd)
+
+
+class _SeqLayer(Layer):
+    """Shared: the compute dtype, and FLOPs a sequence for the MFU
+    count (nnet/net.py: analytic_flops_per_example)."""
+
+    @property
+    def cd(self):
+        return jnp.bfloat16 if self.param.compute_dtype == "bfloat16" \
+            else _F32
+
+    def flops_per_example(self) -> float:
+        return 0.0
+
+
+class EmbedLayer(_SeqLayer):
+    """Integer ids ``(batch, time)`` -> rows of the held vocabulary
+    slice ``(batch, time, nhidden)``. ``nvocab`` is the rows held here;
+    ids are in ``[0, nvocab)``."""
+
+    def __init__(self, cfg=()):
+        self.nvocab = 0
+        super().__init__(cfg)
+
+    def set_param(self, name, val):
+        super().set_param(name, val)
+        if name == "nvocab":
+            self.nvocab = int(val)
+
+    def infer_shape(self, in_shapes: List[Shape3]) -> List[Shape3]:
+        s = self._expect_one(in_shapes)
+        if not s.is_mat:
+            raise ValueError("embed: input must be a matrix of ids "
+                             "(input_shape = 1,1,<time>)")
+        if self.nvocab <= 0 or self.param.num_hidden <= 0:
+            raise ValueError("embed: must set nvocab and nhidden")
+        self.in_shapes = [s]
+        self.out_shapes = [seq_shape(s.x, self.param.num_hidden)]
+        return self.out_shapes
+
+    def init_params(self, key):
+        p = self.param
+        return {"wmat": p.rand_init_weight(
+            key, (self.nvocab, p.num_hidden), self.nvocab, p.num_hidden)}
+
+    def forward(self, params, state, inputs, is_train, rng):
+        ids = inputs[0]
+        if not jnp.issubdtype(ids.dtype, jnp.integer):
+            ids = ids.astype(jnp.int32)     # a float batch of whole numbers
+        rows = jnp.take(params["wmat"].astype(self.cd), ids, axis=0)
+        return [rows], state
+
+
+class RMSNormLayer(_SeqLayer):
+    """Root-mean-square normalisation over the features, learned scale
+    (tag ``wmat``, ones at start), no bias. ``eps`` default 1e-6."""
+
+    def __init__(self, cfg=()):
+        self.eps = 1e-6
+        super().__init__(cfg)
+
+    def set_param(self, name, val):
+        super().set_param(name, val)
+        if name == "eps":
+            self.eps = float(val)
+
+    def infer_shape(self, in_shapes: List[Shape3]) -> List[Shape3]:
+        s = self._expect_one(in_shapes)
+        if not (s.is_seq or s.is_mat):
+            raise ValueError("rmsnorm: input must be a sequence or a matrix")
+        self.in_shapes = [s]
+        self.out_shapes = [s]
+        return self.out_shapes
+
+    def init_params(self, key):
+        return {"wmat": jnp.ones((self.in_shapes[0].x,), _F32)}
+
+    def forward(self, params, state, inputs, is_train, rng):
+        return [rms_norm(inputs[0], params["wmat"], self.eps)], state
+
+
+class AddLayer(_SeqLayer):
+    """n-to-1 elementwise sum of equal shapes: the join of a residual
+    fork (reading one node from two layers is the fork). ``remat =
+    block`` ends a recomputed segment after each one (nnet/net.py)."""
+
+    def infer_shape(self, in_shapes: List[Shape3]) -> List[Shape3]:
+        if len(in_shapes) < 2:
+            raise ValueError("add: needs more than one input")
+        if any(tuple(s) != tuple(in_shapes[0]) or s.is_seq
+               != in_shapes[0].is_seq for s in in_shapes):
+            raise ValueError("add: shape mismatch %r" % (in_shapes,))
+        self.in_shapes = list(in_shapes)
+        self.out_shapes = [in_shapes[0]]
+        return self.out_shapes
+
+    def forward(self, params, state, inputs, is_train, rng):
+        return [functools.reduce(jnp.add, inputs)], state
+
+
+class SwiGLULayer(_SeqLayer):
+    """``Wdown(silu(Wgate x) * Wup x)``, no biases; ``nhidden`` is the
+    inner width, the output has the input's features."""
+
+    def infer_shape(self, in_shapes: List[Shape3]) -> List[Shape3]:
+        s = _expect_seq("swiglu", self._expect_one(in_shapes))
+        if self.param.num_hidden <= 0:
+            raise ValueError("swiglu: must set nhidden")
+        self.in_shapes = [s]
+        self.out_shapes = [s]
+        return self.out_shapes
+
+    def init_params(self, key):
+        p, d = self.param, self.in_shapes[0].x
+        kg, ku, kd = jax.random.split(key, 3)
+        w = p.num_hidden
+        return {"wgate": p.rand_init_weight(kg, (d, w), d, w),
+                "wup": p.rand_init_weight(ku, (d, w), d, w),
+                "wdown": p.rand_init_weight(kd, (w, d), w, d)}
+
+    def forward(self, params, state, inputs, is_train, rng):
+        return [swiglu(inputs[0], params["wgate"], params["wup"],
+                       params["wdown"], self.cd)], state
+
+    def flops_per_example(self) -> float:
+        s = self.in_shapes[0]
+        return 6.0 * s.y * s.x * self.param.num_hidden
+
+
+# -- multi-head latent attention ---------------------------------------------
+
+
+def rope_tables(time: int, dim: int, theta: float):
+    """cos and sin of ``pos * theta^(-2i/dim)``, each ``(time, dim/2)``,
+    float32."""
+    inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=_F32) / dim))
+    ang = jnp.arange(time, dtype=_F32)[:, None] * inv[None, :]
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def apply_rope(x, cos, sin):
+    """Rotate the interleaved pairs ``(x[2i], x[2i+1])`` of the last axis
+    (DeepSeek's layout) by the position's angle. ``x`` is ``(batch, time,
+    ..., dim)``; the result holds the rotated even members in its first
+    half and the odd ones in its second (queries and keys alike, so their
+    products are those of the interleaved form). Float32 inside."""
+    x32 = x.astype(_F32)
+    even, odd = x32[..., 0::2], x32[..., 1::2]
+    shape = (1, cos.shape[0]) + (1,) * (x.ndim - 3) + (cos.shape[1],)
+    c, s = cos.reshape(shape), sin.reshape(shape)
+    return jnp.concatenate([even * c - odd * s, odd * c + even * s],
+                           axis=-1).astype(x.dtype)
+
+
+def _attend(q, k, v, q0: int, scale: float):
+    """One block of queries, starting at position ``q0``, over the keys
+    up to its end: causal ``softmax(q k^T * scale) v`` with float32
+    scores. All ``(batch, heads, time, dim)``: heads beside the batch, so
+    that every product is a plain batched matrix product with ``dim`` or
+    ``time`` on the lanes (with heads minor the MXU's output used 16 of
+    its 128 lanes and the step took 3.8 s; my chip run, PR 28)."""
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=_F32)
+    qi = q0 + jnp.arange(q.shape[2])[:, None]
+    ki = jnp.arange(k.shape[2])[None, :]
+    s = jnp.where(ki <= qi, s * scale, -1e30)
+    m = jax.lax.optimization_barrier(
+        jax.lax.stop_gradient(jnp.max(s, axis=-1, keepdims=True)))
+    p = jnp.exp(s - m)
+    p = p / jnp.sum(p, axis=-1, keepdims=True)
+    return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
+
+
+def causal_attention(q, k, v, scale: float, q_block: int):
+    """Causal attention over ``(batch, heads, time, dim)`` in blocks of
+    ``q_block`` queries, each against the keys up to its own end only (so
+    about half the square is computed), each recomputed in the backward
+    pass: the largest score tensor alive is ``(batch, heads, q_block,
+    time)``."""
+    t = q.shape[2]
+    bq = q_block if 0 < q_block < t else t
+    if t % bq:
+        raise ValueError("mla_attention: q_block %d does not divide the "
+                         "sequence length %d" % (bq, t))
+    outs = []
+    for i in range(t // bq):
+        lo, hi = i * bq, (i + 1) * bq
+        block = jax.checkpoint(functools.partial(_attend, q0=lo, scale=scale))
+        outs.append(block(q[:, :, lo:hi], k[:, :, :hi], v[:, :, :hi]))
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=2)
+
+
+class MLAAttentionLayer(_SeqLayer):
+    """Multi-head latent attention (DeepSeek-V2/V3), the form without a
+    query low-rank (``q_lora_rank`` null), causal, no biases:
+
+        q = x Wq            -> heads of [q_nope | q_rope]
+        [c | k_r] = x Wkva  -> c of kv_lora_rank, one shared k_r
+        [k_nope | v] = RMSNorm(c) Wkvb, per head
+        RoPE on q_rope and k_r; k = [k_nope | k_r]
+        y = softmax(q k^T / sqrt(d_nope + d_rope)) v Wo
+    """
+
+    def __init__(self, cfg=()):
+        self.nhead = 0
+        self.d_nope = 0
+        self.d_rope = 0
+        self.d_v = 0
+        self.kv_rank = 0
+        self.rope_theta = 10000.0
+        self.eps = 1e-6
+        self.q_block = 0
+        super().__init__(cfg)
+
+    def set_param(self, name, val):
+        super().set_param(name, val)
+        if name == "nhead":
+            self.nhead = int(val)
+        if name == "qk_nope_head_dim":
+            self.d_nope = int(val)
+        if name == "qk_rope_head_dim":
+            self.d_rope = int(val)
+        if name == "v_head_dim":
+            self.d_v = int(val)
+        if name == "kv_lora_rank":
+            self.kv_rank = int(val)
+        if name == "rope_theta":
+            self.rope_theta = float(val)
+        if name == "eps":
+            self.eps = float(val)
+        if name == "q_block":
+            self.q_block = int(val)
+
+    def infer_shape(self, in_shapes: List[Shape3]) -> List[Shape3]:
+        s = _expect_seq("mla_attention", self._expect_one(in_shapes))
+        if min(self.nhead, self.d_nope, self.d_rope, self.d_v,
+               self.kv_rank) <= 0 or self.d_rope % 2:
+            raise ValueError(
+                "mla_attention: must set nhead, qk_nope_head_dim, "
+                "qk_rope_head_dim (even), v_head_dim, kv_lora_rank")
+        self.in_shapes = [s]
+        self.out_shapes = [s]
+        return self.out_shapes
+
+    def _widths(self) -> Dict[str, Tuple[int, int]]:
+        d, h = self.in_shapes[0].x, self.nhead
+        return {"wq": (d, h * (self.d_nope + self.d_rope)),
+                "wkva": (d, self.kv_rank + self.d_rope),
+                "wkvb": (self.kv_rank, h * (self.d_nope + self.d_v)),
+                "wo": (h * self.d_v, d)}
+
+    def init_params(self, key):
+        p = self.param
+        out = {tag: p.rand_init_weight(k, shape, *shape)
+               for (tag, shape), k in zip(
+                   self._widths().items(), jax.random.split(key, 4))}
+        out["kvnorm"] = jnp.ones((self.kv_rank,), _F32)
+        return out
+
+    def forward(self, params, state, inputs, is_train, rng):
+        x, cd, h = inputs[0], self.cd, self.nhead
+        b, t, _ = x.shape
+        q = _dot(x, params["wq"], cd).reshape(
+            b, t, h, self.d_nope + self.d_rope)
+        q_nope, q_rope = q[..., :self.d_nope], q[..., self.d_nope:]
+        ckr = _dot(x, params["wkva"], cd)
+        c = rms_norm(ckr[..., :self.kv_rank], params["kvnorm"], self.eps)
+        kv = _dot(c, params["wkvb"], cd).reshape(
+            b, t, h, self.d_nope + self.d_v)
+        k_nope, v = kv[..., :self.d_nope], kv[..., self.d_nope:]
+        cos, sin = rope_tables(t, self.d_rope, self.rope_theta)
+        q_rope = apply_rope(q_rope, cos, sin)
+        k_rope = apply_rope(ckr[..., self.kv_rank:], cos, sin)
+        # heads beside the batch; the shared k_r is repeated a head
+        heads = lambda a: a.transpose(0, 2, 1, 3)
+        q = heads(jnp.concatenate([q_nope, q_rope], axis=-1))
+        k = heads(jnp.concatenate([k_nope, jnp.broadcast_to(
+            k_rope[:, :, None, :], (b, t, h, self.d_rope))], axis=-1))
+        o = causal_attention(
+            q, k, heads(v), 1.0 / math.sqrt(self.d_nope + self.d_rope),
+            self.q_block)
+        return [_dot(heads(o).reshape(b, t, h * self.d_v), params["wo"],
+                     cd)], state
+
+    def flops_per_example(self) -> float:
+        """Projections, and the causal half of the square: a query sees
+        (time + 1) / 2 keys on average."""
+        t = self.in_shapes[0].y
+        proj = sum(2.0 * a * b for a, b in self._widths().values())
+        core = 2.0 * self.nhead * (self.d_nope + self.d_rope + self.d_v) \
+            * (t + 1) / 2.0
+        return t * (proj + core)
+
+
+# -- the expert layer ---------------------------------------------------------
+
+
+def dispatch_plan(picks, weights, first: int, count: int, block: int):
+    """Where each pick that lands on a held expert goes.
+
+    ``picks`` ``(tokens, topk)`` are expert ids over ALL experts,
+    ``weights`` their combine weights. The held experts are ``first ..
+    first + count``. Rows are laid out expert by expert, each expert's
+    rows padded to whole blocks of ``block``, so that a block belongs to
+    one expert; nothing is bounded by a capacity, so no pick is dropped
+    however uneven the routing. Returns
+
+    tok    (rows,) int32  the token of each row; ``tokens`` marks padding
+    cw     (rows,) f32    the row's combine weight, 0 in padding
+    expert (blocks,) int32 the held expert (0-based) of each block
+    nb     () int32       blocks in use: the loops run this far
+    load   (count,) int32 picks each held expert got
+    """
+    n, k = picks.shape
+    rows = (-(-n * k // block) + count) * block
+    flat = picks.reshape(-1) - first
+    held = (flat >= 0) & (flat < count)
+    onehot = (flat[:, None] == jnp.arange(count)[None, :]).astype(jnp.int32)
+    rank = jnp.sum((jnp.cumsum(onehot, axis=0) - 1) * onehot, axis=1)
+    load = jnp.sum(onehot, axis=0)
+    nblk = (load + block - 1) // block
+    ends = jnp.cumsum(nblk)
+    start = (ends - nblk) * block
+    dest = jnp.where(held, start[jnp.clip(flat, 0, count - 1)] + rank, rows)
+    tok = jnp.full((rows,), n, jnp.int32).at[dest].set(
+        jnp.arange(n * k, dtype=jnp.int32) // k, mode="drop")
+    cw = jnp.zeros((rows,), _F32).at[dest].set(
+        weights.reshape(-1).astype(_F32), mode="drop")
+    expert = jnp.clip(jnp.searchsorted(
+        ends, jnp.arange(rows // block), side="right"), 0, count - 1)
+    return tok, cw, expert.astype(jnp.int32), ends[-1].astype(jnp.int32), load
+
+
+def _block_rows(b, block, x, tok, cw):
+    idx = jax.lax.dynamic_slice(tok, (b * block,), (block,))
+    c = jax.lax.dynamic_slice(cw, (b * block,), (block,))
+    # padding rows point past the last token: they read zeros
+    return idx, c, jnp.take(x, idx, axis=0, mode="fill", fill_value=0)
+
+
+def _gate_up(xs, wg, wu):
+    a = jnp.dot(xs, wg, preferred_element_type=_F32)
+    u = jnp.dot(xs, wu, preferred_element_type=_F32)
+    return a, u
+
+
+def _grouped_impl(x, wgate, wup, wdown, cw, tok, expert, nb, block):
+    """``out[t] = sum over the rows r of token t of cw[r] * E_expert(r)(x[t])``
+    with ``E`` a SwiGLU, over the plan of ``dispatch_plan``: a loop over
+    the ``nb`` blocks in use (a trip count the routing decides, which is
+    why the backward pass is written by hand below). ``x`` is ``(tokens,
+    d)``, the weights ``(held, d, w)`` / ``(held, w, d)``; the result is
+    float32."""
+    def body(b, out):
+        idx, c, xs = _block_rows(b, block, x, tok, cw)
+        e = expert[b]
+        a, u = _gate_up(xs, wgate[e], wup[e])
+        h = (jax.nn.silu(a) * u).astype(x.dtype)
+        y = jnp.dot(h, wdown[e], preferred_element_type=_F32)
+        return out.at[idx].add(c[:, None] * y, mode="drop")
+    return jax.lax.fori_loop(0, nb, body, jnp.zeros(x.shape, _F32))
+
+
+grouped_swiglu = jax.custom_vjp(_grouped_impl, nondiff_argnums=(8,))
+
+
+def _grouped_fwd(x, wgate, wup, wdown, cw, tok, expert, nb, block):
+    out = _grouped_impl(x, wgate, wup, wdown, cw, tok, expert, nb, block)
+    return out, (x, wgate, wup, wdown, cw, tok, expert, nb)
+
+
+def _grouped_bwd(block, res, g_out):
+    """Each block again: recompute its hidden rows, then the products'
+    transposes. Weight gradients accumulate in float32."""
+    x, wgate, wup, wdown, cw, tok, expert, nb = res
+    g_out = g_out.astype(_F32)
+
+    def body(b, carry):
+        dx, dwg, dwu, dwd, dcw = carry
+        idx, c, xs = _block_rows(b, block, x, tok, cw)
+        g = jnp.take(g_out, idx, axis=0, mode="fill", fill_value=0)
+        e = expert[b]
+        a, u = _gate_up(xs, wgate[e], wup[e])
+        sig = jax.nn.sigmoid(a)
+        act = a * sig
+        h = (act * u).astype(x.dtype)
+        y = jnp.dot(h, wdown[e], preferred_element_type=_F32)
+        dcw = jax.lax.dynamic_update_slice(
+            dcw, jnp.sum(y * g, axis=-1), (b * block,))
+        dy = (c[:, None] * g).astype(x.dtype)
+        dh = jnp.dot(dy, wdown[e].T, preferred_element_type=_F32)
+        dwd = dwd.at[e].add(jnp.dot(h.T, dy, preferred_element_type=_F32))
+        du = (dh * act).astype(x.dtype)
+        da = (dh * u * (sig * (1.0 + a * (1.0 - sig)))).astype(x.dtype)
+        dwg = dwg.at[e].add(jnp.dot(xs.T, da, preferred_element_type=_F32))
+        dwu = dwu.at[e].add(jnp.dot(xs.T, du, preferred_element_type=_F32))
+        dxs = jnp.dot(da, wgate[e].T, preferred_element_type=_F32) \
+            + jnp.dot(du, wup[e].T, preferred_element_type=_F32)
+        return dx.at[idx].add(dxs, mode="drop"), dwg, dwu, dwd, dcw
+
+    zeros = [jnp.zeros(a.shape, _F32) for a in (x, wgate, wup, wdown, cw)]
+    dx, dwg, dwu, dwd, dcw = jax.lax.fori_loop(0, nb, body, tuple(zeros))
+    return (dx.astype(x.dtype), dwg.astype(wgate.dtype),
+            dwu.astype(wup.dtype), dwd.astype(wdown.dtype), dcw,
+            None, None, None)
+
+
+grouped_swiglu.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+class MoELayer(_SeqLayer):
+    """Sigmoid-routed expert layer with shared experts (DeepSeek-V3's,
+    ``noaux_tc`` without group limits):
+
+        s = sigmoid(x Wr)                      all nexpert, float32
+        picks = top-k of s + bias              bias is layer STATE
+        w_i = scale * s_i / sum_picked s_j     from s, not s + bias
+        y = sum_{held picks} w_i E_i(x) + S(x)
+
+    ``E_i`` is a SwiGLU of width ``nhidden``, ``S`` one SwiGLU of width
+    ``nshared * nhidden``. ``expert_first`` / ``expert_count`` say which
+    experts live here (default: all). The bias is seeded from
+    ``bias_seed`` at ``bias_sigma`` and held fixed (its update rate is
+    not part of the published config). State also carries the last
+    forward's counters for the ``moe`` telemetry record: ``load`` (picks
+    each held expert got), ``picks_held``, ``dropped``.
+    """
+
+    sub_scopes = ("route", "dispatch", "experts", "combine", "shared")
+
+    def __init__(self, cfg=()):
+        self.nexpert = 0
+        self.topk = 0
+        self.nshared = 0
+        self.scale = 1.0
+        self.norm_topk = 1
+        self.first = 0
+        self.count = 0
+        self.block = 512
+        self.bias_seed = 0
+        self.bias_sigma = 0.0
+        super().__init__(cfg)
+
+    def set_param(self, name, val):
+        super().set_param(name, val)
+        if name == "nexpert":
+            self.nexpert = int(val)
+        if name == "topk":
+            self.topk = int(val)
+        if name == "nshared":
+            self.nshared = int(val)
+        if name == "routed_scaling_factor":
+            self.scale = float(val)
+        if name == "norm_topk_prob":
+            self.norm_topk = int(val)
+        if name == "expert_first":
+            self.first = int(val)
+        if name == "expert_count":
+            self.count = int(val)
+        if name == "expert_block":
+            self.block = int(val)
+        if name == "bias_seed":
+            self.bias_seed = int(val)
+        if name == "bias_sigma":
+            self.bias_sigma = float(val)
+
+    def infer_shape(self, in_shapes: List[Shape3]) -> List[Shape3]:
+        s = _expect_seq("moe", self._expect_one(in_shapes))
+        if self.count == 0:
+            self.count = self.nexpert - self.first
+        if min(self.nexpert, self.topk, self.param.num_hidden) <= 0 \
+                or self.topk > self.nexpert or self.first < 0 \
+                or self.count <= 0 or self.first + self.count > self.nexpert:
+            raise ValueError(
+                "moe: must set nexpert, topk <= nexpert, nhidden, and "
+                "expert_first / expert_count inside nexpert")
+        self.in_shapes = [s]
+        self.out_shapes = [s]
+        return self.out_shapes
+
+    def init_params(self, key):
+        p, d, w, e = self.param, self.in_shapes[0].x, self.param.num_hidden, \
+            self.count
+        ks = jax.random.split(key, 7)
+        out = {"router": p.rand_init_weight(ks[0], (d, self.nexpert), d,
+                                            self.nexpert),
+               "egate": p.rand_init_weight(ks[1], (e, d, w), d, w),
+               "eup": p.rand_init_weight(ks[2], (e, d, w), d, w),
+               "edown": p.rand_init_weight(ks[3], (e, w, d), w, d)}
+        if self.nshared:
+            sw = self.nshared * w
+            out.update(sgate=p.rand_init_weight(ks[4], (d, sw), d, sw),
+                       sup=p.rand_init_weight(ks[5], (d, sw), d, sw),
+                       sdown=p.rand_init_weight(ks[6], (sw, d), sw, d))
+        return out
+
+    def init_state(self):
+        bias = self.bias_sigma * jax.random.normal(
+            jax.random.PRNGKey(self.bias_seed), (self.nexpert,), _F32)
+        return {"bias": bias,
+                "load": jnp.zeros((self.count,), jnp.int32),
+                "picks_held": jnp.int32(0), "dropped": jnp.int32(0)}
+
+    def route(self, xt, router, bias):
+        """(picks, weights) of each token: float32 throughout, the
+        product at full precision (a bf16 pass would move near-tied
+        picks)."""
+        s = jax.nn.sigmoid(jnp.dot(xt.astype(_F32), router.astype(_F32),
+                                   precision=jax.lax.Precision.HIGHEST))
+        _, picks = jax.lax.top_k(s + bias[None, :], self.topk)
+        w = jnp.take_along_axis(s, picks, axis=1)
+        if self.norm_topk:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        return picks, w * self.scale
+
+    def forward(self, params, state, inputs, is_train, rng):
+        x, cd = inputs[0], self.cd
+        b, t, d = x.shape
+        xt = x.reshape(b * t, d)
+        with jax.named_scope("route"):
+            picks, w = self.route(xt, params["router"], state["bias"])
+        with jax.named_scope("dispatch"):
+            tok, cw, expert, nb, load = dispatch_plan(
+                picks, w, self.first, self.count, self.block)
+        with jax.named_scope("experts"):
+            y = grouped_swiglu(
+                xt.astype(cd), params["egate"].astype(cd),
+                params["eup"].astype(cd), params["edown"].astype(cd),
+                cw, tok, expert, nb, self.block)
+        if self.nshared:
+            with jax.named_scope("shared"):
+                shared = swiglu(xt, params["sgate"], params["sup"],
+                                params["sdown"], cd)
+        with jax.named_scope("combine"):
+            if self.nshared:
+                y = y + shared.astype(_F32)
+            out = y.astype(x.dtype).reshape(b, t, d)
+        held = jnp.sum(load)
+        new_state = dict(state, load=load, picks_held=held,
+                         dropped=held - jnp.sum(tok < b * t))
+        return [out], new_state
+
+    def flops_per_example(self) -> float:
+        """Router, shared experts, and the routed experts at the picks
+        that land on held experts in expectation: ``topk * count /
+        nexpert`` a token."""
+        s, w = self.in_shapes[0], self.param.num_hidden
+        per_token = 2.0 * s.x * self.nexpert \
+            + 6.0 * s.x * w * self.nshared \
+            + 6.0 * s.x * w * self.topk * self.count / self.nexpert
+        return s.y * per_token

@@ -13,6 +13,8 @@ from .alexnet import alexnet
 from .inception import inception_bn, inception_bn_tiny
 from .bowl import kaggle_bowl
 from .kaiming import kaiming
+from .kimi_vl import decoder_lm, kimi_vl_a3b, kimi_vl_a3b_tiny
 
 __all__ = ["mnist_mlp", "mnist_conv", "alexnet", "inception_bn",
-           "inception_bn_tiny", "kaggle_bowl", "kaiming"]
+           "inception_bn_tiny", "kaggle_bowl", "kaiming", "decoder_lm",
+           "kimi_vl_a3b", "kimi_vl_a3b_tiny"]

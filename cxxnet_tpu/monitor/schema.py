@@ -56,6 +56,13 @@ REQUIRED: Dict[str, tuple] = {
     # analytic FLOPs for MFU math + the layout/fusion pass decisions
     "model_info": ("flops_per_example", "train_flops_per_example",
                    "params", "layers"),
+    # one per dispatch of a net with expert layers (layers/sequence.py:
+    # MoELayer), from the LAST step of the dispatch: per layer key the
+    # picks each held expert got (load_min / load_mean / load_max), the
+    # share of all picks that landed on held experts (held_share) and
+    # picks that found no row (dropped: 0, the dispatch has no capacity)
+    "moe": ("step", "layers", "dropped", "held_share",
+            "load_max_over_mean"),
     # input_layout is the pin that took hold (not the one asked for);
     # pallas_interpret says whether this process builds its Pallas
     # kernels interpreted (never true on the tpu backend unless chosen)
@@ -205,6 +212,16 @@ REQUIRED: Dict[str, tuple] = {
     "retrieval": ("queries", "k", "metric", "recall", "wall_ms"),
 }
 
+# keys a record may carry beyond its required ones (a stream written
+# before they existed lacks them and still validates): ``tokens`` =
+# ``examples`` x ``model_info.tokens_per_example`` (a sequence net's
+# example is one sequence; 1 for every other net), and FLOPs a token
+# trained beside FLOPs an example
+OPTIONAL: Dict[str, tuple] = {
+    "step": ("tokens",),
+    "model_info": ("tokens_per_example", "train_flops_per_token"),
+}
+
 _TIMING_KEYS = ("wall_ms", "data_wait_ms", "total_ms", "max_ms",
                 "mean_ms", "p50_ms", "p99_ms", "h2d_ms",
                 "consumer_wait_ms", "wall_s", "examples_per_sec",
@@ -213,7 +230,8 @@ _TIMING_KEYS = ("wall_ms", "data_wait_ms", "total_ms", "max_ms",
                 "rows_per_sec", "gather_ms", "serialize_ms",
                 "write_ms", "fsync_ms", "quantize_ms",
                 "backprop_ms", "reduce_ms", "step_ms", "window_s",
-                "dur_ns")
+                "dur_ns", "tokens", "tokens_per_example",
+                "train_flops_per_token")
 
 # ratio fields must sit in [0, 1]
 _RATIO_KEYS = ("buffer_reuse_rate", "fill_rate", "pad_fraction",

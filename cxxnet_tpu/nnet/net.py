@@ -41,7 +41,19 @@ class FuncNet:
         # top of forward; None = cast only. Set by the trainer that
         # adopted it from an iterator chain (NetTrainer.set_input_norm)
         self.input_norm = None
+        self.block_remat = False     # the trainer's remat = block
         self._build()
+        # does the first layer read integer ids (an ``embed`` on node
+        # 0)? Then a batch is int32 and nothing normalises it
+        self.ids_input = any(
+            graph.effective_type(li) == "embed" and 0 in info.nindex_in
+            for li, info in enumerate(graph.layers))
+        # positions of a sequence net's example (the label a position
+        # its loss takes); 1 for every other net
+        seq = [self.node_shapes[graph.layers[li].nindex_in[0]]
+               for li in self.loss_layer_indices()]
+        self.tokens_per_example = max(
+            (s.y for s in seq if s.is_seq), default=1)
 
     # -- construction ----------------------------------------------------
 
@@ -275,40 +287,23 @@ class FuncNet:
 
     @property
     def scope_names(self) -> Tuple[str, ...]:
+        """Every scope a layer opens: its own, and the named parts
+        inside it where a layer type has some (``sub_scopes``)."""
+        inner = {n for layer in self.layer_objs
+                 for n in getattr(layer, "sub_scopes", ())}
         return tuple(self.layer_scope(li)
-                     for li in range(len(self.graph.layers)))
+                     for li in range(len(self.graph.layers))) \
+            + tuple(sorted(inner))
 
-    def forward(self, params: Params, state: NetState,
-                data: jnp.ndarray,
-                extra: Sequence[jnp.ndarray] = (),
-                is_train: bool = False,
-                rng: Optional[jax.Array] = None,
-                collect_logits: bool = False,
-                mask: Optional[jnp.ndarray] = None):
-        """Run all connections in config order.
-
-        Returns (node_values, new_state, loss_inputs) where loss_inputs
-        maps layer index -> pre-transform logits of each loss layer
-        (only when collect_logits).
-        """
+    def _run_layers(self, lo: int, hi: int, params: Params,
+                    new_state: NetState, nodes: List, loss_inputs: Dict,
+                    is_train: bool, rng, collect_logits: bool, mask) -> None:
+        """Connections ``lo .. hi`` in config order, writing ``nodes``,
+        ``new_state`` and ``loss_inputs`` in place."""
         g = self.graph
-        nodes: List[Optional[jnp.ndarray]] = [None] * g.num_nodes
-        if not jnp.issubdtype(data.dtype, jnp.floating):
-            # uint8 pipeline: pixels ship to the device raw and are
-            # normalized here (4x less host->device traffic), in
-            # float32 and in the host augmenter's order (buf -= mean;
-            # buf *= scale), so the first layer sees the host path's
-            # values to the bit. A floating input is some host's
-            # finished work and passes untouched
-            with jax.named_scope("input_norm"):
-                data = self._normalize_raw(data, mask)
-        nodes[0] = data
-        for i in range(g.extra_data_num):
-            nodes[1 + i] = extra[i]
-        new_state: NetState = dict(state)
-        loss_inputs: Dict[int, jnp.ndarray] = {}
         fold_eval = self._bn_fold_eval and not is_train
-        for li, info in enumerate(g.layers):
+        for li in range(lo, hi):
+            info = g.layers[li]
             if li in self._identity_layers \
                     or li in self._pool_passthrough \
                     or (fold_eval and li in self._fold_bns):
@@ -357,6 +352,98 @@ class FuncNet:
                 new_state[pkey] = s2
             for ni, v in zip(info.nindex_out, outs):
                 nodes[ni] = v
+
+    def _segments(self) -> List[Tuple[int, int]]:
+        """``remat = block``'s cuts: a segment ends after each ``add``
+        layer (the join that closes a residual block); what follows the
+        last one (final norm, head) is a segment too."""
+        cuts = [li + 1 for li, info in enumerate(self.graph.layers)
+                if self.graph.effective_type(li) == "add"]
+        ends = sorted(set(cuts + [len(self.graph.layers)]))
+        return list(zip([0] + ends[:-1], ends))
+
+    def _run_segment(self, lo: int, hi: int, keep, params, new_state, nodes,
+                     loss_inputs, rng, collect_logits, mask) -> None:
+        """One segment under ``jax.checkpoint``: only what later layers
+        (or the caller, ``keep``) read of it is stored; its inside is
+        recomputed when the backward pass reaches it, one segment at a
+        time (the barriers of ``prevent_cse`` tie each recomputation to
+        the cotangent that needs it)."""
+        g = self.graph
+        made = {ni for li in range(lo, hi) for ni in g.layers[li].nindex_out}
+        read_in = {ni for li in range(lo, hi) for ni in g.layers[li].nindex_in}
+        later = {ni for li in range(hi, len(g.layers))
+                 for ni in g.layers[li].nindex_in} | set(keep)
+        live_in = {ni: nodes[ni] for ni in sorted(read_in)
+                   if nodes[ni] is not None}
+        live_out = sorted(made & later)
+        keys = sorted({g.layer_key(g.param_layer_index(li))
+                       for li in range(lo, hi)})
+
+        def run(p_seg, s_seg, live, rng):
+            seg_nodes = [None] * g.num_nodes
+            for ni, v in live.items():
+                seg_nodes[ni] = v
+            st, logits = dict(s_seg), {}
+            self._run_layers(lo, hi, p_seg, st, seg_nodes, logits, True,
+                             rng, collect_logits, mask)
+            return ({ni: seg_nodes[ni] for ni in live_out},
+                    {k: st[k] for k in keys if k in st}, logits)
+
+        outs, st, logits = jax.checkpoint(run)(
+            {k: params[k] for k in keys if k in params},
+            {k: new_state[k] for k in keys if k in new_state}, live_in, rng)
+        for ni, v in outs.items():
+            nodes[ni] = v
+        new_state.update(st)
+        loss_inputs.update(logits)
+
+    def forward(self, params: Params, state: NetState,
+                data: jnp.ndarray,
+                extra: Sequence[jnp.ndarray] = (),
+                is_train: bool = False,
+                rng: Optional[jax.Array] = None,
+                collect_logits: bool = False,
+                mask: Optional[jnp.ndarray] = None,
+                keep_nodes: Sequence[int] = ()):
+        """Run all connections in config order.
+
+        With ``block_remat`` set (the trainer's ``remat = block``) a
+        training pass runs segment by segment under ``jax.checkpoint``
+        (``_segments``); a node no later layer reads is then None in
+        the returned list unless ``keep_nodes`` names it.
+
+        Returns (node_values, new_state, loss_inputs) where loss_inputs
+        maps layer index -> pre-transform logits of each loss layer
+        (only when collect_logits).
+        """
+        g = self.graph
+        nodes: List[Optional[jnp.ndarray]] = [None] * g.num_nodes
+        if self.ids_input:
+            pass        # integer ids: the embed layer's to look up
+        elif not jnp.issubdtype(data.dtype, jnp.floating):
+            # uint8 pipeline: pixels ship to the device raw and are
+            # normalized here (4x less host->device traffic), in
+            # float32 and in the host augmenter's order (buf -= mean;
+            # buf *= scale), so the first layer sees the host path's
+            # values to the bit. A floating input is some host's
+            # finished work and passes untouched
+            with jax.named_scope("input_norm"):
+                data = self._normalize_raw(data, mask)
+        nodes[0] = data
+        for i in range(g.extra_data_num):
+            nodes[1 + i] = extra[i]
+        new_state: NetState = dict(state)
+        loss_inputs: Dict[int, jnp.ndarray] = {}
+        if is_train and self.block_remat:
+            for lo, hi in self._segments():
+                self._run_segment(lo, hi, keep_nodes, params, new_state,
+                                  nodes, loss_inputs, rng, collect_logits,
+                                  mask)
+        else:
+            self._run_layers(0, len(g.layers), params, new_state, nodes,
+                             loss_inputs, is_train, rng, collect_logits,
+                             mask)
         return nodes, new_state, loss_inputs
 
     def _normalize_raw(self, data, mask):
@@ -395,7 +482,7 @@ class FuncNet:
         """
         nodes, new_state, loss_inputs = self.forward(
             params, state, data, extra=extra, is_train=True, rng=rng,
-            collect_logits=True, mask=mask)
+            collect_logits=True, mask=mask, keep_nodes=collect_nodes)
         slices = {name: (a, b) for name, a, b in self.graph.label_slices()}
         total = jnp.float32(0.0)
         for li, logit in loss_inputs.items():
@@ -430,7 +517,11 @@ class FuncNet:
         conv/dense contractions; a training step is ~3x — one forward
         plus two backward GEMMs per contraction). XLA's own
         cost_analysis undercounts fused TPU convolutions ~15x
-        (doc/perf_profile.md), so MFU telemetry uses this count."""
+        (doc/perf_profile.md), so MFU telemetry uses this count. An
+        example of a sequence net is one sequence: a ``fullc`` counts
+        every position, and the sequence layers count themselves
+        (``flops_per_example``: causal attention at half the square,
+        routed experts at the picks that land on held experts)."""
         g = self.graph
         total = 0
         for li in range(len(g.layers)):
@@ -444,7 +535,11 @@ class FuncNet:
                           * out.ch * out.y * out.x)
             elif t in ("fullc", "pallas_fullc", "fixconn"):
                 p = layer.param
-                total += 2 * p.num_input_node * p.num_hidden
+                s = layer.in_shapes[0]
+                total += 2 * p.num_input_node * p.num_hidden \
+                    * (s.y if s.is_seq else 1)
+            elif hasattr(layer, "flops_per_example"):
+                total += layer.flops_per_example()
         return float(total)
 
     def loss_layer_indices(self) -> List[int]:

@@ -118,8 +118,8 @@ class NetTrainer:
         #                                  state for seamless resume
         self.remat = "none"              # rematerialization policy for
         #                                  the backward pass: none |
-        #                                  full | dots | conv (see
-        #                                  _wrap_loss_fn)
+        #                                  full | dots | conv | block
+        #                                  (see _wrap_loss_fn)
         self.remat_barrier = 1           # 0: drop checkpoint's CSE
         #                                  barriers (XLA then undoes
         #                                  the recompute — see
@@ -239,8 +239,10 @@ class NetTrainer:
             if name == "save_optimizer":
                 self.save_optimizer = int(val)
             if name == "remat":
-                if val not in ("none", "0", "full", "dots", "conv"):
-                    raise ValueError("remat must be none|full|dots|conv")
+                if val not in ("none", "0", "full", "dots", "conv",
+                               "block"):
+                    raise ValueError(
+                        "remat must be none|full|dots|conv|block")
                 self.remat = "none" if val == "0" else val
             if name == "remat_barrier":
                 self.remat_barrier = int(val)
@@ -340,6 +342,9 @@ class NetTrainer:
             ni = self.net.node_index_by_name(node) if node else top
             self._metric_nodes.append(ni)
         self._label_slices = self.graph.label_slices()
+        # expert layers leave their counters in their state (_emit_moe)
+        self._moe_keys = [lk for lk, st in self.net_state.items()
+                          if "picks_held" in st]
         # serve_dtype activation BEFORE the programs build: the specs
         # live on the layer objects and must be pinned before any
         # forward traces (nnet/quantize.attach)
@@ -500,6 +505,15 @@ class NetTrainer:
             * conv — save ONLY conv-layer outputs (tagged ``conv_out``
               in layers/conv.py); FC dots, BN, activations and pools
               are recomputed.
+            * block — the net itself runs segment by segment under
+              ``jax.checkpoint``, a segment ending after each ``add``
+              layer (``FuncNet._segments``): only the residual stream
+              between a decoder's blocks is stored, and each block's
+              inside is recomputed when the backward pass reaches it,
+              one block at a time. (One checkpoint over the whole loss
+              with a save-these-names policy stores the same but lets
+              the compiler recompute every block at once: 0.6 GB over
+              a v5e at 2 x 8k positions; my compile, PR 28.)
 
             remat_barrier=0 drops the optimization barriers
             (prevent_cse=False). Measured (doc/perf_profile.md r5):
@@ -514,7 +528,8 @@ class NetTrainer:
             fn = (lambda p, s, d, l, m, e, r:
                   net.loss_fn(p, s, d, l, m, extra=e, rng=r,
                               collect_nodes=metric_nodes))
-            if self.remat == "none":
+            net.block_remat = self.remat == "block"
+            if self.remat in ("none", "block"):
                 return fn
             barrier = bool(self.remat_barrier)
             if self.remat == "full":
@@ -1140,7 +1155,8 @@ class NetTrainer:
         Shapes must be fully known: batch_size from the config, the
         instance shape from ``input_shape``, input dtype from what the
         run's iterator handed over: uint8 once a normalisation was
-        adopted (``set_input_norm``, before this call), else float32.
+        adopted (``set_input_norm``, before this call), else float32;
+        int32 where the net's first layer looks up ids (``embed``).
         Nets with
         ``extra_data`` inputs and eval iterators with a different
         batch_size fall back to the jit path for those dispatches —
@@ -1155,7 +1171,8 @@ class NetTrainer:
         with self._span("setup.precompile") as span:
             from ..io.data import inst_array_shape
             self._enable_persistent_cache()
-            dtype = np.dtype(np.float32 if self.input_norm is None
+            dtype = np.dtype(np.int32 if self.net.ids_input
+                             else np.float32 if self.input_norm is None
                              else np.uint8)
             tag = self._dtype_tag(dtype)
             # GLOBAL batch shapes: multi-process dispatch arrays come out of
@@ -1424,14 +1441,16 @@ class NetTrainer:
 
     def _ship(self, arr: np.ndarray, sharding) -> jnp.ndarray:
         """Cast-and-transfer policy shared by per-batch and K-window
-        placement: u8 pixels ship raw (1/4 bytes, device casts), all
-        else float32; under multi-process dp each rank contributes its
+        placement: u8 pixels ship raw (1/4 bytes, device casts), the
+        int32 ids of a net that embeds them ship as they are, all else
+        float32; under multi-process dp each rank contributes its
         local shard of the global batch (config batch_size is GLOBAL,
         split across ranks like the reference splits across PS
         workers). bf16 rows also ship raw — a bf16-warmed serve ladder
         staging through here must not silently up-cast (and recompile)
         on the H2D path."""
-        if arr.dtype != np.uint8 and arr.dtype != _BF16:
+        ids = self.net.ids_input and arr.dtype == np.int32
+        if arr.dtype != np.uint8 and arr.dtype != _BF16 and not ids:
             arr = np.asarray(arr, np.float32)  # cxxlint: disable=CXL003 -- host-side cast before the H2D ship; input is host numpy
         if jax.process_count() > 1:
             return jax.make_array_from_process_local_data(sharding, arr)
@@ -1545,6 +1564,9 @@ class NetTrainer:
         self._mon.emit("model_info",
                        flops_per_example=fwd,
                        train_flops_per_example=3.0 * fwd,
+                       tokens_per_example=net.tokens_per_example,
+                       train_flops_per_token=3.0 * fwd
+                       / net.tokens_per_example,
                        params=n_params,
                        layers=len(net.graph.layers))
         self._mon.emit("layout",
@@ -1614,11 +1636,40 @@ class NetTrainer:
         self._mon.emit(
             "step", step=self._steps_total, round=self.round,
             dispatch=kind, n_batches=n_batches, examples=examples,
+            tokens=examples * self.net.tokens_per_example,
             wall_ms=wall * 1e3, data_wait_ms=wait * 1e3,
             examples_per_sec=examples / wall if wall > 0 else 0.0,
             update_counter=self.update_counter, lr=lr,
             loss=float(self._last_loss),
             compile=compiled)
+        self._emit_moe(examples // n_batches)
+
+    def _emit_moe(self, rows: int) -> None:
+        """One ``moe`` record a dispatch of a net with expert layers:
+        what each layer's held experts got in the dispatch's LAST step,
+        from the counters the layers leave in their state (the loss
+        this dispatch was closed by is already on the host, so the
+        state is ready: no further wait)."""
+        layers = {}
+        for lkey in self._moe_keys:
+            st = self.net_state[lkey]
+            layer = self.net.layer_objs[self._layer_index[lkey]]
+            load = np.asarray(st["load"], np.float64)  # cxxlint: disable=CXL003 -- monitor-gated fetch of a few counters after the loss
+            picks = rows * layer.in_shapes[0].y * layer.topk
+            layers[lkey] = {
+                "load_min": float(load.min()), "load_mean": float(load.mean()),
+                "load_max": float(load.max()),
+                "held_share": float(st["picks_held"]) / picks,
+                "dropped": int(st["dropped"])}
+        if layers:
+            self._mon.emit(
+                "moe", step=self._steps_total, layers=layers,
+                dropped=sum(v["dropped"] for v in layers.values()),
+                held_share=float(np.mean([v["held_share"]
+                                          for v in layers.values()])),
+                load_max_over_mean=max(
+                    v["load_max"] / max(v["load_mean"], 1e-9)
+                    for v in layers.values()))
 
     def end_round(self) -> None:
         """Close the current round's counter window (idempotent):

@@ -186,3 +186,53 @@ def test_alexnet_data_parallel_step_compiles_for_four_v5e(topo):
     text = compiled.as_text()
     assert "all-reduce" in text or "reduce-scatter" in text
     assert "all-gather" in text
+
+
+@pytest.mark.parametrize("kind", ["mla_attention", "moe"])
+def test_decoder_layer_compiles_for_one_v5e_at_published_widths(topo, kind):
+    """The two heavy sequence layers at the language-model cell's shapes
+    (2 x 8,192 positions, hidden 2048, bfloat16), forward and backward.
+    What the chip showed and only the chip's compiler can say (PERF.md,
+    PR 28): the attention's row maximum must stay a plain reduce (XLA
+    rewrote it into a ``reduce-window`` 2 x keys - 1 wide, 8,192 times the
+    work) and no product may come out heads-minor (16 of 128 lanes)."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from cxxnet_tpu.layers import create_layer, seq_shape
+    cfg = {"mla_attention": dict(
+        nhead=16, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        kv_lora_rank=512, rope_theta=800000.0, eps=1e-5, q_block=1024),
+        "moe": dict(nexpert=64, topk=6, nhidden=1408, nshared=2,
+                    routed_scaling_factor=2.446, expert_count=8,
+                    expert_block=512, bias_sigma=0.01)}[kind]
+    layer = create_layer(kind, [(k, str(v)) for k, v in cfg.items()]
+                         + [("dtype", "bfloat16")])
+    layer.infer_shape([seq_shape(8192, 2048)])
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
+
+    params = jax.tree.map(on, jax.eval_shape(layer.init_params,
+                                             jax.random.PRNGKey(0)))
+    state = jax.tree.map(on, jax.eval_shape(layer.init_state))
+    x = jax.ShapeDtypeStruct((2, 8192, 2048), jnp.bfloat16, sharding=one)
+
+    def loss(p, x):
+        (y,), _ = layer.forward(p, state_v, [x], True, None)
+        return jnp.sum(y.astype(jnp.float32))
+
+    def step(p, s, x):
+        nonlocal state_v
+        state_v = s
+        return jax.grad(loss, argnums=(0, 1))(p, x)
+
+    state_v = None
+    text = jax.jit(step).lower(params, state, x).compile().as_text()
+    wide = re.findall(r"reduce-window\([^\n]*window=\{size=([0-9x]+)", text)
+    assert all(max(map(int, w.split("x"))) <= 1024 for w in wide), wide
+    minor = re.findall(r"= [a-z0-9]+\[[0-9,]*,(\d+)\]\{[^}]*\} convolution\(",
+                       text)
+    assert minor and "16" not in minor, sorted(set(minor))
