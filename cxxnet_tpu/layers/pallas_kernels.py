@@ -595,6 +595,306 @@ def pool_concat_applicable(h: int, w: int, total_ch: int, k: int,
     return 3 * per_item <= 6 * 1024 * 1024
 
 
+# ------------------------------------------- fused causal attention
+
+# a masked score, as the XLA core writes it (layers/sequence.py)
+_MASKED = -1e30
+_NT = (((1,), (1,)), ((), ()))       # a @ b.T
+_TN = (((0,), (0,)), ((), ()))       # a.T @ b
+_ATTN_VMEM = 64 * 1024 * 1024
+
+
+def _diag_key_tile(i, bq, bk):
+    """The last key tile that query tile ``i`` sees."""
+    return ((i + 1) * bq - 1) // bk
+
+
+def _first_query_tile(j, bq, bk):
+    """The first query tile that sees key tile ``j``."""
+    return j * bk // bq
+
+
+def _attn_fwd_kernel(scale, bq, bk, nparts, *refs):
+    """One (batch x head, query tile i, key tile j) step of the online
+    softmax: ``s = q k^T * scale`` on the MXU, the running row maximum
+    ``m``, row sum ``l`` and the un-normalised ``acc = sum p v`` in
+    float32 VMEM scratch across the key tiles, ``p`` in the values'
+    dtype for ``p v``. Key tiles wholly above the diagonal do nothing
+    (and fetch nothing: the index maps stop at the diagonal); only a
+    tile the diagonal crosses pays for the mask. At the diagonal's tile
+    the output is normalised once and the row log-sum-exp goes out as a
+    lane-dense row."""
+    from jax.experimental import pallas as pl
+    q_refs, k_refs = refs[:nparts], refs[nparts:2 * nparts]
+    v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = refs[2 * nparts:]
+    i, j = pl.program_id(1), pl.program_id(2)
+    last = _diag_key_tile(i, bq, bk)
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, _MASKED, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def tile(masked):
+        s = sum(jax.lax.dot_general(q[...], k[...], _NT,
+                                    preferred_element_type=jnp.float32)
+                for q, k in zip(q_refs, k_refs)) * scale
+        if masked:
+            qi = i * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            ki = j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(ki <= qi, s, _MASKED)
+        m_prev = m_ref[...]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_next)
+        p = jnp.exp(s - m_next)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[...] = m_next
+        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+            p.astype(v_ref.dtype), v_ref[...],
+            preferred_element_type=jnp.float32)
+
+    below = (j + 1) * bk - 1 <= i * bq     # every key before every query
+    pl.when(below)(lambda: tile(False))
+    pl.when(jnp.logical_and(j <= last, jnp.logical_not(below)))(
+        lambda: tile(True))
+
+    @pl.when(j == last)
+    def _():
+        l = l_ref[...]
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
+        lse = m_ref[...] + jnp.log(l)
+        lse_ref[...] = jnp.broadcast_to(lse, (bq, 128)).T[:1]
+
+
+def _attn_bwd_kernel(scale, bq, bk, nparts, *refs):
+    """One (batch x head, key tile j, query tile i) step of the backward
+    pass, keys on the sublanes and queries on the lanes so that the
+    saved log-sum-exp and ``D = rowsum(dO * O)`` broadcast as rows:
+    ``p^T = exp(k q^T * scale - lse)`` again from the residuals, ``dV +=
+    p^T dO``, ``dS^T = p^T * (v dO^T - D)``, ``dK += dS^T q`` in float32
+    scratch across the query tiles, ``dQ += dS k`` into the float32
+    ``dQ`` of the whole sequence, which stays in VMEM for all of one
+    batch x head. ``scale`` multiplies ``dK`` once at the end and ``dQ``
+    outside. Query tiles wholly before the key tile do nothing."""
+    from jax.experimental import pallas as pl
+    q_refs, k_refs = refs[:nparts], refs[nparts:2 * nparts]
+    v_ref, do_ref, lse_ref, dd_ref = refs[2 * nparts:2 * nparts + 4]
+    outs = refs[2 * nparts + 4:]
+    dq_refs, dk_refs, dv_ref = outs[:nparts], outs[nparts:2 * nparts], \
+        outs[2 * nparts]
+    dk_accs, dv_acc = outs[2 * nparts + 1:3 * nparts + 1], outs[-1]
+    j, i = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(jnp.logical_and(j == 0, i == 0))
+    def _():
+        for dq in dq_refs:
+            dq[...] = jnp.zeros(dq.shape, jnp.float32)
+
+    @pl.when(i == 0)
+    def _():
+        for acc in dk_accs + (dv_acc,):
+            acc[...] = jnp.zeros(acc.shape, jnp.float32)
+
+    def tile(masked):
+        st = sum(jax.lax.dot_general(k[...], q[...], _NT,
+                                     preferred_element_type=jnp.float32)
+                 for q, k in zip(q_refs, k_refs)) * scale
+        if masked:
+            ki = j * bk + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
+            qi = i * bq + jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
+            st = jnp.where(ki <= qi, st, _MASKED)
+        pt = jnp.exp(st - lse_ref[...])
+        do = do_ref[...]
+        dv_acc[...] += jnp.dot(pt.astype(do.dtype), do,
+                               preferred_element_type=jnp.float32)
+        dpt = jax.lax.dot_general(v_ref[...], do, _NT,
+                                  preferred_element_type=jnp.float32)
+        dst = (pt * (dpt - dd_ref[...])).astype(do.dtype)
+        rows = pl.ds(pl.multiple_of(i * bq, bq), bq)
+        for q, k, dq, dk_acc in zip(q_refs, k_refs, dq_refs, dk_accs):
+            dk_acc[...] += jnp.dot(dst, q[...],
+                                   preferred_element_type=jnp.float32)
+            dq[rows, :] += jax.lax.dot_general(
+                dst, k[...], _TN, preferred_element_type=jnp.float32)
+
+    below = (j + 1) * bk - 1 <= i * bq     # every key before every query
+    pl.when(below)(lambda: tile(False))
+    pl.when(jnp.logical_and(i >= _first_query_tile(j, bq, bk),
+                            jnp.logical_not(below)))(lambda: tile(True))
+
+    @pl.when(i == pl.num_programs(2) - 1)
+    def _():
+        for dk, acc in zip(dk_refs, dk_accs):
+            dk[...] = (acc[...] * scale).astype(dk.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _attn_fwd_call(qs, ks, v, scale, bq, bk):
+    """``(o, lse)`` of ``qs[n]`` ``(bh, t, d_n)`` against ``ks[n]``
+    ``(bh or fewer, t, d_n)`` (a part with fewer leading entries is
+    shared by the heads of a batch item) and ``v`` ``(bh, t, dv)``; the
+    score is the sum of the parts' products."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    bh, t, dv = v.shape
+    n = len(qs)
+
+    def at_q(b, i, j):
+        return b, i, 0
+
+    def at_k(share):
+        # past the diagonal the same tile again: nothing is fetched
+        return lambda b, i, j: (
+            b // share, jnp.minimum(j, _diag_key_tile(i, bq, bk)), 0)
+
+    return pl.pallas_call(
+        partial(_attn_fwd_kernel, scale, bq, bk, n),
+        grid=(bh, t // bq, t // bk),
+        in_specs=[pl.BlockSpec((None, bq, q.shape[2]), at_q) for q in qs]
+        + [pl.BlockSpec((None, bk, k.shape[2]), at_k(bh // k.shape[0]))
+           for k in ks]
+        + [pl.BlockSpec((None, bk, dv), at_k(1))],
+        out_specs=[pl.BlockSpec((None, bq, dv), at_q),
+                   pl.BlockSpec((None, 1, bq), lambda b, i, j: (b, 0, i))],
+        out_shape=[jax.ShapeDtypeStruct((bh, t, dv), v.dtype),
+                   jax.ShapeDtypeStruct((bh, 1, t), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_ATTN_VMEM),
+        interpret=_build_interpret(),
+    )(*qs, *ks, v)
+
+
+def _attn_bwd_call(qs, ks, v, do, lse, dd, scale, bq, bk):
+    """``(dqs, dks, dv)``: ``dqs`` float32 and without ``scale`` (the
+    caller's cast applies it), ``dks`` a head each whatever ``ks``
+    shares, in the keys' dtype."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    bh, t, dv = v.shape
+    n = len(qs)
+
+    def at_k(share):
+        return lambda b, j, i: (b // share, j, 0)
+
+    def first(j, i):
+        # before the key tile's first query tile the same tile again
+        return jnp.maximum(i, _first_query_tile(j, bq, bk))
+
+    def at_q(b, j, i):
+        return b, first(j, i), 0
+
+    def at_row(b, j, i):
+        return b, 0, first(j, i)
+
+    def whole(b, j, i):
+        return b, 0, 0
+
+    return pl.pallas_call(
+        partial(_attn_bwd_kernel, scale, bq, bk, n),
+        grid=(bh, t // bk, t // bq),
+        in_specs=[pl.BlockSpec((None, bq, q.shape[2]), at_q) for q in qs]
+        + [pl.BlockSpec((None, bk, k.shape[2]), at_k(bh // k.shape[0]))
+           for k in ks]
+        + [pl.BlockSpec((None, bk, dv), at_k(1)),
+           pl.BlockSpec((None, bq, dv), at_q),
+           pl.BlockSpec((None, 1, bq), at_row),
+           pl.BlockSpec((None, 1, bq), at_row)],
+        out_specs=[pl.BlockSpec((None, t, q.shape[2]), whole) for q in qs]
+        + [pl.BlockSpec((None, bk, k.shape[2]), at_k(1)) for k in ks]
+        + [pl.BlockSpec((None, bk, dv), at_k(1))],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, jnp.float32) for q in qs]
+        + [jax.ShapeDtypeStruct((bh,) + k.shape[1:], k.dtype) for k in ks]
+        + [jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((bk, k.shape[2]), jnp.float32)
+                        for k in ks]
+        + [pltpu.VMEM((bk, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_ATTN_VMEM),
+        interpret=_build_interpret(),
+    )(*qs, *ks, v, do, lse, dd)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _attention(qs, ks, v, scale, bq, bk):
+    return _attn_fwd_call(qs, ks, v, scale, bq, bk)[0]
+
+
+def _attention_fwd(qs, ks, v, scale, bq, bk):
+    o, lse = _attn_fwd_call(qs, ks, v, scale, bq, bk)
+    return o, (qs, ks, v, o, lse)
+
+
+def _attention_bwd(scale, bq, bk, res, do):
+    qs, ks, v, o, lse = res
+    dd = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                 axis=-1)[:, None, :]
+    out = _attn_bwd_call(qs, ks, v, do, lse, dd, scale, bq, bk)
+    n = len(qs)
+    dqs = tuple((dq * scale).astype(q.dtype) for dq, q in zip(out[:n], qs))
+    # a part the heads share gets the sum of their gradients
+    dks = tuple(dk if dk.shape == k.shape else
+                dk.reshape((k.shape[0], -1) + k.shape[1:])
+                .astype(jnp.float32).sum(axis=1).astype(k.dtype)
+                for dk, k in zip(out[n:2 * n], ks))
+    return dqs, dks, out[2 * n]
+
+
+_attention.defvjp(_attention_fwd, _attention_bwd)
+
+
+_ATTN_TILES = (1024, 512, 256, 128)
+
+
+def _attn_tiles(time: int, q_block: int):
+    """(query tile, key tile): the largest of ``_ATTN_TILES`` that
+    divides ``time``, the query tile no larger than ``q_block`` (0: no
+    cap). No search at run time: on a v5e at 2 x 16 x 8,192 a layer's
+    two forwards and one backward took 31.9 ms at 1,024 x 1,024, 34.0 at
+    512 x 1,024, 33.8 at 2,048 x 1,024, 42.0 at 1,024 x 512, 43.7 at
+    512 x 512, 73.6 at 256 x 256 (my chip run, PR 29)."""
+    fits = [b for b in _ATTN_TILES if time % b == 0]
+    capped = [b for b in fits if not 0 < q_block < b]
+    return (capped[0] if capped else 0), (fits[0] if fits else 0)
+
+
+def causal_attention_applicable(time: int, q_block: int, qk_widths,
+                                v_width: int) -> bool:
+    """Shape gate of :func:`causal_attention`: the sequence tiles (a
+    multiple of 128 positions, a query tile within ``q_block``), every
+    part of the query/key features is whole half-lanes (64) and the
+    values whole lanes (128), and the float32 ``dQ`` of one sequence,
+    which the backward kernel keeps in VMEM twice over, stays within
+    half of the kernels' VMEM."""
+    lanes = sum(_pad_to(d, 128) for d in qk_widths)
+    return (min(_attn_tiles(time, q_block)) > 0
+            and all(d > 0 and d % 64 == 0 for d in qk_widths)
+            and v_width > 0 and v_width % 128 == 0
+            and 2 * 4 * time * lanes <= _ATTN_VMEM // 2)
+
+
+def causal_attention(qs, ks, v, scale: float, q_block: int = 0):
+    """Causal ``softmax(sum_n qs[n] ks[n]^T * scale) v`` as one fused
+    kernel a direction: no score tile leaves VMEM. ``qs[n]`` ``(batch,
+    heads, time, d_n)``; ``ks[n]`` the same, or with one head where the
+    heads share that part of the key (MLA's ``k_rope``); ``v`` ``(batch,
+    heads, time, dv)``. Products take the operands' dtype with float32
+    accumulation, the softmax is float32. Differentiable in ``qs``,
+    ``ks`` and ``v``; the backward pass recomputes the probabilities
+    from ``q``, ``k`` and the saved row log-sum-exp."""
+    b, h, t, dv = v.shape
+    bq, bk = _attn_tiles(t, q_block)
+    flat = lambda a: a.reshape((-1,) + a.shape[2:])
+    o = _attention(tuple(map(flat, qs)), tuple(map(flat, ks)), flat(v),
+                   scale, bq, bk)
+    return o.reshape(b, h, t, dv)
+
+
 class PallasFullConnectLayer(FullConnectLayer):
     """fullc with the matmul lowered through the Pallas kernel
     (config name ``pallas_fullc``); numerically identical to ``fullc``

@@ -27,6 +27,7 @@ from typing import Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 
+from . import pallas_kernels
 from .base import Layer, Shape3, seq_shape
 
 _F32 = jnp.float32
@@ -262,6 +263,8 @@ class MLAAttentionLayer(_SeqLayer):
         y = softmax(q k^T / sqrt(d_nope + d_rope)) v Wo
     """
 
+    sub_scopes = ("core",)
+
     def __init__(self, cfg=()):
         self.nhead = 0
         self.d_nope = 0
@@ -271,6 +274,7 @@ class MLAAttentionLayer(_SeqLayer):
         self.rope_theta = 10000.0
         self.eps = 1e-6
         self.q_block = 0
+        self.fused_core = False
         super().__init__(cfg)
 
     def set_param(self, name, val):
@@ -299,6 +303,9 @@ class MLAAttentionLayer(_SeqLayer):
             raise ValueError(
                 "mla_attention: must set nhead, qk_nope_head_dim, "
                 "qk_rope_head_dim (even), v_head_dim, kv_lora_rank")
+        # which core runs is what the shapes allow, not a key
+        self.fused_core = pallas_kernels.causal_attention_applicable(
+            s.y, self.q_block, (self.d_nope, self.d_rope), self.d_v)
         self.in_shapes = [s]
         self.out_shapes = [s]
         return self.out_shapes
@@ -332,14 +339,21 @@ class MLAAttentionLayer(_SeqLayer):
         cos, sin = rope_tables(t, self.d_rope, self.rope_theta)
         q_rope = apply_rope(q_rope, cos, sin)
         k_rope = apply_rope(ckr[..., self.kv_rank:], cos, sin)
-        # heads beside the batch; the shared k_r is repeated a head
-        heads = lambda a: a.transpose(0, 2, 1, 3)
-        q = heads(jnp.concatenate([q_nope, q_rope], axis=-1))
-        k = heads(jnp.concatenate([k_nope, jnp.broadcast_to(
-            k_rope[:, :, None, :], (b, t, h, self.d_rope))], axis=-1))
-        o = causal_attention(
-            q, k, heads(v), 1.0 / math.sqrt(self.d_nope + self.d_rope),
-            self.q_block)
+        heads = lambda a: a.transpose(0, 2, 1, 3)   # heads beside the batch
+        scale = 1.0 / math.sqrt(self.d_nope + self.d_rope)
+        with jax.named_scope("core"):
+            if self.fused_core:
+                # the score is q_nope k_nope^T + q_rope k_r^T, the one
+                # shared k_r as it is
+                o = pallas_kernels.causal_attention(
+                    (heads(q_nope), heads(q_rope)),
+                    (heads(k_nope), k_rope[:, None]), heads(v), scale,
+                    self.q_block)
+            else:
+                q = heads(jnp.concatenate([q_nope, q_rope], axis=-1))
+                k = heads(jnp.concatenate([k_nope, jnp.broadcast_to(
+                    k_rope[:, :, None, :], (b, t, h, self.d_rope))], axis=-1))
+                o = causal_attention(q, k, heads(v), scale, self.q_block)
         return [_dot(heads(o).reshape(b, t, h * self.d_v), params["wo"],
                      cd)], state
 

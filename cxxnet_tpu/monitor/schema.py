@@ -220,6 +220,9 @@ REQUIRED: Dict[str, tuple] = {
 OPTIONAL: Dict[str, tuple] = {
     "step": ("tokens",),
     "model_info": ("tokens_per_example", "train_flops_per_token"),
+    # mla_attention layers of the net, and how many of them run the
+    # fused causal-attention kernel (layers/pallas_kernels.py)
+    "layout": ("attention_layers", "attention_fused_layers"),
 }
 
 _TIMING_KEYS = ("wall_ms", "data_wait_ms", "total_ms", "max_ms",

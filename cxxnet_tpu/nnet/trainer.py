@@ -1569,6 +1569,10 @@ class NetTrainer:
                        / net.tokens_per_example,
                        params=n_params,
                        layers=len(net.graph.layers))
+        # attention layers, and those whose causal core is the fused
+        # kernel (layers/sequence.py: the shapes decide)
+        cores = [layer.fused_core for layer in net.layer_objs
+                 if hasattr(layer, "fused_core")]
         self._mon.emit("layout",
                        # what took hold, not what was asked for
                        input_layout=self.input_layout_effective,
@@ -1578,6 +1582,8 @@ class NetTrainer:
                        # how any Pallas kernel of this process is built
                        # (layers/pallas_kernels.interpret)
                        pallas_interpret=_pallas.interpret(),
+                       attention_layers=len(cores),
+                       attention_fused_layers=sum(cores),
                        **net.layout_summary)
         if self.quant_report.get("active"):
             r = self.quant_report

@@ -236,3 +236,41 @@ def test_decoder_layer_compiles_for_one_v5e_at_published_widths(topo, kind):
     minor = re.findall(r"= [a-z0-9]+\[[0-9,]*,(\d+)\]\{[^}]*\} convolution\(",
                        text)
     assert minor and "16" not in minor, sorted(set(minor))
+    if kind == "mla_attention":
+        # the causal core is the fused kernel, forward and backward, and
+        # no float32 block of scores (2 x 16 heads x 1,024 queries or
+        # more x 1,024 keys or more) exists outside it
+        assert layer.fused_core and text.count("tpu_custom_call") >= 2
+        scores = re.findall(r"f32\[(?:2,16|32),\d{4,},\d{4,}\]", text)
+        assert not scores, sorted(set(scores))
+
+
+def test_causal_attention_compiles_for_one_v5e_at_the_cells_shapes(topo):
+    """The fused causal attention alone, forward and backward, at the
+    language-model cell's shapes (2 x 16 heads x 8,192 positions, queries
+    and keys of 128 + 64 with one shared k_rope, values of 128, q_block
+    1,024, bfloat16): Mosaic takes the 64-wide part, the transposed
+    product of the backward pass and the 64 MB of VMEM the kernels ask
+    for (the float32 dQ of a sequence stays there)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from cxxnet_tpu.layers import pallas_kernels as pk
+    one = SingleDeviceSharding(topo.devices[0])
+    b, h, t = 2, 16, 8192
+    assert pk.causal_attention_applicable(t, 1024, (128, 64), 128)
+
+    def sds(heads, d):
+        return jax.ShapeDtypeStruct((b, heads, t, d), jnp.bfloat16,
+                                    sharding=one)
+
+    def loss(qn, qr, kn, kr, v):
+        o = pk.causal_attention((qn, qr), (kn, kr), v, 192 ** -0.5, 1024)
+        return jnp.sum(o.astype(jnp.float32))
+
+    args = (sds(h, 128), sds(h, 64), sds(h, 128), sds(1, 64), sds(h, 128))
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))) \
+        .lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 2
+    grads = compiled.out_info
+    assert [g.shape for g in grads] == [a.shape for a in args]
