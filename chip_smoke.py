@@ -13,9 +13,7 @@ file and ``key=value`` overrides), at AlexNet's published width
 3. pred     ``task = pred`` from that snapshot over the same archive
 4. serve    ``task = export`` seals a bundle, ``task = serve`` boots FROM
             the bundle and answers a few hundred closed-loop requests
-5. scanned step   ``bench.measure("alexnet_up2")``: ``run_steps``,
-            ``update_period = 2`` and ``input_layout = rowmajor``
-6. kernels  each Pallas kernel of ``layers/pallas_kernels.py`` compiled by
+5. kernels  each Pallas kernel of ``layers/pallas_kernels.py`` compiled by
             Mosaic at a real width of a zoo model, forward and VJP,
             against a plain ``jax.numpy`` reference
 
@@ -24,7 +22,7 @@ what was printed. Any failed check raises, so the script exits non-zero;
 nothing is caught to let a run finish. It is a smoke: it prints wall
 and compile times per phase, and no rate under a metric's name.
 
-    python chip_smoke.py            # one chip, all six phases
+    python chip_smoke.py            # one chip, all five phases
     python chip_smoke.py --chips 4  # ONLY data-parallel training over the
                                     # four chips of one host, against the
                                     # same steps on a one-device mesh
@@ -73,14 +71,11 @@ class Size(NamedTuple):
     serve_clients: int
     serve_requests: int     # per client
     serve_request_rows: int
-    bench_steps: int
-    bench_batch: Optional[int]   # None = the bench model's own
 
 
 REAL = Size(batch=256, image=227, src_image=256, n_images=1024,
             dispatch_period=4, rounds=3, serve_buckets="8,32",
-            serve_clients=8, serve_requests=32, serve_request_rows=4,
-            bench_steps=20, bench_batch=None)
+            serve_clients=8, serve_requests=32, serve_request_rows=4)
 
 NCLASS = 1000
 
@@ -207,13 +202,26 @@ def check_run_start(recs: Sequence[Dict], platform: str) -> None:
 # -- phases 1-4: the CLI path ----------------------------------------------
 
 
+def make_raw_rec(path: str, n: int, size: int) -> None:
+    """Pack ``n`` seeded RAW uint8 tensors (no JPEG) of ``size`` x
+    ``size`` x 3 into a RecordIO archive, labels ``i % 1000``."""
+    import numpy as np
+    from cxxnet_tpu.io.recordio import (RecordIOWriter,
+                                        pack_raw_tensor_record)
+    rng = np.random.RandomState(0)
+    w = RecordIOWriter(path)
+    for i in range(n):
+        img = rng.randint(0, 255, (size, size, 3), np.uint8)
+        w.write_record(pack_raw_tensor_record(i, float(i % 1000), img))
+    w.close()
+
+
 def phase_data(out: str, size: Size) -> Dict:
-    import bench
     from cxxnet_tpu.io.recordio import native_available
     check(size.n_images % size.batch == 0,
           "n_images must be a multiple of batch")
     path = os.path.join(out, "train_raw.rec")
-    bench._make_raw_rec(path, n=size.n_images, size=size.src_image)
+    make_raw_rec(path, n=size.n_images, size=size.src_image)
     return {"records": size.n_images, "bytes": os.path.getsize(path),
             "recordio": "native" if native_available() else "python"}
 
@@ -336,31 +344,7 @@ def phase_serve(conf: str, out: str, size: Size, platform: str,
             "compile_events": summ["compile_events"]}
 
 
-# -- phase 5: the scanned step and the layout pin ---------------------------
-
-
-def phase_scanned_step(size: Size) -> Dict:
-    import bench
-    cap = bench.measure(model="alexnet_up2", steps=size.bench_steps,
-                        batch=size.bench_batch)
-    check(cap["zero_recompiles"], "run_steps recompiled in a window")
-    check(cap["precompile_programs"] == 1,
-          "expected ONE run_steps program, got %d"
-          % cap["precompile_programs"])
-    # the pin read back from the executable, then the record
-    check(cap["input_major_to_minor"] == [0, 1, 2, 3],
-          "compiled batch input is laid out %r, not row-major"
-          % cap["input_major_to_minor"])
-    check(cap["layout"]["input_layout"] == "rowmajor",
-          "layout record reports input_layout=%r"
-          % cap["layout"]["input_layout"])
-    return {"model": "alexnet_up2", "steps": size.bench_steps,
-            "input_layout": cap["layout"]["input_layout"],
-            "input_major_to_minor": cap["input_major_to_minor"],
-            "zero_recompiles": cap["zero_recompiles"]}
-
-
-# -- phase 6: the Pallas kernels, compiled ----------------------------------
+# -- phase 5: the Pallas kernels, compiled ----------------------------------
 
 
 class KernelCase(NamedTuple):
@@ -782,8 +766,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             conf, OUT_DIR, REAL, "tpu", train["snapshot"]), meter)
         run_phase("serve", lambda: phase_serve(
             conf, OUT_DIR, REAL, "tpu", train["snapshot"]), meter)
-        run_phase("scanned_step", lambda: phase_scanned_step(REAL),
-                  meter)
         run_phase("kernels", lambda: phase_kernels(
             real=True, compiled=True), meter)
     print(json.dumps({"ok": True, "device": {

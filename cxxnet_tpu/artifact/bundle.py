@@ -624,9 +624,9 @@ def read_index_member(path: str, manifest: Dict[str, Any] = None
 
 
 def serve_cfg_from_bundle(path: str) -> List[Tuple[str, str]]:
-    """Config pairs a conf-less boot (``serve_bench --artifact``)
-    derives from the manifest: the sealed bucket ladder, serve dtype
-    and node. Appended FIRST so an explicit config still wins."""
+    """Config pairs a conf-less boot derives from the manifest: the
+    sealed bucket ladder, serve dtype and node. Appended FIRST so an
+    explicit config still wins."""
     man = bundle_manifest(path)
     pairs = [
         ("serve_buckets", ",".join(str(b) for b in man["buckets"])),

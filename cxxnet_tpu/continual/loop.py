@@ -247,9 +247,9 @@ class ContinualLoop:
 
     ``should_stop`` is polled at every boundary (the CLI passes its
     SIGTERM/SIGINT flag); ``on_generation(record)`` fires after every
-    generation attempt's record is emitted — the soak drivers
-    (``tools/serve_bench.py --generations``, the tier-1 test) use it
-    to coordinate client traffic with the loop's lifecycle.
+    generation attempt's record is emitted — the soak test
+    (tests/test_continual.py) uses it to coordinate client traffic
+    with the loop's lifecycle.
     """
 
     def __init__(self, cfg: Sequence[Tuple[str, str]], trainer,

@@ -17,8 +17,8 @@ works unchanged) and routes each request to a replica process:
   failure (connection refused/reset, torn reply: the signature of a
   replica dying mid-request) retries the SAME rows on another replica,
   excluding the failed one. Losing a replica mid-traffic therefore
-  drops **zero** requests (pinned by tests and the
-  ``serve_bench --replicas`` kill scenario). A ``closed`` reply
+  drops **zero** requests (pinned by tests/test_fleet.py and
+  tests/test_fleet_datapath.py). A ``closed`` reply
   (replica draining) retries the same way; a ``busy`` reply retries
   once on a less-loaded replica before shedding.
 - **fleet-wide tenant quotas** — the per-tenant token buckets
@@ -1506,8 +1506,8 @@ class FleetBalancer:
                 "errors": c["errors"], "retries": c["retries"],
                 "canary": pin,
                 # self-report: this door's OWN load, uniform with the
-                # replica tier's /healthz so serve_bench and the
-                # controller read both tiers the same way
+                # replica tier's /healthz so the controller reads
+                # both tiers the same way
                 "inflight": inflight,
                 "channel_depth": chan_depth,
                 "quota_shares": self.quota.share_snapshot(),

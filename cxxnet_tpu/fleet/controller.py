@@ -318,8 +318,8 @@ class FleetController:
 
     def front_doors(self) -> List[Dict[str, Any]]:
         """Every door of the tier as ``(id, host, http, binary)``
-        descriptors — b0 in-process plus the spawned doors; what the
-        bench and clients iterate for failover endpoints."""
+        descriptors — b0 in-process plus the spawned doors; what
+        clients iterate for failover endpoints."""
         doors = [{"id": self.balancer.balancer_id,
                   "host": self.tier.host,
                   "http_port": self.balancer.http_port,

@@ -159,7 +159,7 @@ def endpoint_entry(member_id: str, role: str, host: str,
 class EndpointRegistry:
     """The fleet's shared discovery file.
 
-    Single-writer (the controller — or the bench harness standing in
+    Single-writer (the controller — or a test harness standing in
     for it), many readers. Readers cache on mtime so the balancer's
     sync loop costs a ``stat`` per poll, not a parse."""
 

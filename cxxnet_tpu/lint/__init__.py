@@ -13,8 +13,7 @@ Usage (CLI)::
     python -m cxxnet_tpu.lint cxxnet_tpu/ tools/
     python -m cxxnet_tpu.lint --format json --select CXL002,CXL006
 
-Exit codes follow the bench.py convention: 0 clean, 1 findings,
-2 usage error.
+Exit codes: 0 clean, 1 findings, 2 usage error.
 
 Suppressions are inline and must carry a reason::
 

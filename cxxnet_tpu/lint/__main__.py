@@ -1,7 +1,6 @@
 """CLI: ``python -m cxxnet_tpu.lint [paths...]``.
 
-Exit codes follow the bench.py convention: 0 clean, 1 findings,
-2 usage error (argparse owns 2)."""
+Exit codes: 0 clean, 1 findings, 2 usage error (argparse owns 2)."""
 
 from __future__ import annotations
 

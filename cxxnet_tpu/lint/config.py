@@ -46,7 +46,7 @@ PROGRAM_BUILDERS = {
     # the step_breakdown measurement programs (doc/distributed.md
     # "Overlapped gradient sync"): a grad-only program and a group-
     # granular reduce-only program, built once per measurement call by
-    # bench --hosts / the scaling sweep — never on the training path
+    # the scaling sweep — never on the training path
     "cxxnet_tpu/parallel/gradsync.py": (
         "measure_step_breakdown",
     ),

@@ -216,8 +216,8 @@ class JsonlSink:
 
 
 class MemorySink:
-    """In-process record list — the test/bench sink (bench.py reads
-    its throughput from these records instead of re-derived timers)."""
+    """In-process record list — the sink the tests and
+    ``chip_smoke.py`` read records back from."""
 
     enabled = True
 

@@ -1,8 +1,8 @@
 """Record vocabulary and validation for the monitor event stream.
 
 One place defines what each event must carry, so the smoke test, the
-bench capture, and any downstream consumer of ``BENCH_r*.json``
-throughput fields all check against the same contract. Validation is
+benchmark's readers (``benchmarks/``) and any downstream consumer of
+the stream all check against the same contract. Validation is
 deliberately structural (required keys, value sanity) rather than a
 full JSON-Schema dependency: the container must not grow new packages.
 
@@ -171,15 +171,15 @@ REQUIRED: Dict[str, tuple] = {
                     "start_record"),
     "dist_retry": ("what", "attempts", "recovered"),
     # one per world size of the dryrun scaling sweep
-    # (parallel/scaling.py, the bench.py --hosts capture path behind
-    # MULTICHIP_r*.json): throughput, the data-wait share of the step
-    # wall time, and the per-host consumed-row accounting
+    # (parallel/scaling.py, a test harness on virtual CPU devices):
+    # throughput, the data-wait share of the step wall time, and the
+    # per-host consumed-row accounting
     "scaling_point": ("hosts", "local_devices", "global_batch",
                       "examples_per_sec", "data_wait_share",
                       "rows_per_host", "zero_recompiles"),
     # per-step time/byte split under a grad_sync mode
-    # (parallel/gradsync.py, emitted per scaling-sweep point and by
-    # bench.py --hosts): gradient-program wall, the standalone
+    # (parallel/gradsync.py, emitted per scaling-sweep point):
+    # gradient-program wall, the standalone
     # group-granular reduce wall, the full dispatched step wall, the
     # hidden-reduce fraction, and the optimizer-state footprint —
     # logical (unsharded) vs distinct bytes resident per host (the

@@ -1131,9 +1131,10 @@ class NetTrainer:
         (``compile_cache_dir``): recompiles across RUNS become cache
         deserializations — the first-round compile cost is paid once
         per (program, jaxlib, flags) per machine. Where the directory
-        comes from is one rule shared with bench.py and chip_smoke.py
-        (utils/compile_cache.py): ``JAX_COMPILATION_CACHE_DIR`` in the
-        environment wins over the key."""
+        comes from is one rule shared with benchmarks/run.py and
+        chip_smoke.py (utils/compile_cache.py):
+        ``JAX_COMPILATION_CACHE_DIR`` in the environment wins over the
+        key."""
         from ..utils.compile_cache import enable_compile_cache
         enable_compile_cache(self.compile_cache_dir)
 
@@ -1162,8 +1163,8 @@ class NetTrainer:
         batch_size fall back to the jit path for those dispatches —
         precompile never changes results, only when compilation
         happens. ``per_batch=False`` compiles ONLY the ``run_steps``
-        program (the bench capture path — no wasted minutes on update/
-        pred variants the capture never dispatches). With
+        program (a resident-batch job — no wasted minutes on update/
+        pred variants it never dispatches). With
         ``input_layout = rowmajor`` the lowered programs pin the batch
         input's device layout channels-minor. Returns the number of
         programs compiled."""

@@ -87,9 +87,7 @@ def default_data_axis(batch_size: int,
     """The trainer's default mesh rule: the largest data-axis size
     that divides the global batch (the reference similarly drops
     devices that would get an empty slice, nnet_impl-inl.hpp:378-387).
-    One definition shared by ``NetTrainer._post_init`` and bench.py's
-    ``--compare`` topology guard, so the recorded and expected
-    topologies cannot drift."""
+    ``NetTrainer._post_init`` is its caller."""
     if n_devices is None:
         n_devices = len(jax.devices())
     return max(d for d in range(1, n_devices + 1)

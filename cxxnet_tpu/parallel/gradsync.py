@@ -36,10 +36,9 @@ pinned by the dryrun parity tests at H=2 and H=4
 
 :func:`measure_step_breakdown` is the measurement half: the
 schema-validated ``step_breakdown`` record (backprop ms, reduce ms,
-overlap ratio, optimizer-state bytes/host) behind ``bench.py --hosts``
-and :mod:`.scaling`. A CPU dryrun's collectives are shared-memory
-copies, not DCN — the record says so; device columns stay pending a
-chip window (ROADMAP item 5).
+overlap ratio, optimizer-state bytes/host) behind :mod:`.scaling`.
+A CPU dryrun's collectives are shared-memory copies, not DCN — the
+record says so; device columns stay pending a chip window.
 """
 
 from __future__ import annotations
@@ -239,7 +238,7 @@ def measure_step_breakdown(trainer, batch, repeats: int = 3
     under ``optim_shard = 1`` the per-host number drops to ~1/hosts.
 
     Honesty: this advances the trainer by ``repeats + 1`` real updates
-    (call it at a measurement boundary, as bench/scaling do), and on a
+    (call it at a measurement boundary, as the scaling sweep does), and on a
     CPU dryrun every collective is a shared-memory copy, not DCN — the
     timings bound the schedule shape only; device columns stay pending
     a chip window (doc/distributed.md).

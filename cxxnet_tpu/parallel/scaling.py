@@ -1,5 +1,6 @@
-"""The dryrun scaling sweep behind ``bench.py --hosts`` and the
-``MULTICHIP_r*.json`` records.
+"""The dryrun scaling sweep: a test harness for the multi-host input
+path (tests/test_multihost_dryrun.py, tests/test_gradsync.py). It runs
+on virtual CPU devices and is not a measurement of any chip.
 
 Runs the SAME multi-host input path the CLI trains through
 (:func:`cxxnet_tpu.parallel.topology.build_dryrun_feed` — one
@@ -20,8 +21,7 @@ single-process dryrun can honestly measure:
 
 What it can NOT measure — and says so in the record: cross-host
 collective time. A dryrun runs one process with zero DCN traffic, so
-the on-chip scaling curve is marked pending a device window (the
-r07/r08 convention for device-only columns).
+the on-chip scaling curve is marked pending a device window.
 """
 
 from __future__ import annotations

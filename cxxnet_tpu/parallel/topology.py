@@ -309,8 +309,8 @@ def build_dryrun_feed(block_cfg, batch_cfg, hosts: int,
                       global_batch: int,
                       start_record: int = 0) -> DryrunFeed:
     """Build the H per-host iterator chains + assembler for one data
-    block — the ONE construction main.py's train path and the bench
-    scaling sweep share, so the measured path is the shipped path.
+    block — the ONE construction main.py's train path and the dryrun
+    scaling sweep share, so the tested path is the shipped path.
 
     Each host chain gets the deterministic batch-block shard params
     (``shard_kind = batch``: host h owns rows [h*b, (h+1)*b) of every

@@ -10,8 +10,7 @@ The serving subsystem (doc/serving.md). Pieces:
   bounded queue, reject-with-busy backpressure, per-request deadlines,
   exception propagation, graceful drain, pipelined H2D hand-off
 - :mod:`~cxxnet_tpu.serve.server` — config-driven ``ServeSession`` and
-  the closed-loop client drive behind ``task = serve`` and
-  ``tools/serve_bench.py``
+  the closed-loop client drive behind ``task = serve``
 
 The fleet layer (``task = serve_fleet``, doc/serving.md):
 

@@ -368,7 +368,7 @@ class DynamicBatcher:
     def fill_stats(self) -> Dict[str, Any]:
         """Cumulative micro-batch economics (batches, rows, bucket
         rows, pad rows + derived fill/pad ratios) — exported through
-        the fleet ``/healthz`` so the multi-replica bench can report
+        the fleet ``/healthz`` so a reader of every replica can report
         pad fraction fleet-wide (doc/serving.md "Fleet data path")."""
         with self._stats:
             c = dict(self.counters)

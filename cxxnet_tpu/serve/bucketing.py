@@ -8,10 +8,10 @@ masks them with the same ``num_batch_padd`` machinery the training tail
 batches use — steady-state serving then touches only the executables the
 warmup compiled.
 
-The helpers here are shared by the serve engine, ``wrapper.Net``'s
-pred-executable cache, and ``tools/serve_bench.py``; keeping them in one
-place is what lets the schema guarantee "zero compile events after
-warmup" mean the same thing everywhere.
+The helpers here are shared by the serve engine and ``wrapper.Net``'s
+pred-executable cache; keeping them in one place is what lets the
+schema guarantee "zero compile events after warmup" mean the same thing
+everywhere.
 """
 
 from __future__ import annotations

@@ -73,8 +73,7 @@ def _aliases_host(buf: np.ndarray, dev) -> bool:
 def input_dtype_for(serve_dtype: str):
     """The staging dtype a ladder warms for a ``serve_dtype``: bf16
     ladders warm and stage bf16 (half the H2D bytes); int8/fp8 graphs
-    quantize on device, so their input stays f32. One definition shared
-    by :func:`build_engine` and ``tools/serve_bench.py``."""
+    quantize on device, so their input stays f32."""
     import jax.numpy as jnp
     return jnp.bfloat16 if serve_dtype == "bfloat16" else np.float32
 

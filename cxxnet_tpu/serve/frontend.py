@@ -302,7 +302,7 @@ def read_reply_tagged(rfile) -> Tuple[Optional[int], str, Any]:
 
 class BinaryClient:
     """Minimal persistent-connection client for the binary protocol
-    (the closed-loop drive in tests and ``tools/serve_bench.py``)."""
+    (the closed-loop drive in the tests)."""
 
     def __init__(self, host: str, port: int, timeout: float = 30.0):
         self.sock = socket.create_connection((host, port),
@@ -1116,9 +1116,8 @@ class FleetServer:
                 rsnap = r.counters_snapshot()
                 row["search_compile_events"] = rsnap["compile_events"]
                 row["search_aot_hits"] = rsnap["aot_hits"]
-            # cumulative batch economics (fill/pad): what the fleet
-            # bench aggregates across replicas (doc/serving.md "Fleet
-            # data path")
+            # cumulative batch economics (fill/pad), to be summed
+            # across replicas (doc/serving.md "Fleet data path")
             row.update(batcher.fill_stats())
             models.append(row)
         return {

@@ -203,7 +203,7 @@ def run_closed_loop(session: ServeSession, pool: np.ndarray,
     session: each sends ``requests`` requests of ``request_rows``
     consecutive pool rows (wrapping), waiting for each result before
     sending the next — the classic serving load model, and the drive
-    behind both ``task = serve`` and ``tools/serve_bench.py``.
+    behind ``task = serve``.
 
     Returns aggregate stats (client errors surface in ``errors``; a
     failed request does not kill its client loop)."""

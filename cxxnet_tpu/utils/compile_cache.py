@@ -1,17 +1,17 @@
 """Where jax's persistent compilation cache lives — one rule, one place.
 
 The directory is part of the cache key, so a cache that moves never
-hits. The rule every entry point shares (trainer, ``bench.py``,
-``chip_smoke.py``):
+hits. The rule every entry point shares (trainer,
+``benchmarks/run.py``, ``chip_smoke.py``):
 
 - ``JAX_COMPILATION_CACHE_DIR`` set in the environment wins. jax reads
   it into ``jax_compilation_cache_dir`` itself at import; the program
   sets no directory in code, and a ``compile_cache_dir`` key that names
   another place is ignored with one warning.
 - otherwise the ``compile_cache_dir`` key, when the run carries one;
-- otherwise the caller's fixed default (``bench.py`` and
-  ``chip_smoke.py`` pass :data:`REPO_CACHE_DIR`; the trainer passes
-  none, so a plain run without the key caches nothing — as before).
+- otherwise the caller's fixed default (``chip_smoke.py`` passes
+  :data:`REPO_CACHE_DIR`, ``benchmarks/run.py`` the same path; the
+  trainer passes none, so a plain run without the key caches nothing).
 """
 
 from __future__ import annotations

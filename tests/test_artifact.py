@@ -497,24 +497,31 @@ def test_watcher_same_counter_snapshot_to_bundle_upgrade(tmp_path):
     router.close_all(drain=True)
 
 
-# -- serve_bench cold-start column ----------------------------------------
+# -- conf-less cold start ------------------------------------------------
 
 
-def test_serve_bench_artifact_cold_start_record(exported, tmp_path,
-                                                capsys):
-    import tools.serve_bench as sb
-    _, snap, bundle = exported
-    out = str(tmp_path / "SB.json")
-    rc = sb.main(["--artifact", bundle, "--clients", "1",
-                  "--requests", "4", "--out", out])
-    assert rc == 0
-    rec = json.load(open(out))
-    assert rec["zero_recompiles"]
-    (cold,) = rec["cold_start"]
-    assert cold["via"] == "artifact"
-    assert cold["compile_events"] == 0
-    assert cold["warmup_programs"] == 0
-    assert cold["artifact_hits"] > 0 and cold["artifact_rebuilds"] == 0
-    assert cold["fingerprint_match"] is True
-    assert cold["time_to_first_reply_s"] > 0
-    capsys.readouterr()
+def test_confless_bundle_boot_first_reply_and_closed_loop(exported):
+    """A boot that knows only the bundle (the serve contract read from
+    its manifest, ``serve_cfg_from_bundle``): the first reply and a
+    closed-loop client after it come with every program an artifact
+    hit, none rebuilt, a matching fingerprint and no compile event."""
+    from cxxnet_tpu.serve import ServeSession, run_closed_loop
+    _, _, bundle = exported
+    sink = MemorySink()
+    sess = ServeSession(ab.serve_cfg_from_bundle(bundle),
+                        model_path=bundle, monitor=Monitor(sink))
+    try:
+        first = sess.predict(np.zeros((1, 24), np.float32))
+        agg = run_closed_loop(
+            sess, np.random.RandomState(0).rand(16, 24)
+            .astype(np.float32), 1, 4)
+    finally:
+        summary = sess.close()
+    assert first.shape[0] == 1 and agg["ok"] == 4
+    assert validate_records(sink.records) == []
+    assert [r for r in sink.records if r["event"] == "compile"] == []
+    assert sess.warmup_programs == 0
+    assert summary["compile_events"] == 0
+    (art,) = [r for r in sink.records if r["event"] == "artifact_load"]
+    assert art["hits"] > 0 and art["rebuilds"] == 0
+    assert art["fingerprint_match"] is True

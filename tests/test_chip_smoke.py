@@ -23,8 +23,7 @@ import chip_smoke as cs
 
 TINY = cs.Size(batch=8, image=67, src_image=72, n_images=32,
                dispatch_period=2, rounds=2, serve_buckets="2,4",
-               serve_clients=2, serve_requests=4, serve_request_rows=2,
-               bench_steps=2, bench_batch=4)
+               serve_clients=2, serve_requests=4, serve_request_rows=2)
 
 KERNELS = ["matmul", "bn_apply", "conv_epilogue[f32]",
            "conv_epilogue[int32]", "pool_concat[avg]",
@@ -82,14 +81,6 @@ def test_export_and_serve_from_bundle_phase(cli):
     assert line["artifact_rebuilds"] == 0
     assert line["compile_events"] == 0
     assert line["requests"] == 8 and line["rows"] == 16
-
-
-def test_scanned_step_phase_reads_the_pin_back(meter):
-    line = cs.run_phase("scanned_step",
-                        lambda: cs.phase_scanned_step(TINY), meter)
-    assert line["input_layout"] == "rowmajor"
-    assert line["input_major_to_minor"] == [0, 1, 2, 3]
-    assert line["zero_recompiles"] is True
 
 
 def test_kernel_case_names():

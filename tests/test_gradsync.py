@@ -15,13 +15,10 @@ doc/updater.md "Optimizer-state placement"):
 - frozen (``lr_mult = 0``) groups allocate no optimizer state,
 - sharded optimizer state round-trips the snapshot format and
   survives an elastic H=4 -> H=2 resume no-dup/no-loss,
-- ``bench.py --compare`` refuses a grad_sync/optim_shard mismatch
-  with exit 2 (the dtype/topology guard convention),
-- the committed MULTICHIP_r17.json sweep carries overlap ratio and
-  bytes/host per point with the honest CPU-dryrun caveat.
+- the dryrun scaling sweep carries overlap ratio and bytes/host per
+  point.
 """
 
-import json
 import os
 import signal
 import sys
@@ -32,7 +29,6 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(__file__)))
 
-import bench
 from cxxnet_tpu.main import EXIT_PREEMPTED, LearnTask
 from cxxnet_tpu.monitor import MemorySink, Monitor, set_global
 from cxxnet_tpu.monitor.schema import (read_jsonl, validate_record,
@@ -505,61 +501,3 @@ def test_scaling_sweep_emits_step_breakdown():
         assert bd["opt_state_bytes_per_host"] * bd["hosts"] \
             == bd["opt_state_bytes_unsharded"]
         assert 0.0 <= bd["overlap_ratio"] <= 1.0
-
-
-# -- bench --compare refuses cross-sync diffs ------------------------------
-
-
-def test_bench_compare_refuses_cross_sync(tmp_path, monkeypatch,
-                                          capsys):
-    """A prior record measured under grad_sync=overlap is refused by a
-    default (fused) compare sweep before it starts — exit 2, the
-    dtype/topology convention; --allow-sync-mismatch is the
-    override."""
-    old = {"metric": "images/sec/chip on ImageNet AlexNet",
-           "value": 100.0,
-           "models": {"alexnet": {"value": 100.0,
-                                  "grad_sync": "overlap",
-                                  "optim_shard": 0}}}
-    p = str(tmp_path / "old.json")
-    with open(p, "w") as f:
-        json.dump(old, f)
-    monkeypatch.setattr(sys, "argv", ["bench.py", "--compare", p])
-    with pytest.raises(SystemExit) as ei:
-        bench.main()
-    assert ei.value.code == 2
-    assert "grad-sync" in capsys.readouterr().err
-    # the helper, directly: both knobs guard, untagged records pass
-    assert bench.sync_mismatches(old["models"], "overlap", 0) == []
-    assert bench.sync_mismatches(old["models"], "overlap", 1) == [
-        ("alexnet", "optim_shard", 0, 1)]
-    assert bench.sync_mismatches({"alexnet": {"value": 1.0}},
-                                 "fused", 0) == []
-
-
-# -- the committed r17 record ----------------------------------------------
-
-
-def test_multichip_r17_record_shape():
-    """The committed overlap+ZeRO sweep record: overlap ratio and
-    bytes/host per point, exact 1/H state sharding, and the honest
-    CPU-dryrun caveat (the r07/r08 pending-device-window
-    convention)."""
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "MULTICHIP_r17.json")
-    with open(path) as f:
-        rec = json.load(f)
-    assert rec["dryrun"] is True
-    assert rec["loss_parity"] is True and rec["exactly_once"] is True
-    assert rec["grad_sync"] == "overlap" and rec["optim_shard"] == 1
-    assert "pending a device window" in rec["on_chip"]
-    assert "pending" in rec["breakdown_caveat"]
-    assert sorted(p["hosts"] for p in rec["points"]) == [1, 2, 4, 8]
-    for p in rec["points"]:
-        assert p["zero_recompiles"] is True
-        bd = p["step_breakdown"]
-        assert bd["grad_sync"] == "overlap" and bd["optim_shard"] == 1
-        assert 0.0 <= bd["overlap_ratio"] <= 1.0
-        assert bd["opt_state_bytes_per_host"] * p["hosts"] \
-            == bd["opt_state_bytes_unsharded"]
-        assert bd["backprop_ms"] >= 0 and bd["reduce_ms"] >= 0

@@ -1,5 +1,4 @@
-"""Accuracy gate for the BN/concat topology class (VERDICT r3 §3, held
-out per VERDICT r4 missing §2).
+"""Accuracy gate for the BN/concat topology class, on held-out data.
 
 The reference's headline accuracy claims live on Inception-BN
 (/root/reference/example/ImageNet/Inception-BN.conf:13-15, rec@1

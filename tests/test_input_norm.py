@@ -519,7 +519,7 @@ def test_removed_keys_are_gone():
     observes: no module, document or example mentions them."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     hits = []
-    for top in ("cxxnet_tpu", "doc", "example", "bench.py", "chip_smoke.py",
+    for top in ("cxxnet_tpu", "doc", "example", "chip_smoke.py",
                 "benchmarks", "tools", "wrapper"):
         path = os.path.join(root, top)
         files = [path] if os.path.isfile(path) else [

@@ -7,7 +7,7 @@ Three layers:
    and passing, independent of the real tree's state;
 2. machinery: suppressions (reason required, unused flagged), the
    baseline round trip, CLI exit codes (0 clean / 1 findings /
-   2 usage — the bench.py convention);
+   2 usage);
 3. the gate: ``run_lint`` over the real ``cxxnet_tpu/`` + ``tools/``
    asserts ZERO unsuppressed findings, which is what makes cxxlint a
    permanent regression fence rather than a one-shot audit.

@@ -107,7 +107,7 @@ def test_inception_train_step_tiny():
 
 
 def test_inception_bn_multidevice_real_shapes():
-    """Pod-config rehearsal (VERDICT r1 #10): ONE update step of the
+    """Pod-config rehearsal: ONE update step of the
     full Inception-BN config at 224x224 batch 32 on the 8-device
     virtual mesh (dp=4 x tp=2), asserting finite loss and that the
     intended shardings actually materialized."""

@@ -27,7 +27,6 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(__file__)))
 
-import bench
 from cxxnet_tpu.main import EXIT_PREEMPTED, LearnTask
 from cxxnet_tpu.monitor import MemorySink, Monitor, set_global
 from cxxnet_tpu.monitor.schema import read_jsonl, validate_records
@@ -406,7 +405,7 @@ def test_allreduce_retry_exhaustion_reraises(monkeypatch):
         parallel.set_allreduce_retry(2)
 
 
-# -- scaling sweep + bench topology guard ----------------------------------
+# -- scaling sweep -----------------------------------------------------------
 
 
 def test_dryrun_scaling_sweep_invariants():
@@ -422,46 +421,3 @@ def test_dryrun_scaling_sweep_invariants():
     assert all(p["zero_recompiles"] for p in rec["points"])
     assert rec["points"][1]["rows_per_host"] == [32, 32]
     assert "pending a device window" in rec["on_chip"]
-
-
-def test_bench_compare_refuses_cross_topology(tmp_path, monkeypatch,
-                                              capsys):
-    """A prior record measured at a different mesh/process topology is
-    refused before the sweep with exit 2 (argparse's usage exit), the
-    dtype-guard convention."""
-    old = {"metric": "images/sec/chip on ImageNet AlexNet",
-           "value": 100.0,
-           "models": {"alexnet": {
-               "value": 100.0, "dtype": "bfloat16",
-               "topology": {"mesh": {"data": 2, "model": 1},
-                            "process_count": 1, "device_count": 2}}}}
-    p = str(tmp_path / "old.json")
-    with open(p, "w") as f:
-        json.dump(old, f)
-    monkeypatch.setattr(sys, "argv", ["bench.py", "--compare", p])
-    with pytest.raises(SystemExit) as ei:
-        bench.main()
-    assert ei.value.code == 2
-    assert "topolog" in capsys.readouterr().err
-    # a matching topology passes the guard (nothing to refuse)
-    good = dict(old["models"]["alexnet"])
-    good["topology"] = bench.expected_topology(256)
-    assert bench.topology_mismatches({"alexnet": good}) == []
-    # untagged (pre-topology) records compare freely
-    assert bench.topology_mismatches({"alexnet": {"value": 1.0}}) == []
-
-
-def test_multichip_r14_record_shape():
-    """The committed scaling record carries the dryrun accounting and
-    the honest pending-device-window caveat (the r07/r08 convention)."""
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "MULTICHIP_r14.json")
-    with open(path) as f:
-        rec = json.load(f)
-    assert rec["dryrun"] is True
-    assert rec["loss_parity"] is True and rec["exactly_once"] is True
-    assert "pending a device window" in rec["on_chip"]
-    for p in rec["points"]:
-        assert sum(p["rows_per_host"]) == rec["dataset_rows"]
-        assert p["zero_recompiles"] is True
-    assert sorted(p["hosts"] for p in rec["points"]) == [1, 2, 4, 8]

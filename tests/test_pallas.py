@@ -113,8 +113,8 @@ def test_pallas_relu_max_pool_matches_xla(rng):
 
 
 def test_pairtest_pallas_relu_max_pooling(rng):
-    """pairtest-relu_max_pooling-pallas_relu_max_pooling: the VERDICT
-    r3 §4 validation flow for the fused stem-pool kernel."""
+    """pairtest-relu_max_pooling-pallas_relu_max_pooling: the
+    validation flow for the fused stem-pool kernel."""
     layer = create_layer("pairtest-relu_max_pooling-pallas_relu_max_pooling",
                          [("kernel_size", "3"), ("stride", "1")])
     layer.infer_shape([Shape3(8, 11, 11)])

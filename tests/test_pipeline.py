@@ -118,7 +118,8 @@ def test_augment_vectorize_0_forces_per_instance():
     assert ba._aug is None
 
 
-def test_vectorized_parity_on_zero_padded_tail():
+@pytest.mark.parametrize("n", [11, 5])   # short tail / dataset < batch
+def test_vectorized_parity_on_zero_padded_tail(n):
     """round_batch=0 zero-filler rows must stay EXACT zeros in the
     vectorized path too (the per-instance path pads after the
     transform; the whole-batch mean/scale must not leak -mean*scale
@@ -136,15 +137,14 @@ def test_vectorized_parity_on_zero_padded_tail():
         ba.init()
         return list(ba)
 
-    for n in (11, 5):                 # short tail / dataset < batch
-        va, rb = chain(1, n), chain(0, n)
-        assert len(va) == len(rb)
-        assert va[-1].num_batch_padd > 0
-        for bv, br in zip(va, rb):
-            np.testing.assert_array_equal(bv.data, br.data)
-            np.testing.assert_array_equal(bv.label, br.label)
-        pad = va[-1].num_batch_padd
-        np.testing.assert_array_equal(va[-1].data[8 - pad:], 0.0)
+    va, rb = chain(1, n), chain(0, n)
+    assert len(va) == len(rb)
+    assert va[-1].num_batch_padd > 0
+    for bv, br in zip(va, rb):
+        np.testing.assert_array_equal(bv.data, br.data)
+        np.testing.assert_array_equal(bv.label, br.label)
+    pad = va[-1].num_batch_padd
+    np.testing.assert_array_equal(va[-1].data[8 - pad:], 0.0)
 
 
 def test_second_epoch_identical_under_deferral():
@@ -160,11 +160,12 @@ def test_second_epoch_identical_under_deferral():
 # -- zero-copy ring assembly ---------------------------------------------
 
 
-def test_aligned_empty_is_page_aligned():
-    for shape, dt in [((3, 5, 7), np.float32), ((16,), np.uint8)]:
-        a = _aligned_empty(shape, dt)
-        assert a.shape == shape and a.dtype == dt
-        assert a.ctypes.data % 4096 == 0
+@pytest.mark.parametrize("shape,dt", [((3, 5, 7), np.float32),
+                                      ((16,), np.uint8)])
+def test_aligned_empty_is_page_aligned(shape, dt):
+    a = _aligned_empty(shape, dt)
+    assert a.shape == shape and a.dtype == dt
+    assert a.ctypes.data % 4096 == 0
 
 
 def test_ring_buffer_reuse_after_release():

@@ -299,12 +299,12 @@ eta = 0.1
     assert q.quant_report["active"] and q.quant_report["layers"] == 2
 
 
-def test_backend_native_probe_is_cached_and_boolean():
-    for dt in ("int8", "fp8"):
-        for op in ("dot", "conv"):
-            a = backend_native(dt, op)
-            assert isinstance(a, bool)
-            assert backend_native(dt, op) is a
+@pytest.mark.parametrize("dt", ["int8", "fp8"])
+def test_backend_native_probe_is_cached_and_boolean(dt):
+    for op in ("dot", "conv"):
+        a = backend_native(dt, op)
+        assert isinstance(a, bool)
+        assert backend_native(dt, op) is a
 
 
 def test_bf16_serve_epilogue_keeps_bf16_activations():

@@ -69,21 +69,21 @@ def test_roundtrip_interop(tmp_path, wpy, rpy):
     r.close()
 
 
-def test_sharded_read_covers_all(tmp_path):
+@pytest.mark.parametrize("nparts", [2, 3, 5])
+def test_sharded_read_covers_all(tmp_path, nparts):
     path = str(tmp_path / "s.rec")
     w = RecordIOWriter(path, force_python=True)
     payloads = _payloads(n=200, seed=3)
     for p in payloads:
         w.write_record(p)
     w.close()
-    for nparts in (2, 3, 5):
-        got = []
-        for pi in range(nparts):
-            r = RecordIOReader(path, pi, nparts, force_python=True)
-            got.extend(list(r))
-            r.close()
-        assert sorted(got) == sorted(payloads), \
-            "shard split lost/duplicated records (nparts=%d)" % nparts
+    got = []
+    for pi in range(nparts):
+        r = RecordIOReader(path, pi, nparts, force_python=True)
+        got.extend(list(r))
+        r.close()
+    assert sorted(got) == sorted(payloads), \
+        "shard split lost/duplicated records"
 
 
 @pytest.mark.skipif(not native_available(), reason="native lib not built")

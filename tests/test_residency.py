@@ -30,7 +30,8 @@ from cxxnet_tpu.monitor.schema import validate_records
 from cxxnet_tpu.nnet.quantize import Calibrator
 from cxxnet_tpu.nnet.trainer import NetTrainer
 from cxxnet_tpu.parallel import make_mesh
-from cxxnet_tpu.serve import InferenceEngine, ServeSession
+from cxxnet_tpu.serve import (InferenceEngine, ServeSession,
+                              run_closed_loop)
 from cxxnet_tpu.serve.router import ModelRouter, UnknownModelError
 from cxxnet_tpu.utils.config import parse_config
 
@@ -370,16 +371,30 @@ def test_bundle_roundtrip_residency_zero_compiles_byte_identical(
     assert art2 and art2[-1]["hits"] == 0
 
 
-# -- serve_bench ----------------------------------------------------------
+# -- closed-loop sweeps leave the weight tree alone -----------------------
 
 
-def test_serve_bench_device_mem_column(capsys):
-    import json
-    import tools.serve_bench as sb
-    rc = sb.main(["--clients", "1,2", "--requests", "4",
-                  "--device-mem"])
-    assert rc == 0
-    rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    mem = [p["device_mem_bytes"] for p in rec["sweep"]]
-    assert len(mem) == 2 and all(b > 0 for b in mem)
-    assert mem[0] == mem[1]               # leak guard holds
+def test_resident_bytes_equal_across_client_sweeps():
+    """A fresh session a sweep point, driven by ``run_closed_loop``:
+    the ``weight_residency`` record's resident device bytes are
+    positive and the same at one client and at two (the leak guard: a
+    busier batcher adds nothing to the weight tree)."""
+    mem = []
+    for clients in (1, 2):
+        sink = MemorySink()
+        mon = Monitor(sink)
+        eng = InferenceEngine(_trainer(monitor=mon),
+                              buckets=(1, 2, 4, 8), monitor=mon)
+        sess = ServeSession(parse_config(FOLD_CONF), engine=eng,
+                            monitor=mon)
+        try:
+            agg = run_closed_loop(sess, _rows(16), clients, 4)
+        finally:
+            summary = sess.close()
+        assert agg["ok"] == clients * 4
+        assert summary["compile_events"] == 0
+        assert validate_records(sink.records) == []
+        res = [r for r in sink.records
+               if r["event"] == "weight_residency"]
+        mem.append(res[-1]["bytes"])
+    assert mem[0] == mem[1] > 0

@@ -166,23 +166,23 @@ def test_search_sig_roundtrips_via_manifest_repr():
 # -- the search engine (jax cpu, standalone registry) --------------------
 
 
-def test_engine_exact_parity_and_zero_postwarmup_compiles():
+@pytest.mark.parametrize("metric", ["dot", "cosine"])
+def test_engine_exact_parity_and_zero_postwarmup_compiles(metric):
     rng = np.random.RandomState(1)
-    for metric in ("dot", "cosine"):
-        idx = _rand_index(rows=20, dim=5, metric=metric, seed=2)
-        eng = RetrievalEngine(idx, ProgramRegistry(), k=4,
-                              buckets=(2, 4))
-        compiled = eng.warmup(warm_run=True)
-        assert compiled == 2
-        assert eng.counters_snapshot()["compile_events"] == 0
-        q = rng.randn(5, 5).astype(np.float32)   # chunks 4 + 1(pad->2)
-        ids, scores = eng.search(q)
-        oids, oscores = oracle_topk(idx, q, 4)
-        np.testing.assert_array_equal(ids, oids)
-        np.testing.assert_allclose(scores, oscores, atol=1e-5)
-        snap = eng.counters_snapshot()
-        assert snap["compile_events"] == 0 and snap["aot_hits"] == 2
-        assert snap["pad_rows"] == 1
+    idx = _rand_index(rows=20, dim=5, metric=metric, seed=2)
+    eng = RetrievalEngine(idx, ProgramRegistry(), k=4,
+                          buckets=(2, 4))
+    compiled = eng.warmup(warm_run=True)
+    assert compiled == 2
+    assert eng.counters_snapshot()["compile_events"] == 0
+    q = rng.randn(5, 5).astype(np.float32)   # chunks 4 + 1(pad->2)
+    ids, scores = eng.search(q)
+    oids, oscores = oracle_topk(idx, q, 4)
+    np.testing.assert_array_equal(ids, oids)
+    np.testing.assert_allclose(scores, oscores, atol=1e-5)
+    snap = eng.counters_snapshot()
+    assert snap["compile_events"] == 0 and snap["aot_hits"] == 2
+    assert snap["pad_rows"] == 1
 
 
 def test_engine_duplicate_scores_match_oracle_tie_break():
@@ -237,9 +237,13 @@ def test_parse_model_op_grammar():
     assert parse_model_op("m#embed") == ("m", "embed", None)
     assert parse_model_op("m#search:5") == ("m", "search", 5)
     assert parse_model_op("#fsearch:1") == ("", "fsearch", 1)
-    for bad in ("m#predict", "m#search:0", "m#search:x", "m#"):
-        with pytest.raises(ValueError):
-            parse_model_op(bad)
+
+
+@pytest.mark.parametrize("bad", ["m#predict", "m#search:0",
+                                 "m#search:x", "m#"])
+def test_parse_model_op_rejects_malformed(bad):
+    with pytest.raises(ValueError):
+        parse_model_op(bad)
 
 
 def test_pack_search_result_wire_form():
