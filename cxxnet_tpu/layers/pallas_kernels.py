@@ -895,6 +895,322 @@ def causal_attention(qs, ks, v, scale: float, q_block: int = 0):
     return o.reshape(b, h, t, dv)
 
 
+# ------------------------------------------- grouped expert products
+
+_EXPERT_VMEM = 96 * 1024 * 1024
+_LANES = 128
+
+
+def _expert_call(kernel, expert, tok, nb, block, ins, outs, acc=None,
+                 scratch=()):
+    """One grouped kernel over the row blocks in use. ``expert``
+    ``(blocks,)`` and ``tok`` ``(rows,)`` are scalar-prefetched: whose
+    block each is, and the token of each row. The grid is the traced
+    ``nb``, so work follows the routing. A two-dimensional operand is
+    rows: block ``i`` of it; rows past ``nb * block`` of such an output
+    are never written. A three-dimensional one is a matrix a held
+    expert: ``expert[i]``'s, whole, so consecutive blocks of one expert
+    fetch it once; such an output starts as zeros (an input left in HBM,
+    aliased to it), which an expert without a block keeps. ``acc``,
+    where given, stays in HBM too and is aliased to one more, last
+    output: the kernel reads and writes it by its own copies. The kernel
+    sees the inputs, one unused reference a matrix output, ``acc``, the
+    outputs, the scratch."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def spec(a):
+        if len(a.shape) == 2:
+            return pl.BlockSpec((block, a.shape[1]), lambda i, e, t: (i, 0))
+        return pl.BlockSpec((None,) + tuple(a.shape[1:]),
+                            lambda i, e, t: (e[i], 0, 0))
+
+    in_specs, out_specs = [spec(a) for a in ins], [spec(o) for o in outs]
+    ins, outs, aliases = list(ins), list(outs), {}
+    for at, o in enumerate(list(outs)):
+        if len(o.shape) == 3:
+            aliases[2 + len(ins)] = at
+            ins.append(jnp.zeros(o.shape, o.dtype))
+    if acc is not None:
+        aliases[2 + len(ins)] = len(outs)
+        ins.append(acc)
+        outs.append(jax.ShapeDtypeStruct(acc.shape, acc.dtype))
+        out_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * (len(ins)
+                                                       - len(in_specs))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(nb,),
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=list(scratch)),
+        out_shape=outs,
+        input_output_aliases=aliases,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_EXPERT_VMEM),
+        interpret=_build_interpret(),
+    )(expert, tok, *ins)
+
+
+# The token-major sums (the layer's result, and the gradient of its
+# input) are kept as SLABS while the kernels add rows to them: a row of
+# ``d = c * 128`` float32 as ``c`` rows of 128, so that a token's row is
+# one piece of HBM (8 KB at 2,048) that a copy can address. Mosaic takes
+# no one-row slice of a ``(tokens, d)`` array, whose rows lie eight to a
+# tile. ``_from_slabs`` turns the sums back into rows.
+
+
+def _token_copies(i, tok_ref, acc_hbm, buf, sem, block, dump, to_hbm):
+    """Start one copy a row of block ``i`` between ``acc_hbm``'s slab of
+    the row's token and the row's slab of ``buf``, ``to_hbm`` or from
+    it. A padding row's token is past the last one: its slab is
+    ``dump``, which nobody reads."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    c = buf.shape[0] // block
+
+    def row(r, carry):
+        t = jnp.minimum(tok_ref[i * block + r], dump)
+        mine = buf.at[pl.ds(pl.multiple_of(r * c, c), c)]
+        theirs = acc_hbm.at[pl.ds(pl.multiple_of(t * c, c), c)]
+        (pltpu.make_async_copy(mine, theirs, sem) if to_hbm
+         else pltpu.make_async_copy(theirs, mine, sem)).start()
+        return carry
+
+    # (unrolled by hand by 2, 4 or 8 it is no faster: PERF.md, PR 31)
+    jax.lax.fori_loop(0, block, row, 0)
+
+
+def _token_copies_wait(acc_hbm, buf, sem):
+    """Wait for a block's copies, all at once: a copy's semaphore counts
+    bytes, and a block's copies move as many as one copy of all of
+    ``buf`` would."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    pltpu.make_async_copy(acc_hbm.at[pl.ds(0, buf.shape[0])], buf, sem).wait()
+
+
+def _add_to_tokens_begin(tok_ref, acc_hbm, buf, sems, block, dump):
+    """First half of ``acc[tok[r]] += rows[r]`` for this block: once the
+    block before has written its rows back (a token may be in both),
+    start reading this block's tokens' sums; the products run
+    meanwhile."""
+    from jax.experimental import pallas as pl
+    # (interpreted, a grid position can be asked for at a kernel's top
+    # level only: not under ``pl.when``)
+    i = pl.program_id(0)
+
+    @pl.when(i > 0)
+    def _():
+        _token_copies_wait(acc_hbm, buf, sems.at[1])
+
+    _token_copies(i, tok_ref, acc_hbm, buf, sems.at[0], block, dump, False)
+
+
+def _add_to_tokens_end(tok_ref, acc_hbm, buf, sems, block, dump, rows):
+    """Second half: the sums have arrived, ``rows`` ``(block, d)`` are
+    added and the copies back start; the last block waits for its own.
+    A token is in a block at most once (a block is one expert's, and a
+    token picks an expert once), so no two rows of a block share a
+    slab but the padding's."""
+    from jax.experimental import pallas as pl
+    c = buf.shape[0] // block
+    i = pl.program_id(0)
+    _token_copies_wait(acc_hbm, buf, sems.at[0])
+    for k in range(c):
+        buf[pl.ds(k, block, stride=c), :] += \
+            rows[:, k * _LANES:(k + 1) * _LANES]
+    _token_copies(i, tok_ref, acc_hbm, buf, sems.at[1], block, dump, True)
+
+    @pl.when(i == pl.num_programs(0) - 1)
+    def _():
+        _token_copies_wait(acc_hbm, buf, sems.at[1])
+
+
+def _from_slabs_kernel(c, s_ref, o_ref):
+    from jax.experimental import pallas as pl
+    for k in range(c):
+        o_ref[:, k * _LANES:(k + 1) * _LANES] = s_ref[
+            pl.ds(k, o_ref.shape[0], stride=c), :].astype(o_ref.dtype)
+
+
+def _from_slabs(slabs, tokens, dtype):
+    """``(tokens, c * 128)`` rows in ``dtype`` of the first ``tokens`` of
+    ``slabs`` ``(more * c, 128)``."""
+    from jax.experimental import pallas as pl
+    c = slabs.shape[0] // (tokens + 1)
+    bt = next(b for b in (512, 256, 128, 64, 32, 16, 8) if tokens % b == 0)
+    return pl.pallas_call(
+        partial(_from_slabs_kernel, c),
+        grid=(tokens // bt,),
+        in_specs=[pl.BlockSpec((bt * c, _LANES), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((bt, c * _LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((tokens, c * _LANES), dtype),
+        interpret=_build_interpret(),
+    )(slabs)
+
+
+def _token_sums(tokens, d, block):
+    """Zeroed slabs for ``tokens`` rows of ``d`` and the padding's dump,
+    and the scratch a kernel needs to add a block of rows to them."""
+    from jax.experimental.pallas import tpu as pltpu
+    c = d // _LANES
+    return jnp.zeros(((tokens + 1) * c, _LANES), jnp.float32), \
+        [pltpu.VMEM((block * c, _LANES), jnp.float32),
+         pltpu.SemaphoreType.DMA((2,))]
+
+
+def _gate_up(x, wg_ref, wu_ref):
+    """``a = x Wgate``, ``u = x Wup`` in float32, ``sigmoid(a)`` and
+    ``silu(a)``: none of the four leaves VMEM."""
+    a = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
+    u = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
+    sig = jax.nn.sigmoid(a)
+    return a, u, sig, a * sig
+
+
+def _experts_fwd_kernel(tokens, e_ref, tok_ref, x_ref, cw_ref, wg_ref, wu_ref,
+                        wd_ref, _, out_hbm, buf, sems):
+    """A block of rows through its expert's SwiGLU, added to its tokens:
+    ``out[tok] += cw * ((silu(x Wgate) * (x Wup)) Wdown)``, the hidden
+    rows in the operands' dtype for the third product, the combine
+    weight applied and the sum kept in float32."""
+    block = x_ref.shape[0]
+    _add_to_tokens_begin(tok_ref, out_hbm, buf, sems, block, tokens)
+    x = x_ref[...]
+    _, u, _, act = _gate_up(x, wg_ref, wu_ref)
+    y = jnp.dot((act * u).astype(x.dtype), wd_ref[...],
+                preferred_element_type=jnp.float32)
+    _add_to_tokens_end(tok_ref, out_hbm, buf, sems, block, tokens,
+                       cw_ref[...] * y)
+
+
+def _experts_bwd_kernel(tokens, e_ref, tok_ref, x_ref, g_ref, cw_ref, wg_ref,
+                        wu_ref, wd_ref, _, da_ref, du_ref, hc_ref, dcw_ref,
+                        dx_hbm, buf, sems):
+    """The same block backward, rows in and rows out: the hidden rows
+    again from ``x``, ``dh0 = g Wdown^T``, the combine weight's gradient
+    ``sum(h * dh0)`` (which is ``sum((h Wdown) * g)`` without forming
+    ``h Wdown``), ``da`` and ``du`` through the SwiGLU with ``dh = cw *
+    dh0``, and ``dx[tok] += da Wgate^T + du Wup^T`` in float32. ``da``,
+    ``du`` and ``cw * h`` go out in the operands' dtype for the weight
+    gradients' kernel."""
+    block = x_ref.shape[0]
+    _add_to_tokens_begin(tok_ref, dx_hbm, buf, sems, block, tokens)
+    x, c = x_ref[...], cw_ref[...]
+    a, u, sig, act = _gate_up(x, wg_ref, wu_ref)
+    h = (act * u).astype(x.dtype).astype(jnp.float32)
+    dh0 = jax.lax.dot_general(g_ref[...], wd_ref[...], _NT,
+                              preferred_element_type=jnp.float32)
+    dcw_ref[...] = jnp.sum(h * dh0, axis=1, keepdims=True)
+    hc_ref[...] = (c * h).astype(hc_ref.dtype)
+    dh = c * dh0
+    du = (dh * act).astype(x.dtype)
+    da = (dh * u * (sig * (1.0 + a * (1.0 - sig)))).astype(x.dtype)
+    da_ref[...] = da
+    du_ref[...] = du
+    dx = jax.lax.dot_general(
+        da, wg_ref[...], _NT, preferred_element_type=jnp.float32) \
+        + jax.lax.dot_general(
+            du, wu_ref[...], _NT, preferred_element_type=jnp.float32)
+    _add_to_tokens_end(tok_ref, dx_hbm, buf, sems, block, tokens, dx)
+
+
+def _experts_wgrad_kernel(nout, e_ref, tok_ref, lhs_ref, *refs):
+    """``out[n][e] = sum over e's blocks of lhs^T rhs[n]``: rows are the
+    reduction axis, so the float32 sums stay in VMEM scratch over an
+    expert's consecutive blocks and are cast and written once, at its
+    last block."""
+    from jax.experimental import pallas as pl
+    rhs_refs, out_refs, acc_refs = (refs[:nout], refs[2 * nout:3 * nout],
+                                    refs[3 * nout:])
+    i, e = pl.program_id(0), e_ref[pl.program_id(0)]
+    before = e_ref[jnp.maximum(i - 1, 0)]
+    after = e_ref[jnp.minimum(i + 1, e_ref.shape[0] - 1)]
+
+    @pl.when(jnp.logical_or(i == 0, before != e))
+    def _():
+        for acc in acc_refs:
+            acc[...] = jnp.zeros(acc.shape, jnp.float32)
+
+    lhs = lhs_ref[...]
+    for rhs, acc in zip(rhs_refs, acc_refs):
+        acc[...] += jax.lax.dot_general(lhs, rhs[...], _TN,
+                                        preferred_element_type=jnp.float32)
+
+    @pl.when(jnp.logical_or(i == pl.num_programs(0) - 1, after != e))
+    def _():
+        for out, acc in zip(out_refs, acc_refs):
+            out[...] = acc[...].astype(out.dtype)
+
+
+def experts_forward(xs, cw, tok, wgate, wup, wdown, expert, nb, block,
+                    tokens):
+    """``out[t] = sum over the rows r of token t of cw[r] *
+    E_expert(r)(xs[r])``, ``(tokens, d)`` float32, over the rows of the
+    ``nb`` blocks in use. ``xs`` ``(rows, d)`` are the gathered tokens
+    laid out expert by expert in whole blocks (``dispatch_plan`` in
+    layers/sequence.py), ``cw`` ``(rows, 1)`` float32 their combine
+    weights, ``tok`` ``(rows,)`` int32 their tokens (``tokens`` or more
+    marks padding; a block holds a token once), the weights ``(held, d,
+    w)`` / ``(held, w, d)``, ``expert`` ``(blocks,)`` int32. The rows
+    are added to their tokens inside the kernel: XLA's scatter-add of as
+    many rows takes longer than the products (PERF.md, PR 31)."""
+    acc, scratch = _token_sums(tokens, xs.shape[1], block)
+    out, = _expert_call(
+        partial(_experts_fwd_kernel, tokens), expert, tok, nb, block,
+        (xs, cw, wgate, wup, wdown), (), acc, scratch)
+    return _from_slabs(out, tokens, jnp.float32)
+
+
+def experts_backward(xs, g, cw, tok, wgate, wup, wdown, expert, nb, block,
+                     tokens):
+    """Gradients of :func:`experts_forward` for ``g`` ``(rows, d)``, the
+    result's cotangent gathered like ``xs``: ``(dx, dwgate, dwup,
+    dwdown, dcw)``, ``dx`` ``(tokens, d)`` (what ``xs`` was gathered
+    from) in the operands' dtype, summed in float32; the weights' in
+    the weights' dtype with zeros for an expert no block belongs to;
+    ``dcw`` ``(rows, 1)``, not written past ``nb * block``."""
+    from jax.experimental.pallas import tpu as pltpu
+    rows, d = xs.shape
+    w = wgate.shape[2]
+    acc, scratch = _token_sums(tokens, d, block)
+    da, du, hc, dcw, dx = _expert_call(
+        partial(_experts_bwd_kernel, tokens), expert, tok, nb, block,
+        (xs, g, cw, wgate, wup, wdown),
+        (jax.ShapeDtypeStruct((rows, w), xs.dtype),
+         jax.ShapeDtypeStruct((rows, w), xs.dtype),
+         jax.ShapeDtypeStruct((rows, w), xs.dtype),
+         jax.ShapeDtypeStruct((rows, 1), jnp.float32)),
+        acc, scratch)
+    dwg, dwu = _expert_call(
+        partial(_experts_wgrad_kernel, 2), expert, tok, nb, block,
+        (xs, da, du),
+        (jax.ShapeDtypeStruct(wgate.shape, wgate.dtype),
+         jax.ShapeDtypeStruct(wup.shape, wup.dtype)),
+        scratch=[pltpu.VMEM((d, w), jnp.float32)] * 2)
+    dwd, = _expert_call(
+        partial(_experts_wgrad_kernel, 1), expert, tok, nb, block, (hc, g),
+        (jax.ShapeDtypeStruct(wdown.shape, wdown.dtype),),
+        scratch=[pltpu.VMEM((w, d), jnp.float32)])
+    return _from_slabs(dx, tokens, xs.dtype), dwg, dwu, dwd, dcw
+
+
+def grouped_experts_applicable(d: int, w: int, block: int, dtype) -> bool:
+    """Shape gate of the grouped expert kernels: the model width and the
+    experts' width are whole lanes (128), the row block whole lanes too
+    (it is the contraction of the weight gradients), and the kernel
+    with most in VMEM, the backward one (three weight matrices twice
+    over, a block of ``x`` and ``g`` twice over and of ``dx`` once in
+    float32, the float32 rows of width ``w`` it works on) stays within
+    the kernels' VMEM."""
+    size = jnp.dtype(dtype).itemsize
+    vmem = 6 * d * w * size + block * (4 * d * size + 8 * d + 40 * w)
+    return (min(d, w, block) > 0 and d % _LANES == 0 and w % _LANES == 0
+            and block % _LANES == 0 and vmem <= _EXPERT_VMEM)
+
+
 class PallasFullConnectLayer(FullConnectLayer):
     """fullc with the matmul lowered through the Pallas kernel
     (config name ``pallas_fullc``); numerically identical to ``fullc``

@@ -406,11 +406,15 @@ def dispatch_plan(picks, weights, first: int, count: int, block: int):
     return tok, cw, expert.astype(jnp.int32), ends[-1].astype(jnp.int32), load
 
 
+def _take_rows(x, idx):
+    # padding rows point past the last token: they read zeros
+    return jnp.take(x, idx, axis=0, mode="fill", fill_value=0)
+
+
 def _block_rows(b, block, x, tok, cw):
     idx = jax.lax.dynamic_slice(tok, (b * block,), (block,))
     c = jax.lax.dynamic_slice(cw, (b * block,), (block,))
-    # padding rows point past the last token: they read zeros
-    return idx, c, jnp.take(x, idx, axis=0, mode="fill", fill_value=0)
+    return idx, c, _take_rows(x, idx)
 
 
 def _gate_up(xs, wg, wu):
@@ -419,13 +423,10 @@ def _gate_up(xs, wg, wu):
     return a, u
 
 
-def _grouped_impl(x, wgate, wup, wdown, cw, tok, expert, nb, block):
-    """``out[t] = sum over the rows r of token t of cw[r] * E_expert(r)(x[t])``
-    with ``E`` a SwiGLU, over the plan of ``dispatch_plan``: a loop over
-    the ``nb`` blocks in use (a trip count the routing decides, which is
-    why the backward pass is written by hand below). ``x`` is ``(tokens,
-    d)``, the weights ``(held, d, w)`` / ``(held, w, d)``; the result is
-    float32."""
+def _loop_forward(x, wgate, wup, wdown, cw, tok, expert, nb, block):
+    """The experts a block at a time: a loop over the ``nb`` blocks in
+    use. It needs no buffer of rows, so it holds whatever the routing
+    does."""
     def body(b, out):
         idx, c, xs = _block_rows(b, block, x, tok, cw)
         e = expert[b]
@@ -436,34 +437,25 @@ def _grouped_impl(x, wgate, wup, wdown, cw, tok, expert, nb, block):
     return jax.lax.fori_loop(0, nb, body, jnp.zeros(x.shape, _F32))
 
 
-grouped_swiglu = jax.custom_vjp(_grouped_impl, nondiff_argnums=(8,))
-
-
-def _grouped_fwd(x, wgate, wup, wdown, cw, tok, expert, nb, block):
-    out = _grouped_impl(x, wgate, wup, wdown, cw, tok, expert, nb, block)
-    return out, (x, wgate, wup, wdown, cw, tok, expert, nb)
-
-
-def _grouped_bwd(block, res, g_out):
+def _loop_backward(x, wgate, wup, wdown, cw, tok, expert, nb, block, g_out):
     """Each block again: recompute its hidden rows, then the products'
     transposes. Weight gradients accumulate in float32."""
-    x, wgate, wup, wdown, cw, tok, expert, nb = res
-    g_out = g_out.astype(_F32)
-
     def body(b, carry):
         dx, dwg, dwu, dwd, dcw = carry
         idx, c, xs = _block_rows(b, block, x, tok, cw)
-        g = jnp.take(g_out, idx, axis=0, mode="fill", fill_value=0)
+        g = _take_rows(g_out, idx)
         e = expert[b]
         a, u = _gate_up(xs, wgate[e], wup[e])
         sig = jax.nn.sigmoid(a)
         act = a * sig
         h = (act * u).astype(x.dtype)
-        y = jnp.dot(h, wdown[e], preferred_element_type=_F32)
+        # sum(h * (g Wdown^T)) is sum((h Wdown) * g) without the product
+        dh0 = jnp.dot(g.astype(x.dtype), wdown[e].T,
+                      preferred_element_type=_F32)
         dcw = jax.lax.dynamic_update_slice(
-            dcw, jnp.sum(y * g, axis=-1), (b * block,))
+            dcw, jnp.sum(h.astype(_F32) * dh0, axis=-1), (b * block,))
+        dh = c[:, None] * dh0
         dy = (c[:, None] * g).astype(x.dtype)
-        dh = jnp.dot(dy, wdown[e].T, preferred_element_type=_F32)
         dwd = dwd.at[e].add(jnp.dot(h.T, dy, preferred_element_type=_F32))
         du = (dh * act).astype(x.dtype)
         da = (dh * u * (sig * (1.0 + a * (1.0 - sig)))).astype(x.dtype)
@@ -476,8 +468,108 @@ def _grouped_bwd(block, res, g_out):
     zeros = [jnp.zeros(a.shape, _F32) for a in (x, wgate, wup, wdown, cw)]
     dx, dwg, dwu, dwd, dcw = jax.lax.fori_loop(0, nb, body, tuple(zeros))
     return (dx.astype(x.dtype), dwg.astype(wgate.dtype),
-            dwu.astype(wup.dtype), dwd.astype(wdown.dtype), dcw,
-            None, None, None)
+            dwu.astype(wup.dtype), dwd.astype(wdown.dtype), dcw)
+
+
+def _gather_blocks(x, tok, nb, block, budget):
+    """``x[tok]`` for the rows of the blocks in use among the plan's
+    first ``budget``, zeros after them: eight blocks a trip of a loop
+    that follows ``nb``, because XLA's gather costs the same 45 ns a row
+    whatever the row holds (PERF.md, PR 31) and a step uses a third of
+    the buffer when its routing is even."""
+    group = math.gcd(budget, 8)
+    rows = group * block
+    if not budget:
+        return jnp.zeros((0, x.shape[1]), x.dtype)
+
+    def body(i, xs):
+        idx = jax.lax.dynamic_slice(tok, (i * rows,), (rows,))
+        return jax.lax.dynamic_update_slice(
+            xs, _take_rows(x, idx), (i * rows, 0))
+
+    return jax.lax.fori_loop(
+        0, -(-jnp.minimum(nb, budget) // group), body,
+        jnp.zeros((budget * block, x.shape[1]), x.dtype))
+
+
+def _kernel_backward(xs, wgate, wup, wdown, cw, tok, expert, nb, block,
+                     g_out):
+    """The backward pass of the grouped kernels' schedule
+    (layers/pallas_kernels.py: experts_backward) over the gathered rows
+    ``xs``, which hold the ``nb`` blocks in use."""
+    rows = xs.shape[0]
+    dx, dwg, dwu, dwd, dcw = pallas_kernels.experts_backward(
+        xs, _gather_blocks(g_out.astype(xs.dtype), tok, nb, block,
+                           rows // block), cw[:rows, None],
+        tok[:rows], wgate, wup, wdown, expert, nb, block, g_out.shape[0])
+    # the kernel wrote the rows of the blocks in use and no others
+    dcw = jnp.where(jnp.arange(rows) < nb * block, dcw[:, 0], 0.0)
+    return dx, dwg, dwu, dwd, jnp.zeros(cw.shape, _F32).at[:rows].set(dcw)
+
+
+def _by_budget(nb, budget, blocks, kernel, loop, nothing):
+    """``kernel()`` where the blocks in use fit the kernels' row buffers,
+    ``loop()`` where they do not (or no kernel applies: ``budget`` 0).
+    Not ``lax.cond``: each is the body of a loop of one trip or none, the
+    first starting from ``nothing`` (zeros shaped as the result) and the
+    second from the first's results. The device runs the same thing,
+    and the profiler reports a ``while`` as what encloses its body's
+    ops, which trace reductions know to leave out, where a conditional
+    and its branch's call each come back as one more op as long as all
+    they hold."""
+    if not budget:
+        return loop()
+    if budget >= blocks:
+        return kernel()
+    fits = (nb <= budget).astype(jnp.int32)
+    done = jax.lax.fori_loop(0, fits, lambda _, res: kernel(), nothing)
+    return jax.lax.fori_loop(0, 1 - fits, lambda _, res: loop(), done)
+
+
+def _grouped_fwd(x, wgate, wup, wdown, cw, tok, expert, nb, block, budget):
+    """``grouped_swiglu`` and what its backward pass needs: the operands,
+    and the rows of the plan's first ``budget`` blocks, which the
+    kernels' schedule reads in both directions (gathered whichever
+    schedule the step takes: the loop is the rare one)."""
+    xs = _gather_blocks(x, tok, nb, block, budget)
+    out = _by_budget(
+        nb, budget, expert.shape[0],
+        lambda: pallas_kernels.experts_forward(
+            xs, cw[:len(xs), None], tok[:len(xs)], wgate, wup, wdown, expert,
+            nb, block, x.shape[0]),
+        lambda: _loop_forward(x, wgate, wup, wdown, cw, tok, expert, nb,
+                              block),
+        jnp.zeros(x.shape, _F32))
+    return out, (x, wgate, wup, wdown, cw, tok, expert, nb, xs)
+
+
+def _grouped_impl(x, wgate, wup, wdown, cw, tok, expert, nb, block, budget):
+    """``out[t] = sum over the rows r of token t of cw[r] * E_expert(r)(x[t])``
+    with ``E`` a SwiGLU, over the plan of ``dispatch_plan``. ``x`` is
+    ``(tokens, d)``, the weights ``(held, d, w)`` / ``(held, w, d)``; the
+    result is float32. Two schedules of one computation, both
+    proportional to the ``nb`` blocks in use (a count the routing
+    decides, which is why the backward pass is written by hand): grouped
+    kernels over a buffer of ``budget`` blocks of rows, and the loop a
+    block at a time where the routing needs more than that; ``budget``
+    0 is the loop alone."""
+    return _grouped_fwd(x, wgate, wup, wdown, cw, tok, expert, nb, block,
+                        budget)[0]
+
+
+grouped_swiglu = jax.custom_vjp(_grouped_impl, nondiff_argnums=(8, 9))
+
+
+def _grouped_bwd(block, budget, res, g_out):
+    x, wgate, wup, wdown, cw, tok, expert, nb, xs = res
+    grads = _by_budget(
+        nb, budget, expert.shape[0],
+        lambda: _kernel_backward(xs, wgate, wup, wdown, cw, tok, expert, nb,
+                                 block, g_out),
+        lambda: _loop_backward(x, wgate, wup, wdown, cw, tok, expert, nb,
+                               block, g_out.astype(_F32)),
+        tuple(jnp.zeros_like(a) for a in (x, wgate, wup, wdown, cw)))
+    return grads + (None, None, None)
 
 
 grouped_swiglu.defvjp(_grouped_fwd, _grouped_bwd)
@@ -498,7 +590,15 @@ class MoELayer(_SeqLayer):
     ``bias_seed`` at ``bias_sigma`` and held fixed (its update rate is
     not part of the published config). State also carries the last
     forward's counters for the ``moe`` telemetry record: ``load`` (picks
-    each held expert got), ``picks_held``, ``dropped``.
+    each held expert got), ``picks_held``, ``dropped``; and ``grouped``,
+    the forward passes so far whose experts ran as the grouped kernels.
+
+    Which schedule the experts run (``grouped_swiglu``) is what the
+    shapes allow, not a key: the grouped kernels where the widths tile
+    (``pallas_kernels.grouped_experts_applicable``), with buffers for
+    three times the rows the held experts get when the routing is even
+    plus a block an expert, and the loop a block at a time in a step whose
+    routing needs more rows than that, and everywhere else.
     """
 
     sub_scopes = ("route", "dispatch", "experts", "combine", "shared")
@@ -514,6 +614,7 @@ class MoELayer(_SeqLayer):
         self.block = 512
         self.bias_seed = 0
         self.bias_sigma = 0.0
+        self.grouped = False
         super().__init__(cfg)
 
     def set_param(self, name, val):
@@ -549,6 +650,8 @@ class MoELayer(_SeqLayer):
             raise ValueError(
                 "moe: must set nexpert, topk <= nexpert, nhidden, and "
                 "expert_first / expert_count inside nexpert")
+        self.grouped = pallas_kernels.grouped_experts_applicable(
+            s.x, self.param.num_hidden, self.block, self.cd)
         self.in_shapes = [s]
         self.out_shapes = [s]
         return self.out_shapes
@@ -574,7 +677,25 @@ class MoELayer(_SeqLayer):
             jax.random.PRNGKey(self.bias_seed), (self.nexpert,), _F32)
         return {"bias": bias,
                 "load": jnp.zeros((self.count,), jnp.int32),
-                "picks_held": jnp.int32(0), "dropped": jnp.int32(0)}
+                "picks_held": jnp.int32(0), "dropped": jnp.int32(0),
+                "grouped": jnp.int32(0)}
+
+    def budget(self, tokens: int) -> int:
+        """Blocks of rows the grouped kernels' buffers hold for a step
+        of ``tokens``: three times the picks that land on held experts
+        in expectation (a net that trains only the experts it holds
+        draws picks to them: 2.4 times the even share within forty steps
+        on one batch, PERF.md, PR 31), plus a block an expert for the
+        padding, and never
+        more than the plan's own bound; 0 where the kernels do not
+        apply (the layer's widths, or a step whose tokens are not whole
+        sublanes of 8)."""
+        if not self.grouped or tokens % 8:
+            return 0
+        picks = tokens * self.topk
+        even = 3 * picks * self.count // self.nexpert
+        return min(-(-even // self.block), -(-picks // self.block)) \
+            + self.count
 
     def route(self, xt, router, bias):
         """(picks, weights) of each token: float32 throughout, the
@@ -597,11 +718,12 @@ class MoELayer(_SeqLayer):
         with jax.named_scope("dispatch"):
             tok, cw, expert, nb, load = dispatch_plan(
                 picks, w, self.first, self.count, self.block)
+        budget = self.budget(b * t)
         with jax.named_scope("experts"):
             y = grouped_swiglu(
                 xt.astype(cd), params["egate"].astype(cd),
                 params["eup"].astype(cd), params["edown"].astype(cd),
-                cw, tok, expert, nb, self.block)
+                cw, tok, expert, nb, self.block, budget)
         if self.nshared:
             with jax.named_scope("shared"):
                 shared = swiglu(xt, params["sgate"], params["sup"],
@@ -611,8 +733,10 @@ class MoELayer(_SeqLayer):
                 y = y + shared.astype(_F32)
             out = y.astype(x.dtype).reshape(b, t, d)
         held = jnp.sum(load)
+        took = (nb <= budget).astype(jnp.int32) if budget else 0
         new_state = dict(state, load=load, picks_held=held,
-                         dropped=held - jnp.sum(tok < b * t))
+                         dropped=held - jnp.sum(tok < b * t),
+                         grouped=state["grouped"] + took)
         return [out], new_state
 
     def flops_per_example(self) -> float:

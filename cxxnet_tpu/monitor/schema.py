@@ -221,8 +221,14 @@ OPTIONAL: Dict[str, tuple] = {
     "step": ("tokens",),
     "model_info": ("tokens_per_example", "train_flops_per_token"),
     # mla_attention layers of the net, and how many of them run the
-    # fused causal-attention kernel (layers/pallas_kernels.py)
-    "layout": ("attention_layers", "attention_fused_layers"),
+    # fused causal-attention kernel (layers/pallas_kernels.py); moe
+    # layers, and how many of them run their experts as the grouped
+    # kernels while a step's routing fits the kernels' row buffers
+    "layout": ("attention_layers", "attention_fused_layers",
+               "moe_layers", "moe_grouped_layers"),
+    # the share of the dispatch's passes through an expert layer that
+    # did (forward; the other passes took the loop a block at a time)
+    "moe": ("grouped_share",),
 }
 
 _TIMING_KEYS = ("wall_ms", "data_wait_ms", "total_ms", "max_ms",
@@ -238,7 +244,8 @@ _TIMING_KEYS = ("wall_ms", "data_wait_ms", "total_ms", "max_ms",
 
 # ratio fields must sit in [0, 1]
 _RATIO_KEYS = ("buffer_reuse_rate", "fill_rate", "pad_fraction",
-               "agree_rate", "data_wait_share", "overlap_ratio", "recall")
+               "agree_rate", "data_wait_share", "overlap_ratio", "recall",
+               "grouped_share")
 
 
 def validate_record(rec: Dict[str, Any]) -> List[str]:

@@ -345,6 +345,9 @@ class NetTrainer:
         # expert layers leave their counters in their state (_emit_moe)
         self._moe_keys = [lk for lk, st in self.net_state.items()
                           if "picks_held" in st]
+        # their passes through the grouped kernels, as of the last record
+        self._moe_grouped = {
+            lk: int(self.net_state[lk]["grouped"]) for lk in self._moe_keys}
         # serve_dtype activation BEFORE the programs build: the specs
         # live on the layer objects and must be pinned before any
         # forward traces (nnet/quantize.attach)
@@ -1574,6 +1577,10 @@ class NetTrainer:
         # kernel (layers/sequence.py: the shapes decide)
         cores = [layer.fused_core for layer in net.layer_objs
                  if hasattr(layer, "fused_core")]
+        # expert layers, and those whose experts run as the grouped
+        # kernels while the routing fits their buffers (the same)
+        grouped = [layer.grouped for layer in net.layer_objs
+                   if hasattr(layer, "grouped")]
         self._mon.emit("layout",
                        # what took hold, not what was asked for
                        input_layout=self.input_layout_effective,
@@ -1585,6 +1592,8 @@ class NetTrainer:
                        pallas_interpret=_pallas.interpret(),
                        attention_layers=len(cores),
                        attention_fused_layers=sum(cores),
+                       moe_layers=len(grouped),
+                       moe_grouped_layers=sum(grouped),
                        **net.layout_summary)
         if self.quant_report.get("active"):
             r = self.quant_report
@@ -1649,15 +1658,18 @@ class NetTrainer:
             update_counter=self.update_counter, lr=lr,
             loss=float(self._last_loss),
             compile=compiled)
-        self._emit_moe(examples // n_batches)
+        self._emit_moe(examples // n_batches, n_batches)
 
-    def _emit_moe(self, rows: int) -> None:
+    def _emit_moe(self, rows: int, n_batches: int) -> None:
         """One ``moe`` record a dispatch of a net with expert layers:
         what each layer's held experts got in the dispatch's LAST step,
-        from the counters the layers leave in their state (the loss
-        this dispatch was closed by is already on the host, so the
-        state is ready: no further wait)."""
+        and the share of the dispatch's ``n_batches`` passes a layer in
+        which the experts ran as the grouped kernels, from the counters
+        the layers leave in their state (the loss this dispatch was
+        closed by is already on the host, so the state is ready: no
+        further wait)."""
         layers = {}
+        took = 0
         for lkey in self._moe_keys:
             st = self.net_state[lkey]
             layer = self.net.layer_objs[self._layer_index[lkey]]
@@ -1668,6 +1680,9 @@ class NetTrainer:
                 "load_max": float(load.max()),
                 "held_share": float(st["picks_held"]) / picks,
                 "dropped": int(st["dropped"])}
+            passes = int(st["grouped"])
+            took += passes - self._moe_grouped[lkey]
+            self._moe_grouped[lkey] = passes
         if layers:
             self._mon.emit(
                 "moe", step=self._steps_total, layers=layers,
@@ -1676,7 +1691,8 @@ class NetTrainer:
                                           for v in layers.values()])),
                 load_max_over_mean=max(
                     v["load_max"] / max(v["load_mean"], 1e-9)
-                    for v in layers.values()))
+                    for v in layers.values()),
+                grouped_share=took / float(n_batches * len(layers)))
 
     def end_round(self) -> None:
         """Close the current round's counter window (idempotent):

@@ -243,6 +243,94 @@ def test_decoder_layer_compiles_for_one_v5e_at_published_widths(topo, kind):
         assert layer.fused_core and text.count("tpu_custom_call") >= 2
         scores = re.findall(r"f32\[(?:2,16|32),\d{4,},\d{4,}\]", text)
         assert not scores, sorted(set(scores))
+    else:
+        # the experts' two schedules, each the body of a loop of one
+        # trip or none (layers/sequence.py: _by_budget): the grouped
+        # kernels (the backward pass's three and the one that turns dx's
+        # slabs back into rows) where the blocks in use fit the 80 the
+        # buffers hold, and the loop a block at a time otherwise. This
+        # loss needs no forward value, so of the forward pass the gather
+        # of 80 blocks of rows is left alone
+        assert layer.grouped and layer.budget(2 * 8192) == 80
+        assert " conditional(" not in text
+        assert "bf16[40960,2048]" in text
+        kernels, loop = _loop_bodies(text, "(experts)")[
+            "transpose(jvp(experts))"]
+        # (the kernels' side holds a loop too: the cotangent's gather)
+        assert kernels.count("tpu_custom_call") == 4
+        assert "tpu_custom_call" not in loop and " while(" in loop
+
+
+def _loop_bodies(text, scope):
+    """``{scope path: [text reachable from the body of each loop]}`` of
+    the outermost ``while`` instructions of an HLO module whose op_name
+    holds ``scope``, in program order."""
+    import re
+    comps = {m.group(1): m.group(2) for m in re.finditer(
+        r"^(?:ENTRY )?%?([\w.\-]+) \([^\n]*\{\n(.*?)^\}", text,
+        re.M | re.S)}
+
+    def reach(name, seen):
+        if name in seen or name not in comps:
+            return ""
+        seen.add(name)
+        body = comps[name]
+        called = re.findall(
+            r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)", body)
+        return body + "".join(reach(c, seen) for c in called)
+
+    out = {}
+    for m in re.finditer(r" while\([^\n]*body=%?([\w.\-]+)[^\n]*"
+                         r"op_name=\"([^\"]*)\"", text):
+        path = m.group(2).split("/")
+        if scope in m.group(2) and path[-1] == "while" \
+                and "while" not in path[:-1]:
+            out.setdefault("/".join(p for p in path if scope in p), []) \
+                .append(reach(m.group(1), set()))
+    return out
+
+
+def test_grouped_expert_kernels_compile_for_one_v5e_at_the_cells_shapes(topo):
+    """The routed experts' kernels alone at the language-model cell's
+    shapes (buffers of 80 blocks of 512 rows = 40,960 rows of 2,048, 8
+    experts of 2,048 x 1,408, bfloat16, a traced count of blocks in use
+    as the grid): Mosaic takes the traced grid, an expert's three whole
+    weight matrices twice over beside the float32 rows of the backward
+    kernel (over 70 MB of the 96 MB of VMEM the kernels ask for; 64 MB
+    does not hold it), a copy a row between a token's slab of the sums
+    in HBM and VMEM, and the weight gradients' transposed products with
+    their float32 sums in scratch."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from cxxnet_tpu.layers import pallas_kernels as pk
+    one = SingleDeviceSharding(topo.devices[0])
+    held, d, w, block, rows = 8, 2048, 1408, 512, 80 * 512
+    assert pk.grouped_experts_applicable(d, w, block, jnp.bfloat16)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    xs, g, cw = sds((rows, d)), sds((rows, d)), sds((rows, 1), jnp.float32)
+    ws = (sds((held, d, w)), sds((held, d, w)), sds((held, w, d)))
+    expert, nb = sds((200,), jnp.int32), sds((), jnp.int32)
+    tok, tokens = sds((rows,), jnp.int32), 2 * 8192
+    fwd = jax.jit(lambda xs, cw, tok, *a: pk.experts_forward(
+        xs, cw, tok, *a, block, tokens)).lower(
+            xs, cw, tok, *ws, expert, nb).compile()
+    # the experts' kernel, and the one that turns the sums' slabs to rows
+    assert fwd.as_text().count("tpu_custom_call") == 2
+    assert (fwd.out_info.shape, fwd.out_info.dtype) \
+        == ((tokens, d), jnp.float32)
+    bwd = jax.jit(lambda xs, g, cw, tok, *a: pk.experts_backward(
+        xs, g, cw, tok, *a, block, tokens)).lower(
+            xs, g, cw, tok, *ws, expert, nb).compile()
+    assert bwd.as_text().count("tpu_custom_call") == 4
+    dx, dwg, dwu, dwd, dcw = bwd.out_info
+    assert (dx.shape, dx.dtype) == ((tokens, d), jnp.bfloat16)
+    assert [(o.shape, o.dtype) for o in (dwg, dwu, dwd)] \
+        == [(a.shape, a.dtype) for a in ws]
+    assert dcw.shape == (rows, 1)
 
 
 def test_causal_attention_compiles_for_one_v5e_at_the_cells_shapes(topo):
