@@ -614,7 +614,34 @@ def _first_query_tile(j, bq, bk):
     return j * bk // bq
 
 
-def _attn_fwd_kernel(scale, bq, bk, nparts, *refs):
+def _first_key_tile(i, bq, bk, window):
+    """The first key tile that query tile ``i`` sees through a window:
+    the one that holds key ``i * bq - window + 1``."""
+    lo = i * bq - window + 1
+    return (max(lo, 0) if isinstance(i, int) else jnp.maximum(lo, 0)) // bk
+
+
+def _last_query_tile(j, bq, bk, window, nq):
+    """The last query tile that sees key tile ``j`` through a window:
+    the one that holds query ``(j + 1) * bk - 1 + window - 1``."""
+    hi = ((j + 1) * bk + window - 2) // bq
+    return min(hi, nq - 1) if isinstance(j, int) else jnp.minimum(hi, nq - 1)
+
+
+def _band_tiles(time, bq, bk, window):
+    """(key tiles a query tile visits, query tiles a key tile visits):
+    the most over the sequence, so the grids' inner axes are as long as
+    the band is wide and no longer."""
+    nq, nk = time // bq, time // bk
+    if not window:
+        return nk, nq
+    return (max(_diag_key_tile(i, bq, bk)
+                - _first_key_tile(i, bq, bk, window) + 1 for i in range(nq)),
+            max(_last_query_tile(j, bq, bk, window, nq)
+                - _first_query_tile(j, bq, bk) + 1 for j in range(nk)))
+
+
+def _attn_fwd_kernel(scale, bq, bk, nparts, window, *refs):
     """One (batch x head, query tile i, key tile j) step of the online
     softmax: ``s = q k^T * scale`` on the MXU, the running row maximum
     ``m``, row sum ``l`` and the un-normalised ``acc = sum p v`` in
@@ -623,14 +650,20 @@ def _attn_fwd_kernel(scale, bq, bk, nparts, *refs):
     (and fetch nothing: the index maps stop at the diagonal); only a
     tile the diagonal crosses pays for the mask. At the diagonal's tile
     the output is normalised once and the row log-sum-exp goes out as a
-    lane-dense row."""
+    lane-dense row. With a ``window`` a query sees the keys ``0 <= i - j <
+    window`` only: the key axis of the grid counts from the first tile of
+    the query tile's band, so the tiles wholly before the band are never
+    visited, and the tile the band's far edge crosses is masked too."""
     from jax.experimental import pallas as pl
     q_refs, k_refs = refs[:nparts], refs[nparts:2 * nparts]
     v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = refs[2 * nparts:]
     i, j = pl.program_id(1), pl.program_id(2)
     last = _diag_key_tile(i, bq, bk)
+    first = _first_key_tile(i, bq, bk, window) if window else 0
+    if window:
+        j = first + j
 
-    @pl.when(j == 0)
+    @pl.when(j == first)
     def _():
         m_ref[...] = jnp.full(m_ref.shape, _MASKED, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
@@ -643,7 +676,10 @@ def _attn_fwd_kernel(scale, bq, bk, nparts, *refs):
         if masked:
             qi = i * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             ki = j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(ki <= qi, s, _MASKED)
+            seen = ki <= qi
+            if window:
+                seen = jnp.logical_and(seen, qi - ki < window)
+            s = jnp.where(seen, s, _MASKED)
         m_prev = m_ref[...]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_next)
@@ -655,6 +691,8 @@ def _attn_fwd_kernel(scale, bq, bk, nparts, *refs):
             preferred_element_type=jnp.float32)
 
     below = (j + 1) * bk - 1 <= i * bq     # every key before every query
+    if window:                             # ... and none too far back
+        below = jnp.logical_and(below, (i + 1) * bq - 1 - j * bk < window)
     pl.when(below)(lambda: tile(False))
     pl.when(jnp.logical_and(j <= last, jnp.logical_not(below)))(
         lambda: tile(True))
@@ -667,7 +705,7 @@ def _attn_fwd_kernel(scale, bq, bk, nparts, *refs):
         lse_ref[...] = jnp.broadcast_to(lse, (bq, 128)).T[:1]
 
 
-def _attn_bwd_kernel(scale, bq, bk, nparts, *refs):
+def _attn_bwd_kernel(scale, bq, bk, nparts, window, *refs):
     """One (batch x head, key tile j, query tile i) step of the backward
     pass, keys on the sublanes and queries on the lanes so that the
     saved log-sum-exp and ``D = rowsum(dO * O)`` broadcast as rows:
@@ -676,7 +714,10 @@ def _attn_bwd_kernel(scale, bq, bk, nparts, *refs):
     scratch across the query tiles, ``dQ += dS k`` into the float32
     ``dQ`` of the whole sequence, which stays in VMEM for all of one
     batch x head. ``scale`` multiplies ``dK`` once at the end and ``dQ``
-    outside. Query tiles wholly before the key tile do nothing."""
+    outside. Query tiles wholly before the key tile do nothing; with a
+    ``window`` the query axis of the grid counts from the key tile's first
+    query tile and is as long as the band is wide, so the query tiles
+    wholly past the band are never visited."""
     from jax.experimental import pallas as pl
     q_refs, k_refs = refs[:nparts], refs[nparts:2 * nparts]
     v_ref, do_ref, lse_ref, dd_ref = refs[2 * nparts:2 * nparts + 4]
@@ -685,13 +726,16 @@ def _attn_bwd_kernel(scale, bq, bk, nparts, *refs):
         outs[2 * nparts]
     dk_accs, dv_acc = outs[2 * nparts + 1:3 * nparts + 1], outs[-1]
     j, i = pl.program_id(1), pl.program_id(2)
+    step = i                               # along the grid's query axis
+    if window:
+        i = _first_query_tile(j, bq, bk) + step
 
-    @pl.when(jnp.logical_and(j == 0, i == 0))
+    @pl.when(jnp.logical_and(j == 0, step == 0))
     def _():
         for dq in dq_refs:
             dq[...] = jnp.zeros(dq.shape, jnp.float32)
 
-    @pl.when(i == 0)
+    @pl.when(step == 0)
     def _():
         for acc in dk_accs + (dv_acc,):
             acc[...] = jnp.zeros(acc.shape, jnp.float32)
@@ -703,7 +747,10 @@ def _attn_bwd_kernel(scale, bq, bk, nparts, *refs):
         if masked:
             ki = j * bk + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
             qi = i * bq + jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
-            st = jnp.where(ki <= qi, st, _MASKED)
+            seen = ki <= qi
+            if window:
+                seen = jnp.logical_and(seen, qi - ki < window)
+            st = jnp.where(seen, st, _MASKED)
         pt = jnp.exp(st - lse_ref[...])
         do = do_ref[...]
         dv_acc[...] += jnp.dot(pt.astype(do.dtype), do,
@@ -719,25 +766,35 @@ def _attn_bwd_kernel(scale, bq, bk, nparts, *refs):
                 dst, k[...], _TN, preferred_element_type=jnp.float32)
 
     below = (j + 1) * bk - 1 <= i * bq     # every key before every query
+    if window:
+        # ... none too far back, and the tile one of the sequence's (the
+        # grid's axis may run past the key tile's last query tile)
+        seen = i <= _last_query_tile(j, bq, bk, window,
+                                     dq_refs[0].shape[0] // bq)
+        below = jnp.logical_and(jnp.logical_and(below, seen),
+                                (i + 1) * bq - 1 - j * bk < window)
     pl.when(below)(lambda: tile(False))
-    pl.when(jnp.logical_and(i >= _first_query_tile(j, bq, bk),
-                            jnp.logical_not(below)))(lambda: tile(True))
+    if not window:
+        seen = i >= _first_query_tile(j, bq, bk)
+    pl.when(jnp.logical_and(seen, jnp.logical_not(below)))(
+        lambda: tile(True))
 
-    @pl.when(i == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _():
         for dk, acc in zip(dk_refs, dk_accs):
             dk[...] = (acc[...] * scale).astype(dk.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _attn_fwd_call(qs, ks, v, scale, bq, bk):
+def _attn_fwd_call(qs, ks, v, scale, bq, bk, window):
     """``(o, lse)`` of ``qs[n]`` ``(bh, t, d_n)`` against ``ks[n]``
-    ``(bh or fewer, t, d_n)`` (a part with fewer leading entries is
-    shared by the heads of a batch item) and ``v`` ``(bh, t, dv)``; the
-    score is the sum of the parts' products."""
+    ``(bh or fewer, t, d_n)`` and ``v`` ``(bh or fewer, t, dv)`` (one
+    with fewer leading entries is shared by that many consecutive query
+    heads: a group's key/value head, or MLA's one ``k_rope`` a batch
+    item); the score is the sum of the parts' products."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    bh, t, dv = v.shape
+    bh, t, dv = qs[0].shape[0], v.shape[1], v.shape[2]
     n = len(qs)
 
     def at_q(b, i, j):
@@ -745,16 +802,20 @@ def _attn_fwd_call(qs, ks, v, scale, bq, bk):
 
     def at_k(share):
         # past the diagonal the same tile again: nothing is fetched
+        if window:
+            return lambda b, i, j: (b // share, jnp.minimum(
+                _first_key_tile(i, bq, bk, window) + j,
+                _diag_key_tile(i, bq, bk)), 0)
         return lambda b, i, j: (
             b // share, jnp.minimum(j, _diag_key_tile(i, bq, bk)), 0)
 
     return pl.pallas_call(
-        partial(_attn_fwd_kernel, scale, bq, bk, n),
-        grid=(bh, t // bq, t // bk),
+        partial(_attn_fwd_kernel, scale, bq, bk, n, window),
+        grid=(bh, t // bq, _band_tiles(t, bq, bk, window)[0]),
         in_specs=[pl.BlockSpec((None, bq, q.shape[2]), at_q) for q in qs]
         + [pl.BlockSpec((None, bk, k.shape[2]), at_k(bh // k.shape[0]))
            for k in ks]
-        + [pl.BlockSpec((None, bk, dv), at_k(1))],
+        + [pl.BlockSpec((None, bk, dv), at_k(bh // v.shape[0]))],
         out_specs=[pl.BlockSpec((None, bq, dv), at_q),
                    pl.BlockSpec((None, 1, bq), lambda b, i, j: (b, 0, i))],
         out_shape=[jax.ShapeDtypeStruct((bh, t, dv), v.dtype),
@@ -769,13 +830,13 @@ def _attn_fwd_call(qs, ks, v, scale, bq, bk):
     )(*qs, *ks, v)
 
 
-def _attn_bwd_call(qs, ks, v, do, lse, dd, scale, bq, bk):
+def _attn_bwd_call(qs, ks, v, do, lse, dd, scale, bq, bk, window):
     """``(dqs, dks, dv)``: ``dqs`` float32 and without ``scale`` (the
-    caller's cast applies it), ``dks`` a head each whatever ``ks``
-    shares, in the keys' dtype."""
+    caller's cast applies it), ``dks`` and ``dv`` a query head each
+    whatever ``ks`` and ``v`` share, in their dtype."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    bh, t, dv = v.shape
+    bh, t, dv = qs[0].shape[0], v.shape[1], v.shape[2]
     n = len(qs)
 
     def at_k(share):
@@ -783,6 +844,11 @@ def _attn_bwd_call(qs, ks, v, do, lse, dd, scale, bq, bk):
 
     def first(j, i):
         # before the key tile's first query tile the same tile again
+        # (with a window: past its last)
+        if window:
+            return jnp.minimum(
+                _first_query_tile(j, bq, bk) + i,
+                _last_query_tile(j, bq, bk, window, t // bq))
         return jnp.maximum(i, _first_query_tile(j, bq, bk))
 
     def at_q(b, j, i):
@@ -795,12 +861,12 @@ def _attn_bwd_call(qs, ks, v, do, lse, dd, scale, bq, bk):
         return b, 0, 0
 
     return pl.pallas_call(
-        partial(_attn_bwd_kernel, scale, bq, bk, n),
-        grid=(bh, t // bk, t // bq),
+        partial(_attn_bwd_kernel, scale, bq, bk, n, window),
+        grid=(bh, t // bk, _band_tiles(t, bq, bk, window)[1]),
         in_specs=[pl.BlockSpec((None, bq, q.shape[2]), at_q) for q in qs]
         + [pl.BlockSpec((None, bk, k.shape[2]), at_k(bh // k.shape[0]))
            for k in ks]
-        + [pl.BlockSpec((None, bk, dv), at_k(1)),
+        + [pl.BlockSpec((None, bk, dv), at_k(bh // v.shape[0])),
            pl.BlockSpec((None, bq, dv), at_q),
            pl.BlockSpec((None, 1, bq), at_row),
            pl.BlockSpec((None, 1, bq), at_row)],
@@ -809,7 +875,7 @@ def _attn_bwd_call(qs, ks, v, do, lse, dd, scale, bq, bk):
         + [pl.BlockSpec((None, bk, dv), at_k(1))],
         out_shape=[jax.ShapeDtypeStruct(q.shape, jnp.float32) for q in qs]
         + [jax.ShapeDtypeStruct((bh,) + k.shape[1:], k.dtype) for k in ks]
-        + [jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        + [jax.ShapeDtypeStruct((bh,) + v.shape[1:], v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, k.shape[2]), jnp.float32)
                         for k in ks]
         + [pltpu.VMEM((bk, dv), jnp.float32)],
@@ -820,29 +886,29 @@ def _attn_bwd_call(qs, ks, v, do, lse, dd, scale, bq, bk):
     )(*qs, *ks, v, do, lse, dd)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _attention(qs, ks, v, scale, bq, bk):
-    return _attn_fwd_call(qs, ks, v, scale, bq, bk)[0]
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _attention(qs, ks, v, scale, bq, bk, window):
+    return _attn_fwd_call(qs, ks, v, scale, bq, bk, window)[0]
 
 
-def _attention_fwd(qs, ks, v, scale, bq, bk):
-    o, lse = _attn_fwd_call(qs, ks, v, scale, bq, bk)
+def _attention_fwd(qs, ks, v, scale, bq, bk, window):
+    o, lse = _attn_fwd_call(qs, ks, v, scale, bq, bk, window)
     return o, (qs, ks, v, o, lse)
 
 
-def _attention_bwd(scale, bq, bk, res, do):
+def _attention_bwd(scale, bq, bk, window, res, do):
     qs, ks, v, o, lse = res
     dd = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                  axis=-1)[:, None, :]
-    out = _attn_bwd_call(qs, ks, v, do, lse, dd, scale, bq, bk)
+    out = _attn_bwd_call(qs, ks, v, do, lse, dd, scale, bq, bk, window)
     n = len(qs)
     dqs = tuple((dq * scale).astype(q.dtype) for dq, q in zip(out[:n], qs))
-    # a part the heads share gets the sum of their gradients
-    dks = tuple(dk if dk.shape == k.shape else
-                dk.reshape((k.shape[0], -1) + k.shape[1:])
-                .astype(jnp.float32).sum(axis=1).astype(k.dtype)
-                for dk, k in zip(out[n:2 * n], ks))
-    return dqs, dks, out[2 * n]
+    # what several query heads share gets the sum of their gradients
+    dkv = tuple(d if d.shape == a.shape else
+                d.reshape((a.shape[0], -1) + a.shape[1:])
+                .astype(jnp.float32).sum(axis=1).astype(a.dtype)
+                for d, a in zip(out[n:], ks + (v,)))
+    return dqs, dkv[:n], dkv[n]
 
 
 _attention.defvjp(_attention_fwd, _attention_bwd)
@@ -864,35 +930,44 @@ def _attn_tiles(time: int, q_block: int):
 
 
 def causal_attention_applicable(time: int, q_block: int, qk_widths,
-                                v_width: int) -> bool:
+                                v_width: int, nhead: int = 1,
+                                nkvhead: int = 1, window: int = 0) -> bool:
     """Shape gate of :func:`causal_attention`: the sequence tiles (a
     multiple of 128 positions, a query tile within ``q_block``), every
     part of the query/key features is whole half-lanes (64) and the
-    values whole lanes (128), and the float32 ``dQ`` of one sequence,
-    which the backward kernel keeps in VMEM twice over, stays within
-    half of the kernels' VMEM."""
+    values whole lanes (128), the float32 ``dQ`` of one sequence, which
+    the backward kernel keeps in VMEM twice over, stays within half of
+    the kernels' VMEM, each key/value head serves a whole group of query
+    heads, and a window is no negative number."""
     lanes = sum(_pad_to(d, 128) for d in qk_widths)
     return (min(_attn_tiles(time, q_block)) > 0
             and all(d > 0 and d % 64 == 0 for d in qk_widths)
             and v_width > 0 and v_width % 128 == 0
-            and 2 * 4 * time * lanes <= _ATTN_VMEM // 2)
+            and 2 * 4 * time * lanes <= _ATTN_VMEM // 2
+            and nkvhead > 0 and nhead % nkvhead == 0 and window >= 0)
 
 
-def causal_attention(qs, ks, v, scale: float, q_block: int = 0):
+def causal_attention(qs, ks, v, scale: float, q_block: int = 0,
+                     window: int = 0):
     """Causal ``softmax(sum_n qs[n] ks[n]^T * scale) v`` as one fused
     kernel a direction: no score tile leaves VMEM. ``qs[n]`` ``(batch,
-    heads, time, d_n)``; ``ks[n]`` the same, or with one head where the
-    heads share that part of the key (MLA's ``k_rope``); ``v`` ``(batch,
-    heads, time, dv)``. Products take the operands' dtype with float32
-    accumulation, the softmax is float32. Differentiable in ``qs``,
-    ``ks`` and ``v``; the backward pass recomputes the probabilities
-    from ``q``, ``k`` and the saved row log-sum-exp."""
-    b, h, t, dv = v.shape
+    heads, time, d_n)``; ``ks[n]`` and ``v`` ``(batch, kv heads, time,
+    d)`` with the query heads a multiple of the kv heads: key/value head
+    ``g`` serves the query heads ``g * heads / kv heads`` on (grouped
+    queries; one kv head is a part all heads share, MLA's ``k_rope``),
+    and its gradient is the sum over them. ``window`` > 0: query ``i``
+    sees key ``j`` iff ``0 <= i - j < window``, and the key tiles wholly
+    outside that band are neither fetched nor computed; 0: every earlier
+    key. Products take the operands' dtype with float32 accumulation,
+    the softmax is float32. Differentiable in ``qs``, ``ks`` and ``v``;
+    the backward pass recomputes the probabilities from ``q``, ``k`` and
+    the saved row log-sum-exp."""
+    b, h, t = qs[0].shape[:3]
     bq, bk = _attn_tiles(t, q_block)
     flat = lambda a: a.reshape((-1,) + a.shape[2:])
     o = _attention(tuple(map(flat, qs)), tuple(map(flat, ks)), flat(v),
-                   scale, bq, bk)
-    return o.reshape(b, h, t, dv)
+                   scale, bq, bk, 0 if window >= t else window)
+    return o.reshape(b, h, t, v.shape[3])
 
 
 # ------------------------------------------- grouped expert products

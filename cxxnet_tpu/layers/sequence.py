@@ -1,11 +1,14 @@
 """Layers over the sequence node ``(batch, time, features)``.
 
 ``embed`` turns a matrix of integer ids into a sequence node; ``rmsnorm``,
-``add``, ``swiglu``, ``mla_attention`` and ``moe`` read and write one
-(``fullc`` and ``softmax`` take one too, see common.py and loss.py).
-Together they are the decoder block of DeepSeek-V3's family: pre-norm
-residual, multi-head latent attention, and a sigmoid-routed expert layer
-with shared experts. ``doc/sequence.md`` lists the config keys.
+``add``, ``swiglu``, ``mla_attention``, ``gqa_attention`` and ``moe`` read
+and write one (``fullc`` and ``softmax`` take one too, see common.py and
+loss.py). Together they are the decoder blocks of two families:
+DeepSeek-V3's (pre-norm residual, multi-head latent attention, a
+sigmoid-routed expert layer with shared experts) and Arcee's ``afmoe``
+(norms before and after each half, grouped-query attention with QK norm,
+an output gate and a window on some layers, the same expert layer).
+``doc/sequence.md`` lists the config keys.
 
 Mixed precision follows the rest of the zoo: ``dtype = bfloat16`` casts
 matmul operands to bf16 (float32 accumulation on the MXU), masters stay
@@ -79,16 +82,20 @@ class _SeqLayer(Layer):
 class EmbedLayer(_SeqLayer):
     """Integer ids ``(batch, time)`` -> rows of the held vocabulary
     slice ``(batch, time, nhidden)``. ``nvocab`` is the rows held here;
-    ids are in ``[0, nvocab)``."""
+    ids are in ``[0, nvocab)``. ``scale`` multiplies the rows (afmoe's
+    ``sqrt(hidden)`` under ``mup_enabled``); 1 leaves them as they are."""
 
     def __init__(self, cfg=()):
         self.nvocab = 0
+        self.scale = 1.0
         super().__init__(cfg)
 
     def set_param(self, name, val):
         super().set_param(name, val)
         if name == "nvocab":
             self.nvocab = int(val)
+        if name == "scale":
+            self.scale = float(val)
 
     def infer_shape(self, in_shapes: List[Shape3]) -> List[Shape3]:
         s = self._expect_one(in_shapes)
@@ -111,6 +118,8 @@ class EmbedLayer(_SeqLayer):
         if not jnp.issubdtype(ids.dtype, jnp.integer):
             ids = ids.astype(jnp.int32)     # a float batch of whole numbers
         rows = jnp.take(params["wmat"].astype(self.cd), ids, axis=0)
+        if self.scale != 1.0:
+            rows = (rows.astype(_F32) * self.scale).astype(rows.dtype)
         return [rows], state
 
 
@@ -201,31 +210,43 @@ def rope_tables(time: int, dim: int, theta: float):
     return jnp.cos(ang), jnp.sin(ang)
 
 
-def apply_rope(x, cos, sin):
-    """Rotate the interleaved pairs ``(x[2i], x[2i+1])`` of the last axis
-    (DeepSeek's layout) by the position's angle. ``x`` is ``(batch, time,
-    ..., dim)``; the result holds the rotated even members in its first
-    half and the odd ones in its second (queries and keys alike, so their
-    products are those of the interleaved form). Float32 inside."""
+def apply_rope(x, cos, sin, halves: bool = False):
+    """Rotate pairs of the last axis by the position's angle. ``x`` is
+    ``(batch, time, ..., dim)``. By default the interleaved pairs
+    ``(x[2i], x[2i+1])`` (DeepSeek's layout): the result holds the rotated
+    even members in its first half and the odd ones in its second (queries
+    and keys alike, so their products are those of the interleaved form).
+    ``halves``: feature ``i`` pairs with ``i + dim/2`` (``x cos +
+    rotate_half(x) sin``, the form ``transformers`` gives afmoe) and every
+    member stays where it was. Float32 inside."""
     x32 = x.astype(_F32)
-    even, odd = x32[..., 0::2], x32[..., 1::2]
+    if halves:
+        half = x.shape[-1] // 2
+        even, odd = x32[..., :half], x32[..., half:]
+    else:
+        even, odd = x32[..., 0::2], x32[..., 1::2]
     shape = (1, cos.shape[0]) + (1,) * (x.ndim - 3) + (cos.shape[1],)
     c, s = cos.reshape(shape), sin.reshape(shape)
     return jnp.concatenate([even * c - odd * s, odd * c + even * s],
                            axis=-1).astype(x.dtype)
 
 
-def _attend(q, k, v, q0: int, scale: float):
+def _attend(q, k, v, q0: int, scale: float, k0: int = 0, window: int = 0):
     """One block of queries, starting at position ``q0``, over the keys
-    up to its end: causal ``softmax(q k^T * scale) v`` with float32
-    scores. All ``(batch, heads, time, dim)``: heads beside the batch, so
-    that every product is a plain batched matrix product with ``dim`` or
-    ``time`` on the lanes (with heads minor the MXU's output used 16 of
-    its 128 lanes and the step took 3.8 s; my chip run, PR 28)."""
+    from position ``k0`` up to its end: causal ``softmax(q k^T * scale)
+    v`` with float32 scores; with a ``window``, query ``i`` sees key ``j``
+    iff ``0 <= i - j < window``. All ``(batch, heads, time, dim)``: heads
+    beside the batch, so that every product is a plain batched matrix
+    product with ``dim`` or ``time`` on the lanes (with heads minor the
+    MXU's output used 16 of its 128 lanes and the step took 3.8 s; my chip
+    run, PR 28)."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=_F32)
     qi = q0 + jnp.arange(q.shape[2])[:, None]
-    ki = jnp.arange(k.shape[2])[None, :]
-    s = jnp.where(ki <= qi, s * scale, -1e30)
+    ki = jnp.arange(k0, k0 + k.shape[2])[None, :]
+    seen = ki <= qi
+    if window:
+        seen = seen & (qi - ki < window)
+    s = jnp.where(seen, s * scale, -1e30)
     m = jax.lax.optimization_barrier(
         jax.lax.stop_gradient(jnp.max(s, axis=-1, keepdims=True)))
     p = jnp.exp(s - m)
@@ -233,22 +254,24 @@ def _attend(q, k, v, q0: int, scale: float):
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
 
 
-def causal_attention(q, k, v, scale: float, q_block: int):
+def causal_attention(q, k, v, scale: float, q_block: int, window: int = 0):
     """Causal attention over ``(batch, heads, time, dim)`` in blocks of
     ``q_block`` queries, each against the keys up to its own end only (so
-    about half the square is computed), each recomputed in the backward
-    pass: the largest score tensor alive is ``(batch, heads, q_block,
-    time)``."""
+    about half the square is computed; with a ``window``, from the first
+    key its first query sees), each recomputed in the backward pass: the
+    largest score tensor alive is ``(batch, heads, q_block, time)``."""
     t = q.shape[2]
     bq = q_block if 0 < q_block < t else t
     if t % bq:
-        raise ValueError("mla_attention: q_block %d does not divide the "
+        raise ValueError("attention: q_block %d does not divide the "
                          "sequence length %d" % (bq, t))
     outs = []
     for i in range(t // bq):
         lo, hi = i * bq, (i + 1) * bq
-        block = jax.checkpoint(functools.partial(_attend, q0=lo, scale=scale))
-        outs.append(block(q[:, :, lo:hi], k[:, :, :hi], v[:, :, :hi]))
+        k0 = max(lo - window + 1, 0) if window else 0
+        block = jax.checkpoint(functools.partial(
+            _attend, q0=lo, scale=scale, k0=k0, window=window))
+        outs.append(block(q[:, :, lo:hi], k[:, :, k0:hi], v[:, :, k0:hi]))
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=2)
 
 
@@ -365,6 +388,122 @@ class MLAAttentionLayer(_SeqLayer):
         core = 2.0 * self.nhead * (self.d_nope + self.d_rope + self.d_v) \
             * (t + 1) / 2.0
         return t * (proj + core)
+
+
+# -- grouped-query attention ---------------------------------------------------
+
+
+class GQAAttentionLayer(_SeqLayer):
+    """Grouped-query attention as afmoe (Arcee's Trinity) has it, causal,
+    no biases:
+
+        q = x Wq -> nhead heads;  k = x Wk, v = x Wv -> nkvhead heads;
+        g = x Wg -> nhead heads
+        q, k <- RMSNorm over a head's features, one learned scale each
+        RoPE on all of a head's features of q and k     (rope = 1)
+        o = softmax(q k^T / sqrt(head_dim)) v, query head h against
+            key/value head h // (nhead / nkvhead); query i sees key j
+            iff 0 <= i - j < window (window = 0: every earlier key)
+        y = (o * sigmoid(g)) Wo
+
+    A model's layers differ in ``rope`` and ``window`` alone (afmoe's
+    sliding layers have both, its full layers neither)."""
+
+    sub_scopes = ("core",)
+
+    def __init__(self, cfg=()):
+        self.nhead = 0
+        self.nkvhead = 0
+        self.head_dim = 0
+        self.window = 0
+        self.rope = 1
+        self.rope_theta = 10000.0
+        self.eps = 1e-6
+        self.q_block = 0
+        self.fused_core = False
+        super().__init__(cfg)
+
+    def set_param(self, name, val):
+        super().set_param(name, val)
+        if name in ("nhead", "nkvhead", "head_dim", "window", "rope",
+                    "q_block"):
+            setattr(self, name, int(val))
+        if name in ("rope_theta", "eps"):
+            setattr(self, name, float(val))
+
+    def infer_shape(self, in_shapes: List[Shape3]) -> List[Shape3]:
+        s = _expect_seq("gqa_attention", self._expect_one(in_shapes))
+        if min(self.nhead, self.nkvhead, self.head_dim) <= 0 \
+                or self.nhead % self.nkvhead or self.head_dim % 2 \
+                or self.window < 0:
+            raise ValueError(
+                "gqa_attention: must set nhead, nkvhead (a divisor of "
+                "nhead), head_dim (even) and window >= 0")
+        # which core runs is what the shapes allow, not a key
+        self.fused_core = pallas_kernels.causal_attention_applicable(
+            s.y, self.q_block, (self.head_dim,), self.head_dim,
+            self.nhead, self.nkvhead, self.window)
+        self.in_shapes = [s]
+        self.out_shapes = [s]
+        return self.out_shapes
+
+    def _widths(self) -> Dict[str, Tuple[int, int]]:
+        d, hd = self.in_shapes[0].x, self.head_dim
+        return {"wq": (d, self.nhead * hd), "wk": (d, self.nkvhead * hd),
+                "wv": (d, self.nkvhead * hd), "wg": (d, self.nhead * hd),
+                "wo": (self.nhead * hd, d)}
+
+    def init_params(self, key):
+        p, widths = self.param, self._widths()
+        out = {tag: p.rand_init_weight(k, shape, *shape)
+               for (tag, shape), k in zip(
+                   widths.items(), jax.random.split(key, len(widths)))}
+        out["qnorm"] = jnp.ones((self.head_dim,), _F32)
+        out["knorm"] = jnp.ones((self.head_dim,), _F32)
+        return out
+
+    def forward(self, params, state, inputs, is_train, rng):
+        x, cd, h, g, hd = inputs[0], self.cd, self.nhead, self.nkvhead, \
+            self.head_dim
+        b, t, _ = x.shape
+        q = _dot(x, params["wq"], cd).reshape(b, t, h, hd)
+        k = _dot(x, params["wk"], cd).reshape(b, t, g, hd)
+        v = _dot(x, params["wv"], cd).reshape(b, t, g, hd)
+        q = rms_norm(q, params["qnorm"], self.eps)
+        k = rms_norm(k, params["knorm"], self.eps)
+        if self.rope:
+            cos, sin = rope_tables(t, hd, self.rope_theta)
+            q, k = apply_rope(q, cos, sin, True), apply_rope(k, cos, sin, True)
+        heads = lambda a: a.transpose(0, 2, 1, 3)   # heads beside the batch
+        scale = 1.0 / math.sqrt(hd)
+        with jax.named_scope("core"):
+            if self.fused_core:
+                o = pallas_kernels.causal_attention(
+                    (heads(q),), (heads(k),), heads(v), scale, self.q_block,
+                    self.window)
+            else:
+                # a key/value head beside each query head of its group
+                each = lambda a: jnp.repeat(heads(a), h // g, axis=1)
+                o = causal_attention(heads(q), each(k), each(v), scale,
+                                     self.q_block, self.window)
+        o = heads(o).reshape(b, t, h * hd)
+        gate = jax.nn.sigmoid(_dot(x, params["wg"], cd).astype(_F32))
+        o = (o.astype(_F32) * gate).astype(o.dtype)
+        return [_dot(o, params["wo"], cd)], state
+
+    def pairs_per_sequence(self) -> float:
+        """Query-key pairs a head computes: ``sum_i min(i + 1, window)``,
+        the causal triangle where no window cuts it."""
+        t = self.in_shapes[0].y
+        w = min(self.window, t) if self.window else t
+        return w * (w + 1) / 2.0 + (t - w) * w
+
+    def flops_per_example(self) -> float:
+        """Projections, and the core at the pairs inside its band."""
+        t = self.in_shapes[0].y
+        proj = sum(2.0 * a * b for a, b in self._widths().values())
+        return t * proj + 4.0 * self.nhead * self.head_dim \
+            * self.pairs_per_sequence()
 
 
 # -- the expert layer ---------------------------------------------------------

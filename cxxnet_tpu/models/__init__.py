@@ -14,7 +14,9 @@ from .inception import inception_bn, inception_bn_tiny
 from .bowl import kaggle_bowl
 from .kaiming import kaiming
 from .kimi_vl import decoder_lm, kimi_vl_a3b, kimi_vl_a3b_tiny
+from .trinity import afmoe_lm, trinity_mini, trinity_mini_tiny
 
 __all__ = ["mnist_mlp", "mnist_conv", "alexnet", "inception_bn",
            "inception_bn_tiny", "kaggle_bowl", "kaiming", "decoder_lm",
-           "kimi_vl_a3b", "kimi_vl_a3b_tiny"]
+           "kimi_vl_a3b", "kimi_vl_a3b_tiny", "afmoe_lm", "trinity_mini",
+           "trinity_mini_tiny"]

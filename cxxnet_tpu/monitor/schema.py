@@ -220,12 +220,14 @@ REQUIRED: Dict[str, tuple] = {
 OPTIONAL: Dict[str, tuple] = {
     "step": ("tokens",),
     "model_info": ("tokens_per_example", "train_flops_per_token"),
-    # mla_attention layers of the net, and how many of them run the
-    # fused causal-attention kernel (layers/pallas_kernels.py); moe
+    # attention layers of the net (mla_attention, gqa_attention), how
+    # many of them run the fused causal-attention kernel
+    # (layers/pallas_kernels.py) and how many see a window of keys; moe
     # layers, and how many of them run their experts as the grouped
     # kernels while a step's routing fits the kernels' row buffers
     "layout": ("attention_layers", "attention_fused_layers",
-               "moe_layers", "moe_grouped_layers"),
+               "attention_window_layers", "moe_layers",
+               "moe_grouped_layers"),
     # the share of the dispatch's passes through an expert layer that
     # did (forward; the other passes took the loop a block at a time)
     "moe": ("grouped_share",),

@@ -520,8 +520,8 @@ class FuncNet:
         (doc/perf_profile.md), so MFU telemetry uses this count. An
         example of a sequence net is one sequence: a ``fullc`` counts
         every position, and the sequence layers count themselves
-        (``flops_per_example``: causal attention at half the square,
-        routed experts at the picks that land on held experts)."""
+        (``flops_per_example``: causal attention at half the square or,
+        under a window, at its band; routed experts at the picks that land on held experts)."""
         g = self.graph
         total = 0
         for li in range(len(g.layers)):

@@ -1575,8 +1575,9 @@ class NetTrainer:
                        layers=len(net.graph.layers))
         # attention layers, and those whose causal core is the fused
         # kernel (layers/sequence.py: the shapes decide)
-        cores = [layer.fused_core for layer in net.layer_objs
-                 if hasattr(layer, "fused_core")]
+        attn = [layer for layer in net.layer_objs
+                if hasattr(layer, "fused_core")]
+        cores = [layer.fused_core for layer in attn]
         # expert layers, and those whose experts run as the grouped
         # kernels while the routing fits their buffers (the same)
         grouped = [layer.grouped for layer in net.layer_objs
@@ -1592,6 +1593,11 @@ class NetTrainer:
                        pallas_interpret=_pallas.interpret(),
                        attention_layers=len(cores),
                        attention_fused_layers=sum(cores),
+                       # those that see a window of keys, not every
+                       # earlier one (gqa_attention's window key)
+                       attention_window_layers=sum(
+                           1 for layer in attn
+                           if getattr(layer, "window", 0) > 0),
                        moe_layers=len(grouped),
                        moe_grouped_layers=sum(grouped),
                        **net.layout_summary)

@@ -42,7 +42,7 @@ def _fused(bq, bk):
     def f(qn, qr, kn, kr, v):
         flat = lambda a: a.reshape((-1,) + a.shape[2:])
         return pk._attention((flat(qn), flat(qr)), (flat(kn), flat(kr)),
-                             flat(v), SCALE, bq, bk).reshape(v.shape)
+                             flat(v), SCALE, bq, bk, 0).reshape(v.shape)
     return f
 
 
@@ -202,3 +202,140 @@ def test_the_layout_record_counts_the_fused_layers_and_a_step_agrees():
         losses.append(t.last_loss)
     assert np.isfinite(losses[0]) and abs(losses[0] - losses[1]) \
         <= 1e-4 * abs(losses[1])
+
+
+# -- grouped key/value heads and a window (PR 32) ------------------------------
+
+G_H, G_D = 4, 128
+
+
+def _grouped_operands(h_kv, dtype="float32"):
+    keys = jax.random.split(jax.random.PRNGKey(1), 4)
+    shapes = [(1, G_H, T, G_D), (1, h_kv, T, G_D), (1, h_kv, T, G_D),
+              (1, G_H, T, G_D)]
+    return [jax.random.normal(k, s, jnp.float32).astype(dtype)
+            for k, s in zip(keys, shapes)]
+
+
+def _grouped_pair(window, bq, bk):
+    """(kernel, XLA core) over (q, k, v) with ``k`` and ``v`` a head a
+    group; the XLA core sees each key/value head repeated beside the
+    query heads of its group, so its gradient sums over them."""
+    scale = G_D ** -0.5
+
+    def fused(q, k, v):
+        flat = lambda a: a.reshape((-1,) + a.shape[2:])
+        return pk._attention((flat(q),), (flat(k),), flat(v), scale, bq, bk,
+                             0 if window >= T else window).reshape(q.shape)
+
+    def xla(q, k, v):
+        each = lambda a: jnp.repeat(a, G_H // a.shape[1], axis=1)
+        return causal_attention(q, each(k), each(v), scale, 128, window)
+
+    return fused, xla
+
+
+# 256: the band's far edge lies on a tile's edge; 200: inside a tile (and
+# inside the query tile's own span at 256 x 128); 72: narrower than a
+# tile; 1000: wider than the sequence, which is no window. Unequal tiles
+# both ways, so that the grids' shortened inner axes, their clamped index
+# maps and the three kinds of tile (inside the band, crossed by an edge,
+# outside) all occur in both kernels.
+@pytest.mark.parametrize("h_kv", [1, 2, 4])
+@pytest.mark.parametrize("window,bq,bk", [
+    (256, 128, 128), (200, 256, 128), (200, 128, 256), (72, 128, 128),
+    (1000, 128, 256), (0, 256, 128)])
+def test_grouped_heads_and_window_match_the_xla_core(window, bq, bk, h_kv):
+    *args, w = _grouped_operands(h_kv)
+    fused, xla = _grouped_pair(window, bq, bk)
+    grads = lambda f: jax.grad(
+        lambda *a: jnp.sum(f(*a) * w), argnums=(0, 1, 2))(*args)
+    got, want = grads(fused), grads(xla)
+    for name, a, b in [("o", fused(*args), xla(*args))] + [
+            ("d" + n, g, r) for n, g, r in zip("qkv", got, want)]:
+        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        assert a.shape == b.shape, name
+        assert np.abs(a - b).max() <= 1e-5 * np.abs(b).max(), \
+            (name, np.abs(a - b).max(), np.abs(b).max())
+
+
+def test_the_window_is_the_inequality_and_shortens_the_grids():
+    """Against the mask written out over the whole square, and the
+    grids' inner axes as long as the band is wide: at the cell's shapes
+    3 of 8 key tiles a query tile, 3 of 8 query tiles a key tile."""
+    q, k, v, _ = _grouped_operands(2)
+    window = 200
+    got = pk.causal_attention((q,), (k,), v, G_D ** -0.5, 128, window)
+    each = lambda a: jnp.repeat(a, G_H // a.shape[1], axis=1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, each(k)) * G_D ** -0.5
+    i, j = jnp.arange(T)[:, None], jnp.arange(T)[None, :]
+    s = jnp.where((0 <= i - j) & (i - j < window), s, -jnp.inf)
+    want = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), each(v))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    assert pk._band_tiles(8192, 1024, 1024, 2048) == (3, 3)
+    assert pk._band_tiles(8192, 1024, 1024, 0) == (8, 8)
+    assert pk._band_tiles(8192, 512, 1024, 2048) == (3, 6)
+    assert pk._band_tiles(512, 128, 128, 256) == (3, 3)
+    assert pk._band_tiles(512, 128, 128, 257) == (3, 3)
+    assert pk._band_tiles(512, 128, 128, 258) == (4, 4)
+    assert pk._band_tiles(512, 256, 128, 72) == (3, 2)
+
+
+# sha256 over the float32 bytes of (o, dq_nope, dq_rope, dk_nope, dk_rope,
+# dv) of MLA's call at this file's operands, computed on the tree before
+# grouped heads and the window (e9a9fc5): the kernel's window = 0 path is
+# that tree's, to the bit.
+@pytest.mark.parametrize("dtype,digest", [
+    ("float32",
+     "25900c99e47ca48bb064e0f785b13b1aed0de0968f94999f9e0b5912f43db91e"),
+    ("bfloat16",
+     "72031130acf62a46575137f88feac96029b8022b78a61f1d27d0b17adff94494")])
+def test_mlas_call_is_unchanged_to_the_bit(dtype, digest):
+    import hashlib
+    *args, w = _operands(dtype)
+    f = lambda qn, qr, kn, kr, v: pk.causal_attention(
+        (qn, qr), (kn, kr), v, SCALE, 256)
+    _, grads = _value_and_grads(f, args, w)
+    h = hashlib.sha256()
+    for x in (f(*args),) + tuple(grads):
+        h.update(np.asarray(x.astype(jnp.float32)).tobytes())
+    assert h.hexdigest() == digest
+
+
+@pytest.mark.parametrize("nhead,nkvhead,window,fits", [
+    (32, 4, 2048, True),      # the cell's sliding layers
+    (32, 4, 0, True),         # its full layer
+    (32, 32, 2048, True),     # a key/value head a query head
+    (32, 1, 0, True),         # one for all
+    (32, 5, 0, False),        # groups of unequal size
+    (32, 4, -1, False),
+])
+def test_applicable_takes_head_groups_and_windows(nhead, nkvhead, window,
+                                                 fits):
+    assert pk.causal_attention_applicable(
+        8192, 1024, (128,), 128, nhead, nkvhead, window) is fits
+
+
+def test_gqa_layer_takes_the_kernel_where_its_shapes_tile():
+    """A ``gqa_attention`` layer at widths that tile runs the kernel
+    under its ``core`` scope, window and groups included; held to the XLA
+    core it gives the same values and gradients."""
+    layer = create_layer("gqa_attention", [(k, str(v)) for k, v in dict(
+        nhead=4, nkvhead=2, head_dim=128, window=200, rope=1, eps=1e-5,
+        q_block=128, init_sigma=0.1).items()])
+    layer.infer_shape([seq_shape(256, 64)])
+    assert layer.fused_core and layer.sub_scopes == ("core",)
+    params = layer.init_params(jax.random.PRNGKey(1))
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 256, 64))
+
+    def loss(p, x):
+        (y,), _ = layer.forward(p, {}, [x], True, None)
+        return jnp.sum(y * jnp.cos(jnp.arange(y.size).reshape(y.shape)))
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.value_and_grad(loss, argnums=(0, 1))(params, x)
+        layer.fused_core = False
+        want = jax.value_and_grad(loss, argnums=(0, 1))(params, x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        assert np.abs(a - b).max() <= 1e-4 * max(1.0, np.abs(b).max())

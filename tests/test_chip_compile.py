@@ -362,3 +362,36 @@ def test_causal_attention_compiles_for_one_v5e_at_the_cells_shapes(topo):
     assert compiled.as_text().count("tpu_custom_call") == 2
     grads = compiled.out_info
     assert [g.shape for g in grads] == [a.shape for a in args]
+
+
+@pytest.mark.parametrize("window", [2048, 0])
+def test_grouped_window_attention_compiles_at_trinitys_shapes(topo, window):
+    """The same kernel as ``gqa_attention`` calls it in
+    ``trinity_mini.train_tokens_8k``: 2 x 32 query heads on 4 key/value
+    heads x 8,192 positions of 128 features, q_block 1,024, bfloat16, a
+    sliding layer's window of 2,048 keys and a full layer's none. Mosaic
+    takes the index maps that start at the band's first tile and the
+    grids whose inner axes are 3 tiles long where the full layer's are 8."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from cxxnet_tpu.layers import pallas_kernels as pk
+    one = SingleDeviceSharding(topo.devices[0])
+    b, h, g, t, d = 2, 32, 4, 8192, 128
+    assert pk.causal_attention_applicable(t, 1024, (d,), d, h, g, window)
+    assert pk._band_tiles(t, 1024, 1024, window) == ((3, 3) if window
+                                                     else (8, 8))
+
+    def sds(heads):
+        return jax.ShapeDtypeStruct((b, heads, t, d), jnp.bfloat16,
+                                    sharding=one)
+
+    def loss(q, k, v):
+        o = pk.causal_attention((q,), (k,), v, d ** -0.5, 1024, window)
+        return jnp.sum(o.astype(jnp.float32))
+
+    args = (sds(h), sds(g), sds(g))
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))) \
+        .lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 2
+    assert [x.shape for x in compiled.out_info] == [a.shape for a in args]
