@@ -68,6 +68,17 @@ def seq_shape(time: int, features: int) -> SeqShape:
     return SeqShape(1, time, features)
 
 
+# What a ``remat = block`` segment keeps of its inside, besides what
+# later layers read (nnet/net.py:_run_segment): the values a layer has
+# named (``jax.ad_checkpoint.checkpoint_name``) as dear to make again
+# and cheap to hold. The fused attention core names its two outputs,
+# ``o`` and the row log-sum-exp (layers/pallas_kernels.py:_attention_fwd):
+# its backward kernel reads both, so a segment that kept neither would
+# run the forward kernel a second time for them. A name outside a
+# ``jax.checkpoint`` is the identity and lowers to nothing.
+BLOCK_REMAT_KEEPS = ("attention_o", "attention_lse")
+
+
 def array_shape(batch: int, s: Shape3) -> Tuple[int, ...]:
     """Concrete array shape for a logical node shape."""
     if s.is_mat:

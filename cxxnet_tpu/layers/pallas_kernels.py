@@ -23,8 +23,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
-from .base import Shape3
+from .base import BLOCK_REMAT_KEEPS, Shape3
 from .common import FullConnectLayer
 
 
@@ -892,7 +893,12 @@ def _attention(qs, ks, v, scale, bq, bk, window):
 
 
 def _attention_fwd(qs, ks, v, scale, bq, bk, window):
-    o, lse = _attn_fwd_call(qs, ks, v, scale, bq, bk, window)
+    # the kernel's two outputs are the residuals that are no inputs: a
+    # remat = block segment keeps them by these names (base.py), so its
+    # backward pass does not run this kernel again to have them
+    o, lse = map(checkpoint_name,
+                 _attn_fwd_call(qs, ks, v, scale, bq, bk, window),
+                 BLOCK_REMAT_KEEPS)
     return o, (qs, ks, v, o, lse)
 
 

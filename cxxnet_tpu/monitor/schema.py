@@ -222,12 +222,14 @@ OPTIONAL: Dict[str, tuple] = {
     "model_info": ("tokens_per_example", "train_flops_per_token"),
     # attention layers of the net (mla_attention, gqa_attention), how
     # many of them run the fused causal-attention kernel
-    # (layers/pallas_kernels.py) and how many see a window of keys; moe
-    # layers, and how many of them run their experts as the grouped
-    # kernels while a step's routing fits the kernels' row buffers
+    # (layers/pallas_kernels.py), of how many of those a remat = block
+    # segment keeps the core's outputs for the backward pass, and how
+    # many see a window of keys; moe layers, and how many of them run
+    # their experts as the grouped kernels while a step's routing fits
+    # the kernels' row buffers
     "layout": ("attention_layers", "attention_fused_layers",
-               "attention_window_layers", "moe_layers",
-               "moe_grouped_layers"),
+               "attention_saved_layers", "attention_window_layers",
+               "moe_layers", "moe_grouped_layers"),
     # the share of the dispatch's passes through an expert layer that
     # did (forward; the other passes took the loop a block at a time)
     "moe": ("grouped_share",),

@@ -23,6 +23,7 @@ import numpy as np
 
 from ..graph import NetGraph
 from ..layers import Layer, Shape3, create_layer
+from ..layers.base import BLOCK_REMAT_KEEPS
 
 Params = Dict[str, Dict[str, jnp.ndarray]]
 NetState = Dict[str, Dict[str, jnp.ndarray]]
@@ -365,10 +366,13 @@ class FuncNet:
     def _run_segment(self, lo: int, hi: int, keep, params, new_state, nodes,
                      loss_inputs, rng, collect_logits, mask) -> None:
         """One segment under ``jax.checkpoint``: only what later layers
-        (or the caller, ``keep``) read of it is stored; its inside is
-        recomputed when the backward pass reaches it, one segment at a
-        time (the barriers of ``prevent_cse`` tie each recomputation to
-        the cotangent that needs it)."""
+        (or the caller, ``keep``) read of it is stored, and what its
+        layers have named as dear to make again (``BLOCK_REMAT_KEEPS``:
+        the fused attention core's two outputs; a segment without that
+        kernel names nothing); the rest of its inside is recomputed
+        when the backward pass reaches it, one segment at a time (the
+        barriers of ``prevent_cse`` tie each recomputation to the
+        cotangent that needs it)."""
         g = self.graph
         made = {ni for li in range(lo, hi) for ni in g.layers[li].nindex_out}
         read_in = {ni for li in range(lo, hi) for ni in g.layers[li].nindex_in}
@@ -390,7 +394,9 @@ class FuncNet:
             return ({ni: seg_nodes[ni] for ni in live_out},
                     {k: st[k] for k in keys if k in st}, logits)
 
-        outs, st, logits = jax.checkpoint(run)(
+        outs, st, logits = jax.checkpoint(
+            run, policy=jax.checkpoint_policies.save_only_these_names(
+                *BLOCK_REMAT_KEEPS))(
             {k: params[k] for k in keys if k in params},
             {k: new_state[k] for k in keys if k in new_state}, live_in, rng)
         for ni, v in outs.items():

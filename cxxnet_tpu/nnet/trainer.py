@@ -1593,6 +1593,12 @@ class NetTrainer:
                        pallas_interpret=_pallas.interpret(),
                        attention_layers=len(cores),
                        attention_fused_layers=sum(cores),
+                       # those whose core's o and row log-sum-exp a
+                       # remat = block segment keeps, so that the
+                       # core's forward kernel runs once a step
+                       # (layers/base.py: BLOCK_REMAT_KEEPS)
+                       attention_saved_layers=(
+                           sum(cores) if self.remat == "block" else 0),
                        # those that see a window of keys, not every
                        # earlier one (gqa_attention's window key)
                        attention_window_layers=sum(
