@@ -28,8 +28,8 @@ from .conv import (BatchNormLayer, ConvolutionLayer, InsanityPoolingLayer,
 from .loss import LossLayer, LpLossLayer, MultiLogisticLayer, SoftmaxLayer
 from .pairtest import PairTestLayer
 from .pallas_kernels import PallasFullConnectLayer
-from .sequence import (AddLayer, EmbedLayer, GQAAttentionLayer,
-                       MLAAttentionLayer, MoELayer,
+from .sequence import (AddLayer, EmbedLayer, GatedDeltaLayer,
+                       GQAAttentionLayer, MLAAttentionLayer, MoELayer,
                        RMSNormLayer, SwiGLULayer)
 from .torch_adapter import TorchLayer
 
@@ -83,6 +83,7 @@ _FACTORY: Dict[str, Callable[..., Layer]] = {
     "swiglu": lambda cfg, **kw: SwiGLULayer(cfg),
     "mla_attention": lambda cfg, **kw: MLAAttentionLayer(cfg),
     "gqa_attention": lambda cfg, **kw: GQAAttentionLayer(cfg),
+    "gated_delta": lambda cfg, **kw: GatedDeltaLayer(cfg),
     "moe": lambda cfg, **kw: MoELayer(cfg),
 }
 

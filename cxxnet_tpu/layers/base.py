@@ -74,9 +74,15 @@ def seq_shape(time: int, features: int) -> SeqShape:
 # and cheap to hold. The fused attention core names its two outputs,
 # ``o`` and the row log-sum-exp (layers/pallas_kernels.py:_attention_fwd):
 # its backward kernel reads both, so a segment that kept neither would
-# run the forward kernel a second time for them. A name outside a
-# ``jax.checkpoint`` is the identity and lowers to nothing.
-BLOCK_REMAT_KEEPS = ("attention_o", "attention_lse")
+# run the forward kernel a second time for them. The delta rule names
+# the triangular inverse a chunk (layers/sequence.py:_solve_unit_lower):
+# sixty small float32 products a layer, more than half of its scan's
+# device time when a step made them three times (PERF.md, PR 34), and
+# 67 MB a layer to hold in bfloat16 at 2 x 8,192 positions. A name
+# outside a ``jax.checkpoint`` is the identity and lowers to nothing.
+ATTENTION_KEEPS = ("attention_o", "attention_lse")
+DELTA_KEEPS = ("delta_solve",)
+BLOCK_REMAT_KEEPS = ATTENTION_KEEPS + DELTA_KEEPS
 
 
 def array_shape(batch: int, s: Shape3) -> Tuple[int, ...]:

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
-from .base import BLOCK_REMAT_KEEPS, Shape3
+from .base import ATTENTION_KEEPS, Shape3
 from .common import FullConnectLayer
 
 
@@ -898,7 +898,7 @@ def _attention_fwd(qs, ks, v, scale, bq, bk, window):
     # backward pass does not run this kernel again to have them
     o, lse = map(checkpoint_name,
                  _attn_fwd_call(qs, ks, v, scale, bq, bk, window),
-                 BLOCK_REMAT_KEEPS)
+                 ATTENTION_KEEPS)
     return o, (qs, ks, v, o, lse)
 
 

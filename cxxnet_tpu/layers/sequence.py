@@ -1,19 +1,23 @@
 """Layers over the sequence node ``(batch, time, features)``.
 
 ``embed`` turns a matrix of integer ids into a sequence node; ``rmsnorm``,
-``add``, ``swiglu``, ``mla_attention``, ``gqa_attention`` and ``moe`` read
-and write one (``fullc`` and ``softmax`` take one too, see common.py and
-loss.py). Together they are the decoder blocks of two families:
-DeepSeek-V3's (pre-norm residual, multi-head latent attention, a
-sigmoid-routed expert layer with shared experts) and Arcee's ``afmoe``
+``add``, ``swiglu``, ``mla_attention``, ``gqa_attention``, ``gated_delta``
+and ``moe`` read and write one (``fullc`` and ``softmax`` take one too,
+see common.py and loss.py). Together they are the decoder blocks of three
+families: DeepSeek-V3's (pre-norm residual, multi-head latent attention,
+a sigmoid-routed expert layer with shared experts), Arcee's ``afmoe``
 (norms before and after each half, grouped-query attention with QK norm,
-an output gate and a window on some layers, the same expert layer).
+an output gate and a window on some layers, the same expert layer) and
+Qwen3-Next's (a gated delta-rule linear-attention layer on three layers
+of four, gated attention with RoPE on part of a head on the fourth, the
+expert layer routed by a softmax with a gate on its shared expert).
 ``doc/sequence.md`` lists the config keys.
 
 Mixed precision follows the rest of the zoo: ``dtype = bfloat16`` casts
 matmul operands to bf16 (float32 accumulation on the MXU), masters stay
-float32. Normalisations, the rotary embedding, attention's softmax and
-the router's scores are computed in float32 whatever the dtype.
+float32. Normalisations, the rotary embedding, attention's softmax, the
+router's scores and the delta rule's decays, triangular solve and state
+are computed in float32 whatever the dtype.
 
 An expert layer is told which experts it holds (``expert_first``,
 ``expert_count``): it routes over all ``nexpert``, computes the part of
@@ -29,9 +33,10 @@ from typing import Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from . import pallas_kernels
-from .base import Layer, Shape3, seq_shape
+from .base import DELTA_KEEPS, Layer, Shape3, seq_shape
 
 _F32 = jnp.float32
 
@@ -394,20 +399,23 @@ class MLAAttentionLayer(_SeqLayer):
 
 
 class GQAAttentionLayer(_SeqLayer):
-    """Grouped-query attention as afmoe (Arcee's Trinity) has it, causal,
-    no biases:
+    """Grouped-query attention with QK norm and an output gate, as afmoe
+    (Arcee's Trinity) and Qwen3-Next's full-attention layers have it,
+    causal, no biases:
 
         q = x Wq -> nhead heads;  k = x Wk, v = x Wv -> nkvhead heads;
         g = x Wg -> nhead heads
         q, k <- RMSNorm over a head's features, one learned scale each
-        RoPE on all of a head's features of q and k     (rope = 1)
+        RoPE on the first rope_dim features of a head of q and k, the
+            others passed through (rope = 1; rope_dim = 0: all of them)
         o = softmax(q k^T / sqrt(head_dim)) v, query head h against
             key/value head h // (nhead / nkvhead); query i sees key j
             iff 0 <= i - j < window (window = 0: every earlier key)
         y = (o * sigmoid(g)) Wo
 
-    A model's layers differ in ``rope`` and ``window`` alone (afmoe's
-    sliding layers have both, its full layers neither)."""
+    afmoe's layers differ in ``rope`` and ``window`` alone (its sliding
+    layers have both, its full layers neither); Qwen3-Next's rotate 64
+    of a head's 256 features and have no window."""
 
     sub_scopes = ("core",)
 
@@ -417,6 +425,7 @@ class GQAAttentionLayer(_SeqLayer):
         self.head_dim = 0
         self.window = 0
         self.rope = 1
+        self.rope_dim = 0
         self.rope_theta = 10000.0
         self.eps = 1e-6
         self.q_block = 0
@@ -426,7 +435,7 @@ class GQAAttentionLayer(_SeqLayer):
     def set_param(self, name, val):
         super().set_param(name, val)
         if name in ("nhead", "nkvhead", "head_dim", "window", "rope",
-                    "q_block"):
+                    "rope_dim", "q_block"):
             setattr(self, name, int(val))
         if name in ("rope_theta", "eps"):
             setattr(self, name, float(val))
@@ -435,10 +444,12 @@ class GQAAttentionLayer(_SeqLayer):
         s = _expect_seq("gqa_attention", self._expect_one(in_shapes))
         if min(self.nhead, self.nkvhead, self.head_dim) <= 0 \
                 or self.nhead % self.nkvhead or self.head_dim % 2 \
-                or self.window < 0:
+                or self.window < 0 or self.rope_dim % 2 \
+                or not 0 <= self.rope_dim <= self.head_dim:
             raise ValueError(
                 "gqa_attention: must set nhead, nkvhead (a divisor of "
-                "nhead), head_dim (even) and window >= 0")
+                "nhead), head_dim (even), window >= 0 and an even "
+                "rope_dim within head_dim")
         # which core runs is what the shapes allow, not a key
         self.fused_core = pallas_kernels.causal_attention_applicable(
             s.y, self.q_block, (self.head_dim,), self.head_dim,
@@ -472,8 +483,15 @@ class GQAAttentionLayer(_SeqLayer):
         q = rms_norm(q, params["qnorm"], self.eps)
         k = rms_norm(k, params["knorm"], self.eps)
         if self.rope:
-            cos, sin = rope_tables(t, hd, self.rope_theta)
-            q, k = apply_rope(q, cos, sin, True), apply_rope(k, cos, sin, True)
+            rd = self.rope_dim or hd
+            cos, sin = rope_tables(t, rd, self.rope_theta)
+            if rd == hd:
+                turn = lambda a: apply_rope(a, cos, sin, True)
+            else:       # the features past rope_dim carry no position
+                turn = lambda a: jnp.concatenate(
+                    [apply_rope(a[..., :rd], cos, sin, True), a[..., rd:]],
+                    axis=-1)
+            q, k = turn(q), turn(k)
         heads = lambda a: a.transpose(0, 2, 1, 3)   # heads beside the batch
         scale = 1.0 / math.sqrt(hd)
         with jax.named_scope("core"):
@@ -504,6 +522,324 @@ class GQAAttentionLayer(_SeqLayer):
         proj = sum(2.0 * a * b for a, b in self._widths().values())
         return t * proj + 4.0 * self.nhead * self.head_dim \
             * self.pairs_per_sequence()
+
+
+# -- gated delta-rule linear attention ------------------------------------------
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _inv_unit_lower_impl(a):
+    c = a.shape[-1]
+    mm = functools.partial(jnp.matmul, precision=_HIGHEST)
+    m = c
+    while m > 16 and m % 2 == 0:
+        m //= 2
+    row, col = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
+    # every block stays where it lies in the whole matrix (products of
+    # block-diagonal matrices): a block of 16 columns alone would fill an
+    # eighth of a tile's lanes
+    n = jnp.where(row // m == col // m, -a, 0.0)
+    out, power, terms = jnp.eye(c, dtype=a.dtype) + n, n, 2
+    while terms < m:
+        power = mm(power, power)
+        out, terms = out + mm(out, power), 2 * terms
+    while m < c:
+        below = jnp.where((row // (2 * m) == col // (2 * m))
+                          & (row // m != col // m), a, 0.0)
+        out, m = out - mm(mm(out, below), out), 2 * m
+    return out
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _solve_unit_lower(a, cd):
+    """``(I + a)^-1`` in ``cd`` for ``a`` strictly lower triangular over
+    its last two axes, made in float32 by matrix products alone: the
+    diagonal blocks of at most 16 rows as the finite series ``(I - a)(I +
+    a^2)(I + a^4)...`` (``a`` is nilpotent, so it ends; its terms stay
+    small over so few rows), then blocks of twice the rows from pairs of
+    them, ``[[P, 0], [-Q a21 P, Q]] = D - D a21 D`` with ``D = diag(P,
+    Q)``: the forward substitution of the triangular solve a block at a
+    time. Its gradient is the inverse's own, ``-T^T dT T^T``, from the
+    ``T`` it handed on, so the backward pass keeps that and none of the
+    steps that made it; the forward rule names it (``DELTA_KEEPS``), so
+    that a ``jax.checkpoint`` that keeps the name does not make it
+    again."""
+    return _inv_unit_lower_impl(a).astype(cd)
+
+
+def _solve_unit_lower_fwd(a, cd):
+    t = checkpoint_name(_inv_unit_lower_impl(a).astype(cd), *DELTA_KEEPS)
+    return t, t
+
+
+def _solve_unit_lower_bwd(cd, t, g):
+    tt = t.astype(_F32).swapaxes(-1, -2)
+    mm = functools.partial(jnp.matmul, precision=_HIGHEST)
+    c = t.shape[-1]
+    # a's upper half is no argument: only the strictly lower part moves
+    return (jnp.where(jnp.arange(c)[:, None] > jnp.arange(c)[None, :],
+                      -mm(mm(tt, g.astype(_F32)), tt), 0.0),)
+
+
+_solve_unit_lower.defvjp(_solve_unit_lower_fwd, _solve_unit_lower_bwd)
+
+
+@jax.custom_vjp
+def _decay_state(keep, s, s_cd):
+    """``keep * s``: the state a chunk's decay leaves. The gradient in
+    ``keep`` reads the chunk's copy of the state in the compute dtype,
+    which the products keep anyway, so that the scan's backward pass
+    holds no float32 state a chunk."""
+    return keep[..., None, None] * s
+
+
+def _decay_state_fwd(keep, s, s_cd):
+    return keep[..., None, None] * s, (keep, s_cd)
+
+
+def _decay_state_bwd(res, g):
+    keep, s_cd = res
+    return (jnp.sum(g * s_cd.astype(_F32), axis=(-1, -2)),
+            keep[..., None, None] * g, jnp.zeros_like(s_cd))
+
+
+_decay_state.defvjp(_decay_state_fwd, _decay_state_bwd)
+
+
+def gated_delta_rule(q, k, v, g, beta, chunk: int, cd):
+    """The gated delta rule over a sequence, in chunks of ``chunk``
+    positions. A value head carries a state ``S`` (key width x value
+    width, zeros at the start) along time:
+
+        S <- exp(g_t) S;  u_t = beta_t (v_t - S^T k_t)
+        S <- S + k_t u_t^T;  o_t = S^T q_t
+
+    ``q``, ``k`` ``(batch, time, key heads, dk)`` (normalised and scaled
+    by the caller), ``v`` ``(batch, time, value heads, dv)``, ``g <= 0``
+    and ``beta`` ``(batch, time, value heads)`` float32; key head ``h``
+    serves the value heads ``h * r .. (h + 1) * r``. Returns ``o``
+    ``(batch, time, value heads, dv)`` in ``cd``.
+
+    Inside a chunk, with ``G`` the running sum of ``g`` from its start
+    and ``S0`` the state there: ``(I + A) u = beta (v - exp(G) k S0)``
+    with ``A_ij = beta_i exp(G_i - G_j) k_i.k_j`` for ``j < i``, so
+    ``u = T (beta v) - T (beta exp(G) k) S0`` with ``T = (I + A)^-1``
+    made once a chunk whatever the state; then ``o = exp(G) q S0 + ((q
+    k^T) * exp(G_i - G_j), j <= i) u`` and the next chunk starts from
+    ``exp(G_end) S0 + (exp(G_end - G) k)^T u``. Only that last line and
+    ``u`` run one chunk after the other (a ``lax.scan`` whose step is two
+    products a head); what comes before (``before``) and after
+    (``after``) is products over all chunks at once. Every decay is a
+    difference of running sums, at most 1. Products take operands in
+    ``cd`` with float32 results, and what one product hands the next
+    (``T``, ``u``, a chunk's copy of the state) is held in ``cd``; the
+    decays, the solve and the state from chunk to chunk are float32.
+    Differentiable by ``jax``'s rules; ``before`` and ``after`` are made
+    again in the backward pass (``jax.checkpoint``: small products) but
+    for ``T``, which is kept by its name, so what a layer holds for it is
+    q, k, v, ``T``, the scan's inputs and a state a chunk, all in
+    ``cd``."""
+    b, t, hk, dk = q.shape
+    hv, dv = v.shape[2], v.shape[3]
+    r, c = hv // hk, min(chunk, t)
+    pad = -t % c
+    if pad:     # positions that neither decay nor write: g 0, k 0, beta 0
+        q, k, v, g, beta = (jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (
+            a.ndim - 2)) for a in (q, k, v, g, beta))
+    n = (t + pad) // c
+    # chunks first (the scan's axis), heads beside the batch, a key
+    # head's value heads beside it
+    q, k = (a.reshape(b, n, c, hk, dk).transpose(1, 0, 3, 2, 4).astype(cd)
+            for a in (q, k))                                 # n b h c k
+    v = v.reshape(b, n, c, hk, r, dv).transpose(1, 0, 3, 4, 2, 5).astype(cd)
+    g, beta = (a.astype(_F32).reshape(b, n, c, hk, r).transpose(
+        1, 0, 3, 4, 2) for a in (g, beta))                   # n b h r c
+    i, j = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
+
+    def decays(run):                                          # n b h r c c
+        return jnp.exp(jnp.where(
+            i >= j, run[..., :, None] - run[..., None, :], -jnp.inf))
+
+    def before(k, v, g, beta):
+        run = jnp.cumsum(g, axis=-1)
+        kk = jnp.einsum("nbhik,nbhjk->nbhij", k, k,
+                        preferred_element_type=_F32)
+        solve = _solve_unit_lower(jnp.where(
+            i > j, beta[..., :, None] * decays(run) * kk[:, :, :, None],
+            0.0), cd)
+        u0 = jnp.einsum("nbhrij,nbhrjv->nbhriv", solve,
+                        (beta[..., None] * v.astype(_F32)).astype(cd),
+                        preferred_element_type=_F32).astype(cd)
+        kf = k.astype(_F32)[:, :, :, None]                   # n b h 1 c k
+        w = jnp.einsum("nbhrij,nbhrjk->nbhrik", solve,
+                       ((beta * jnp.exp(run))[..., None] * kf).astype(cd),
+                       preferred_element_type=_F32).astype(cd)
+        end = run[..., -1:]
+        return w, u0, (jnp.exp(end - run)[..., None] * kf).astype(cd), \
+            jnp.exp(end[..., 0])
+
+    def step(s, xs):
+        # the state is float32 from chunk to chunk; the products read it,
+        # and u, in cd, and so does everything after the scan
+        w_n, u0_n, k_n, keep = xs
+        s_cd = s.astype(cd)
+        u = (u0_n.astype(_F32) - jnp.einsum(
+            "bhrik,bhrkv->bhriv", w_n, s_cd,
+            preferred_element_type=_F32)).astype(cd)
+        nxt = _decay_state(keep, s, s_cd) + jnp.einsum(
+            "bhrik,bhriv->bhrkv", k_n, u, preferred_element_type=_F32)
+        return nxt, (s_cd, u)
+
+    def after(q, k, g, s0, u):
+        run = jnp.cumsum(g, axis=-1)
+        qk = jnp.einsum("nbhik,nbhjk->nbhij", q, k,
+                        preferred_element_type=_F32)
+        return (jnp.exp(run)[..., None] * jnp.einsum(
+            "nbhik,nbhrkv->nbhriv", q, s0, preferred_element_type=_F32)
+            + jnp.einsum("nbhrij,nbhrjv->nbhriv",
+                         (qk[:, :, :, None] * decays(run)).astype(cd), u,
+                         preferred_element_type=_F32)).astype(cd)
+
+    _, (s0, u) = jax.lax.scan(
+        step, jnp.zeros((b, hk, r, dk, dv), _F32),
+        jax.checkpoint(before, policy=jax.checkpoint_policies
+                       .save_only_these_names(*DELTA_KEEPS))(k, v, g, beta))
+    o = jax.checkpoint(after)(q, k, g, s0, u)
+    return o.transpose(1, 0, 4, 2, 3, 5).reshape(b, n * c, hv, dv)[:, :t]
+
+
+class GatedDeltaLayer(_SeqLayer):
+    """Gated DeltaNet's mixer as Qwen3-Next has it, causal, no biases:
+
+        [q | k | v] = x Wqkv -> nkhead, nkhead heads of key_dim and
+            nvhead heads of value_dim, side by side; z = x Wz -> nvhead
+            heads of value_dim; b = x Wb, a = x Wa -> one a value head
+        [q | k | v] <- silu(causal depthwise convolution of conv_kernel
+            taps along time, no bias)
+        beta = sigmoid(b);  g = -exp(A_log) softplus(a + dt_bias)
+        q, k <- x / sqrt(sum x^2 + 1e-6) over a head;  q <- q / sqrt(key_dim)
+        o = the gated delta rule (``gated_delta_rule``), key head h
+            serving the value heads h r .. (h + 1) r, in chunks of
+            ``chunk`` positions
+        y = ((RMSNorm over a head's value_dim of o) * silu(z)) Wo
+
+    ``A_log`` starts as ``log(U(0, 16))``, ``dt_bias`` and the norm's
+    scale at 1, the convolution's taps as ``U(-1/2, 1/2)`` (torch's
+    default for a depthwise kernel of four)."""
+
+    # "short_conv", not "conv": a reduction that reads an op's innermost
+    # scope as a layer type would count it as a convolution layer
+    sub_scopes = ("proj", "short_conv", "scan", "gate_norm", "out")
+
+    def __init__(self, cfg=()):
+        self.nkhead = 0
+        self.nvhead = 0
+        self.key_dim = 0
+        self.value_dim = 0
+        self.conv_kernel = 4
+        self.chunk = 64
+        self.eps = 1e-6
+        super().__init__(cfg)
+
+    def set_param(self, name, val):
+        super().set_param(name, val)
+        if name in ("nkhead", "nvhead", "key_dim", "value_dim",
+                    "conv_kernel", "chunk"):
+            setattr(self, name, int(val))
+        if name == "eps":
+            self.eps = float(val)
+
+    def infer_shape(self, in_shapes: List[Shape3]) -> List[Shape3]:
+        s = _expect_seq("gated_delta", self._expect_one(in_shapes))
+        if min(self.nkhead, self.nvhead, self.key_dim, self.value_dim,
+               self.conv_kernel, self.chunk) <= 0 \
+                or self.nvhead % self.nkhead:
+            raise ValueError(
+                "gated_delta: must set nkhead, nvhead (a multiple of "
+                "nkhead), key_dim, value_dim, and conv_kernel, chunk > 0")
+        self.in_shapes = [s]
+        self.out_shapes = [s]
+        return self.out_shapes
+
+    def _widths(self) -> Dict[str, Tuple[int, int]]:
+        d = self.in_shapes[0].x
+        kw, vw = self.nkhead * self.key_dim, self.nvhead * self.value_dim
+        return {"wqkv": (d, 2 * kw + vw), "wz": (d, vw),
+                "wb": (d, self.nvhead), "wa": (d, self.nvhead),
+                "wo": (vw, d)}
+
+    def init_params(self, key):
+        p, widths = self.param, self._widths()
+        ks = jax.random.split(key, len(widths) + 2)
+        out = {tag: p.rand_init_weight(k, shape, *shape)
+               for (tag, shape), k in zip(widths.items(), ks)}
+        out["conv"] = jax.random.uniform(
+            ks[-2], (self.conv_kernel, widths["wqkv"][1]), _F32, -0.5, 0.5)
+        out["alog"] = jnp.log(jax.random.uniform(
+            ks[-1], (self.nvhead,), _F32, jnp.finfo(_F32).tiny, 16.0))
+        out["dtbias"] = jnp.ones((self.nvhead,), _F32)
+        out["norm"] = jnp.ones((self.value_dim,), _F32)
+        return out
+
+    def forward(self, params, state, inputs, is_train, rng):
+        x, cd = inputs[0], self.cd
+        b, t, _ = x.shape
+        hk, hv, dk, dv = self.nkhead, self.nvhead, self.key_dim, \
+            self.value_dim
+        with jax.named_scope("proj"):
+            qkv = _dot(x, params["wqkv"], cd)
+            z = _dot(x, params["wz"], cd)
+            beta = jax.nn.sigmoid(_dot(x, params["wb"], cd).astype(_F32))
+            g = -jnp.exp(params["alog"]) * jax.nn.softplus(
+                _dot(x, params["wa"], cd).astype(_F32) + params["dtbias"])
+        kw = hk * dk
+
+        def conv(qkv, taps):
+            # a channel alone, the taps' last on the position itself and
+            # zeros before the sequence: conv_kernel shifted products,
+            # operands in cd, summed in float32 (as XLA's depthwise
+            # convolution it took 56 ms a layer a step on the chip, nine
+            # times the projections beside it; PERF.md, PR 34)
+            taps = taps.astype(cd).astype(_F32)
+            past = jnp.pad(qkv, [(0, 0), (self.conv_kernel - 1, 0), (0, 0)])
+            qkv = jax.nn.silu(sum(
+                taps[i] * past[:, i:i + t].astype(_F32)
+                for i in range(self.conv_kernel)))
+            unit = lambda a: a * jax.lax.rsqrt(
+                jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+            q = unit(qkv[..., :kw].reshape(b, t, hk, dk)) / math.sqrt(dk)
+            k = unit(qkv[..., kw:2 * kw].reshape(b, t, hk, dk))
+            return q.astype(cd), k.astype(cd), \
+                qkv[..., 2 * kw:].reshape(b, t, hv, dv).astype(cd)
+
+        def gate_norm(o, z, scale):
+            return (rms_norm(o.astype(_F32), scale, self.eps) * jax.nn.silu(
+                z.astype(_F32).reshape(b, t, hv, dv))).astype(cd)
+
+        # the passes between the products are made again in the backward
+        # pass from what goes into them, in cd (their float32 insides,
+        # held for it, were most of what a layer's backward pass held)
+        with jax.named_scope("short_conv"):
+            q, k, v = jax.checkpoint(conv)(qkv, params["conv"])
+        with jax.named_scope("scan"):
+            o = gated_delta_rule(q, k, v, g, beta, self.chunk, cd)
+        with jax.named_scope("gate_norm"):
+            y = jax.checkpoint(gate_norm)(o, z, params["norm"])
+        with jax.named_scope("out"):
+            return [_dot(y.reshape(b, t, hv * dv), params["wo"], cd)], state
+
+    def flops_per_example(self) -> float:
+        """Projections, the convolution, and the rule at the
+        recurrence's work: three products of key_dim x value_dim a value
+        head a position (the decayed state read by the key, the key's
+        write, the query's read), not what the chunked form adds."""
+        t = self.in_shapes[0].y
+        widths = self._widths()
+        proj = sum(2.0 * a * b for a, b in widths.values())
+        conv = 2.0 * self.conv_kernel * widths["wqkv"][1]
+        rule = 6.0 * self.nvhead * self.key_dim * self.value_dim
+        return t * (proj + conv + rule)
 
 
 # -- the expert layer ---------------------------------------------------------
@@ -715,22 +1051,27 @@ grouped_swiglu.defvjp(_grouped_fwd, _grouped_bwd)
 
 
 class MoELayer(_SeqLayer):
-    """Sigmoid-routed expert layer with shared experts (DeepSeek-V3's,
-    ``noaux_tc`` without group limits):
+    """Routed expert layer with shared experts, a chip's share of it:
+    DeepSeek-V3's (sigmoid scores, ``noaux_tc`` without group limits) and,
+    by two keys, Qwen3-Next's (softmax scores, a gate on the shared
+    expert):
 
         s = sigmoid(x Wr)                      all nexpert, float32
+            softmax(x Wr) over them            (score_func = softmax)
         picks = top-k of s + bias              bias is layer STATE
         w_i = scale * s_i / sum_picked s_j     from s, not s + bias
         y = sum_{held picks} w_i E_i(x) + S(x)
+            ... + sigmoid(x w_s) S(x)          (shared_gate = 1)
 
     ``E_i`` is a SwiGLU of width ``nhidden``, ``S`` one SwiGLU of width
     ``nshared * nhidden``. ``expert_first`` / ``expert_count`` say which
     experts live here (default: all). The bias is seeded from
-    ``bias_seed`` at ``bias_sigma`` and held fixed (its update rate is
-    not part of the published config). State also carries the last
-    forward's counters for the ``moe`` telemetry record: ``load`` (picks
-    each held expert got), ``picks_held``, ``dropped``; and ``grouped``,
-    the forward passes so far whose experts ran as the grouped kernels.
+    ``bias_seed`` at ``bias_sigma`` (0: no bias) and held fixed (its
+    update rate is not part of the published config). State also carries
+    the last forward's counters for the ``moe`` telemetry record:
+    ``load`` (picks each held expert got), ``picks_held``, ``dropped``;
+    and ``grouped``, the forward passes so far whose experts ran as the
+    grouped kernels.
 
     Which schedule the experts run (``grouped_swiglu``) is what the
     shapes allow, not a key: the grouped kernels where the widths tile
@@ -753,6 +1094,8 @@ class MoELayer(_SeqLayer):
         self.block = 512
         self.bias_seed = 0
         self.bias_sigma = 0.0
+        self.score_func = "sigmoid"
+        self.shared_gate = 0
         self.grouped = False
         super().__init__(cfg)
 
@@ -778,6 +1121,13 @@ class MoELayer(_SeqLayer):
             self.bias_seed = int(val)
         if name == "bias_sigma":
             self.bias_sigma = float(val)
+        if name == "score_func":
+            if val not in ("sigmoid", "softmax"):
+                raise ValueError("moe: score_func must be sigmoid or "
+                                 "softmax, not %r" % val)
+            self.score_func = val
+        if name == "shared_gate":
+            self.shared_gate = int(val)
 
     def infer_shape(self, in_shapes: List[Shape3]) -> List[Shape3]:
         s = _expect_seq("moe", self._expect_one(in_shapes))
@@ -785,10 +1135,12 @@ class MoELayer(_SeqLayer):
             self.count = self.nexpert - self.first
         if min(self.nexpert, self.topk, self.param.num_hidden) <= 0 \
                 or self.topk > self.nexpert or self.first < 0 \
-                or self.count <= 0 or self.first + self.count > self.nexpert:
+                or self.count <= 0 or self.first + self.count > self.nexpert \
+                or (self.shared_gate and not self.nshared):
             raise ValueError(
-                "moe: must set nexpert, topk <= nexpert, nhidden, and "
-                "expert_first / expert_count inside nexpert")
+                "moe: must set nexpert, topk <= nexpert, nhidden, "
+                "expert_first / expert_count inside nexpert, and nshared "
+                "where shared_gate is on")
         self.grouped = pallas_kernels.grouped_experts_applicable(
             s.x, self.param.num_hidden, self.block, self.cd)
         self.in_shapes = [s]
@@ -809,6 +1161,11 @@ class MoELayer(_SeqLayer):
             out.update(sgate=p.rand_init_weight(ks[4], (d, sw), d, sw),
                        sup=p.rand_init_weight(ks[5], (d, sw), d, sw),
                        sdown=p.rand_init_weight(ks[6], (sw, d), sw, d))
+        if self.shared_gate:
+            # its own key, so that the other tensors start where they
+            # do without the gate
+            out["sharedgate"] = p.rand_init_weight(
+                jax.random.fold_in(key, 7), (d, 1), d, 1)
         return out
 
     def init_state(self):
@@ -840,8 +1197,10 @@ class MoELayer(_SeqLayer):
         """(picks, weights) of each token: float32 throughout, the
         product at full precision (a bf16 pass would move near-tied
         picks)."""
-        s = jax.nn.sigmoid(jnp.dot(xt.astype(_F32), router.astype(_F32),
-                                   precision=jax.lax.Precision.HIGHEST))
+        s = jnp.dot(xt.astype(_F32), router.astype(_F32),
+                    precision=jax.lax.Precision.HIGHEST)
+        s = jax.nn.sigmoid(s) if self.score_func == "sigmoid" \
+            else jax.nn.softmax(s, axis=-1)
         _, picks = jax.lax.top_k(s + bias[None, :], self.topk)
         w = jnp.take_along_axis(s, picks, axis=1)
         if self.norm_topk:
@@ -867,6 +1226,10 @@ class MoELayer(_SeqLayer):
             with jax.named_scope("shared"):
                 shared = swiglu(xt, params["sgate"], params["sup"],
                                 params["sdown"], cd)
+                if self.shared_gate:
+                    shared = jax.nn.sigmoid(_dot(
+                        xt, params["sharedgate"], cd).astype(_F32)) \
+                        * shared.astype(_F32)
         with jax.named_scope("combine"):
             if self.nshared:
                 y = y + shared.astype(_F32)
@@ -879,11 +1242,11 @@ class MoELayer(_SeqLayer):
         return [out], new_state
 
     def flops_per_example(self) -> float:
-        """Router, shared experts, and the routed experts at the picks
-        that land on held experts in expectation: ``topk * count /
-        nexpert`` a token."""
+        """Router, shared experts (and their gate), and the routed
+        experts at the picks that land on held experts in expectation:
+        ``topk * count / nexpert`` a token."""
         s, w = self.in_shapes[0], self.param.num_hidden
         per_token = 2.0 * s.x * self.nexpert \
-            + 6.0 * s.x * w * self.nshared \
+            + 6.0 * s.x * w * self.nshared + 2.0 * s.x * self.shared_gate \
             + 6.0 * s.x * w * self.topk * self.count / self.nexpert
         return s.y * per_token

@@ -226,10 +226,12 @@ OPTIONAL: Dict[str, tuple] = {
     # segment keeps the core's outputs for the backward pass, and how
     # many see a window of keys; moe layers, and how many of them run
     # their experts as the grouped kernels while a step's routing fits
-    # the kernels' row buffers
+    # the kernels' row buffers; linear-attention layers (gated_delta),
+    # and the positions a chunk of their scan along time holds
     "layout": ("attention_layers", "attention_fused_layers",
                "attention_saved_layers", "attention_window_layers",
-               "moe_layers", "moe_grouped_layers"),
+               "moe_layers", "moe_grouped_layers",
+               "linear_attention_layers", "linear_attention_chunk"),
     # the share of the dispatch's passes through an expert layer that
     # did (forward; the other passes took the loop a block at a time)
     "moe": ("grouped_share",),

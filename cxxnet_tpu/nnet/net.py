@@ -368,8 +368,9 @@ class FuncNet:
         """One segment under ``jax.checkpoint``: only what later layers
         (or the caller, ``keep``) read of it is stored, and what its
         layers have named as dear to make again (``BLOCK_REMAT_KEEPS``:
-        the fused attention core's two outputs; a segment without that
-        kernel names nothing); the rest of its inside is recomputed
+        the fused attention core's two outputs, the delta rule's
+        triangular inverse; a segment with neither names nothing); the
+        rest of its inside is recomputed
         when the backward pass reaches it, one segment at a time (the
         barriers of ``prevent_cse`` tie each recomputation to the
         cotangent that needs it)."""

@@ -1582,6 +1582,10 @@ class NetTrainer:
         # kernels while the routing fits their buffers (the same)
         grouped = [layer.grouped for layer in net.layer_objs
                    if hasattr(layer, "grouped")]
+        # linear-attention layers (gated_delta: a state along time, in
+        # chunks) and the largest chunk among them
+        chunks = [layer.chunk for layer in net.layer_objs
+                  if hasattr(layer, "chunk")]
         self._mon.emit("layout",
                        # what took hold, not what was asked for
                        input_layout=self.input_layout_effective,
@@ -1606,6 +1610,8 @@ class NetTrainer:
                            if getattr(layer, "window", 0) > 0),
                        moe_layers=len(grouped),
                        moe_grouped_layers=sum(grouped),
+                       linear_attention_layers=len(chunks),
+                       linear_attention_chunk=max(chunks, default=0),
                        **net.layout_summary)
         if self.quant_report.get("active"):
             r = self.quant_report

@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 
 from cxxnet_tpu.layers import pallas_kernels as pk
-from cxxnet_tpu.layers.base import BLOCK_REMAT_KEEPS
+from cxxnet_tpu.layers.base import ATTENTION_KEEPS, BLOCK_REMAT_KEEPS
 from cxxnet_tpu.models.kimi_vl import decoder_lm
 from cxxnet_tpu.models.trinity import afmoe_lm
 from cxxnet_tpu.monitor import MemorySink, Monitor
@@ -206,7 +206,8 @@ def test_attention_outside_a_checkpoint_lowers_to_the_unnamed_text(
         lambda v: jnp.sum(pk._attention((qn, qr), (kn, kr), v, 0.07, 128,
                                         128, 0))))(v).jaxpr)
         if e.primitive.name == "name"]
-    assert sorted(names) == sorted(BLOCK_REMAT_KEEPS)
+    assert sorted(names) == sorted(ATTENTION_KEEPS)
+    assert set(ATTENTION_KEEPS) < set(BLOCK_REMAT_KEEPS)
     monkeypatch.setattr(pk, "checkpoint_name", lambda x, name: x)
     assert named == text()
     assert not any(n in named for n in BLOCK_REMAT_KEEPS)

@@ -261,6 +261,57 @@ def test_decoder_layer_compiles_for_one_v5e_at_published_widths(topo, kind):
         assert "tpu_custom_call" not in loop and " while(" in loop
 
 
+@pytest.mark.parametrize("kind", ["gqa_attention", "gated_delta_rule"])
+def test_qwen3_next_mixers_compile_for_one_v5e_at_the_cells_shapes(topo, kind):
+    """The two mixers of ``qwen3_next.train_tokens_8k`` (2 x 8,192
+    positions, bfloat16), forward and backward. Gated attention: 16 query
+    heads on 2 key/value heads of 256 features, 64 of them rotated: the
+    fused kernel takes it (a head width it had not seen: 16.8 MB of
+    ``dQ``, inside its gate) and no float32 block of scores exists
+    outside it. The delta rule in chunks of 64: the scan stays a loop,
+    its running sums are windows of a chunk and no wider, and what its
+    backward pass holds stays under 3 GB (2.3; it was 3.9 a layer with
+    the float32 insides of the passes around it kept, and the step did
+    not fit)."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from cxxnet_tpu.layers import create_layer, seq_shape
+    from cxxnet_tpu.layers.sequence import gated_delta_rule
+    one = SingleDeviceSharding(topo.devices[0])
+    bf, f32 = jnp.bfloat16, jnp.float32
+
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    if kind == "gqa_attention":
+        layer = create_layer(kind, [(k, str(v)) for k, v in dict(
+            nhead=16, nkvhead=2, head_dim=256, window=0, rope=1, rope_dim=64,
+            rope_theta=1e7, eps=1e-6, q_block=1024, dtype="bfloat16").items()])
+        layer.infer_shape([seq_shape(8192, 2048)])
+        params = jax.tree.map(lambda a: on(a.shape, a.dtype), jax.eval_shape(
+            layer.init_params, jax.random.PRNGKey(0)))
+        text = jax.jit(jax.grad(lambda p, x: jnp.sum(layer.forward(
+            p, {}, [x], True, None)[0][0].astype(f32)), argnums=(0, 1))).lower(
+                params, on((2, 8192, 2048), bf)).compile().as_text()
+        assert layer.fused_core and text.count("tpu_custom_call") >= 2
+        scores = re.findall(r"f32\[(?:2,16|32),\d{4,},\d{4,}\]", text)
+        assert not scores, sorted(set(scores))
+        return
+    args = (on((2, 8192, 16, 128), bf), on((2, 8192, 16, 128), bf),
+            on((2, 8192, 32, 128), bf), on((2, 8192, 32), f32),
+            on((2, 8192, 32), f32))
+    compiled = jax.jit(jax.grad(lambda *a: jnp.sum(gated_delta_rule(
+        *a, 64, bf).astype(f32)), argnums=(0, 1, 2, 3, 4))).lower(
+            *args).compile()
+    text = compiled.as_text()
+    assert " while(" in text
+    wide = re.findall(r"reduce-window\([^\n]*window=\{size=([0-9x]+)", text)
+    assert wide and all(max(map(int, w.split("x"))) <= 64 for w in wide), wide
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+
+
 def _loop_bodies(text, scope):
     """``{scope path: [text reachable from the body of each loop]}`` of
     the outermost ``while`` instructions of an HLO module whose op_name

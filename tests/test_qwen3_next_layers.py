@@ -1,0 +1,283 @@
+"""Qwen3-Next's layers (layers/sequence.py: ``gated_delta``,
+``gqa_attention``'s ``rope_dim``, ``moe``'s ``score_func`` and
+``shared_gate``) against the plain reference
+(cxxnet_tpu/reference/qwen3_next.py): the chunked delta rule against the
+recurrence a position at a time, value and every gradient; the triangular
+inverse, and that a block's checkpoint keeps it; the whole mixer; RoPE on
+part of a head; the softmax router and the gated shared expert, with the
+defaults what they were. The whole model is tests/test_qwen3_next_model.py's.
+"""
+
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from cxxnet_tpu.layers import create_layer, seq_shape
+from cxxnet_tpu.layers.sequence import _solve_unit_lower, gated_delta_rule
+from cxxnet_tpu.reference import qwen3_next as ref
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# the reference's names for the sizes qwen3_next_tiny builds
+TINY = dict(
+    vocab_size=64, hidden_size=32, num_hidden_layers=4,
+    full_attention_interval=4, num_attention_heads=4, num_key_value_heads=2,
+    head_dim=8, partial_rotary_factor=0.5, rope_theta=1e7,
+    rms_norm_eps=1e-6, linear_num_key_heads=2, linear_num_value_heads=4,
+    linear_key_head_dim=8, linear_value_head_dim=6, linear_conv_kernel_dim=4,
+    moe_intermediate_size=24, shared_expert_intermediate_size=24,
+    num_experts=8, num_experts_per_tok=3, norm_topk_prob=True)
+T, D = 16, 32
+
+
+def _layer(kind, cfg, in_shape, seed=0):
+    layer = create_layer(kind, [(k, str(v)) for k, v in cfg.items()])
+    layer.infer_shape([in_shape])
+    return layer, layer.init_params(jax.random.PRNGKey(seed)), \
+        layer.init_state()
+
+
+def _close(a, b, tol=1e-5):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    assert a.shape == b.shape
+    assert np.abs(a - b).max() <= tol * max(1.0, np.abs(b).max()), \
+        np.abs(a - b).max()
+
+
+def _x(seed=1, batch=2, t=T):
+    return jax.random.normal(jax.random.PRNGKey(seed), (batch, t, D))
+
+
+def _both(fn, w):
+    """fn's value and its gradients in every argument, jitted."""
+    return jax.jit(lambda *a: (fn(*a), jax.grad(
+        lambda *a: jnp.sum(w * fn(*a)), argnums=tuple(range(len(a))))(*a)))
+
+
+# -- the delta rule --------------------------------------------------------------
+
+
+def _rule_inputs(t, seed=0, b=2, hk=2, hv=4, dk=8, dv=6):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q, k = (ref.l2_norm(jax.random.normal(key, (b, t, hk, dk)))
+            for key in ks[:2])
+    v = jax.random.normal(ks[2], (b, t, hv, dv))
+    # decays from almost none to exp(-20) a position, as A_log's start gives
+    g = -jnp.exp(jax.random.uniform(ks[3], (b, t, hv), minval=-4, maxval=3))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, t, hv)))
+    return (q / dk ** 0.5, k, v, g, beta), jax.random.normal(
+        ks[5], (b, t, hv, dv))
+
+
+def _recurrence(q, k, v, g, beta):
+    r = v.shape[2] // q.shape[2]
+    each = lambda a: jnp.repeat(a, r, axis=2)
+    return jnp.stack([ref.delta_rule(each(q)[b], each(k)[b], v[b], g[b],
+                                     beta[b], None, 0, False)
+                      for b in range(q.shape[0])])
+
+
+@pytest.mark.parametrize("t,chunk", [(16, 4), (14, 4), (128, 64), (40, 64)])
+def test_chunked_delta_rule_matches_the_recurrence(t, chunk):
+    """Value and the gradients in q, k, v, g and beta, float32: several
+    chunks, a length no chunk divides (padded), the published chunk of 64
+    (whose solve is made of four blocks of 16) over two chunks and over
+    a part of one; the value heads twice the key heads."""
+    args, w = _rule_inputs(t)
+    with jax.default_matmul_precision("highest"):
+        (of, gf), (og, gg) = _both(
+            lambda *a: gated_delta_rule(*a, chunk, jnp.float32), w)(*args), \
+            _both(_recurrence, w)(*args)
+    _close(of, og, 5e-5)
+    for a, b in zip(gf, gg):
+        _close(a, b, 1e-4)
+    assert float(jnp.abs(og).max()) > 0.1
+
+
+def test_the_solve_inverts_a_unit_lower_triangle():
+    for c in (3, 4, 16, 24, 64):
+        a = jnp.tril(0.3 * jax.random.normal(jax.random.PRNGKey(c),
+                                             (2, c, c)), -1)
+        with jax.default_matmul_precision("highest"):
+            _close(_solve_unit_lower(a, jnp.float32) @ (jnp.eye(c) + a),
+                   jnp.broadcast_to(jnp.eye(c), a.shape), 1e-5)
+
+
+def test_a_block_keeps_the_solve_and_makes_it_once():
+    """Under a ``remat = block`` segment's checkpoint (nnet/net.py: it
+    keeps what carries a name of ``BLOCK_REMAT_KEEPS``) the triangular
+    inverse's float32 products appear once in the gradient's program, in
+    the first forward pass, beside the two of its own backward rule; a
+    segment that keeps no name makes the inverse twice (the first pass
+    and the segment's recomputation, which keeps it for the rule's own
+    backward pass). The values are the same."""
+    from test_block_remat_keeps import _eqns
+    from cxxnet_tpu.layers.base import BLOCK_REMAT_KEEPS, DELTA_KEEPS
+    assert set(DELTA_KEEPS) < set(BLOCK_REMAT_KEEPS)
+    args, w = _rule_inputs(32)
+
+    def grad_of(names):
+        rule = jax.checkpoint(
+            lambda *a: gated_delta_rule(*a, 16, jnp.float32),
+            policy=jax.checkpoint_policies.save_only_these_names(*names))
+        return jax.grad(lambda *a: jnp.sum(w * rule(*a)),
+                        argnums=(0, 1, 2, 3, 4))
+
+    def exact_products(names):
+        return sum(1 for e in _eqns(jax.make_jaxpr(grad_of(names))(
+            *args).jaxpr) if e.primitive.name == "dot_general"
+            and "HIGHEST" in str(e.params["precision"]))
+
+    one = 6         # a block of 16: three squarings, three products
+    assert exact_products(BLOCK_REMAT_KEEPS) == one + 2
+    assert exact_products(()) == 2 * one + 2
+    for a, b in zip(grad_of(BLOCK_REMAT_KEEPS)(*args), grad_of(())(*args)):
+        _close(a, b, 1e-5)
+
+
+@pytest.mark.parametrize("t", [16, 10])
+def test_gated_delta_layer_matches_the_reference(t):
+    """The whole mixer: projections, the convolution (a channel alone,
+    zeros before the sequence), SiLU, the unit-length q and k, the rule,
+    the gated norm a head, the output projection."""
+    layer, p, st = _layer("gated_delta", dict(
+        nkhead=2, nvhead=4, key_dim=8, value_dim=6, conv_kernel=4, chunk=4,
+        eps=1e-6, init_sigma=0.3), seq_shape(t, D))
+    assert set(p) == {"wqkv", "wz", "wb", "wa", "wo", "conv", "alog",
+                      "dtbias", "norm"}
+    assert p["conv"].shape == (4, 2 * 2 * 8 + 4 * 6)
+    assert float(jnp.abs(p["conv"]).max()) <= 0.5
+    assert float(jnp.exp(p["alog"]).max()) <= 16.0
+    # scales off one, so that a norm left out shows
+    p = dict(p, norm=p["norm"] + 0.1 * _x(3)[0, 0, :6],
+             dtbias=p["dtbias"] + 0.2 * _x(4)[0, 0, :4])
+    x, w = _x(t=t), _x(9, t=t)
+    plain = lambda p, x: jnp.stack([ref.gated_delta_net(
+        p, x[b], TINY, None, 0, False) for b in range(x.shape[0])])
+    with jax.default_matmul_precision("highest"):
+        (yf, gf), (yg, gg) = _both(
+            lambda p, x: layer.forward(p, st, [x], True, None)[0][0],
+            w)(p, x), _both(plain, w)(p, x)
+    _close(yf, yg)
+    for a, b in zip(jax.tree_util.tree_leaves(gf),
+                    jax.tree_util.tree_leaves(gg)):
+        _close(a, b, 2e-5)
+    # causal: a later position moves no earlier output
+    y2 = layer.forward(p, st, [x.at[:, t - 1].add(1.0)], True, None)[0][0]
+    assert float(jnp.abs(y2 - yf)[:, :t - 1].max()) < 1e-5
+    assert float(jnp.abs(y2 - yf)[:, t - 1].max()) > 1e-3
+
+
+def test_gated_delta_refuses_heads_it_cannot_group():
+    for bad in (dict(nkhead=3, nvhead=4, key_dim=8, value_dim=6),
+                dict(nkhead=2, nvhead=4, key_dim=8),
+                dict(nkhead=2, nvhead=4, key_dim=8, value_dim=6, chunk=0)):
+        with pytest.raises(ValueError, match="gated_delta"):
+            _layer("gated_delta", bad, seq_shape(T, D))
+
+
+# -- RoPE on part of a head ------------------------------------------------------
+
+
+@pytest.mark.parametrize("rope_dim", [4, 8])
+def test_rope_dim_matches_the_reference(rope_dim):
+    """4 of a head's 8 features rotated, the others passed through; 8 of
+    8 is the reference's factor 1."""
+    layer, p, st = _layer("gqa_attention", dict(
+        nhead=4, nkvhead=2, head_dim=8, rope=1, rope_dim=rope_dim,
+        rope_theta=1e7, eps=1e-6, q_block=8, init_sigma=0.3),
+        seq_shape(T, D))
+    p = dict(p, qnorm=p["qnorm"] + 0.1 * _x(3)[0, 0, :8])
+    cfg = dict(TINY, partial_rotary_factor=rope_dim / 8)
+    x, w = _x(), _x(9)
+    plain = lambda p, x: jnp.stack([ref.attention(
+        p, x[b], cfg, None, None, False) for b in range(x.shape[0])])
+    with jax.default_matmul_precision("highest"):
+        (yf, gf), (yg, gg) = _both(
+            lambda p, x: layer.forward(p, st, [x], True, None)[0][0],
+            w)(p, x), _both(plain, w)(p, x)
+    _close(yf, yg)
+    for a, b in zip(jax.tree_util.tree_leaves(gf),
+                    jax.tree_util.tree_leaves(gg)):
+        _close(a, b)
+
+
+def test_rope_dim_default_is_all_of_a_head_to_the_bit():
+    cfg = dict(nhead=4, nkvhead=2, head_dim=8, rope=1, rope_theta=1e4,
+               eps=1e-5, init_sigma=0.3)
+    old, p, st = _layer("gqa_attention", cfg, seq_shape(T, D))
+    full, _, _ = _layer("gqa_attention", dict(cfg, rope_dim=8),
+                        seq_shape(T, D))
+    part, _, _ = _layer("gqa_attention", dict(cfg, rope_dim=4),
+                        seq_shape(T, D))
+    a, b, c = (l.forward(p, st, [_x()], True, None)[0][0]
+               for l in (old, full, part))
+    assert old.rope_dim == 0 and bool(jnp.all(a == b))
+    assert float(jnp.abs(a - c).max()) > 1e-3
+    # the program the default traces names no slice of a head
+    text = str(jax.make_jaxpr(
+        lambda x: old.forward(p, st, [x], True, None)[0][0])(_x()))
+    assert text == str(jax.make_jaxpr(
+        lambda x: full.forward(p, st, [x], True, None)[0][0])(_x()))
+    for bad in (3, 10):
+        with pytest.raises(ValueError, match="rope_dim"):
+            _layer("gqa_attention", dict(cfg, rope_dim=bad), seq_shape(T, D))
+
+
+# -- the expert layer's two keys -------------------------------------------------
+
+MOE = dict(nexpert=8, topk=3, nhidden=24, nshared=1, expert_block=4,
+           init_sigma=0.3)
+
+
+def test_softmax_router_and_gated_shared_expert_match_the_reference():
+    layer, p, st = _layer("moe", dict(
+        MOE, score_func="softmax", shared_gate=1, expert_first=2,
+        expert_count=4, bias_sigma=0), seq_shape(T, D))
+    assert p["sharedgate"].shape == (D, 1)
+    assert float(jnp.abs(st["bias"]).max()) == 0.0
+    x, w = _x(), _x(9)
+    plain = lambda p, x: ref.moe(p, x.reshape(-1, D), TINY, (2, 4), None
+                                 ).reshape(x.shape)
+    with jax.default_matmul_precision("highest"):
+        (yf, gf), (yg, gg) = _both(
+            lambda p, x: layer.forward(p, st, [x], True, None)[0][0],
+            w)(p, x), _both(plain, w)(p, x)
+    _close(yf, yg)
+    assert set(gf[0]) == set(gg[0])
+    for a, b in zip(jax.tree_util.tree_leaves(gf),
+                    jax.tree_util.tree_leaves(gg)):
+        _close(a, b, 2e-5)
+    # a token's weights over its picks add up to one
+    picks, wts = layer.route(x.reshape(-1, D), p["router"], st["bias"])
+    _close(jnp.sum(wts, axis=-1), jnp.ones(2 * T), 1e-6)
+
+
+def test_moe_defaults_are_the_sigmoid_router_to_the_bit():
+    """Neither key set: the parameters, the values and the traced program
+    are what ``score_func = sigmoid``, ``shared_gate = 0`` give, and the
+    gate's own key leaves the other tensors' start alone."""
+    cfg = dict(MOE, routed_scaling_factor=2.446, bias_seed=3, bias_sigma=0.5)
+    old, p, st = _layer("moe", cfg, seq_shape(T, D))
+    same, p2, _ = _layer("moe", dict(cfg, score_func="sigmoid",
+                                     shared_gate=0), seq_shape(T, D))
+    gated, p3, _ = _layer("moe", dict(cfg, shared_gate=1), seq_shape(T, D))
+    soft, _, _ = _layer("moe", dict(cfg, score_func="softmax"),
+                        seq_shape(T, D))
+    assert set(p) == set(p2) == set(p3) - {"sharedgate"}
+    assert all(bool(jnp.all(p[k] == p3[k])) for k in p)
+    run = lambda l, q: l.forward(q, st, [_x()], True, None)[0][0]
+    assert bool(jnp.all(run(old, p) == run(same, p)))
+    assert str(jax.make_jaxpr(lambda x: old.forward(
+        p, st, [x], True, None)[0][0])(_x())) == str(jax.make_jaxpr(
+            lambda x: same.forward(p, st, [x], True, None)[0][0])(_x()))
+    assert float(jnp.abs(run(old, p) - run(gated, p3)).max()) > 1e-3
+    assert float(jnp.abs(run(old, p) - run(soft, p)).max()) > 1e-3
+    with pytest.raises(ValueError, match="score_func"):
+        _layer("moe", dict(cfg, score_func="tanh"), seq_shape(T, D))
+    with pytest.raises(ValueError, match="shared_gate"):
+        _layer("moe", dict(cfg, nshared=0, shared_gate=1), seq_shape(T, D))
