@@ -669,7 +669,8 @@ def pipeline_snapshot(it) -> Optional[dict]:
     monitor attached) and consumer waits from PrefetchIterators, and
     what the chain delivers: ``input_dtype`` of the last batch
     assembled and ``norm_on_device`` (1 when mean/scale were handed to
-    the consumer, ``defer_normalize``).
+    the consumer, ``defer_normalize``). An imgrec source adds its
+    decode counters (``ImageRecordIterator.decode_snapshot``).
     Returns None when the chain has neither (nothing to report)."""
     found = False
     input_dtype = ""
@@ -678,8 +679,11 @@ def pipeline_snapshot(it) -> Optional[dict]:
     h2d_ms = 0.0
     h2d_batches = 0
     wait_ms = 0.0
+    decode: dict = {}
     node = it
     while node is not None:
+        if hasattr(node, "decode_snapshot"):
+            decode = node.decode_snapshot()
         if isinstance(node, BatchAdapter):
             found = True
             s = node.ring_snapshot()
@@ -707,4 +711,5 @@ def pipeline_snapshot(it) -> Optional[dict]:
             "h2d_batches": h2d_batches,
             "consumer_wait_ms": round(wait_ms, 3),
             "input_dtype": input_dtype,
-            "norm_on_device": norm_on_device}
+            "norm_on_device": norm_on_device,
+            **decode}

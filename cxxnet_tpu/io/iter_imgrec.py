@@ -12,6 +12,13 @@ reads image records from a .rec archive, decodes JPEG in a thread pool
   (label_width > 1 support, :120-147) without repacking
 - ``shuffle_chunk``: shuffles decode chunks within a window
 
+The decode pool (doc/io.md): a chunk of ``_chunk`` records goes to the
+pool as contiguous slices, one task a thread, and ``_AHEAD`` chunks are
+in flight, so the pool decodes the next chunk while the thread that
+called ``next`` crops, assembles and copies the one handed out. The
+sequence of DataInst is that of a loop decoding one record after the
+other.
+
 Emits DataInst (float32 NHWC in [0,255], or contiguous uint8 RGB once
 the augmenter above switched ``emit_uint8`` on: ``defer_normalize``,
 io/data.py); stack augment/batch adapters on top (the factory wires
@@ -20,9 +27,14 @@ this like the reference's chained iterators).
 
 from __future__ import annotations
 
+import collections
+import math
 import os
+import threading
+import time
+from concurrent import futures
 from concurrent.futures import ThreadPoolExecutor
-from typing import Dict, List, Optional
+from typing import Deque, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -31,6 +43,21 @@ from .recordio import (RAW_TENSOR_FLAG, RecordIOReader,
                        parse_image_record, record_flag,
                        unpack_raw_tensor_record)
 from ..utils.stream import open_stream
+
+
+# chunks read and handed to the pool but not yet handed out: while one
+# chunk is consumed the next is being decoded. With the chunk in
+# ``_buf`` that is 2 x 256 decoded records, 100 MB of 256 x 256 uint8
+_AHEAD = 2
+
+
+def usable_cpus() -> int:
+    """The CPUs this process may run on: its affinity mask where the
+    platform has one (a container's or ``taskset``'s cut shows there,
+    not in ``os.cpu_count()``), else the machine's count."""
+    if hasattr(os, "sched_getaffinity"):
+        return len(os.sched_getaffinity(0)) or 1
+    return os.cpu_count() or 1
 
 
 class ImageRecordIterator(IIterator):
@@ -55,7 +82,15 @@ class ImageRecordIterator(IIterator):
         self._shard_plan = None
         self._rec_seq = 0
         self._pass_ended = False
-        self.nthread = max(4, os.cpu_count() or 4)
+        # the pool's size, and the slices a chunk is cut into: half the
+        # CPUs the process may use, no fewer than 4; ``nthread =`` wins.
+        # Half, because the other threads of a job want cores too (the
+        # one that calls next, the trainer's, the runtime's) and every
+        # worker more asks for the interpreter lock twice an image: on
+        # 13 CPUs 6 workers decode 7 % more a second than 13, 4 or 8
+        # (PERF.md, PR 35)
+        self._cpus = usable_cpus()
+        self.nthread = max(4, self._cpus // 2)
         self.shuffle = 0
         self.seed = 0
         self._label_map: Optional[Dict[int, np.ndarray]] = None
@@ -64,6 +99,15 @@ class ImageRecordIterator(IIterator):
         self._buf: List[DataInst] = []
         self._bufpos = 0
         self._chunk = 256
+        # chunks in flight, oldest first: (records, a future a slice)
+        self._ahead: Deque[Tuple[int, List[futures.Future]]] = \
+            collections.deque()
+        # per-round decode counters (``decode_snapshot``); the workers
+        # add their time under the lock
+        self._stat_lock = threading.Lock()
+        self._chunks = 0
+        self._chunks_ready = 0
+        self._busy_ns = 0
 
     def set_param(self, name: str, val: str) -> None:
         if name in ("path_imgrec", "image_rec"):   # reference alias
@@ -193,6 +237,7 @@ class ImageRecordIterator(IIterator):
                 and (self._pass_ended or self._rec_seq > 0):
             self._shard_plan = self._shard_plan.steady()
         self._pass_ended = False
+        self._drop_ahead()
         for r in self._readers:
             r.reset()
         self._cur_reader = 0
@@ -201,21 +246,43 @@ class ImageRecordIterator(IIterator):
 
     # -- decode ----------------------------------------------------------
 
-    def _decode(self, rec: bytes) -> Optional[DataInst]:
-        if record_flag(rec) == RAW_TENSOR_FLAG:
-            # pre-decoded uint8 tensor record: no jpeg in the loop
-            index, label, data = unpack_raw_tensor_record(rec)
-            if not self.emit_uint8:
-                data = data.astype(np.float32)
-            return self._with_label(index, label, data)
-        import cv2
-        index, label, labels, payload = parse_image_record(rec)
-        img = cv2.imdecode(np.frombuffer(payload, np.uint8),
-                           cv2.IMREAD_COLOR)
-        if img is None:
-            return None
-        return self._with_label(index, label,
-                                rgb_pixels(img, self.emit_uint8), labels)
+    def _decode_slice(self, recs: List[bytes]
+                      ) -> List[Optional[DataInst]]:
+        """One contiguous slice of a chunk, in record order; None for a
+        record no decoder takes. Three passes, not a record at a time:
+        the Python around the decoder (headers before, labels after) is
+        then not run between two OpenCV calls, so a worker coming back
+        from a call needs the interpreter lock for a loop step and not
+        for the parsing of the next record, and a pool of workers does
+        not queue for the lock (doc/io.md)."""
+        t0 = time.perf_counter_ns()
+        uint8 = self.emit_uint8
+        heads = []
+        for rec in recs:
+            if record_flag(rec) == RAW_TENSOR_FLAG:
+                # pre-decoded uint8 tensor record: no jpeg in the loop
+                index, label, data = unpack_raw_tensor_record(rec)
+                heads.append((index, label, None, data, True))
+            else:
+                index, label, labels, payload = parse_image_record(rec)
+                heads.append((index, label, labels,
+                              np.frombuffer(payload, np.uint8), False))
+        if not all(raw for *_, raw in heads):
+            import cv2
+        pixels: List[Optional[np.ndarray]] = []
+        for *_, data, raw in heads:
+            if raw:
+                pixels.append(data if uint8 else data.astype(np.float32))
+                continue
+            img = cv2.imdecode(data, cv2.IMREAD_COLOR)
+            pixels.append(None if img is None else rgb_pixels(img, uint8))
+        out = [None if px is None
+               else self._with_label(index, label, px, labels)
+               for (index, label, labels, _, _), px in zip(heads, pixels)]
+        dur = time.perf_counter_ns() - t0
+        with self._stat_lock:
+            self._busy_ns += dur
+        return out
 
     def _with_label(self, index: int, label: float,
                     data: np.ndarray,
@@ -235,25 +302,72 @@ class ImageRecordIterator(IIterator):
             lab = np.full((self.label_width,), label, np.float32)
         return DataInst(index=index, data=data, label=lab)
 
-    def _fill(self) -> bool:
+    def _read_chunk(self) -> List[bytes]:
+        """The next ``_chunk`` records this host owns, fewer at the
+        pass's end: a reader is asked for what the chunk still lacks in
+        one call (``next_records``), so the last record read is the
+        chunk's last, as in a loop a record at a time."""
         recs: List[bytes] = []
         with self.span("io.read"):
             while len(recs) < self._chunk and \
                     self._cur_reader < len(self._readers):
-                r = self._readers[self._cur_reader].next_record()
-                if r is None:
+                got = self._readers[self._cur_reader].next_records(
+                    self._chunk - len(recs))
+                if not got:
                     self._cur_reader += 1
                     continue
-                if self._shard_plan is not None:
-                    owned = self._shard_plan.owns(self._rec_seq)
+                if self._shard_plan is None:
+                    recs += got
+                    continue
+                for r in got:
+                    # another host's record: no decode
+                    if self._shard_plan.owns(self._rec_seq):
+                        recs.append(r)
                     self._rec_seq += 1
-                    if not owned:
-                        continue         # another host's record: no decode
-                recs.append(r)
-        if not recs:
+        return recs
+
+    def _submit_ahead(self) -> None:
+        """Read chunks and hand them to the pool until ``_AHEAD`` are
+        in flight or the pass has no record left (the readers are not
+        rewound: the next pass starts at ``before_first``). A chunk is
+        cut into one contiguous slice a thread: a record a task would
+        cost the pool more in futures and lock hand-overs than the
+        decode itself takes."""
+        pool = self._pool
+        if pool is None:                 # closed
+            return
+        while len(self._ahead) < _AHEAD \
+                and self._cur_reader < len(self._readers):
+            recs = self._read_chunk()
+            if not recs:
+                return
+            step = math.ceil(len(recs) / self.nthread)
+            self._ahead.append((len(recs), [
+                pool.submit(self._decode_slice, recs[i:i + step])
+                for i in range(0, len(recs), step)]))
+
+    def _drop_ahead(self) -> None:
+        """Forget the chunks in flight: cancel the slices still queued
+        and wait for the ones a thread is in, so that nothing of an old
+        pass is running, or can be handed out, when the next begins."""
+        running = [f for _, futs in self._ahead for f in futs
+                   if not f.cancel()]
+        self._ahead.clear()
+        futures.wait(running)
+
+    def _fill(self) -> bool:
+        self._submit_ahead()
+        if not self._ahead:
             return False
-        with self.span("io.decode", n=len(recs)):
-            insts = list(self._pool.map(self._decode, recs))
+        n, futs = self._ahead.popleft()
+        ready = all(f.done() for f in futs)
+        # the time this thread is blocked on the pool: the exposed part
+        # of the decode, what is left after the look-ahead
+        with self.span("io.decode", n=n):
+            insts = [i for f in futs for i in f.result()]
+        with self._stat_lock:
+            self._chunks += 1
+            self._chunks_ready += int(ready)
         insts = [i for i in insts if i is not None]
         if self.shuffle:
             self._rng.shuffle(insts)
@@ -261,6 +375,21 @@ class ImageRecordIterator(IIterator):
         # progress was made even if every record in this chunk failed to
         # decode; next() loops to the following chunk
         return True
+
+    def decode_snapshot(self) -> dict:
+        """Per-round decode counters (reset on read) for the
+        ``pipeline`` record: chunks handed out, how many of them the
+        pool had finished when they were asked for, the workers' summed
+        time inside their slices; and what sized the pool."""
+        with self._stat_lock:
+            out = {"decode_chunks": self._chunks,
+                   "decode_ahead_ready": self._chunks_ready,
+                   "decode_busy_ms": round(self._busy_ns / 1e6, 3),
+                   "decode_pool": self.nthread,
+                   "decode_cpus": self._cpus,
+                   "cpu_count": os.cpu_count() or 0}
+            self._chunks = self._chunks_ready = self._busy_ns = 0
+        return out
 
     def next(self) -> bool:
         while self._bufpos >= len(self._buf):
@@ -278,6 +407,7 @@ class ImageRecordIterator(IIterator):
         if self._pool is not None:
             self._pool.shutdown(wait=False, cancel_futures=True)
             self._pool = None
+        self._ahead.clear()
         for r in self._readers:
             if hasattr(r, "close"):
                 r.close()

@@ -235,6 +235,13 @@ OPTIONAL: Dict[str, tuple] = {
     # the share of the dispatch's passes through an expert layer that
     # did (forward; the other passes took the loop a block at a time)
     "moe": ("grouped_share",),
+    # an imgrec source's decode stage over the round (io/iter_imgrec.py):
+    # chunks handed out, how many of them the pool had finished when
+    # they were asked for, the workers' summed time inside their
+    # slices; the pool's threads, the CPU count that sized it (the
+    # process's affinity mask) and the machine's
+    "pipeline": ("decode_chunks", "decode_ahead_ready", "decode_busy_ms",
+                 "decode_pool", "decode_cpus", "cpu_count"),
 }
 
 _TIMING_KEYS = ("wall_ms", "data_wait_ms", "total_ms", "max_ms",
@@ -246,7 +253,7 @@ _TIMING_KEYS = ("wall_ms", "data_wait_ms", "total_ms", "max_ms",
                 "write_ms", "fsync_ms", "quantize_ms",
                 "backprop_ms", "reduce_ms", "step_ms", "window_s",
                 "dur_ns", "tokens", "tokens_per_example",
-                "train_flops_per_token")
+                "train_flops_per_token", "decode_busy_ms")
 
 # ratio fields must sit in [0, 1]
 _RATIO_KEYS = ("buffer_reuse_rate", "fill_rate", "pad_fraction",

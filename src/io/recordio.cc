@@ -217,6 +217,8 @@ void CXNRecordIOWriterFree(void *handle) {
 struct CXNReaderState {
   cxxnet_tpu::RecordIOReader reader;
   std::string buf;
+  std::string batch;             // NextN: the records end to end
+  std::vector<uint64_t> sizes;   // NextN: their lengths
   CXNReaderState(const char *path, int pi, int np)
       : reader(path, pi, np) {}
 };
@@ -239,6 +241,21 @@ const char *CXNRecordIOReaderNext(void *handle, uint64_t *size) {
   }
   *size = r->buf.size();
   return r->buf.data();
+}
+
+uint64_t CXNRecordIOReaderNextN(void *handle, uint64_t n,
+                                const char **data,
+                                const uint64_t **sizes) {
+  auto *r = static_cast<CXNReaderState *>(handle);
+  r->batch.clear();
+  r->sizes.clear();
+  while (r->sizes.size() < n && r->reader.NextRecord(&r->buf)) {
+    r->batch.append(r->buf);
+    r->sizes.push_back(r->buf.size());
+  }
+  *data = r->batch.data();
+  *sizes = r->sizes.data();
+  return r->sizes.size();
 }
 
 void CXNRecordIOReaderReset(void *handle) {

@@ -86,6 +86,12 @@ void *CXNRecordIOReaderCreate(const char *path, int part_index,
 /* returns pointer to internal buffer valid until next call; len=0 at
  * end of shard */
 const char *CXNRecordIOReaderNext(void *handle, uint64_t *size);
+/* up to n records in one call: *data points at their bytes laid end to
+ * end, *sizes at their lengths, both valid until the next call; returns
+ * how many were read (fewer than n only at the end of the shard) */
+uint64_t CXNRecordIOReaderNextN(void *handle, uint64_t n,
+                                const char **data,
+                                const uint64_t **sizes);
 void CXNRecordIOReaderReset(void *handle);
 void CXNRecordIOReaderFree(void *handle);
 }
