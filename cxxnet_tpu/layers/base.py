@@ -78,11 +78,17 @@ def seq_shape(time: int, features: int) -> SeqShape:
 # the triangular inverse a chunk (layers/sequence.py:_solve_unit_lower):
 # sixty small float32 products a layer, more than half of its scan's
 # device time when a step made them three times (PERF.md, PR 34), and
-# 67 MB a layer to hold in bfloat16 at 2 x 8,192 positions. A name
-# outside a ``jax.checkpoint`` is the identity and lowers to nothing.
+# 67 MB a layer to hold in bfloat16 at 2 x 8,192 positions. Where the
+# rule's scan is the fused kernels, their forward rule names the forward
+# kernel's three outputs (layers/pallas_kernels.py:_gated_delta_scan_fwd:
+# ``o``, a state a chunk and ``u``, which the backward kernel reads; 134
+# + 268 + 134 MB a layer there), so that it runs once a step: 3.43 ->
+# 3.50 sequences a second in the cell (PERF.md, PR 37). A name outside
+# a ``jax.checkpoint`` is the identity and lowers to nothing.
 ATTENTION_KEEPS = ("attention_o", "attention_lse")
 DELTA_KEEPS = ("delta_solve",)
-BLOCK_REMAT_KEEPS = ATTENTION_KEEPS + DELTA_KEEPS
+DELTA_SCAN_KEEPS = ("delta_o", "delta_state", "delta_u")
+BLOCK_REMAT_KEEPS = ATTENTION_KEEPS + DELTA_KEEPS + DELTA_SCAN_KEEPS
 
 
 def array_shape(batch: int, s: Shape3) -> Tuple[int, ...]:

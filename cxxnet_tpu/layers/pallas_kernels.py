@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
-from .base import ATTENTION_KEEPS, Shape3
+from .base import ATTENTION_KEEPS, DELTA_SCAN_KEEPS, Shape3
 from .common import FullConnectLayer
 
 
@@ -974,6 +974,321 @@ def causal_attention(qs, ks, v, scale: float, q_block: int = 0,
     o = _attention(tuple(map(flat, qs)), tuple(map(flat, ks)), flat(v),
                    scale, bq, bk, 0 if window >= t else window)
     return o.reshape(b, h, t, v.shape[3])
+
+
+# ------------------------------------------- gated delta rule's scan
+
+_DELTA_VMEM = 64 * 1024 * 1024
+_DELTA_TILES = (512, 256, 128)
+_NN = (((1,), (0,)), ((), ()))       # a @ b
+
+
+def _delta_tile(time: int) -> int:
+    """Positions a grid step of the delta rule's kernels takes: the
+    largest of ``_DELTA_TILES`` that divides ``time`` (0: none does)."""
+    return next((b for b in _DELTA_TILES if time % b == 0), 0)
+
+
+def _mm(a, b, dims=_NN):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _stage_gates(rows_ref, cols_ref):
+    """``rows_ref`` ``(r, n)`` float32, a row a value head along time,
+    into ``cols_ref`` ``(r, n, 128)`` with a position a sublane and every
+    lane the same: what scales a chunk's rows."""
+    rows = rows_ref[...]
+    for p in range(rows.shape[0]):
+        cols_ref[p] = jnp.broadcast_to(rows[p:p + 1],
+                                       (_LANES, rows.shape[1])).T
+
+
+def _delta_gates(a, width):
+    """A chunk's decays from the running sum of one value head's ``g``,
+    ``a`` ``(c, 128)`` with every lane the same: ``exp(a)`` ``(c, 1)``,
+    ``exp(a_i - a_j)`` for ``j <= i`` (zeros above), ``exp(a_end - a)``
+    ``(c, 1)`` and ``exp(a_end)`` as a row of ``width`` lanes.
+    Differences of running sums, so none passes 1."""
+    c = a.shape[0]
+    i = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    end, a = a[c - 1:c], a[:, :1]
+    a_row = jnp.sum(jnp.where(i == j, a, 0.0), axis=0, keepdims=True)
+    return (jnp.exp(a), jnp.exp(jnp.where(i >= j, a - a_row, -jnp.inf)),
+            jnp.exp(end[:, :1] - a),
+            jnp.exp(jnp.concatenate([end] * (width // _LANES), axis=1)))
+
+
+def _delta_fwd_kernel(c, r, q_ref, k_ref, v_ref, run_ref, beta_ref, t_ref,
+                      o_ref, s0_ref, u_ref, s_ref, a_ref, b_ref):
+    """One (batch, key head, time tile) step of the gated delta rule
+    (layers/sequence.py: gated_delta_rule), the tile's chunks one after
+    the other and the key head's ``r`` value heads side by side. The
+    float32 state ``S`` ``(r, dk, dv)`` lives in VMEM scratch across the
+    time tiles. A chunk, with ``T`` its triangular inverse: ``u = T (beta
+    v) - (T (beta exp(G) k)) S``, ``o = exp(G) q S + ((q k^T) * decays)
+    u``, ``S <- exp(G_end) S + (exp(G_end - G) k)^T u``. Products take
+    operands in the inputs' dtype with float32 results, the decays and
+    the state are float32. Besides ``o`` the chunk's starting state and
+    ``u`` go out in the operands' dtype: the backward kernel reads
+    them."""
+    from jax.experimental import pallas as pl
+    cd = q_ref.dtype
+    dv = v_ref.shape[1] // r
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_ref[...] = jnp.zeros(s_ref.shape, jnp.float32)
+
+    _stage_gates(run_ref, a_ref)
+    _stage_gates(beta_ref, b_ref)
+
+    def chunk(n, carry):
+        rows = pl.ds(pl.multiple_of(n * c, c), c)
+        q, k = q_ref[rows, :], k_ref[rows, :]
+        kf = k.astype(jnp.float32)
+        qk = _mm(q, k, _NT)
+        for p in range(r):
+            at = slice(p * dv, (p + 1) * dv)
+            beta = b_ref[p, rows, :][:, :1]
+            grow, decay, tail, keep = _delta_gates(a_ref[p, rows, :], dv)
+            solve = t_ref[p, rows, :]
+            s = s_ref[p]
+            s_cd = s.astype(cd)
+            s0_ref[p, n] = s_cd
+            u0 = _mm(solve, (beta * v_ref[rows, at].astype(jnp.float32))
+                     .astype(cd)).astype(cd)
+            w = _mm(solve, ((beta * grow) * kf).astype(cd)).astype(cd)
+            u = (u0.astype(jnp.float32) - _mm(w, s_cd)).astype(cd)
+            u_ref[rows, at] = u
+            o_ref[rows, at] = (grow * _mm(q, s_cd) + _mm(
+                (qk * decay).astype(cd), u)).astype(cd)
+            s_ref[p] = keep * s + _mm((tail * kf).astype(cd), u, _TN)
+        return carry
+
+    jax.lax.fori_loop(0, q_ref.shape[0] // c, chunk, 0)
+
+
+def _delta_bwd_kernel(c, r, q_ref, k_ref, v_ref, run_ref, beta_ref, t_ref,
+                      s0_ref, u_ref, do_ref, dq_ref, dk_ref, dv_ref,
+                      drun_ref, dbeta_ref, dt_ref, ds_ref, a_ref, b_ref,
+                      da_ref, db_ref):
+    """The same step backward, the time tiles and a tile's chunks last
+    to first, the gradient of the state ``dS`` ``(r, dk, dv)`` float32
+    in VMEM scratch as the state was. A chunk's products are made again
+    from its inputs, its starting state and ``u``; cotangents are cast
+    to the operands' dtype where a product reads them. ``dq`` and ``dk``
+    are summed over the key head's value heads here."""
+    from jax.experimental import pallas as pl
+    cd = q_ref.dtype
+    f32 = jnp.float32
+    dv = v_ref.shape[1] // r
+    nc = q_ref.shape[0] // c
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        ds_ref[...] = jnp.zeros(ds_ref.shape, f32)
+
+    _stage_gates(run_ref, a_ref)
+    _stage_gates(beta_ref, b_ref)
+
+    i = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    last = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0) == c - 1
+
+    def chunk(m, carry):
+        n = nc - 1 - m
+        rows = pl.ds(pl.multiple_of(n * c, c), c)
+        q, k = q_ref[rows, :], k_ref[rows, :]
+        kf = k.astype(f32)
+        qk = _mm(q, k, _NT)
+        dq = jnp.zeros(kf.shape, f32)
+        dk = jnp.zeros(kf.shape, f32)
+        for p in range(r):
+            at = slice(p * dv, (p + 1) * dv)
+            beta = b_ref[p, rows, :][:, :1]
+            grow, decay, tail, keep = _delta_gates(a_ref[p, rows, :], dv)
+            solve = t_ref[p, rows, :]
+            s_cd, u = s0_ref[p, n], u_ref[rows, at]
+            vf = v_ref[rows, at].astype(f32)
+            do = do_ref[rows, at]
+            ds = ds_ref[p]
+            ds_cd = ds.astype(cd)
+            dof, bg = do.astype(f32), beta * grow
+            vb, kb = (beta * vf).astype(cd), (bg * kf).astype(cd)
+            kd = tail * kf
+            kd_cd = kd.astype(cd)
+            w = _mm(solve, kb).astype(cd)
+            # o = exp(G) q S + P u
+            edo = (grow * dof).astype(cd)
+            dq += _mm(edo, s_cd, _NT)
+            dsc = _mm(q, edo, _TN)
+            dgrow = jnp.sum(dof * _mm(q, s_cd), axis=1, keepdims=True)
+            dp = _mm(do, u, _NT)
+            pf = qk * decay
+            du = _mm(pf.astype(cd), do, _TN)
+            dqk = (dp * decay).astype(cd)
+            dq += _mm(dqk, k)
+            dk += _mm(dqk, q, _TN)
+            mix = dp * pf
+            da = jnp.sum(mix, axis=1, keepdims=True) - jnp.sum(
+                jnp.where(i == j, jnp.sum(mix, axis=0, keepdims=True), 0.0),
+                axis=1, keepdims=True)
+            # S' = exp(G_end) S + (exp(G_end - G) k)^T u
+            dkd = _mm(u, ds_cd, _NT)
+            du += _mm(kd_cd, ds_cd)
+            dtail = jnp.sum(dkd * kd, axis=1, keepdims=True)
+            da -= dtail
+            dend = keep[:, :1] * jnp.sum(ds * s_cd.astype(f32),
+                                         keepdims=True) \
+                + jnp.sum(dtail, axis=0, keepdims=True)
+            dk += tail * dkd
+            # u = T (beta v) - (T (beta exp(G) k)) S
+            du = du.astype(cd)
+            dw = (-_mm(du, s_cd, _NT)).astype(cd)
+            dsc -= _mm(w, du, _TN)
+            dt_ref[p, rows, :] = (_mm(du, vb, _NT) + _mm(dw, kb, _NT)) \
+                .astype(dt_ref.dtype)
+            dvb, dkb = _mm(solve, du, _TN), _mm(solve, dw, _TN)
+            dv_ref[rows, at] = (beta * dvb).astype(dv_ref.dtype)
+            dk += bg * dkb
+            dkbk = jnp.sum(dkb * kf, axis=1, keepdims=True)
+            dgrow += beta * dkbk
+            da += grow * dgrow + jnp.where(last, dend, 0.0)
+            da_ref[p, rows, :] = jnp.broadcast_to(da, (c, _LANES))
+            db_ref[p, rows, :] = jnp.broadcast_to(
+                jnp.sum(dvb * vf, axis=1, keepdims=True) + grow * dkbk,
+                (c, _LANES))
+            ds_ref[p] = keep * ds + dsc
+        dq_ref[rows, :] = dq.astype(dq_ref.dtype)
+        dk_ref[rows, :] = dk.astype(dk_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, nc, chunk, 0)
+    for p in range(r):
+        drun_ref[p:p + 1, :] = da_ref[p].T[:1]
+        dbeta_ref[p:p + 1, :] = db_ref[p].T[:1]
+
+
+def _delta_specs(nt, bt, dk, r, dv, c, backward):
+    """Block specs of the delta rule's kernels over ``(batch, key head,
+    time tile)``, the time tiles last to first where ``backward``: rows
+    of a key head's features (q, k), of its value heads' (v, o, u),
+    gates a value head along time, a matrix of ``c`` columns a chunk a
+    value head (``T``), a state a chunk a value head."""
+    from jax.experimental import pallas as pl
+    at = (lambda i: nt - 1 - i) if backward else (lambda i: i)
+    return (pl.BlockSpec((None, bt, dk), lambda b, h, i: (b, at(i), h)),
+            pl.BlockSpec((None, bt, r * dv), lambda b, h, i: (b, at(i), h)),
+            pl.BlockSpec((None, None, r, bt),
+                         lambda b, h, i: (b, h, 0, at(i))),
+            pl.BlockSpec((None, None, r, bt, c),
+                         lambda b, h, i: (b, h, 0, at(i), 0)),
+            pl.BlockSpec((None, None, r, bt // c, dk, dv),
+                         lambda b, h, i: (b, h, 0, at(i), 0, 0)))
+
+
+def _delta_call(kernel, ins, specs, outs, out_specs, scratch, grid):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    return pl.pallas_call(
+        kernel, grid=grid, in_specs=specs, out_specs=out_specs,
+        out_shape=outs, scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_DELTA_VMEM),
+        interpret=_build_interpret(),
+    )(*ins)
+
+
+def _delta_plan(q, v, solve, backward):
+    """(chunk, value heads a key head, time tile, grid, block specs)."""
+    b, t, kw = q.shape
+    hk, r, c = solve.shape[1], solve.shape[2], solve.shape[4]
+    bt = _delta_tile(t)
+    return c, r, bt, (b, hk, t // bt), _delta_specs(
+        t // bt, bt, kw // hk, r, v.shape[2] // (hk * r), c, backward)
+
+
+def _delta_fwd_call(q, k, v, run, beta, solve):
+    from jax.experimental.pallas import tpu as pltpu
+    c, r, bt, grid, (qs, vs, gs, ts, ss) = _delta_plan(q, v, solve, False)
+    (b, t, kw), hk = q.shape, grid[1]
+    dk, dv = kw // hk, v.shape[2] // (hk * r)
+    return _delta_call(
+        partial(_delta_fwd_kernel, c, r), (q, k, v, run, beta, solve),
+        [qs, qs, vs, gs, gs, ts],
+        [jax.ShapeDtypeStruct(v.shape, v.dtype),
+         jax.ShapeDtypeStruct((b, hk, r, t // c, dk, dv), v.dtype),
+         jax.ShapeDtypeStruct(v.shape, v.dtype)], [vs, ss, vs],
+        [pltpu.VMEM((r, dk, dv), jnp.float32)]
+        + [pltpu.VMEM((r, bt, _LANES), jnp.float32)] * 2, grid)
+
+
+def _delta_bwd_call(q, k, v, run, beta, solve, s0, u, do):
+    from jax.experimental.pallas import tpu as pltpu
+    c, r, bt, grid, (qs, vs, gs, ts, ss) = _delta_plan(q, v, solve, True)
+    return _delta_call(
+        partial(_delta_bwd_kernel, c, r),
+        (q, k, v, run, beta, solve, s0, u, do),
+        [qs, qs, vs, gs, gs, ts, ss, vs, vs],
+        [jax.ShapeDtypeStruct(a.shape, a.dtype)
+         for a in (q, k, v, run, beta, solve)], [qs, qs, vs, gs, gs, ts],
+        [pltpu.VMEM(s0.shape[2:3] + s0.shape[4:], jnp.float32)]
+        + [pltpu.VMEM((r, bt, _LANES), jnp.float32)] * 4, grid)
+
+
+@jax.custom_vjp
+def gated_delta_scan(q, k, v, run, beta, solve):
+    """The gated delta rule along time as one fused kernel a direction:
+    a chunk's intermediates and the running state stay in VMEM. ``q``,
+    ``k`` ``(batch, time, key heads * dk)`` and ``v`` ``(batch, time,
+    value heads * dv)`` in the compute dtype, a head's features side by
+    side; ``run`` (the running sum of ``g`` from its chunk's start) and
+    ``beta`` ``(batch, key heads, r, time)`` float32; ``solve`` ``(batch,
+    key heads, r, time, chunk)``, a chunk's triangular inverse ``T`` in
+    its ``chunk`` rows. ``time`` is whole tiles (``_delta_tile``).
+    Returns ``o`` shaped as ``v``. Differentiable in all six: the
+    forward kernel hands the backward one every chunk's starting state
+    and ``u`` in the compute dtype."""
+    return _delta_fwd_call(q, k, v, run, beta, solve)[0]
+
+
+def _gated_delta_scan_fwd(q, k, v, run, beta, solve):
+    o, s0, u = map(checkpoint_name,
+                   _delta_fwd_call(q, k, v, run, beta, solve),
+                   DELTA_SCAN_KEEPS)
+    return o, (q, k, v, run, beta, solve, s0, u)
+
+
+def _gated_delta_scan_bwd(res, do):
+    return tuple(_delta_bwd_call(*res, do))
+
+
+gated_delta_scan.defvjp(_gated_delta_scan_fwd, _gated_delta_scan_bwd)
+
+
+def gated_delta_applicable(time: int, chunk: int, dk: int, dv: int, r: int,
+                           dtype) -> bool:
+    """Shape gate of :func:`gated_delta_scan`: key and value heads of
+    whole lanes (128), a chunk of 64 or 128 positions (``time`` itself
+    where the sequence is shorter), a padded ``time`` that a time tile
+    divides, bfloat16 or float32 operands, and the backward kernel's
+    blocks (twice over) and scratch within half of the kernels' VMEM."""
+    if min(time, chunk, dk, dv, r) <= 0 or dk % _LANES or dv % _LANES \
+            or jnp.dtype(dtype) not in (jnp.dtype(jnp.bfloat16),
+                                        jnp.dtype(jnp.float32)):
+        return False
+    c = min(chunk, time)
+    bt = _delta_tile(_pad_to(time, c))
+    if c not in (64, 128) or not bt:
+        return False
+    size = jnp.dtype(dtype).itemsize
+    blocks = bt * size * (4 * dk + 4 * r * dv + 2 * r * _LANES) \
+        + r * (bt // c) * dk * dv * size + 4 * 8 * bt * 4
+    scratch = 4 * r * (dk * dv + 4 * bt * _LANES)
+    return 2 * blocks + scratch <= _DELTA_VMEM // 2
 
 
 # ------------------------------------------- grouped expert products

@@ -607,7 +607,46 @@ def _decay_state_bwd(res, g):
 _decay_state.defvjp(_decay_state_fwd, _decay_state_bwd)
 
 
-def gated_delta_rule(q, k, v, g, beta, chunk: int, cd):
+def _fused_delta_rule(q, k, v, g, beta, c: int, cd):
+    """``gated_delta_rule`` through the fused kernels
+    (pallas_kernels.gated_delta_scan) for a ``time`` of whole chunks of
+    ``c``: the running sums and a chunk's triangular inverse are made
+    here, over all chunks at once and again in the backward pass but for
+    ``T`` (kept by its name, as in the other form); everything else of a
+    chunk stays in the kernels' VMEM."""
+    b, t, hk, dk = q.shape
+    hv, dv = v.shape[2], v.shape[3]
+    r, n = hv // hk, t // c
+    # a key head's value heads beside it, time last: a lane a position
+    g, beta = (a.astype(_F32).reshape(b, n, c, hk, r).transpose(0, 3, 4, 1, 2)
+               for a in (g, beta))                           # b h r n c
+    run = jnp.cumsum(g, axis=-1)
+    i, j = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
+
+    def solve(k, run, beta):
+        k = k.reshape(b, n, c, hk, dk)
+        kk = jnp.einsum("bnihk,bnjhk->bhnij", k, k,
+                        preferred_element_type=_F32)
+        decay = jnp.exp(jnp.where(
+            i >= j, run[..., :, None] - run[..., None, :], -jnp.inf))
+        return _solve_unit_lower(jnp.where(
+            i > j, beta[..., :, None] * decay * kk[:, :, None], 0.0), cd)
+
+    k = k.astype(cd)
+    # (a scope for who reads the compiled text: the float32 matrices a
+    # chunk that are left outside the kernels are all under it)
+    with jax.named_scope("solve"):
+        t_inv = jax.checkpoint(
+            solve, policy=jax.checkpoint_policies.save_only_these_names(
+                *DELTA_KEEPS))(k, run, beta)
+    o = pallas_kernels.gated_delta_scan(
+        q.astype(cd).reshape(b, t, hk * dk), k.reshape(b, t, hk * dk),
+        v.astype(cd).reshape(b, t, hv * dv), run.reshape(b, hk, r, t),
+        beta.reshape(b, hk, r, t), t_inv.reshape(b, hk, r, t, c))
+    return o.reshape(b, t, hv, dv)
+
+
+def gated_delta_rule(q, k, v, g, beta, chunk: int, cd, fused=None):
     """The gated delta rule over a sequence, in chunks of ``chunk``
     positions. A value head carries a state ``S`` (key width x value
     width, zeros at the start) along time:
@@ -639,7 +678,17 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int, cd):
     again in the backward pass (``jax.checkpoint``: small products) but
     for ``T``, which is kept by its name, so what a layer holds for it is
     q, k, v, ``T``, the scan's inputs and a state a chunk, all in
-    ``cd``."""
+    ``cd``.
+
+    That is the XLA form. Where the shapes tile
+    (``pallas_kernels.gated_delta_applicable``: heads of whole lanes, a
+    chunk of 64 or 128, a padded ``time`` of whole time tiles; ``fused``
+    None asks it, False keeps the XLA form) the same rule runs as one
+    fused kernel a direction (``_fused_delta_rule``): the same products
+    on the same roundings, but of a chunk only its inputs, ``T``, ``o``
+    and what the backward kernel is handed (the chunk's starting state
+    and ``u``, in ``cd``) cross HBM, and the state stays in VMEM from
+    chunk to chunk."""
     b, t, hk, dk = q.shape
     hv, dv = v.shape[2], v.shape[3]
     r, c = hv // hk, min(chunk, t)
@@ -647,6 +696,10 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int, cd):
     if pad:     # positions that neither decay nor write: g 0, k 0, beta 0
         q, k, v, g, beta = (jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (
             a.ndim - 2)) for a in (q, k, v, g, beta))
+    if fused is None:
+        fused = pallas_kernels.gated_delta_applicable(t, chunk, dk, dv, r, cd)
+    if fused:
+        return _fused_delta_rule(q, k, v, g, beta, c, cd)[:, :t]
     n = (t + pad) // c
     # chunks first (the scan's axis), heads beside the batch, a key
     # head's value heads beside it
@@ -726,7 +779,15 @@ class GatedDeltaLayer(_SeqLayer):
 
     ``A_log`` starts as ``log(U(0, 16))``, ``dt_bias`` and the norm's
     scale at 1, the convolution's taps as ``U(-1/2, 1/2)`` (torch's
-    default for a depthwise kernel of four)."""
+    default for a depthwise kernel of four).
+
+    Which form of the rule runs is what the shapes allow, not a key
+    (``fused_scan``, decided in ``infer_shape``): the fused kernels where
+    ``key_dim`` and ``value_dim`` are multiples of 128, the chunk is 64
+    or 128 and the padded sequence is whole time tiles of 128 positions
+    or more (Qwen3-Next's published widths), the XLA form everywhere
+    else. The ``layout`` record's ``linear_attention_fused_layers`` says
+    how many layers took the kernels."""
 
     # "short_conv", not "conv": a reduction that reads an op's innermost
     # scope as a layer type would count it as a convolution layer
@@ -740,6 +801,7 @@ class GatedDeltaLayer(_SeqLayer):
         self.conv_kernel = 4
         self.chunk = 64
         self.eps = 1e-6
+        self.fused_scan = False
         super().__init__(cfg)
 
     def set_param(self, name, val):
@@ -758,6 +820,10 @@ class GatedDeltaLayer(_SeqLayer):
             raise ValueError(
                 "gated_delta: must set nkhead, nvhead (a multiple of "
                 "nkhead), key_dim, value_dim, and conv_kernel, chunk > 0")
+        # which form of the rule runs is what the shapes allow, not a key
+        self.fused_scan = pallas_kernels.gated_delta_applicable(
+            s.y, self.chunk, self.key_dim, self.value_dim,
+            self.nvhead // self.nkhead, self.cd)
         self.in_shapes = [s]
         self.out_shapes = [s]
         return self.out_shapes
@@ -823,7 +889,8 @@ class GatedDeltaLayer(_SeqLayer):
         with jax.named_scope("short_conv"):
             q, k, v = jax.checkpoint(conv)(qkv, params["conv"])
         with jax.named_scope("scan"):
-            o = gated_delta_rule(q, k, v, g, beta, self.chunk, cd)
+            o = gated_delta_rule(q, k, v, g, beta, self.chunk, cd,
+                                 self.fused_scan)
         with jax.named_scope("gate_norm"):
             y = jax.checkpoint(gate_norm)(o, z, params["norm"])
         with jax.named_scope("out"):

@@ -230,11 +230,13 @@ OPTIONAL: Dict[str, tuple] = {
     # many see a window of keys; moe layers, and how many of them run
     # their experts as the grouped kernels while a step's routing fits
     # the kernels' row buffers; linear-attention layers (gated_delta),
-    # and the positions a chunk of their scan along time holds
+    # the positions a chunk of their scan along time holds, and how
+    # many of them run that scan as the fused kernels
     "layout": ("attention_layers", "attention_fused_layers",
                "attention_saved_layers", "attention_window_layers",
                "moe_layers", "moe_grouped_layers",
-               "linear_attention_layers", "linear_attention_chunk"),
+               "linear_attention_layers", "linear_attention_chunk",
+               "linear_attention_fused_layers"),
     # the share of the dispatch's passes through an expert layer that
     # did (forward; the other passes took the loop a block at a time)
     "moe": ("grouped_share",),

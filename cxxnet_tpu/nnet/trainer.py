@@ -1583,9 +1583,11 @@ class NetTrainer:
         grouped = [layer.grouped for layer in net.layer_objs
                    if hasattr(layer, "grouped")]
         # linear-attention layers (gated_delta: a state along time, in
-        # chunks) and the largest chunk among them
-        chunks = [layer.chunk for layer in net.layer_objs
-                  if hasattr(layer, "chunk")]
+        # chunks), the largest chunk among them, and those whose scan
+        # is the fused kernels (the shapes decide)
+        linear = [layer for layer in net.layer_objs
+                  if hasattr(layer, "fused_scan")]
+        chunks = [layer.chunk for layer in linear]
         self._mon.emit("layout",
                        # what took hold, not what was asked for
                        input_layout=self.input_layout_effective,
@@ -1612,6 +1614,8 @@ class NetTrainer:
                        moe_grouped_layers=sum(grouped),
                        linear_attention_layers=len(chunks),
                        linear_attention_chunk=max(chunks, default=0),
+                       linear_attention_fused_layers=sum(
+                           layer.fused_scan for layer in linear),
                        **net.layout_summary)
         if self.quant_report.get("active"):
             r = self.quant_report

@@ -268,11 +268,14 @@ def test_qwen3_next_mixers_compile_for_one_v5e_at_the_cells_shapes(topo, kind):
     heads on 2 key/value heads of 256 features, 64 of them rotated: the
     fused kernel takes it (a head width it had not seen: 16.8 MB of
     ``dQ``, inside its gate) and no float32 block of scores exists
-    outside it. The delta rule in chunks of 64: the scan stays a loop,
-    its running sums are windows of a chunk and no wider, and what its
-    backward pass holds stays under 3 GB (2.3; it was 3.9 a layer with
-    the float32 insides of the passes around it kept, and the step did
-    not fit)."""
+    outside it. The delta rule in chunks of 64: the scan is the two
+    fused kernels (layers/pallas_kernels.py: gated_delta_scan) and no
+    loop, the float32 matrices a chunk a head (128 chunks x 2 x 16 key
+    or 32 value heads x 64 x 64) that are left outside them all belong
+    to the triangular inverse and what builds its argument (scope
+    ``solve``), the running sums are windows of a chunk and no wider,
+    and what the backward pass holds stays under 3 GB (1.1; the XLA
+    form held 2.3)."""
     import re
     import jax
     import jax.numpy as jnp
@@ -306,7 +309,13 @@ def test_qwen3_next_mixers_compile_for_one_v5e_at_the_cells_shapes(topo, kind):
         *a, 64, bf).astype(f32)), argnums=(0, 1, 2, 3, 4))).lower(
             *args).compile()
     text = compiled.as_text()
-    assert " while(" in text
+    assert text.count("tpu_custom_call") == 2 and " while(" not in text
+    # (an instruction's scope is in its metadata; parameters and the
+    # bitcasts inside fusions carry none)
+    chunk = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines() if "op_name=" in line
+             and re.search(r"= f32\[2,16,(?:2,)?128,64,64\]", line)]
+    assert chunk and all("solve" in name for name in chunk), chunk
     wide = re.findall(r"reduce-window\([^\n]*window=\{size=([0-9x]+)", text)
     assert wide and all(max(map(int, w.split("x"))) <= 64 for w in wide), wide
     assert compiled.memory_analysis().temp_size_in_bytes < 3e9

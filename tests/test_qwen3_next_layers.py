@@ -98,6 +98,160 @@ def test_chunked_delta_rule_matches_the_recurrence(t, chunk):
     assert float(jnp.abs(og).max()) > 0.1
 
 
+def _near(a, b, tol):
+    """``_close`` as a share of ``b``'s largest entry, whatever its size:
+    at heads of 128 features the rule's values stay under 0.1."""
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    assert a.shape == b.shape
+    assert np.abs(a - b).max() <= tol * np.abs(b).max(), \
+        np.abs(a - b).max() / np.abs(b).max()
+
+
+# bfloat16: the XLA form itself lies 5e-3 to 8e-3 of the largest entry
+# from the float32 recurrence on these inputs, value and gradients
+_BF16 = 2e-2
+
+
+@pytest.mark.parametrize("t,hk,hv,dtype", [
+    (256, 1, 2, "float32"),      # two tiles of 128, two value heads a key head
+    (640, 1, 1, "float32"),      # five tiles, a value head a key head
+    (360, 2, 2, "float32"),      # no chunk divides it: padded to 384, 3 tiles
+    (512, 1, 2, "float32"),      # one tile of 512: eight chunks in the kernel
+    (384, 2, 4, "bfloat16"),     # the cell's dtype, three tiles
+    (200, 1, 2, "bfloat16")])    # padded to 256, one tile
+def test_fused_delta_rule_matches_the_recurrence(t, hk, hv, dtype):
+    """The two kernels (pallas_kernels.gated_delta_scan, interpreted)
+    at heads of 128 x 128 in chunks of 64: value and the gradients in q,
+    k, v, g and beta. float32 against the recurrence a position at a
+    time, held as the XLA form is; bfloat16 against the XLA form (the
+    same products on the same roundings: the value to the last bit or
+    two) and, like that form, against the recurrence."""
+    from cxxnet_tpu.layers import pallas_kernels
+    cd = jnp.dtype(dtype)
+    args, w = _rule_inputs(t, hk=hk, hv=hv, dk=128, dv=128)
+    assert pallas_kernels.gated_delta_applicable(t, 64, 128, 128, hv // hk,
+                                                 cd)
+    assert -(-t // 64) * 64 // pallas_kernels._delta_tile(
+        -(-t // 64) * 64) == {256: 1, 640: 5, 360: 3, 512: 1, 384: 3,
+                              200: 1}[t]
+    rule = lambda fused: _both(lambda *a: gated_delta_rule(
+        *a, 64, cd, fused).astype(jnp.float32), w)
+    with jax.default_matmul_precision("highest"):
+        (of, gf), (og, gg) = rule(None)(*args), _both(_recurrence, w)(*args)
+        if dtype == "bfloat16":
+            ox, gx = rule(False)(*args)
+    if dtype == "float32":
+        _near(of, og, 5e-5)
+        for a, b in zip(gf, gg):
+            _near(a, b, 1e-4)
+    else:
+        _near(of, ox, 1e-5)
+        _near(of, og, _BF16)
+        for a, b, c in zip(gf, gx, gg):
+            _near(a, b, _BF16)
+            _near(a, c, _BF16)
+            _near(b, c, _BF16)
+    assert float(jnp.abs(og).max()) > 0.01
+    # the kernels are what ran: two calls in the gradient's program
+    text = str(jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(gated_delta_rule(
+        *a, 64, cd).astype(jnp.float32))))(*args))
+    assert text.count("pallas_call") == 2 and "while" not in text
+
+
+@pytest.mark.parametrize("t,chunk,dk,dv,r,dtype,takes", [
+    (8192, 64, 128, 128, 2, "bfloat16", True),    # the cell's
+    (8192, 64, 128, 128, 2, "float32", True),
+    (8192, 128, 128, 256, 1, "bfloat16", True),
+    (8180, 64, 128, 128, 2, "bfloat16", True),    # padded to 8,192
+    (100, 64, 128, 128, 2, "bfloat16", True),     # padded to 128
+    (128, 256, 128, 128, 1, "float32", True),     # one chunk of 128
+    (8192, 64, 64, 128, 2, "bfloat16", False),    # half a lane of key
+    (8192, 64, 128, 64, 2, "bfloat16", False),    # half a lane of value
+    (15, 64, 128, 128, 2, "bfloat16", False),     # a chunk of 15 positions
+    (8192, 48, 128, 128, 2, "bfloat16", False),   # no tile holds whole chunks
+    (8192, 32, 128, 128, 2, "bfloat16", False),
+    (64, 64, 128, 128, 2, "bfloat16", False),     # no tile so short
+    (8000, 64, 128, 128, 2, "bfloat16", False),   # 125 chunks: no tile's
+    (8192, 64, 128, 128, 2, "float16", False),
+    (8192, 64, 512, 512, 4, "float32", False),    # past the kernels' VMEM
+    (16, 4, 8, 6, 2, "float32", False)])          # the tiny model's
+def test_gated_delta_gate(t, chunk, dk, dv, r, dtype, takes):
+    from cxxnet_tpu.layers.pallas_kernels import gated_delta_applicable
+    assert gated_delta_applicable(t, chunk, dk, dv, r,
+                                  jnp.dtype(dtype)) is takes
+
+
+def test_a_refused_shape_runs_the_xla_form_to_the_bit():
+    """Where the gate refuses (64-wide heads here) the rule and the
+    layer trace the program they traced before the kernels existed: no
+    ``pallas_call``, the scan a loop, ``fused`` left out or False the
+    same jaxpr; the layer says which form it runs."""
+    args, _ = _rule_inputs(128, hk=1, hv=2, dk=64, dv=64)
+    import re
+    # (a custom rule prints as its functions' addresses)
+    one, two = (re.sub(r"0x[0-9a-f]+", "", str(jax.make_jaxpr(
+        lambda *a: gated_delta_rule(*a, 64, jnp.bfloat16, **kw))(*args)))
+        for kw in ({}, {"fused": False}))
+    assert one == two and "pallas_call" not in one and "scan[" in one
+    cfg = dict(nkhead=1, nvhead=2, conv_kernel=4, chunk=64, init_sigma=0.3)
+    for width, fused in ((64, False), (128, True)):
+        layer, p, st = _layer("gated_delta", dict(
+            cfg, key_dim=width, value_dim=width), seq_shape(128, D))
+        assert layer.fused_scan is fused
+        text = str(jax.make_jaxpr(lambda x: layer.forward(
+            p, st, [x], True, None)[0][0])(_x(t=128)))
+        assert ("pallas_call" in text) is fused
+
+
+def test_a_block_keeps_the_fused_scans_outputs_and_runs_it_once():
+    """Under a ``remat = block`` segment's checkpoint the forward kernel's
+    three outputs (``o``, a state a chunk, ``u``) are kept by their names
+    (``DELTA_SCAN_KEEPS``), so the gradient's program holds one forward
+    and one backward kernel; a segment that keeps no name runs the
+    forward kernel a second time. The values are the same."""
+    from test_block_remat_keeps import _eqns
+    from cxxnet_tpu.layers.base import BLOCK_REMAT_KEEPS, DELTA_SCAN_KEEPS
+    assert set(DELTA_SCAN_KEEPS) < set(BLOCK_REMAT_KEEPS)
+    args, w = _rule_inputs(128, b=1, hk=1, hv=2, dk=128, dv=128)
+
+    def grad_of(names):
+        rule = jax.checkpoint(
+            lambda *a: gated_delta_rule(*a, 64, jnp.float32),
+            policy=jax.checkpoint_policies.save_only_these_names(*names))
+        return jax.grad(lambda *a: jnp.sum(w * rule(*a)),
+                        argnums=(0, 1, 2, 3, 4))
+
+    def kernels(names):
+        return sum(1 for e in _eqns(jax.make_jaxpr(grad_of(names))(
+            *args).jaxpr) if e.primitive.name == "pallas_call")
+
+    assert kernels(BLOCK_REMAT_KEEPS) == 2 and kernels(()) == 3
+    for a, b in zip(grad_of(BLOCK_REMAT_KEEPS)(*args), grad_of(())(*args)):
+        _near(a, b, 1e-6)
+
+
+def test_fused_gated_delta_layer_matches_the_reference():
+    """The whole mixer with the kernels inside, float32, a key head
+    serving two value heads of 128 over 128 positions."""
+    cfg = dict(TINY, linear_num_key_heads=1, linear_num_value_heads=2,
+               linear_key_head_dim=128, linear_value_head_dim=128)
+    layer, p, st = _layer("gated_delta", dict(
+        nkhead=1, nvhead=2, key_dim=128, value_dim=128, conv_kernel=4,
+        chunk=64, eps=1e-6, init_sigma=0.3), seq_shape(128, D))
+    assert layer.fused_scan
+    x, w = _x(t=128), _x(9, t=128)
+    plain = lambda p, x: jnp.stack([ref.gated_delta_net(
+        p, x[b], cfg, None, 0, False) for b in range(x.shape[0])])
+    with jax.default_matmul_precision("highest"):
+        (yf, gf), (yg, gg) = _both(
+            lambda p, x: layer.forward(p, st, [x], True, None)[0][0],
+            w)(p, x), _both(plain, w)(p, x)
+    _close(yf, yg, 2e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(gf),
+                    jax.tree_util.tree_leaves(gg)):
+        _close(a, b, 1e-4)
+
+
 def test_the_solve_inverts_a_unit_lower_triangle():
     for c in (3, 4, 16, 24, 64):
         a = jnp.tril(0.3 * jax.random.normal(jax.random.PRNGKey(c),

@@ -145,8 +145,10 @@ def test_records_count_the_linear_attention_layers_and_name_their_parts():
     t.run_steps(DataBatch(data=data, label=lab), 2)
     validate_records(sink.records)
     (layout,) = [r for r in sink.records if r["event"] == "layout"]
+    # (heads of 8 x 6 in chunks of 4: the gate of the fused scan refuses)
     assert (layout["linear_attention_layers"],
-            layout["linear_attention_chunk"]) == (3, 4)
+            layout["linear_attention_chunk"],
+            layout["linear_attention_fused_layers"]) == (3, 4, 0)
     assert (layout["attention_layers"], layout["attention_fused_layers"],
             layout["attention_window_layers"]) == (1, 0, 0)
     assert (layout["moe_layers"], layout["moe_grouped_layers"]) == (4, 0)
@@ -169,8 +171,45 @@ def test_records_count_the_linear_attention_layers_and_name_their_parts():
     sink2 = MemorySink()
     plain.set_monitor(Monitor(sink2))
     (rec,) = [r for r in sink2.records if r["event"] == "layout"]
-    assert (rec["linear_attention_layers"],
-            rec["linear_attention_chunk"]) == (0, 0)
+    assert (rec["linear_attention_layers"], rec["linear_attention_chunk"],
+            rec["linear_attention_fused_layers"]) == (0, 0, 0)
+
+
+def test_records_count_the_layers_whose_scan_is_the_fused_kernels():
+    """The same block with a key head serving two value heads of 128 x
+    128 over 128 positions in chunks of 64: every linear-attention layer
+    takes the kernels (3 of 3) and the step trains through them
+    (interpreted here)."""
+    from cxxnet_tpu.models.qwen3_next import qwen3_next_lm
+    from cxxnet_tpu.monitor import MemorySink, Monitor
+    from cxxnet_tpu.monitor.schema import validate_records
+    t = NetTrainer(parse_config(qwen3_next_lm(
+        vocab=64, hidden=32, num_layers=4, full_attention_interval=4,
+        nhead=4, nkvhead=2, head_dim=8, rope_dim=4, rope_theta=1e7,
+        linear_nkhead=1, linear_nvhead=2, linear_key_dim=128,
+        linear_value_dim=128, linear_conv_kernel=4, linear_chunk=64,
+        rms_norm_eps=1e-6, expert_width=24, num_experts=8,
+        experts_per_tok=3, shared_width=24, experts_held=4, expert_first=2,
+        seq_len=128, batch_size=2, q_block=8, expert_block=4, loss_chunk=8,
+        init_sigma=0.3, lr=0.01))
+        + [("dtype", "bfloat16"), ("seed", "3"), ("silent", "1")])
+    t.init_model()
+    assert [l.fused_scan for l in t.net.layer_objs
+            if hasattr(l, "fused_scan")] == [True] * 3
+    sink = MemorySink()
+    t.set_monitor(Monitor(sink))
+    ids = np.random.RandomState(0).randint(0, 64, (2, 129))
+    t.run_steps(DataBatch(data=ids[:, :128].astype(np.int32),
+                          label=ids[:, 1:].astype(np.float32)), 2)
+    first = t.last_loss
+    t.run_steps(DataBatch(data=ids[:, :128].astype(np.int32),
+                          label=ids[:, 1:].astype(np.float32)), 2)
+    assert np.isfinite(first) and t.last_loss < first
+    validate_records(sink.records)
+    (layout,) = [r for r in sink.records if r["event"] == "layout"]
+    assert (layout["linear_attention_layers"],
+            layout["linear_attention_chunk"],
+            layout["linear_attention_fused_layers"]) == (3, 64, 3)
 
 
 # -- a chip's share of the block ------------------------------------------------
