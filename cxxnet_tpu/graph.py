@@ -291,6 +291,9 @@ class NetGraph:
                 raise ConfigError(
                     "shared layer tag %r not defined before" % stag)
             info.primary_layer_index = self.layer_name_map[stag]
+            # a shared connection's name labels its scope only
+            # (nnet/net.py: layer_scope); its parameters are the primary's
+            info.name = lname
         else:
             info.type = ltype
             if lname:

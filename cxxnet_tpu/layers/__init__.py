@@ -28,9 +28,9 @@ from .conv import (BatchNormLayer, ConvolutionLayer, InsanityPoolingLayer,
 from .loss import LossLayer, LpLossLayer, MultiLogisticLayer, SoftmaxLayer
 from .pairtest import PairTestLayer
 from .pallas_kernels import PallasFullConnectLayer
-from .sequence import (AddLayer, EmbedLayer, GatedDeltaLayer,
-                       GQAAttentionLayer, MLAAttentionLayer, MoELayer,
-                       RMSNormLayer, SwiGLULayer)
+from .sequence import (AddLayer, EmbedLayer, GatedConvLayer,
+                       GatedDeltaLayer, GQAAttentionLayer, MLAAttentionLayer,
+                       MoELayer, RMSNormLayer, SwiGLULayer)
 from .torch_adapter import TorchLayer
 
 _FACTORY: Dict[str, Callable[..., Layer]] = {
@@ -84,6 +84,7 @@ _FACTORY: Dict[str, Callable[..., Layer]] = {
     "mla_attention": lambda cfg, **kw: MLAAttentionLayer(cfg),
     "gqa_attention": lambda cfg, **kw: GQAAttentionLayer(cfg),
     "gated_delta": lambda cfg, **kw: GatedDeltaLayer(cfg),
+    "gated_conv": lambda cfg, **kw: GatedConvLayer(cfg),
     "moe": lambda cfg, **kw: MoELayer(cfg),
 }
 

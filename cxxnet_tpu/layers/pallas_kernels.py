@@ -941,14 +941,16 @@ def causal_attention_applicable(time: int, q_block: int, qk_widths,
     """Shape gate of :func:`causal_attention`: the sequence tiles (a
     multiple of 128 positions, a query tile within ``q_block``), every
     part of the query/key features is whole half-lanes (64) and the
-    values whole lanes (128), the float32 ``dQ`` of one sequence, which
+    values whole lanes (128) or one half-lane (64: LFM2's heads, which
+    the kernels take as they are, a block of 64 lanes), the float32
+    ``dQ`` of one sequence, which
     the backward kernel keeps in VMEM twice over, stays within half of
     the kernels' VMEM, each key/value head serves a whole group of query
     heads, and a window is no negative number."""
     lanes = sum(_pad_to(d, 128) for d in qk_widths)
     return (min(_attn_tiles(time, q_block)) > 0
             and all(d > 0 and d % 64 == 0 for d in qk_widths)
-            and v_width > 0 and v_width % 128 == 0
+            and v_width > 0 and (v_width % 128 == 0 or v_width == 64)
             and 2 * 4 * time * lanes <= _ATTN_VMEM // 2
             and nkvhead > 0 and nhead % nkvhead == 0 and window >= 0)
 

@@ -1,16 +1,19 @@
 """Layers over the sequence node ``(batch, time, features)``.
 
 ``embed`` turns a matrix of integer ids into a sequence node; ``rmsnorm``,
-``add``, ``swiglu``, ``mla_attention``, ``gqa_attention``, ``gated_delta``
-and ``moe`` read and write one (``fullc`` and ``softmax`` take one too,
-see common.py and loss.py). Together they are the decoder blocks of three
-families: DeepSeek-V3's (pre-norm residual, multi-head latent attention,
-a sigmoid-routed expert layer with shared experts), Arcee's ``afmoe``
-(norms before and after each half, grouped-query attention with QK norm,
-an output gate and a window on some layers, the same expert layer) and
-Qwen3-Next's (a gated delta-rule linear-attention layer on three layers
-of four, gated attention with RoPE on part of a head on the fourth, the
-expert layer routed by a softmax with a gate on its shared expert).
+``add``, ``swiglu``, ``mla_attention``, ``gqa_attention``, ``gated_delta``,
+``gated_conv`` and ``moe`` read and write one (``fullc`` and ``softmax``
+take one too, see common.py and loss.py). Together they are the decoder
+blocks of four families: DeepSeek-V3's (pre-norm residual, multi-head
+latent attention, a sigmoid-routed expert layer with shared experts),
+Arcee's ``afmoe`` (norms before and after each half, grouped-query
+attention with QK norm, an output gate and a window on some layers, the
+same expert layer), Qwen3-Next's (a gated delta-rule linear-attention
+layer on three layers of four, gated attention with RoPE on part of a
+head on the fourth, the expert layer routed by a softmax with a gate on
+its shared expert) and LiquidAI's LFM2 (a double-gated short convolution on three layers of
+four, grouped-query attention without an output gate on the fourth, the
+expert layer with no shared expert, the head tied to the embedding).
 ``doc/sequence.md`` lists the config keys.
 
 Mixed precision follows the rest of the zoo: ``dtype = bfloat16`` casts
@@ -88,11 +91,20 @@ class EmbedLayer(_SeqLayer):
     """Integer ids ``(batch, time)`` -> rows of the held vocabulary
     slice ``(batch, time, nhidden)``. ``nvocab`` is the rows held here;
     ids are in ``[0, nvocab)``. ``scale`` multiplies the rows (afmoe's
-    ``sqrt(hidden)`` under ``mup_enabled``); 1 leaves them as they are."""
+    ``sqrt(hidden)`` under ``mup_enabled``); 1 leaves them as they are.
+
+    Applied to a sequence node of ``nhidden`` features (a second
+    connection of the same layer, ``layer[a->b] = share[<its name>]``) it
+    is the head tied to the embedding: ``h E^T``, logits over the held
+    rows ``(batch, time, nvocab)``, no scale. Which of the two a
+    connection does is what it reads, not a key; the one matrix
+    ``wmat`` is in the tree once and its gradient is the sum of both
+    uses (autodiff's, as for any shared layer)."""
 
     def __init__(self, cfg=()):
         self.nvocab = 0
         self.scale = 1.0
+        self.tied_head = False
         super().__init__(cfg)
 
     def set_param(self, name, val):
@@ -104,11 +116,20 @@ class EmbedLayer(_SeqLayer):
 
     def infer_shape(self, in_shapes: List[Shape3]) -> List[Shape3]:
         s = self._expect_one(in_shapes)
-        if not s.is_mat:
-            raise ValueError("embed: input must be a matrix of ids "
-                             "(input_shape = 1,1,<time>)")
         if self.nvocab <= 0 or self.param.num_hidden <= 0:
             raise ValueError("embed: must set nvocab and nhidden")
+        if s.is_seq:
+            # the tied head; the lookup's shapes stay the layer's own
+            if s.x != self.param.num_hidden:
+                raise ValueError(
+                    "embed as a head: the sequence has %d features, the "
+                    "rows %d" % (s.x, self.param.num_hidden))
+            self.tied_head = True
+            return [seq_shape(s.y, self.nvocab)]
+        if not s.is_mat:
+            raise ValueError("embed: input must be a matrix of ids "
+                             "(input_shape = 1,1,<time>), or a sequence "
+                             "node for the tied head")
         self.in_shapes = [s]
         self.out_shapes = [seq_shape(s.x, self.param.num_hidden)]
         return self.out_shapes
@@ -120,6 +141,9 @@ class EmbedLayer(_SeqLayer):
 
     def forward(self, params, state, inputs, is_train, rng):
         ids = inputs[0]
+        if ids.ndim == 3:       # hidden states, not ids: the tied head
+            return [jnp.einsum("btd,vd->btv", ids.astype(self.cd),
+                               params["wmat"].astype(self.cd))], state
         if not jnp.issubdtype(ids.dtype, jnp.integer):
             ids = ids.astype(jnp.int32)     # a float batch of whole numbers
         rows = jnp.take(params["wmat"].astype(self.cd), ids, axis=0)
@@ -400,8 +424,8 @@ class MLAAttentionLayer(_SeqLayer):
 
 class GQAAttentionLayer(_SeqLayer):
     """Grouped-query attention with QK norm and an output gate, as afmoe
-    (Arcee's Trinity) and Qwen3-Next's full-attention layers have it,
-    causal, no biases:
+    (Arcee's Trinity) and Qwen3-Next's full-attention layers have it, or
+    without the gate (``gate = 0``: LFM2's), causal, no biases:
 
         q = x Wq -> nhead heads;  k = x Wk, v = x Wv -> nkvhead heads;
         g = x Wg -> nhead heads
@@ -411,11 +435,18 @@ class GQAAttentionLayer(_SeqLayer):
         o = softmax(q k^T / sqrt(head_dim)) v, query head h against
             key/value head h // (nhead / nkvhead); query i sees key j
             iff 0 <= i - j < window (window = 0: every earlier key)
-        y = (o * sigmoid(g)) Wo
+        y = (o * sigmoid(g)) Wo;  with gate = 0: y = o Wo, and no Wg
 
     afmoe's layers differ in ``rope`` and ``window`` alone (its sliding
     layers have both, its full layers neither); Qwen3-Next's rotate 64
-    of a head's 256 features and have no window."""
+    of a head's 256 features and have no window; LFM2's have 32 query
+    heads on 8 key/value heads of 64 features, all rotated, and no gate.
+    ``gate`` is a structural key as ``rope`` and ``window`` are: it
+    decides which parameters exist.
+
+    The causal core is the fused kernel where the shapes tile
+    (``pallas_kernels.causal_attention_applicable``: heads of 64 features
+    or whole lanes, a sequence of whole tiles), the XLA form elsewhere."""
 
     sub_scopes = ("core",)
 
@@ -426,6 +457,7 @@ class GQAAttentionLayer(_SeqLayer):
         self.window = 0
         self.rope = 1
         self.rope_dim = 0
+        self.gate = 1
         self.rope_theta = 10000.0
         self.eps = 1e-6
         self.q_block = 0
@@ -435,7 +467,7 @@ class GQAAttentionLayer(_SeqLayer):
     def set_param(self, name, val):
         super().set_param(name, val)
         if name in ("nhead", "nkvhead", "head_dim", "window", "rope",
-                    "rope_dim", "q_block"):
+                    "rope_dim", "q_block", "gate"):
             setattr(self, name, int(val))
         if name in ("rope_theta", "eps"):
             setattr(self, name, float(val))
@@ -460,9 +492,12 @@ class GQAAttentionLayer(_SeqLayer):
 
     def _widths(self) -> Dict[str, Tuple[int, int]]:
         d, hd = self.in_shapes[0].x, self.head_dim
-        return {"wq": (d, self.nhead * hd), "wk": (d, self.nkvhead * hd),
-                "wv": (d, self.nkvhead * hd), "wg": (d, self.nhead * hd),
-                "wo": (self.nhead * hd, d)}
+        out = {"wq": (d, self.nhead * hd), "wk": (d, self.nkvhead * hd),
+               "wv": (d, self.nkvhead * hd), "wg": (d, self.nhead * hd),
+               "wo": (self.nhead * hd, d)}
+        if not self.gate:
+            del out["wg"]
+        return out
 
     def init_params(self, key):
         p, widths = self.param, self._widths()
@@ -505,8 +540,9 @@ class GQAAttentionLayer(_SeqLayer):
                 o = causal_attention(heads(q), each(k), each(v), scale,
                                      self.q_block, self.window)
         o = heads(o).reshape(b, t, h * hd)
-        gate = jax.nn.sigmoid(_dot(x, params["wg"], cd).astype(_F32))
-        o = (o.astype(_F32) * gate).astype(o.dtype)
+        if self.gate:
+            gate = jax.nn.sigmoid(_dot(x, params["wg"], cd).astype(_F32))
+            o = (o.astype(_F32) * gate).astype(o.dtype)
         return [_dot(o, params["wo"], cd)], state
 
     def pairs_per_sequence(self) -> float:
@@ -522,6 +558,96 @@ class GQAAttentionLayer(_SeqLayer):
         proj = sum(2.0 * a * b for a, b in self._widths().values())
         return t * proj + 4.0 * self.nhead * self.head_dim \
             * self.pairs_per_sequence()
+
+
+# -- the causal short convolution, and LFM2's mixer ------------------------------
+
+
+def causal_depthwise_conv(x, taps, cd):
+    """``y_t = sum_i taps[i] x_(t - K + 1 + i)``, ``K = len(taps)``: a
+    channel alone, the taps' last on the position itself and zeros before
+    the sequence. ``x`` ``(batch, time, channels)``, ``taps`` ``(K,
+    channels)``; ``K`` shifted products with the operands in ``cd``,
+    summed in float32 and returned so (as XLA's depthwise convolution it
+    took 56 ms a layer a step on the chip, nine times the projections
+    beside it; PERF.md, PR 34). The one causal convolution of the
+    sequence layers: ``gated_delta`` and ``gated_conv`` both call it, each
+    with its own passes around it."""
+    kernel, t = taps.shape[0], x.shape[1]
+    taps = taps.astype(cd).astype(_F32)
+    past = jnp.pad(x.astype(cd), [(0, 0), (kernel - 1, 0), (0, 0)])
+    return sum(taps[i] * past[:, i:i + t].astype(_F32)
+               for i in range(kernel))
+
+
+class GatedConvLayer(_SeqLayer):
+    """LFM2's double-gated short convolution (LiquidAI), causal, no
+    biases, no activation:
+
+        [B | C | x] = u Win          three parts of the input's width
+        y = C * conv(B * x)          elementwise gates around a causal
+                                     depthwise convolution of
+                                     ``conv_kernel`` taps along time
+        out = y Wout
+
+    The width is the sequence node's own. ``taps`` start as ``U(-1 /
+    sqrt(K), 1 / sqrt(K))`` (torch's default for a depthwise kernel of
+    ``K`` taps). The convolution is ``causal_depthwise_conv``, the one
+    ``gated_delta`` uses; the two gates and it are made again in the
+    backward pass from ``[B | C | x]`` in the compute dtype."""
+
+    # "short_conv", not "conv": see gated_delta's sub_scopes
+    sub_scopes = ("in_proj", "short_conv", "out_proj")
+
+    def __init__(self, cfg=()):
+        self.conv_kernel = 3
+        super().__init__(cfg)
+
+    def set_param(self, name, val):
+        super().set_param(name, val)
+        if name == "conv_kernel":
+            self.conv_kernel = int(val)
+
+    def infer_shape(self, in_shapes: List[Shape3]) -> List[Shape3]:
+        s = _expect_seq("gated_conv", self._expect_one(in_shapes))
+        if self.conv_kernel <= 0:
+            raise ValueError("gated_conv: conv_kernel must be positive")
+        self.in_shapes = [s]
+        self.out_shapes = [s]
+        return self.out_shapes
+
+    def init_params(self, key):
+        p, d = self.param, self.in_shapes[0].x
+        kin, ktaps, kout = jax.random.split(key, 3)
+        bound = 1.0 / math.sqrt(self.conv_kernel)
+        return {"win": p.rand_init_weight(kin, (d, 3 * d), d, 3 * d),
+                "taps": jax.random.uniform(ktaps, (self.conv_kernel, d), _F32,
+                                           -bound, bound),
+                "wout": p.rand_init_weight(kout, (d, d), d, d)}
+
+    def forward(self, params, state, inputs, is_train, rng):
+        x, cd = inputs[0], self.cd
+        d = x.shape[-1]
+
+        def gated_conv(bcx, taps):
+            b, c, xx = (bcx[..., i * d:(i + 1) * d].astype(_F32)
+                        for i in range(3))
+            return (c * causal_depthwise_conv((b * xx).astype(cd), taps,
+                                              cd)).astype(cd)
+
+        with jax.named_scope("in_proj"):
+            bcx = _dot(x, params["win"], cd)
+        # the passes between the products are made again in the backward
+        # pass from what goes into them, in cd (as gated_delta's)
+        with jax.named_scope("short_conv"):
+            y = jax.checkpoint(gated_conv)(bcx, params["taps"])
+        with jax.named_scope("out_proj"):
+            return [_dot(y, params["wout"], cd)], state
+
+    def flops_per_example(self) -> float:
+        """The two projections and the convolution's taps."""
+        s = self.in_shapes[0]
+        return s.y * (2.0 * s.x * 4 * s.x + 2.0 * self.conv_kernel * s.x)
 
 
 # -- gated delta-rule linear attention ------------------------------------------
@@ -862,16 +988,7 @@ class GatedDeltaLayer(_SeqLayer):
         kw = hk * dk
 
         def conv(qkv, taps):
-            # a channel alone, the taps' last on the position itself and
-            # zeros before the sequence: conv_kernel shifted products,
-            # operands in cd, summed in float32 (as XLA's depthwise
-            # convolution it took 56 ms a layer a step on the chip, nine
-            # times the projections beside it; PERF.md, PR 34)
-            taps = taps.astype(cd).astype(_F32)
-            past = jnp.pad(qkv, [(0, 0), (self.conv_kernel - 1, 0), (0, 0)])
-            qkv = jax.nn.silu(sum(
-                taps[i] * past[:, i:i + t].astype(_F32)
-                for i in range(self.conv_kernel)))
+            qkv = jax.nn.silu(causal_depthwise_conv(qkv, taps, cd))
             unit = lambda a: a * jax.lax.rsqrt(
                 jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
             q = unit(qkv[..., :kw].reshape(b, t, hk, dk)) / math.sqrt(dk)
@@ -1118,10 +1235,10 @@ grouped_swiglu.defvjp(_grouped_fwd, _grouped_bwd)
 
 
 class MoELayer(_SeqLayer):
-    """Routed expert layer with shared experts, a chip's share of it:
-    DeepSeek-V3's (sigmoid scores, ``noaux_tc`` without group limits) and,
-    by two keys, Qwen3-Next's (softmax scores, a gate on the shared
-    expert):
+    """Routed expert layer, with shared experts or (``nshared = 0``:
+    LFM2's) without, a chip's share of it: DeepSeek-V3's (sigmoid scores,
+    ``noaux_tc`` without group limits) and, by two keys, Qwen3-Next's
+    (softmax scores, a gate on the shared expert):
 
         s = sigmoid(x Wr)                      all nexpert, float32
             softmax(x Wr) over them            (score_func = softmax)
@@ -1131,7 +1248,8 @@ class MoELayer(_SeqLayer):
             ... + sigmoid(x w_s) S(x)          (shared_gate = 1)
 
     ``E_i`` is a SwiGLU of width ``nhidden``, ``S`` one SwiGLU of width
-    ``nshared * nhidden``. ``expert_first`` / ``expert_count`` say which
+    ``nshared * nhidden`` (absent, parameters and pass, at ``nshared =
+    0``). ``expert_first`` / ``expert_count`` say which
     experts live here (default: all). The bias is seeded from
     ``bias_seed`` at ``bias_sigma`` (0: no bias) and held fixed (its
     update rate is not part of the published config). State also carries

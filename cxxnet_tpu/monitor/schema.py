@@ -231,12 +231,15 @@ OPTIONAL: Dict[str, tuple] = {
     # their experts as the grouped kernels while a step's routing fits
     # the kernels' row buffers; linear-attention layers (gated_delta),
     # the positions a chunk of their scan along time holds, and how
-    # many of them run that scan as the fused kernels
+    # many of them run that scan as the fused kernels; gated
+    # short-convolution mixers (gated_conv), and whether the head is the
+    # embedding's own matrix (an embed layer shared onto a sequence node)
     "layout": ("attention_layers", "attention_fused_layers",
                "attention_saved_layers", "attention_window_layers",
                "moe_layers", "moe_grouped_layers",
                "linear_attention_layers", "linear_attention_chunk",
-               "linear_attention_fused_layers"),
+               "linear_attention_fused_layers",
+               "short_conv_layers", "head_tied"),
     # the share of the dispatch's passes through an expert layer that
     # did (forward; the other passes took the loop a block at a time)
     "moe": ("grouped_share",),

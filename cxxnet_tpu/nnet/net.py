@@ -545,6 +545,12 @@ class FuncNet:
                 s = layer.in_shapes[0]
                 total += 2 * p.num_input_node * p.num_hidden \
                     * (s.y if s.is_seq else 1)
+            elif t == "embed":
+                # rows looked up cost nothing; applied to a sequence node
+                # it is the tied head, h E^T at every position
+                s = self.node_shapes[g.layers[li].nindex_in[0]]
+                if s.is_seq:
+                    total += 2 * s.y * s.x * layer.nvocab
             elif hasattr(layer, "flops_per_example"):
                 total += layer.flops_per_example()
         return float(total)

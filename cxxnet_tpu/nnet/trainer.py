@@ -1616,6 +1616,14 @@ class NetTrainer:
                        linear_attention_chunk=max(chunks, default=0),
                        linear_attention_fused_layers=sum(
                            layer.fused_scan for layer in linear),
+                       # gated short-convolution mixers (gated_conv), and
+                       # whether the head is the embedding's own matrix
+                       # (an embed layer applied to a sequence node)
+                       short_conv_layers=sum(
+                           info.type == "gated_conv"
+                           for info in net.graph.layers),
+                       head_tied=any(getattr(layer, "tied_head", False)
+                                     for layer in net.layer_objs),
                        **net.layout_summary)
         if self.quant_report.get("active"):
             r = self.quant_report

@@ -101,7 +101,9 @@ def test_the_public_call_takes_its_tiles_from_the_shapes():
     (8192, 1024, (8, 4), 8, False),         # its widths at the cell's length
     (8200, 1024, (128, 64), 128, False),    # no tile divides the length
     (8192, 64, (128, 64), 128, False),      # q_block under the smallest tile
-    (8192, 1024, (128, 64), 64, False),     # values of half a lane
+    (8192, 1024, (128, 64), 64, True),      # values of one half-lane
+    (8192, 1024, (128, 64), 32, False),     # ... but no narrower
+    (8192, 1024, (128, 64), 192, False),    # ... and no lane and a half
     (32768, 1024, (128, 64), 128, False),   # dQ of a sequence outgrows VMEM
 ])
 def test_applicable_is_a_function_of_the_shapes(time, q_block, qk, v, fits):

@@ -455,3 +455,77 @@ def test_grouped_window_attention_compiles_at_trinitys_shapes(topo, window):
         .lower(*args).compile()
     assert compiled.as_text().count("tpu_custom_call") == 2
     assert [x.shape for x in compiled.out_info] == [a.shape for a in args]
+
+
+@pytest.mark.parametrize("kind", ["gated_conv", "gqa_attention", "core",
+                                  "moe"])
+def test_lfm2_layers_compile_for_one_v5e_at_the_cells_shapes(topo, kind):
+    """What ``lfm2_24b_a2b.train_tokens_8k`` adds (2 x 8,192 positions,
+    hidden 2,048, bfloat16), forward and backward. The short-convolution
+    mixer: no XLA convolution over time is left (the three shifted
+    products fuse; as a depthwise convolution the pass took nine times
+    the projections beside it, PERF.md, PR 34) and what the backward
+    pass holds stays under 1 GB (0.54). The attention layer, 32 query heads on
+    8 key/value heads of 64 features, no gate: the fused kernel takes
+    the 64-wide values as they are and no float32 block of scores exists
+    outside it. The kernel alone at those heads: two custom calls, the
+    gradients in the operands' shapes. The expert layer with no shared
+    expert at width 1,536: the grouped kernels fit their 96 MiB and no
+    ``shared`` scope is opened."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from cxxnet_tpu.layers import create_layer, seq_shape
+    from cxxnet_tpu.layers import pallas_kernels as pk
+    one = SingleDeviceSharding(topo.devices[0])
+    bf, f32 = jnp.bfloat16, jnp.float32
+
+    def on(shape, dtype=bf):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    if kind == "core":
+        b, h, g, t, d = 2, 32, 8, 8192, 64
+        assert pk.causal_attention_applicable(t, 1024, (d,), d, h, g)
+        args = (on((b, h, t, d)), on((b, g, t, d)), on((b, g, t, d)))
+        compiled = jax.jit(jax.grad(lambda q, k, v: jnp.sum(
+            pk.causal_attention((q,), (k,), v, d ** -0.5, 1024).astype(f32)),
+            argnums=(0, 1, 2))).lower(*args).compile()
+        assert compiled.as_text().count("tpu_custom_call") == 2
+        assert [x.shape for x in compiled.out_info] == [a.shape for a in args]
+        return
+    cfg = {"gated_conv": dict(conv_kernel=3),
+           "gqa_attention": dict(nhead=32, nkvhead=8, head_dim=64, window=0,
+                                 rope=1, gate=0, rope_theta=1e6, eps=1e-5,
+                                 q_block=1024),
+           "moe": dict(nexpert=64, topk=4, nhidden=1536, nshared=0,
+                       routed_scaling_factor=1, expert_count=8,
+                       expert_block=512, bias_sigma=0.01)}[kind]
+    layer = create_layer(kind, [(k, str(v)) for k, v in cfg.items()]
+                         + [("dtype", "bfloat16")])
+    layer.infer_shape([seq_shape(8192, 2048)])
+    params = jax.tree.map(lambda a: on(a.shape, a.dtype), jax.eval_shape(
+        layer.init_params, jax.random.PRNGKey(0)))
+    state = jax.tree.map(lambda a: on(a.shape, a.dtype),
+                         jax.eval_shape(layer.init_state))
+    compiled = jax.jit(jax.grad(lambda p, s, x: jnp.sum(layer.forward(
+        p, s, [x], True, None)[0][0].astype(f32)), argnums=(0, 2))).lower(
+            params, state, on((2, 8192, 2048))).compile()
+    text = compiled.as_text()
+    if kind == "gated_conv":
+        # the projections are the only convolutions XLA sees
+        convs = [re.search(r'op_name="([^"]*)"', line).group(1)
+                 for line in text.splitlines() if " convolution(" in line]
+        assert len(convs) == 5 and all(
+            "in_proj" in n or "out_proj" in n for n in convs), convs
+        assert "feature_group_count" not in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+    elif kind == "gqa_attention":
+        assert layer.fused_core and "wg" not in params
+        assert text.count("tpu_custom_call") >= 2
+        scores = re.findall(r"f32\[(?:2,32|64),\d{4,},\d{4,}\]", text)
+        assert not scores, sorted(set(scores))
+    else:
+        assert layer.grouped and layer.budget(2 * 8192) == 56
+        assert "tpu_custom_call" in text and "(shared)" not in text
+        assert " conditional(" not in text
