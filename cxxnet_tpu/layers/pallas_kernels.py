@@ -1191,15 +1191,15 @@ def _delta_specs(nt, bt, dk, r, dv, c, backward):
                          lambda b, h, i: (b, h, 0, at(i), 0, 0)))
 
 
-def _delta_call(kernel, ins, specs, outs, out_specs, scratch, grid):
+def _delta_call(kernel, ins, specs, outs, out_specs, scratch, grid,
+                semantics=("parallel", "parallel", "arbitrary")):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     return pl.pallas_call(
         kernel, grid=grid, in_specs=specs, out_specs=out_specs,
         out_shape=outs, scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=_DELTA_VMEM),
+            dimension_semantics=semantics, vmem_limit_bytes=_DELTA_VMEM),
         interpret=_build_interpret(),
     )(*ins)
 
@@ -1291,6 +1291,274 @@ def gated_delta_applicable(time: int, chunk: int, dk: int, dv: int, r: int,
         + r * (bt // c) * dk * dv * size + 4 * 8 * bt * 4
     scratch = 4 * r * (dk * dv + 4 * bt * _LANES)
     return 2 * blocks + scratch <= _DELTA_VMEM // 2
+
+
+# ------------------------------------------- gated delta rule's short convolution
+
+_CONV_TILES = (1024, 512, 256, 128)
+_CONV_ROWS = 64     # positions a step of the loop inside a tile
+_CONV_BACK = 8      # rows a step reads before (forward) or after itself
+_HALO = 16          # the rows of a bfloat16 block before a tile
+
+
+def _conv_lanes(kw: int, vw: int, dk: int) -> int:
+    """Lanes a grid step of the convolution's kernels takes: the widest
+    of 512, 256, 128 that is whole key heads and divides the key and the
+    value widths (0: none is)."""
+    return next((w for w in (512, 256, 128)
+                 if w % dk == 0 and kw % w == 0 and vw % w == 0), 0)
+
+
+def _conv_tile(time: int, w: int, dtype) -> int:
+    """Positions a grid step takes: the largest of ``_CONV_TILES`` that
+    divides ``time`` and whose backward blocks (twice over: x, dq, dk,
+    dv and dx, the halo, the taps and their gradient) and scratch fit
+    half of the kernels' VMEM (0: none does)."""
+    size = jnp.dtype(dtype).itemsize
+    for bt in _CONV_TILES:
+        blocks = (5 * bt + _HALO) * w * size + 2 * 8 * w * 4
+        if time % bt == 0 and 2 * blocks + 2 * (bt + _HALO) * w * 4 \
+                <= _DELTA_VMEM // 2:
+            return bt
+    return 0
+
+
+def _conv_index(lo, n, nt, backward):
+    """Index map of one of q, k, v, ``n`` column blocks from the grid's
+    column ``lo``, over (batch, column block, time tile), the time tiles
+    last to first where ``backward``. Outside its columns it points at
+    the block it visits first (before) or last (after), so that Pallas,
+    which moves a block when its index changes, neither writes back an
+    output block nor fetches an input one in between."""
+    at = (lambda i: nt - 1 - i) if backward else (lambda i: i)
+
+    def index(b, j, i):
+        c = j - lo
+        return b, jnp.where(c < 0, at(0), jnp.where(c >= n, at(nt - 1),
+                                                    at(i))), \
+            jnp.clip(c, 0, n - 1)
+    return index
+
+
+def _conv_taps(ext_ref, r0, cols, taps):
+    """The taps' products for the ``_CONV_ROWS`` positions from ``r0``
+    of a tile held in ``ext_ref`` from row ``_HALO`` (the positions
+    before it above), float32 and in tap order as
+    layers/sequence.py: causal_depthwise_conv sums them; and the
+    shifted inputs, ``x_(t - K + 1 + m)`` for tap ``m``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    k = len(taps)
+    both = ext_ref[pl.ds(r0 + _HALO - _CONV_BACK, _CONV_ROWS + _CONV_BACK),
+                   cols]
+    xs = [(pltpu.roll(both, k - 1 - m, 0) if m < k - 1 else both)
+          [_CONV_BACK:] for m in range(k)]
+    return sum(taps[m][:, cols] * xs[m] for m in range(k)), xs
+
+
+def _conv_fwd_kernel(nq, dk, x_ref, taps_ref, q_ref, k_ref, v_ref, ext_ref):
+    """One (batch, column block, time tile) step of gated_delta's short
+    convolution (layers/sequence.py: GatedDeltaLayer), the time tiles in
+    order: the taps, SiLU and, in q's and k's columns, the unit length a
+    head of ``dk`` lanes (q's over ``sqrt(dk)``), written in the inputs'
+    dtype to whichever of q, k, v the column block belongs. The
+    ``_HALO`` positions before a tile come from the tile before it in
+    VMEM scratch, zeros before the first. The work goes in chunks of
+    ``_CONV_ROWS`` positions and a head, so that what a chunk computes
+    stays in registers."""
+    from jax.experimental import pallas as pl
+    f32 = jnp.float32
+    bt, w = x_ref.shape
+    taps = [taps_ref[m:m + 1, :].astype(x_ref.dtype).astype(f32)
+            for m in range(taps_ref.shape[0])]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        ext_ref[bt:, :] = jnp.zeros((_HALO, w), f32)
+
+    ext_ref[:_HALO, :] = ext_ref[bt:, :]
+    ext_ref[_HALO:, :] = x_ref[...].astype(f32)
+
+    def part(out_ref, unit, scale):
+        def chunk(n, carry):
+            r0 = pl.multiple_of(n * _CONV_ROWS, _CONV_ROWS)
+            for h in range(w // dk):
+                cols = slice(h * dk, (h + 1) * dk)
+                y, _ = _conv_taps(ext_ref, r0, cols, taps)
+                a = y * jax.nn.sigmoid(y)
+                if unit:
+                    a = a * jax.lax.rsqrt(jnp.sum(
+                        a * a, axis=1, keepdims=True) + 1e-6) * scale
+                out_ref[pl.ds(r0, _CONV_ROWS), cols] = a.astype(out_ref.dtype)
+            return carry
+        jax.lax.fori_loop(0, bt // _CONV_ROWS, chunk, 0)
+
+    j = pl.program_id(1)
+    pl.when(j < nq)(lambda: part(q_ref, True, 1.0 / float(np.sqrt(dk))))
+    pl.when((j >= nq) & (j < 2 * nq))(lambda: part(k_ref, True, 1.0))
+    pl.when(j >= 2 * nq)(lambda: part(v_ref, False, 1.0))
+
+
+def _conv_bwd_kernel(nq, dk, x_ref, before_ref, taps_ref, dq_ref, dk_ref,
+                     dv_ref, dx_ref, dt_ref, ext_ref, dy_ref):
+    """The same step backward, the time tiles and a tile's chunks last to
+    first. The taps, SiLU and the norm are made again from the inputs (the
+    ``_HALO`` positions before a tile are a block of their own); the
+    gradient before the taps, ``dy``, goes to VMEM scratch, whose rows
+    after the tile hold the first ``_HALO`` of the tile after it (zeros
+    after the last), which the input's gradient reads: it is
+    anti-causal. The taps' gradient is summed over the tiles in float32,
+    in a block a (batch, column block)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    f32 = jnp.float32
+    bt, w = x_ref.shape
+    nk = taps_ref.shape[0]
+    span = _CONV_ROWS + _CONV_BACK
+    taps = [taps_ref[m:m + 1, :].astype(x_ref.dtype).astype(f32)
+            for m in range(nk)]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dy_ref[bt:, :] = jnp.zeros((_HALO, w), f32)
+        dt_ref[...] = jnp.zeros(dt_ref.shape, f32)
+
+    ext_ref[:_HALO, :] = before_ref[...].astype(f32)
+
+    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
+    def _():
+        ext_ref[:_HALO, :] = jnp.zeros((_HALO, w), f32)
+
+    ext_ref[_HALO:, :] = x_ref[...].astype(f32)
+
+    def part(g_ref, unit, scale):
+        def chunk(m, carry):
+            r0 = pl.multiple_of((bt // _CONV_ROWS - 1 - m) * _CONV_ROWS,
+                                _CONV_ROWS)
+            rows = pl.ds(r0, _CONV_ROWS)
+            for h in range(w // dk):
+                cols = slice(h * dk, (h + 1) * dk)
+                y, xs = _conv_taps(ext_ref, r0, cols, taps)
+                sg = jax.nn.sigmoid(y)
+                g = g_ref[rows, cols].astype(f32) * scale
+                if unit:
+                    a = y * sg
+                    s = jax.lax.rsqrt(jnp.sum(a * a, axis=1, keepdims=True)
+                                      + 1e-6)
+                    g = s * g - a * (s * s * s) * jnp.sum(
+                        g * a, axis=1, keepdims=True)
+                dy = g * (sg * (1.0 + y * (1.0 - sg)))
+                dy_ref[rows, cols] = dy
+                for t in range(nk):
+                    dt_ref[t:t + 1, cols] += jnp.sum(dy * xs[t], axis=0,
+                                                     keepdims=True)
+                after = dy_ref[pl.ds(r0, span), cols]
+                dx_ref[rows, cols] = sum(
+                    taps[t][:, cols] * (pltpu.roll(after, span - nk + 1 + t, 0)
+                                        if t < nk - 1 else after)
+                    [:_CONV_ROWS] for t in range(nk)).astype(dx_ref.dtype)
+            return carry
+        jax.lax.fori_loop(0, bt // _CONV_ROWS, chunk, 0)
+
+    j = pl.program_id(1)
+    pl.when(j < nq)(lambda: part(dq_ref, True, 1.0 / float(np.sqrt(dk))))
+    pl.when((j >= nq) & (j < 2 * nq))(lambda: part(dk_ref, True, 1.0))
+    pl.when(j >= 2 * nq)(lambda: part(dv_ref, False, 1.0))
+    dy_ref[bt:, :] = dy_ref[:_HALO, :]
+
+
+def _conv_plan(qkv, kw, dk, backward):
+    """(grid, the three parts' block specs, a tile's block spec, tile,
+    lanes) of the convolution's kernels."""
+    from jax.experimental import pallas as pl
+    b, t, c = qkv.shape
+    w = _conv_lanes(kw, c - 2 * kw, dk)
+    bt = _conv_tile(t, w, qkv.dtype)
+    nq, nt = kw // w, t // bt
+    at = (lambda i: nt - 1 - i) if backward else (lambda i: i)
+    parts = [pl.BlockSpec((None, bt, w), _conv_index(lo, n, nt, backward))
+             for lo, n in ((0, nq), (nq, nq), (2 * nq, c // w - 2 * nq))]
+    return (b, c // w, nt), parts, \
+        pl.BlockSpec((None, bt, w), lambda b, j, i: (b, at(i), j)), bt, w
+
+
+_CONV_SEMANTICS = ("parallel", "arbitrary", "arbitrary")
+
+
+def _conv_fwd_call(qkv, taps, kw, dk):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    grid, parts, tile, bt, w = _conv_plan(qkv, kw, dk, False)
+    b, t, c = qkv.shape
+    return _delta_call(
+        partial(_conv_fwd_kernel, kw // w, dk), (qkv, taps),
+        [tile, pl.BlockSpec((taps.shape[0], w), lambda b, j, i: (0, j))],
+        [jax.ShapeDtypeStruct((b, t, n), qkv.dtype)
+         for n in (kw, kw, c - 2 * kw)], parts,
+        [pltpu.VMEM((bt + _HALO, w), jnp.float32)], grid, _CONV_SEMANTICS)
+
+
+def _conv_bwd_call(qkv, taps, dq, dk_, dv, kw, dk):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    grid, parts, tile, bt, w = _conv_plan(qkv, kw, dk, True)
+    nk, nt, per = taps.shape[0], grid[2], bt // _HALO
+    return _delta_call(
+        partial(_conv_bwd_kernel, kw // w, dk), (qkv, qkv, taps, dq, dk_, dv),
+        [tile, pl.BlockSpec((None, _HALO, w), lambda b, j, i: (
+            b, jnp.maximum((nt - 1 - i) * per - 1, 0), j)),
+         pl.BlockSpec((nk, w), lambda b, j, i: (0, j))] + parts,
+        [jax.ShapeDtypeStruct(qkv.shape, qkv.dtype),
+         jax.ShapeDtypeStruct((qkv.shape[0], nk, qkv.shape[2]), jnp.float32)],
+        [tile, pl.BlockSpec((None, nk, w), lambda b, j, i: (b, 0, j))],
+        [pltpu.VMEM((bt + _HALO, w), jnp.float32)] * 2, grid, _CONV_SEMANTICS)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def gated_delta_conv(qkv, taps, kw: int, dk: int):
+    """``gated_delta``'s short convolution as one fused kernel a
+    direction: ``qkv`` ``(batch, time, 2 kw + vw)`` in the compute dtype
+    (the projection's output), ``taps`` ``(K, 2 kw + vw)``; returns q,
+    k ``(batch, time, kw)`` and v ``(batch, time, vw)`` in ``qkv``'s
+    dtype, a head's features side by side (the layout
+    :func:`gated_delta_scan` reads): ``silu(causal depthwise
+    convolution)``, q and k of unit length a head of ``dk``, q over
+    ``sqrt(dk)``. The arithmetic of layers/sequence.py's XLA form
+    (``short_conv``) to float32 rounding, the taps summed in its order;
+    the backward kernel makes it again from ``qkv``
+    and ``taps``, so nothing else is kept for it. The taps' gradient
+    is rounded through the compute dtype, as the XLA form's cast of the
+    taps has it."""
+    return tuple(_conv_fwd_call(qkv, taps, kw, dk))
+
+
+def _gated_delta_conv_fwd(qkv, taps, kw, dk):
+    return tuple(_conv_fwd_call(qkv, taps, kw, dk)), (qkv, taps)
+
+
+def _gated_delta_conv_bwd(kw, dk, res, g):
+    qkv, taps = res
+    dqkv, dt = _conv_bwd_call(qkv, taps, *g, kw, dk)
+    return dqkv, jnp.sum(dt, axis=0).astype(qkv.dtype).astype(taps.dtype)
+
+
+gated_delta_conv.defvjp(_gated_delta_conv_fwd, _gated_delta_conv_bwd)
+
+
+def gated_delta_conv_applicable(time: int, kernel: int, hk: int, hv: int,
+                                dk: int, dv: int, dtype) -> bool:
+    """Shape gate of :func:`gated_delta_conv`: key and value heads of
+    whole lanes (128) whose widths a block of whole key heads divides, at
+    most ``_CONV_BACK + 1`` taps, a ``time`` that a time tile divides
+    with the kernels' blocks and scratch within half of their VMEM, and
+    bfloat16 or float32 operands."""
+    if min(time, kernel, hk, hv, dk, dv) <= 0 or dk % _LANES \
+            or dv % _LANES or kernel - 1 > _CONV_BACK \
+            or jnp.dtype(dtype) not in (jnp.dtype(jnp.bfloat16),
+                                        jnp.dtype(jnp.float32)):
+        return False
+    w = _conv_lanes(hk * dk, hv * dv, dk)
+    return bool(w and _conv_tile(time, w, dtype))
 
 
 # ------------------------------------------- grouped expert products

@@ -888,6 +888,24 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int, cd, fused=None):
     return o.transpose(1, 0, 4, 2, 3, 5).reshape(b, n * c, hv, dv)[:, :t]
 
 
+def short_conv(qkv, taps, hk: int, dk: int, dv: int, cd):
+    """``gated_delta``'s short convolution in XLA: ``silu(causal
+    depthwise convolution)`` of ``[q | k | v]`` (``causal_depthwise_conv``),
+    q and k of unit length a head of ``dk`` and q over ``sqrt(dk)``;
+    returns q, k ``(batch, time, hk, dk)`` and v ``(batch, time, value
+    heads, dv)`` in ``cd``. The form for shapes the fused kernel
+    (``pallas_kernels.gated_delta_conv``) does not take."""
+    b, t, _ = qkv.shape
+    kw = hk * dk
+    qkv = jax.nn.silu(causal_depthwise_conv(qkv, taps, cd))
+    unit = lambda a: a * jax.lax.rsqrt(
+        jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+    q = unit(qkv[..., :kw].reshape(b, t, hk, dk)) / math.sqrt(dk)
+    k = unit(qkv[..., kw:2 * kw].reshape(b, t, hk, dk))
+    return q.astype(cd), k.astype(cd), \
+        qkv[..., 2 * kw:].reshape(b, t, -1, dv).astype(cd)
+
+
 class GatedDeltaLayer(_SeqLayer):
     """Gated DeltaNet's mixer as Qwen3-Next has it, causal, no biases:
 
@@ -913,7 +931,13 @@ class GatedDeltaLayer(_SeqLayer):
     or 128 and the padded sequence is whole time tiles of 128 positions
     or more (Qwen3-Next's published widths), the XLA form everywhere
     else. The ``layout`` record's ``linear_attention_fused_layers`` says
-    how many layers took the kernels."""
+    how many layers took the kernels. So with the short convolution
+    (``fused_conv``): one fused kernel a direction
+    (``pallas_kernels.gated_delta_conv``) where the heads are whole
+    lanes, the taps at most nine and the sequence whole time tiles,
+    writing q, k and v in the layout the scan's kernels read; the XLA
+    form (``short_conv``) elsewhere. ``linear_attention_fused_conv_layers``
+    counts those."""
 
     # "short_conv", not "conv": a reduction that reads an op's innermost
     # scope as a layer type would count it as a convolution layer
@@ -928,6 +952,7 @@ class GatedDeltaLayer(_SeqLayer):
         self.chunk = 64
         self.eps = 1e-6
         self.fused_scan = False
+        self.fused_conv = False
         super().__init__(cfg)
 
     def set_param(self, name, val):
@@ -950,6 +975,9 @@ class GatedDeltaLayer(_SeqLayer):
         self.fused_scan = pallas_kernels.gated_delta_applicable(
             s.y, self.chunk, self.key_dim, self.value_dim,
             self.nvhead // self.nkhead, self.cd)
+        self.fused_conv = pallas_kernels.gated_delta_conv_applicable(
+            s.y, self.conv_kernel, self.nkhead, self.nvhead, self.key_dim,
+            self.value_dim, self.cd)
         self.in_shapes = [s]
         self.out_shapes = [s]
         return self.out_shapes
@@ -985,16 +1013,6 @@ class GatedDeltaLayer(_SeqLayer):
             beta = jax.nn.sigmoid(_dot(x, params["wb"], cd).astype(_F32))
             g = -jnp.exp(params["alog"]) * jax.nn.softplus(
                 _dot(x, params["wa"], cd).astype(_F32) + params["dtbias"])
-        kw = hk * dk
-
-        def conv(qkv, taps):
-            qkv = jax.nn.silu(causal_depthwise_conv(qkv, taps, cd))
-            unit = lambda a: a * jax.lax.rsqrt(
-                jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
-            q = unit(qkv[..., :kw].reshape(b, t, hk, dk)) / math.sqrt(dk)
-            k = unit(qkv[..., kw:2 * kw].reshape(b, t, hk, dk))
-            return q.astype(cd), k.astype(cd), \
-                qkv[..., 2 * kw:].reshape(b, t, hv, dv).astype(cd)
 
         def gate_norm(o, z, scale):
             return (rms_norm(o.astype(_F32), scale, self.eps) * jax.nn.silu(
@@ -1004,7 +1022,14 @@ class GatedDeltaLayer(_SeqLayer):
         # pass from what goes into them, in cd (their float32 insides,
         # held for it, were most of what a layer's backward pass held)
         with jax.named_scope("short_conv"):
-            q, k, v = jax.checkpoint(conv)(qkv, params["conv"])
+            if self.fused_conv:
+                q, k, v = (a.reshape(b, t, -1, d) for a, d in zip(
+                    pallas_kernels.gated_delta_conv(
+                        qkv, params["conv"], hk * dk, dk), (dk, dk, dv)))
+            else:
+                q, k, v = jax.checkpoint(functools.partial(
+                    short_conv, hk=hk, dk=dk, dv=dv, cd=cd))(
+                        qkv, params["conv"])
         with jax.named_scope("scan"):
             o = gated_delta_rule(q, k, v, g, beta, self.chunk, cd,
                                  self.fused_scan)

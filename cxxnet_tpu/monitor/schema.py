@@ -230,8 +230,9 @@ OPTIONAL: Dict[str, tuple] = {
     # many see a window of keys; moe layers, and how many of them run
     # their experts as the grouped kernels while a step's routing fits
     # the kernels' row buffers; linear-attention layers (gated_delta),
-    # the positions a chunk of their scan along time holds, and how
-    # many of them run that scan as the fused kernels; gated
+    # the positions a chunk of their scan along time holds, how many of
+    # them run that scan as the fused kernels and how many their short
+    # convolution as the fused kernel; gated
     # short-convolution mixers (gated_conv), and whether the head is the
     # embedding's own matrix (an embed layer shared onto a sequence node)
     "layout": ("attention_layers", "attention_fused_layers",
@@ -239,6 +240,7 @@ OPTIONAL: Dict[str, tuple] = {
                "moe_layers", "moe_grouped_layers",
                "linear_attention_layers", "linear_attention_chunk",
                "linear_attention_fused_layers",
+               "linear_attention_fused_conv_layers",
                "short_conv_layers", "head_tied"),
     # the share of the dispatch's passes through an expert layer that
     # did (forward; the other passes took the loop a block at a time)

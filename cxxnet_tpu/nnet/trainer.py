@@ -1616,6 +1616,10 @@ class NetTrainer:
                        linear_attention_chunk=max(chunks, default=0),
                        linear_attention_fused_layers=sum(
                            layer.fused_scan for layer in linear),
+                       # those whose short convolution is the fused kernel
+                       # (pallas_kernels.gated_delta_conv)
+                       linear_attention_fused_conv_layers=sum(
+                           layer.fused_conv for layer in linear),
                        # gated short-convolution mixers (gated_conv), and
                        # whether the head is the embedding's own matrix
                        # (an embed layer applied to a sequence node)

@@ -321,6 +321,64 @@ def test_qwen3_next_mixers_compile_for_one_v5e_at_the_cells_shapes(topo, kind):
     assert compiled.memory_analysis().temp_size_in_bytes < 3e9
 
 
+@pytest.mark.parametrize("kind", ["kernels", "layer"])
+def test_gated_delta_conv_compiles_for_one_v5e_at_the_cells_shapes(topo, kind):
+    """The short convolution of ``qwen3_next.train_tokens_8k``'s
+    linear-attention layers (2 x 8,192 positions, 16 key heads and 32
+    value heads of 128, four taps, bfloat16) as the fused kernels
+    (layers/pallas_kernels.py: gated_delta_conv): alone, a forward and a
+    backward custom call and the gradients in the operands' shapes. The
+    whole mixer's gradient under a ``remat = block`` checkpoint: the
+    convolution's forward kernel twice and backward once, the scan's
+    kernels once each; no float32 relayout of q or k and no copy of
+    ``qkv`` (the XLA form made them between its head-tiled arrays and
+    the scan's blocks: 2.71 GB of copies a layer, now 0.89), and what
+    the backward pass holds under 2.5 GB (2.03; the XLA form 2.70)."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from cxxnet_tpu.layers import create_layer, seq_shape
+    from cxxnet_tpu.layers import pallas_kernels as pk
+    from cxxnet_tpu.layers.base import BLOCK_REMAT_KEEPS
+    one = SingleDeviceSharding(topo.devices[0])
+    bf, f32 = jnp.bfloat16, jnp.float32
+
+    def on(shape, dtype=bf):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    if kind == "kernels":
+        assert pk.gated_delta_conv_applicable(8192, 4, 16, 32, 128, 128, bf)
+        args = (on((2, 8192, 8192)), on((4, 8192), f32))
+        step = jax.jit(jax.value_and_grad(lambda qkv, taps: sum(
+            jnp.sum(o.astype(f32)) for o in pk.gated_delta_conv(
+                qkv, taps, 2048, 128)), argnums=(0, 1)))
+        compiled = step.lower(*args).compile()
+        assert compiled.as_text().count("tpu_custom_call") == 2
+        assert [x.shape for x in compiled.out_info[1]] == [
+            a.shape for a in args]
+        return
+    layer = create_layer("gated_delta", [(k, str(v)) for k, v in dict(
+        nkhead=16, nvhead=32, key_dim=128, value_dim=128, conv_kernel=4,
+        chunk=64, eps=1e-6, dtype="bfloat16").items()])
+    layer.infer_shape([seq_shape(8192, 2048)])
+    assert layer.fused_scan and layer.fused_conv
+    params = jax.tree.map(lambda a: on(a.shape, a.dtype), jax.eval_shape(
+        layer.init_params, jax.random.PRNGKey(0)))
+    seg = jax.checkpoint(
+        lambda p, x: layer.forward(p, {}, [x], True, None)[0][0],
+        policy=jax.checkpoint_policies.save_only_these_names(
+            *BLOCK_REMAT_KEEPS))
+    compiled = jax.jit(jax.grad(lambda p, x: jnp.sum(seg(p, x).astype(
+        f32)), argnums=(0, 1))).lower(params, on((2, 8192, 2048))).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 5
+    copies = re.findall(r"= (\w+)\[([0-9,]+)\]\{[^}]*\} copy\(", text)
+    assert not [c for c in copies if c[1] == "2,8192,8192" or (
+        c[0] == "f32" and c[1] == "2048,8,16,128")], copies
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
 def _loop_bodies(text, scope):
     """``{scope path: [text reachable from the body of each loop]}`` of
     the outermost ``while`` instructions of an HLO module whose op_name

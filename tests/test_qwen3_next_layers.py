@@ -198,6 +198,7 @@ def test_a_refused_shape_runs_the_xla_form_to_the_bit():
         layer, p, st = _layer("gated_delta", dict(
             cfg, key_dim=width, value_dim=width), seq_shape(128, D))
         assert layer.fused_scan is fused
+        assert layer.fused_conv is layer.fused_scan
         text = str(jax.make_jaxpr(lambda x: layer.forward(
             p, st, [x], True, None)[0][0])(_x(t=128)))
         assert ("pallas_call" in text) is fused
@@ -238,7 +239,7 @@ def test_fused_gated_delta_layer_matches_the_reference():
     layer, p, st = _layer("gated_delta", dict(
         nkhead=1, nvhead=2, key_dim=128, value_dim=128, conv_kernel=4,
         chunk=64, eps=1e-6, init_sigma=0.3), seq_shape(128, D))
-    assert layer.fused_scan
+    assert layer.fused_scan and layer.fused_conv
     x, w = _x(t=128), _x(9, t=128)
     plain = lambda p, x: jnp.stack([ref.gated_delta_net(
         p, x[b], cfg, None, 0, False) for b in range(x.shape[0])])
@@ -250,6 +251,54 @@ def test_fused_gated_delta_layer_matches_the_reference():
     for a, b in zip(jax.tree_util.tree_leaves(gf),
                     jax.tree_util.tree_leaves(gg)):
         _close(a, b, 1e-4)
+
+
+@pytest.mark.parametrize("t,taps,hk,hv,dk,dv,dtype,takes", [
+    (8192, 4, 16, 32, 128, 128, "bfloat16", True),    # the cell's
+    (8192, 4, 16, 32, 128, 128, "float32", True),
+    (128, 4, 1, 2, 128, 128, "float32", True),        # one tile of 128
+    (8192, 9, 16, 32, 128, 128, "bfloat16", True),    # nine taps
+    (8192, 10, 16, 32, 128, 128, "bfloat16", False),  # past what a step reads
+    (8192, 4, 16, 32, 64, 128, "bfloat16", False),    # half a lane of key
+    (8192, 4, 16, 32, 128, 64, "bfloat16", False),    # half a lane of value
+    (8192, 4, 2, 2, 384, 128, "bfloat16", False),     # no block of whole heads
+    (8000, 4, 16, 32, 128, 128, "bfloat16", False),   # no tile divides it
+    (8192, 4, 16, 32, 128, 128, "float16", False),
+    (16, 4, 2, 4, 8, 6, "float32", False)])           # the tiny model's
+def test_gated_delta_conv_gate(t, taps, hk, hv, dk, dv, dtype, takes):
+    from cxxnet_tpu.layers.pallas_kernels import gated_delta_conv_applicable
+    assert gated_delta_conv_applicable(t, taps, hk, hv, dk, dv,
+                                       jnp.dtype(dtype)) is takes
+
+
+def test_a_block_runs_the_conv_kernels_forward_twice():
+    """Under a ``remat = block`` segment's checkpoint the whole mixer's
+    gradient holds the convolution's forward kernel twice (the step's
+    forward and the segment's recomputation) and its backward kernel
+    once, which makes what it needs again itself; the scan's kernels
+    once each. As the XLA form under ``jax.checkpoint`` the convolution
+    ran forward three times."""
+    from test_block_remat_keeps import _eqns
+    from cxxnet_tpu.layers.base import BLOCK_REMAT_KEEPS
+    layer, p, st = _layer("gated_delta", dict(
+        nkhead=1, nvhead=2, key_dim=128, value_dim=128, conv_kernel=4,
+        chunk=64, eps=1e-6, init_sigma=0.3), seq_shape(128, D))
+    seg = jax.checkpoint(
+        lambda p, x: layer.forward(p, st, [x], True, None)[0][0],
+        policy=jax.checkpoint_policies.save_only_these_names(
+            *BLOCK_REMAT_KEEPS))
+    grad = jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(seg(p, x)),
+                                   argnums=(0, 1)))(p, _x(t=128))
+    # a kernel by its outputs: the convolution's forward q, k, v and
+    # backward d(qkv), the taps' partials; the scan's forward o, a state
+    # a chunk (6-D), u, and backward six gradients
+    kind = {(3, 3): "conv forward", (2, 3): "conv backward",
+            (3, 6): "scan forward", (6, 5): "scan backward"}
+    names = sorted(kind[len(e.params["out_avals"]), max(
+        len(a.shape) for a in e.params["out_avals"])]
+        for e in _eqns(grad.jaxpr) if e.primitive.name == "pallas_call")
+    assert names == ["conv backward", "conv forward", "conv forward",
+                     "scan backward", "scan forward"], names
 
 
 def test_the_solve_inverts_a_unit_lower_triangle():

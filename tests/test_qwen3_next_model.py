@@ -148,7 +148,8 @@ def test_records_count_the_linear_attention_layers_and_name_their_parts():
     # (heads of 8 x 6 in chunks of 4: the gate of the fused scan refuses)
     assert (layout["linear_attention_layers"],
             layout["linear_attention_chunk"],
-            layout["linear_attention_fused_layers"]) == (3, 4, 0)
+            layout["linear_attention_fused_layers"],
+            layout["linear_attention_fused_conv_layers"]) == (3, 4, 0, 0)
     assert (layout["attention_layers"], layout["attention_fused_layers"],
             layout["attention_window_layers"]) == (1, 0, 0)
     assert (layout["moe_layers"], layout["moe_grouped_layers"]) == (4, 0)
@@ -172,7 +173,8 @@ def test_records_count_the_linear_attention_layers_and_name_their_parts():
     plain.set_monitor(Monitor(sink2))
     (rec,) = [r for r in sink2.records if r["event"] == "layout"]
     assert (rec["linear_attention_layers"], rec["linear_attention_chunk"],
-            rec["linear_attention_fused_layers"]) == (0, 0, 0)
+            rec["linear_attention_fused_layers"],
+            rec["linear_attention_fused_conv_layers"]) == (0, 0, 0, 0)
 
 
 def test_records_count_the_layers_whose_scan_is_the_fused_kernels():
@@ -194,8 +196,8 @@ def test_records_count_the_layers_whose_scan_is_the_fused_kernels():
         init_sigma=0.3, lr=0.01))
         + [("dtype", "bfloat16"), ("seed", "3"), ("silent", "1")])
     t.init_model()
-    assert [l.fused_scan for l in t.net.layer_objs
-            if hasattr(l, "fused_scan")] == [True] * 3
+    assert [(l.fused_scan, l.fused_conv) for l in t.net.layer_objs
+            if hasattr(l, "fused_scan")] == [(True, True)] * 3
     sink = MemorySink()
     t.set_monitor(Monitor(sink))
     ids = np.random.RandomState(0).randint(0, 64, (2, 129))
@@ -209,7 +211,8 @@ def test_records_count_the_layers_whose_scan_is_the_fused_kernels():
     (layout,) = [r for r in sink.records if r["event"] == "layout"]
     assert (layout["linear_attention_layers"],
             layout["linear_attention_chunk"],
-            layout["linear_attention_fused_layers"]) == (3, 64, 3)
+            layout["linear_attention_fused_layers"],
+            layout["linear_attention_fused_conv_layers"]) == (3, 64, 3, 3)
 
 
 # -- a chip's share of the block ------------------------------------------------
