@@ -1706,7 +1706,12 @@ def _from_slabs(slabs, tokens, dtype):
     ``slabs`` ``(more * c, 128)``."""
     from jax.experimental import pallas as pl
     c = slabs.shape[0] // (tokens + 1)
-    bt = next(b for b in (512, 256, 128, 64, 32, 16, 8) if tokens % b == 0)
+    # the largest block of rows whose two buffers in and out stay within
+    # the default scoped VMEM (16 MiB: 512 rows at d = 2,048 float32 just
+    # fit, at 2,304 they do not)
+    row = c * _LANES * (4 + jnp.dtype(dtype).itemsize)
+    bt = next(b for b in (512, 256, 128, 64, 32, 16, 8)
+              if tokens % b == 0 and 2 * b * row <= 16 * 1024 * 1024)
     return pl.pallas_call(
         partial(_from_slabs_kernel, c),
         grid=(tokens // bt,),

@@ -231,9 +231,41 @@ class SwiGLULayer(_SeqLayer):
 # -- multi-head latent attention ---------------------------------------------
 
 
-def rope_tables(time: int, dim: int, theta: float):
+def yarn_frequencies(dim: int, theta: float, factor: float, original: int,
+                     beta_fast: float, beta_slow: float):
+    """YaRN's rotary frequencies (``rope_type: yarn``, as ``transformers``'
+    ``_compute_yarn_parameters`` gives them with ``truncate``), ``(dim/2,)``
+    float32: ``inv_extra = theta^(-2i/dim)`` and ``inv_inter = inv_extra /
+    factor``, blended by a ramp over ``i`` from ``floor(d(beta_fast))`` to
+    ``ceil(d(beta_slow))``, ``d(r) = dim ln(original / (2 pi r)) / (2 ln
+    theta)``: ``inv_inter * ramp + inv_extra * (1 - ramp)``, so the fast
+    pairs keep their frequency and the slow ones are divided by
+    ``factor``."""
+    def at(rotations):
+        return dim * math.log(original / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(at(beta_fast)), 0)
+    high = min(math.ceil(at(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extra = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=_F32) / dim))
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=_F32) - low) / (high - low),
+                    0.0, 1.0)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def rope_tables(time: int, dim: int, theta: float, yarn=None):
     """cos and sin of ``pos * theta^(-2i/dim)``, each ``(time, dim/2)``,
-    float32."""
+    float32. ``yarn``, where given, is ``(factor,
+    original_max_position_embeddings, beta_fast, beta_slow,
+    attention_factor)``: the frequencies are ``yarn_frequencies``' and
+    both tables are multiplied by ``attention_factor`` (so a score of q
+    and k rotated by them carries its square)."""
+    if yarn is not None:
+        *keys, mult = yarn
+        ang = jnp.arange(time, dtype=_F32)[:, None] \
+            * yarn_frequencies(dim, theta, *keys)[None, :]
+        return jnp.cos(ang) * mult, jnp.sin(ang) * mult
     inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=_F32) / dim))
     ang = jnp.arange(time, dtype=_F32)[:, None] * inv[None, :]
     return jnp.cos(ang), jnp.sin(ang)
@@ -258,6 +290,19 @@ def apply_rope(x, cos, sin, halves: bool = False):
     c, s = cos.reshape(shape), sin.reshape(shape)
     return jnp.concatenate([even * c - odd * s, odd * c + even * s],
                            axis=-1).astype(x.dtype)
+
+
+def over_batch(mesh, fn, *args):
+    """``fn(*args)``, where the mesh splits the batch (its ``data`` axis
+    has more than one chip) inside ``shard_map`` over that axis: each
+    chip calls ``fn`` on its own rows of every argument (all batch-major)
+    and its result is its rows of the whole. A Pallas kernel must be
+    called so: the partitioner cannot split a Mosaic kernel by itself."""
+    if mesh is None or mesh.shape.get("data", 1) == 1:
+        return fn(*args)
+    spec = jax.sharding.PartitionSpec("data")
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * len(args),
+                         out_specs=spec, check_vma=False)(*args)
 
 
 def _attend(q, k, v, q0: int, scale: float, k0: int = 0, window: int = 0):
@@ -442,7 +487,11 @@ class GQAAttentionLayer(_SeqLayer):
     of a head's 256 features and have no window; LFM2's have 32 query
     heads on 8 key/value heads of 64 features, all rotated, and no gate.
     ``gate`` is a structural key as ``rope`` and ``window`` are: it
-    decides which parameters exist.
+    decides which parameters exist. ``rope_type = yarn`` (Mellum2's full
+    layers) rotates by YaRN's frequencies and scales the tables by
+    ``attention_factor`` (``rope_tables``; keys ``rope_factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+    ``attention_factor``); ``default`` is the plain table.
 
     The causal core is the fused kernel where the shapes tile
     (``pallas_kernels.causal_attention_applicable``: heads of 64 features
@@ -459,29 +508,61 @@ class GQAAttentionLayer(_SeqLayer):
         self.rope_dim = 0
         self.gate = 1
         self.rope_theta = 10000.0
+        self.rope_type = "default"
+        self.rope_factor = 1.0
+        self.original_max_position_embeddings = 0
+        self.beta_fast = 32.0
+        self.beta_slow = 1.0
+        self.attention_factor = 1.0
         self.eps = 1e-6
         self.q_block = 0
         self.fused_core = False
+        self.mesh = None
         super().__init__(cfg)
+
+    def bind_mesh(self, mesh) -> None:
+        """The mesh the layer's program runs on (the trainer's): where it
+        splits the batch, the fused core runs a chip's rows at a time
+        (``over_batch``)."""
+        self.mesh = mesh
 
     def set_param(self, name, val):
         super().set_param(name, val)
         if name in ("nhead", "nkvhead", "head_dim", "window", "rope",
-                    "rope_dim", "q_block", "gate"):
+                    "rope_dim", "q_block", "gate",
+                    "original_max_position_embeddings"):
             setattr(self, name, int(val))
-        if name in ("rope_theta", "eps"):
+        if name in ("rope_theta", "eps", "rope_factor", "beta_fast",
+                    "beta_slow", "attention_factor"):
             setattr(self, name, float(val))
+        if name == "rope_type":
+            if val not in ("default", "yarn"):
+                raise ValueError("gqa_attention: rope_type must be default "
+                                 "or yarn, not %r" % val)
+            self.rope_type = val
+
+    def yarn(self):
+        """``rope_tables``' ``yarn`` argument, None for the plain table."""
+        if self.rope_type != "yarn":
+            return None
+        return (self.rope_factor, self.original_max_position_embeddings,
+                self.beta_fast, self.beta_slow, self.attention_factor)
 
     def infer_shape(self, in_shapes: List[Shape3]) -> List[Shape3]:
         s = _expect_seq("gqa_attention", self._expect_one(in_shapes))
         if min(self.nhead, self.nkvhead, self.head_dim) <= 0 \
                 or self.nhead % self.nkvhead or self.head_dim % 2 \
                 or self.window < 0 or self.rope_dim % 2 \
-                or not 0 <= self.rope_dim <= self.head_dim:
+                or not 0 <= self.rope_dim <= self.head_dim \
+                or (self.rope_type == "yarn" and min(
+                    self.rope_factor, self.original_max_position_embeddings,
+                    self.beta_fast, self.beta_slow) <= 0):
             raise ValueError(
                 "gqa_attention: must set nhead, nkvhead (a divisor of "
-                "nhead), head_dim (even), window >= 0 and an even "
-                "rope_dim within head_dim")
+                "nhead), head_dim (even), window >= 0, an even "
+                "rope_dim within head_dim, and with rope_type = yarn a "
+                "positive rope_factor, original_max_position_embeddings, "
+                "beta_fast and beta_slow")
         # which core runs is what the shapes allow, not a key
         self.fused_core = pallas_kernels.causal_attention_applicable(
             s.y, self.q_block, (self.head_dim,), self.head_dim,
@@ -519,7 +600,7 @@ class GQAAttentionLayer(_SeqLayer):
         k = rms_norm(k, params["knorm"], self.eps)
         if self.rope:
             rd = self.rope_dim or hd
-            cos, sin = rope_tables(t, rd, self.rope_theta)
+            cos, sin = rope_tables(t, rd, self.rope_theta, self.yarn())
             if rd == hd:
                 turn = lambda a: apply_rope(a, cos, sin, True)
             else:       # the features past rope_dim carry no position
@@ -531,9 +612,10 @@ class GQAAttentionLayer(_SeqLayer):
         scale = 1.0 / math.sqrt(hd)
         with jax.named_scope("core"):
             if self.fused_core:
-                o = pallas_kernels.causal_attention(
-                    (heads(q),), (heads(k),), heads(v), scale, self.q_block,
-                    self.window)
+                o = over_batch(self.mesh, lambda q, k, v:
+                               pallas_kernels.causal_attention(
+                                   (q,), (k,), v, scale, self.q_block,
+                                   self.window), heads(q), heads(k), heads(v))
             else:
                 # a key/value head beside each query head of its group
                 each = lambda a: jnp.repeat(heads(a), h // g, axis=1)
@@ -1090,6 +1172,45 @@ def dispatch_plan(picks, weights, first: int, count: int, block: int):
     return tok, cw, expert.astype(jnp.int32), ends[-1].astype(jnp.int32), load
 
 
+# Rows a chip receives in one exchange on an expert axis: a block from
+# each chip that holds every pick of a part of that chip's tokens, so that
+# no routing can overflow it (buffers of twice the even share overflowed
+# on fresh weights in Mellum2's cell, PERF.md). The grouped kernels keep a
+# token id a row in SMEM (1 MiB): twice these rows would pass it.
+EXCHANGE_ROWS = 131072
+
+
+def exchange_plan(picks, first: int, per_chip: int, chips: int,
+                  capacity: int):
+    """Where each pick of a chip's tokens travels on an expert axis of
+    ``chips`` chips holding ``per_chip`` experts each, experts ``first ..
+    first + chips * per_chip`` in order. The send buffer is ``chips``
+    blocks of ``capacity`` rows, one a destination chip (the chip itself
+    among them), each filled in the picks' order; a pick past its
+    destination's ``capacity`` is dropped. Returns
+
+    slot  (picks,) int32   its row in the send buffer; ``chips *
+                           capacity`` (past the end) where dropped or not
+                           on the axis
+    src   (chips * capacity,) int32  the pick each row carries (an index
+                           into ``picks.reshape(-1)``); ``picks.size``
+                           on an empty row
+    want  (chips,) int32   picks each destination was asked to take
+    """
+    flat = picks.reshape(-1) - first
+    dest = jnp.where((flat >= 0) & (flat < chips * per_chip),
+                     flat // per_chip, chips)
+    onehot = (dest[:, None] == jnp.arange(chips)[None, :]).astype(jnp.int32)
+    rank = jnp.sum((jnp.cumsum(onehot, axis=0) - 1) * onehot, axis=1)
+    want = jnp.sum(onehot, axis=0)
+    rows = chips * capacity
+    slot = jnp.where((dest < chips) & (rank < capacity),
+                     dest * capacity + rank, rows)
+    src = jnp.full((rows,), flat.shape[0], jnp.int32).at[slot].set(
+        jnp.arange(flat.shape[0], dtype=jnp.int32), mode="drop")
+    return slot, src, want
+
+
 def _take_rows(x, idx):
     # padding rows point past the last token: they read zeros
     return jnp.take(x, idx, axis=0, mode="fill", fill_value=0)
@@ -1289,9 +1410,28 @@ class MoELayer(_SeqLayer):
     three times the rows the held experts get when the routing is even
     plus a block an expert, and the loop a block at a time in a step whose
     routing needs more rows than that, and everywhere else.
+
+    ``expert_axis`` names a mesh axis (the trainer's ``data`` axis:
+    expert parallelism over the data-parallel chips) that the held
+    experts are spread over, ``expert_count / chips`` a chip, their
+    tensors sharded on their leading axis (``leading_axes``). Where the
+    bound mesh (``bind_mesh``) gives that axis more than one chip, each
+    chip routes its own tokens over all ``nexpert``, sends each pick's row
+    to the chip that holds its expert (``jax.lax.all_to_all`` inside
+    ``shard_map``, scope ``exchange``), runs ``dispatch_plan`` and the
+    grouped kernels over what it received, and sends the results back,
+    where they are weighted and summed into their tokens in float32. A
+    chip exchanges its tokens a part at a time (``part``: ``EXCHANGE_ROWS
+    / (chips * topk)`` tokens, a ``lax.map``, each part made again in the
+    backward pass), and its send buffer holds every pick of a part for
+    each destination (``capacity``), so no pick is dropped however uneven
+    the routing (a plan's ``dropped`` stays counted). The state's
+    ``exchange`` then carries ``[rows sent off-chip, fewest rows a chip
+    received, most]``.
     """
 
-    sub_scopes = ("route", "dispatch", "experts", "combine", "shared")
+    sub_scopes = ("route", "dispatch", "exchange", "experts", "combine",
+                  "shared")
 
     def __init__(self, cfg=()):
         self.nexpert = 0
@@ -1306,6 +1446,8 @@ class MoELayer(_SeqLayer):
         self.bias_sigma = 0.0
         self.score_func = "sigmoid"
         self.shared_gate = 0
+        self.expert_axis = ""
+        self.mesh = None
         self.grouped = False
         super().__init__(cfg)
 
@@ -1338,6 +1480,8 @@ class MoELayer(_SeqLayer):
             self.score_func = val
         if name == "shared_gate":
             self.shared_gate = int(val)
+        if name == "expert_axis":
+            self.expert_axis = val
 
     def infer_shape(self, in_shapes: List[Shape3]) -> List[Shape3]:
         s = _expect_seq("moe", self._expect_one(in_shapes))
@@ -1346,11 +1490,13 @@ class MoELayer(_SeqLayer):
         if min(self.nexpert, self.topk, self.param.num_hidden) <= 0 \
                 or self.topk > self.nexpert or self.first < 0 \
                 or self.count <= 0 or self.first + self.count > self.nexpert \
-                or (self.shared_gate and not self.nshared):
+                or (self.shared_gate and not self.nshared) \
+                or (self.expert_axis and self.nshared):
             raise ValueError(
                 "moe: must set nexpert, topk <= nexpert, nhidden, "
-                "expert_first / expert_count inside nexpert, and nshared "
-                "where shared_gate is on")
+                "expert_first / expert_count inside nexpert, nshared "
+                "where shared_gate is on, and no shared expert on an "
+                "expert axis (not built)")
         self.grouped = pallas_kernels.grouped_experts_applicable(
             s.x, self.param.num_hidden, self.block, self.cd)
         self.in_shapes = [s]
@@ -1381,10 +1527,49 @@ class MoELayer(_SeqLayer):
     def init_state(self):
         bias = self.bias_sigma * jax.random.normal(
             jax.random.PRNGKey(self.bias_seed), (self.nexpert,), _F32)
-        return {"bias": bias,
-                "load": jnp.zeros((self.count,), jnp.int32),
-                "picks_held": jnp.int32(0), "dropped": jnp.int32(0),
-                "grouped": jnp.int32(0)}
+        out = {"bias": bias,
+               "load": jnp.zeros((self.count,), jnp.int32),
+               "picks_held": jnp.int32(0), "dropped": jnp.int32(0),
+               "grouped": jnp.int32(0)}
+        if self.expert_axis:
+            out["exchange"] = jnp.zeros((3,), jnp.int32)
+        return out
+
+    def bind_mesh(self, mesh) -> None:
+        """The mesh the layer's program runs on (the trainer's)."""
+        self.mesh = mesh
+
+    def chips(self) -> int:
+        """Chips the held experts are spread over: the size of
+        ``expert_axis`` in the bound mesh, 1 without either."""
+        if not self.expert_axis or self.mesh is None:
+            return 1
+        chips = int(self.mesh.shape[self.expert_axis])
+        if self.count % chips:
+            raise ValueError("moe: %d held experts do not divide over the "
+                             "%d chips of axis %r"
+                             % (self.count, chips, self.expert_axis))
+        return chips
+
+    def leading_axes(self) -> Dict[str, str]:
+        """The tensors sharded on their leading axis, and the mesh axis:
+        the experts', on an expert axis."""
+        if not self.expert_axis:
+            return {}
+        return {tag: self.expert_axis for tag in ("egate", "eup", "edown")}
+
+    def part(self, tokens: int) -> int:
+        """Tokens a chip exchanges at a time, of its ``tokens``: the most
+        that divide them and whose picks from every chip fill at most
+        ``EXCHANGE_ROWS``."""
+        most = max(EXCHANGE_ROWS // (self.chips() * self.topk), 1)
+        return next(c for c in range(min(tokens, most), 0, -1)
+                    if tokens % c == 0)
+
+    def capacity(self, tokens: int) -> int:
+        """Rows of a chip's send buffer a destination chip, for a chip's
+        ``tokens``: every pick of a ``part``."""
+        return self.part(tokens) * self.topk
 
     def budget(self, tokens: int) -> int:
         """Blocks of rows the grouped kernels' buffers hold for a step
@@ -1423,6 +1608,8 @@ class MoELayer(_SeqLayer):
         xt = x.reshape(b * t, d)
         with jax.named_scope("route"):
             picks, w = self.route(xt, params["router"], state["bias"])
+        if self.chips() > 1:
+            return self._forward_exchange(params, state, x, picks, w)
         with jax.named_scope("dispatch"):
             tok, cw, expert, nb, load = dispatch_plan(
                 picks, w, self.first, self.count, self.block)
@@ -1449,12 +1636,101 @@ class MoELayer(_SeqLayer):
         new_state = dict(state, load=load, picks_held=held,
                          dropped=held - jnp.sum(tok < b * t),
                          grouped=state["grouped"] + took)
+        if "exchange" in state:     # an axis of one chip: nothing travels
+            new_state["exchange"] = jnp.stack([jnp.int32(0), held, held])
+        return [out], new_state
+
+    def _forward_exchange(self, params, state, x, picks, w):
+        """The layer over an expert axis of ``chips()`` chips (the class
+        doc). Inside ``shard_map`` a chip has its own tokens and picks and
+        its ``expert_count / chips`` experts, and exchanges them a part at
+        a time; the routing and the weighted sum stay outside, where the
+        partitioner keeps them with the tokens."""
+        cd, axis, chips = self.cd, self.expert_axis, self.chips()
+        b, t, d = x.shape
+        k, per, block = self.topk, self.count // chips, self.block
+        part = self.part(b * t // chips)
+        cap = part * k
+        rows = chips * cap
+        # a buffer for every row a chip can receive: the kernels always
+        # apply where the widths tile
+        budget = -(-rows // block) + per if self.grouped else 0
+
+        def local(xt, picks, wgate, wup, wdown):
+            me = jax.lax.axis_index(axis)
+
+            # a part made again in the backward pass: what the kernels
+            # and the exchange leave for it is a part's rows, and a map
+            # would keep them for every part
+            @jax.checkpoint
+            def one(args):
+                xt, picks = args
+                with jax.named_scope("dispatch"):
+                    slot, src, want = exchange_plan(picks, self.first, per,
+                                                    chips, cap)
+                    flat = picks.reshape(-1) - self.first
+                    held = jnp.take(flat % per, src, mode="fill",
+                                    fill_value=per)
+                with jax.named_scope("exchange"):
+                    # a row a pick, in the destination's block: the
+                    # tokens' rows gathered (an empty row reads zeros)
+                    # and sent
+                    got = jax.lax.all_to_all(
+                        _take_rows(xt, src // k).reshape(chips, cap, d),
+                        axis, 0, 0).reshape(rows, d)
+                    held = jax.lax.all_to_all(held.reshape(chips, cap),
+                                              axis, 0, 0).reshape(rows)
+                with jax.named_scope("dispatch"):
+                    # every received row is a token with one pick, of
+                    # weight 1 (the pick's weight is applied where it
+                    # came from)
+                    tok, cw, expert, nb, load = dispatch_plan(
+                        held[:, None], jnp.ones((rows, 1), _F32), 0, per,
+                        block)
+                with jax.named_scope("experts"):
+                    y = grouped_swiglu(got, wgate, wup, wdown, cw, tok,
+                                       expert, nb, block, budget)
+                with jax.named_scope("exchange"):
+                    back = jax.lax.all_to_all(
+                        y.astype(xt.dtype).reshape(chips, cap, d), axis, 0,
+                        0).reshape(rows, d)
+                    y = _take_rows(back, slot).reshape(-1, k, d)
+                took = jnp.minimum(want, cap)
+                stats = jnp.stack([jnp.sum(took) - took[me],
+                                   jnp.sum(held < per),
+                                   jnp.sum(want) - jnp.sum(took)])
+                return y, load, stats
+
+            y, load, stats = jax.lax.map(
+                one, (xt.reshape(-1, part, d), picks.reshape(-1, part, k)))
+            return (y.reshape(-1, k, d), jnp.sum(load, axis=0)[None],
+                    jnp.sum(stats, axis=0)[None])
+
+        spec = jax.sharding.PartitionSpec(axis)
+        y, load, stats = jax.shard_map(
+            local, mesh=self.mesh, in_specs=(spec,) * 5,
+            out_specs=(spec, spec, spec), check_vma=False)(
+                x.reshape(b * t, d).astype(cd), picks,
+                params["egate"].astype(cd), params["eup"].astype(cd),
+                params["edown"].astype(cd))
+        with jax.named_scope("combine"):
+            y = jnp.sum(w[..., None] * y.astype(_F32), axis=1)
+            out = y.astype(x.dtype).reshape(b, t, d)
+        received = stats[:, 1]
+        new_state = dict(
+            state, load=load.reshape(self.count),
+            picks_held=jnp.sum(received), dropped=jnp.sum(stats[:, 2]),
+            grouped=state["grouped"] + (1 if budget else 0),
+            exchange=jnp.stack([jnp.sum(stats[:, 0]), jnp.min(received),
+                                jnp.max(received)]))
         return [out], new_state
 
     def flops_per_example(self) -> float:
         """Router, shared experts (and their gate), and the routed
         experts at the picks that land on held experts in expectation:
-        ``topk * count / nexpert`` a token."""
+        ``topk * count / nexpert`` a token. On an expert axis the held
+        experts are those of all its chips, and the tokens all of theirs:
+        with every expert held, all ``topk`` picks a token count."""
         s, w = self.in_shapes[0], self.param.num_hidden
         per_token = 2.0 * s.x * self.nexpert \
             + 6.0 * s.x * w * self.nshared + 2.0 * s.x * self.shared_gate \

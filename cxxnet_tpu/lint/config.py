@@ -37,6 +37,10 @@ PROGRAM_BUILDERS = {
         "ProgramRegistry.install_serialized",
     ),
     "cxxnet_tpu/layers/pallas_kernels.py": ("<module>",),
+    # a net's parameters made on the mesh in one program, once at init
+    # (an expert axis's tensors never whole on one chip) — never
+    # dispatched per step
+    "cxxnet_tpu/nnet/net.py": ("FuncNet.init_on",),
     # the calibration amax program (one jitted forward computing every
     # quantizable layer's activation range per batch) — offline
     # task=quantize path, never dispatched while serving

@@ -233,18 +233,21 @@ OPTIONAL: Dict[str, tuple] = {
     # the positions a chunk of their scan along time holds, how many of
     # them run that scan as the fused kernels and how many their short
     # convolution as the fused kernel; gated
-    # short-convolution mixers (gated_conv), and whether the head is the
-    # embedding's own matrix (an embed layer shared onto a sequence node)
+    # short-convolution mixers (gated_conv), whether the head is the
+    # embedding's own matrix (an embed layer shared onto a sequence node),
+    # and the chips an expert layer's experts are spread over
     "layout": ("attention_layers", "attention_fused_layers",
                "attention_saved_layers", "attention_window_layers",
                "moe_layers", "moe_grouped_layers",
                "linear_attention_layers", "linear_attention_chunk",
                "linear_attention_fused_layers",
                "linear_attention_fused_conv_layers",
-               "short_conv_layers", "head_tied"),
+               "short_conv_layers", "head_tied", "expert_axis_size"),
     # the share of the dispatch's passes through an expert layer that
-    # did (forward; the other passes took the loop a block at a time)
-    "moe": ("grouped_share",),
+    # did (forward; the other passes took the loop a block at a time);
+    # on an expert axis, the worst layer's busiest chip's received rows
+    # over the mean chip's (each layer's own counters are in ``layers``)
+    "moe": ("grouped_share", "exchange_max_over_mean"),
     # an imgrec source's decode stage over the round (io/iter_imgrec.py):
     # chunks handed out, how many of them the pool had finished when
     # they were asked for, the workers' summed time inside their

@@ -277,6 +277,41 @@ class FuncNet:
                 state[lkey] = s
         return params, state
 
+    def bind_mesh(self, mesh) -> None:
+        """Tell the layers that run across chips (an expert layer on an
+        expert axis) the mesh their program runs on."""
+        for layer in self.layer_objs:
+            if hasattr(layer, "bind_mesh"):
+                layer.bind_mesh(mesh)
+
+    def leading_axes(self) -> Dict[str, Dict[str, str]]:
+        """``{layer key: {tag: mesh axis}}``: the parameters sharded on
+        their leading axis (parallel.param_sharding)."""
+        g = self.graph
+        out = {}
+        for li, info in enumerate(g.layers):
+            axes = getattr(self.layer_objs[li], "leading_axes", dict)()
+            if axes and info.type != "share":
+                out[g.layer_key(li)] = axes
+        return out
+
+    def init_on(self, mesh, key: jax.Array,
+                model_parallel_min: int = 0) -> Tuple[Params, NetState]:
+        """``init`` made on the mesh in one program, each parameter on its
+        devices from the start (parallel.param_sharding), the state
+        replicated: a tensor sharded over an expert axis never lies whole
+        on one chip. The values are the program's own, so every caller
+        that starts from them (the trainer, a reference) gets the same
+        ones."""
+        from jax.sharding import NamedSharding, PartitionSpec
+        from ..parallel import param_sharding
+        params, state = jax.eval_shape(self.init, key)
+        shard = param_sharding(mesh, params, model_parallel_min,
+                               self.leading_axes())
+        repl = jax.tree_util.tree_map(
+            lambda _: NamedSharding(mesh, PartitionSpec()), state)
+        return jax.jit(self.init, out_shardings=(shard, repl))(key)
+
     # -- forward ---------------------------------------------------------
 
     def layer_scope(self, li: int) -> str:

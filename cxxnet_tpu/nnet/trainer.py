@@ -306,8 +306,21 @@ class NetTrainer:
         assert self.batch_size > 0, "batch_size must be set"
         self.net = FuncNet(self.graph, self.batch_size)
         key = jax.random.PRNGKey(self.seed)
-        self.params, self.net_state = self.net.init(key)
+        if self.net.leading_axes():
+            # parameters sharded over an expert axis are made on their
+            # chips: whole, with Adam's state, they would not fit one
+            self._default_mesh()
+            self.net.bind_mesh(self.mesh)
+            self.params, self.net_state = self.net.init_on(
+                self.mesh, key, self.model_parallel_min)
+        else:
+            self.params, self.net_state = self.net.init(key)
         self._post_init()
+
+    def _default_mesh(self) -> None:
+        if self.mesh is None:
+            from ..parallel import default_data_axis
+            self.mesh = make_mesh(default_data_axis(self.batch_size), 1)
 
     def _post_init(self) -> None:
         """Everything shared by init_model and load_model."""
@@ -328,9 +341,8 @@ class NetTrainer:
             lk: {tag: self.updaters[lk][tag].init_state(w)
                  for tag, w in pt.items()}
             for lk, pt in self.params.items()}
-        if self.mesh is None:
-            from ..parallel import default_data_axis
-            self.mesh = make_mesh(default_data_axis(self.batch_size), 1)
+        self._default_mesh()
+        self.net.bind_mesh(self.mesh)
         # metric bindings -> node indices
         self._metrics = MetricSet()
         self._train_metrics = MetricSet()
@@ -408,7 +420,8 @@ class NetTrainer:
         self._repl = replicated(mesh)
         self._repl_leaf = self._repl
         self._p_shard = param_sharding(mesh, self.params,
-                                       self.model_parallel_min)
+                                       self.model_parallel_min,
+                                       self.net.leading_axes())
         # optimizer-state shardings (ZeRO-1 over 'data' when enabled)
         self._o_shard = {
             lk: {tag: jax.tree_util.tree_map(
@@ -1582,6 +1595,9 @@ class NetTrainer:
         # kernels while the routing fits their buffers (the same)
         grouped = [layer.grouped for layer in net.layer_objs
                    if hasattr(layer, "grouped")]
+        # the chips an expert layer's experts are spread over (1: none)
+        spread = [layer.chips() for layer in net.layer_objs
+                  if hasattr(layer, "leading_axes")]
         # linear-attention layers (gated_delta: a state along time, in
         # chunks), the largest chunk among them, and those whose scan
         # is the fused kernels (the shapes decide)
@@ -1612,6 +1628,7 @@ class NetTrainer:
                            if getattr(layer, "window", 0) > 0),
                        moe_layers=len(grouped),
                        moe_grouped_layers=sum(grouped),
+                       expert_axis_size=max(spread, default=1),
                        linear_attention_layers=len(chunks),
                        linear_attention_chunk=max(chunks, default=0),
                        linear_attention_fused_layers=sum(
@@ -1714,10 +1731,24 @@ class NetTrainer:
                 "load_max": float(load.max()),
                 "held_share": float(st["picks_held"]) / picks,
                 "dropped": int(st["dropped"])}
+            if "exchange" in st:
+                # on an expert axis: rows sent off their chip, the rows
+                # the chips received (fewest, mean, most) and how many
+                # one chip receives in one exchange
+                sent, fewest, most = (int(v) for v in np.asarray(  # cxxlint: disable=CXL003 -- monitor-gated fetch of three counters after the loss
+                    st["exchange"]))
+                chips = layer.chips()
+                layers[lkey].update(
+                    sent_offchip=sent, received_min=fewest,
+                    received_mean=float(st["picks_held"]) / chips,
+                    received_max=most, capacity=chips * layer.capacity(
+                        rows * layer.in_shapes[0].y // chips))
             passes = int(st["grouped"])
             took += passes - self._moe_grouped[lkey]
             self._moe_grouped[lkey] = passes
         if layers:
+            spread = [v["received_max"] / max(v["received_mean"], 1e-9)
+                      for v in layers.values() if "received_max" in v]
             self._mon.emit(
                 "moe", step=self._steps_total, layers=layers,
                 dropped=sum(v["dropped"] for v in layers.values()),
@@ -1726,7 +1757,9 @@ class NetTrainer:
                 load_max_over_mean=max(
                     v["load_max"] / max(v["load_mean"], 1e-9)
                     for v in layers.values()),
-                grouped_share=took / float(n_batches * len(layers)))
+                grouped_share=took / float(n_batches * len(layers)),
+                **({"exchange_max_over_mean": max(spread)} if spread
+                   else {}))
 
     def end_round(self) -> None:
         """Close the current round's counter window (idempotent):

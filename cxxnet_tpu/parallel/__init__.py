@@ -103,17 +103,28 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
-def param_sharding(mesh: Mesh, params, model_parallel_min: int = 0):
+def param_sharding(mesh: Mesh, params, model_parallel_min: int = 0,
+                   leading=None):
     """Sharding pytree for parameters.
 
     Weights stay replicated except 2-D fullc weights whose output dim is
     divisible by the 'model' axis and exceeds ``model_parallel_min`` —
     those shard on the output dim (the fullc_gather analogue: XLA
-    all-gathers the activations and each shard computes its slice).
+    all-gathers the activations and each shard computes its slice) — and
+    the tensors ``leading`` names (``{layer key: {tag: mesh axis}}``,
+    ``FuncNet.leading_axes``: an expert layer's experts on its expert
+    axis), which shard on their leading axis. Their gradients stay where
+    they are (a chip owns its experts); a replicated weight's gradient is
+    the all-reduce of the chips' parts.
     """
     msize = mesh.shape["model"]
+    leading = leading or {}
 
     def spec(path, leaf):
+        axis = leading and len(path) == 2 and leading.get(
+            path[0].key, {}).get(path[1].key)
+        if axis:
+            return NamedSharding(mesh, P(axis))
         if (msize > 1 and model_parallel_min > 0 and hasattr(leaf, "ndim")
                 and leaf.ndim == 2
                 and leaf.shape[-1] % msize == 0

@@ -587,3 +587,69 @@ def test_lfm2_layers_compile_for_one_v5e_at_the_cells_shapes(topo, kind):
         assert layer.grouped and layer.budget(2 * 8192) == 56
         assert "tpu_custom_call" in text and "(shared)" not in text
         assert " conditional(" not in text
+
+
+@pytest.mark.parametrize("kind", ["moe", "gqa_attention"])
+def test_mellum2_layers_compile_for_four_v5e_at_the_cells_shapes(topo, kind):
+    """What ``mellum2_12b_a2_5b.train_tokens_8k_ep4`` adds (8 x 8,192
+    positions, two sequences a chip, hidden 2,304, bfloat16), forward and
+    backward over a described v5e:2x2. The expert layer on an expert axis
+    of four: its 64 experts 16 a chip, a chip's 16,384 tokens exchanged
+    in parts of 4,096 by all-to-alls (three forward, two for a part made
+    again and two backward), the grouped kernels at hidden 2,304 and
+    width 896 (their rows' conversion back from the float32 slabs in
+    blocks that fit the default scoped VMEM, which 512 rows at 2,304 do
+    not), no all-reduce of an expert's gradient. The full attention layer
+    with YaRN's tables: the fused core a chip's rows at a time inside
+    ``shard_map`` (the partitioner cannot split a Mosaic kernel)."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from cxxnet_tpu.layers import create_layer, seq_shape
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(4, 1), ("data", "model"))
+    bf, f32 = jnp.bfloat16, jnp.float32
+    cfg = {"moe": dict(nexpert=64, topk=8, nhidden=896, nshared=0,
+                       score_func="softmax", expert_block=512,
+                       expert_axis="data"),
+           "gqa_attention": dict(nhead=32, nkvhead=4, head_dim=128, window=0,
+                                 rope=1, gate=0, rope_theta=5e5, eps=1e-6,
+                                 q_block=1024, rope_type="yarn",
+                                 rope_factor=16,
+                                 original_max_position_embeddings=8192,
+                                 beta_fast=32, beta_slow=1,
+                                 attention_factor=1.2772588722239782)}[kind]
+    layer = create_layer(kind, [(k, str(v)) for k, v in cfg.items()]
+                         + [("dtype", "bfloat16")])
+    layer.infer_shape([seq_shape(8192, 2304)])
+    layer.bind_mesh(mesh)
+    lead = getattr(layer, "leading_axes", dict)()
+
+    def on(a, spec=P()):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    shapes = jax.eval_shape(layer.init_params, jax.random.PRNGKey(0))
+    params = {tag: on(a, P(lead[tag]) if tag in lead else P())
+              for tag, a in shapes.items()}
+    state = jax.tree.map(on, jax.eval_shape(layer.init_state))
+    x = on(jax.ShapeDtypeStruct((8, 8192, 2304), bf), P("data"))
+    compiled = jax.jit(jax.grad(lambda p, s, x: jnp.sum(layer.forward(
+        p, s, [x], True, None)[0][0].astype(f32)), argnums=(0, 2))).lower(
+            params, state, x).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    if kind == "moe":
+        assert layer.grouped and layer.chips() == 4
+        assert layer.part(16384) == 4096 and layer.capacity(16384) == 32768
+        assert len(re.findall(r"all-to-all\(", text)) == 7
+        reduces = [ln for ln in text.splitlines()
+                   if re.search(r"all-reduce(-start)?\(", ln)]
+        assert not any(re.search(r"\[(64|16),(2304,896|896,2304)\]", ln)
+                       for ln in reduces)
+        assert " conditional(" not in text
+    else:
+        assert layer.fused_core and layer.yarn() is not None
+        assert text.count("tpu_custom_call") == 2
+        assert not re.search(r"all-(reduce|gather)(-start)?\(.*f32\[4,32",
+                             text)
