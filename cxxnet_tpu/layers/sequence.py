@@ -1172,43 +1172,18 @@ def dispatch_plan(picks, weights, first: int, count: int, block: int):
     return tok, cw, expert.astype(jnp.int32), ends[-1].astype(jnp.int32), load
 
 
-# Rows a chip receives in one exchange on an expert axis: a block from
-# each chip that holds every pick of a part of that chip's tokens, so that
-# no routing can overflow it (buffers of twice the even share overflowed
-# on fresh weights in Mellum2's cell, PERF.md). The grouped kernels keep a
-# token id a row in SMEM (1 MiB): twice these rows would pass it.
+# Picks that can land on a chip's experts in one exchange on an expert
+# axis: every pick of a part of each chip's tokens. The grouped kernels
+# keep a token id a row of their plan in SMEM (1 MiB): twice these rows
+# would pass it.
 EXCHANGE_ROWS = 131072
 
 
-def exchange_plan(picks, first: int, per_chip: int, chips: int,
-                  capacity: int):
-    """Where each pick of a chip's tokens travels on an expert axis of
-    ``chips`` chips holding ``per_chip`` experts each, experts ``first ..
-    first + chips * per_chip`` in order. The send buffer is ``chips``
-    blocks of ``capacity`` rows, one a destination chip (the chip itself
-    among them), each filled in the picks' order; a pick past its
-    destination's ``capacity`` is dropped. Returns
-
-    slot  (picks,) int32   its row in the send buffer; ``chips *
-                           capacity`` (past the end) where dropped or not
-                           on the axis
-    src   (chips * capacity,) int32  the pick each row carries (an index
-                           into ``picks.reshape(-1)``); ``picks.size``
-                           on an empty row
-    want  (chips,) int32   picks each destination was asked to take
-    """
-    flat = picks.reshape(-1) - first
-    dest = jnp.where((flat >= 0) & (flat < chips * per_chip),
-                     flat // per_chip, chips)
-    onehot = (dest[:, None] == jnp.arange(chips)[None, :]).astype(jnp.int32)
-    rank = jnp.sum((jnp.cumsum(onehot, axis=0) - 1) * onehot, axis=1)
-    want = jnp.sum(onehot, axis=0)
-    rows = chips * capacity
-    slot = jnp.where((dest < chips) & (rank < capacity),
-                     dest * capacity + rank, rows)
-    src = jnp.full((rows,), flat.shape[0], jnp.int32).at[slot].set(
-        jnp.arange(flat.shape[0], dtype=jnp.int32), mode="drop")
-    return slot, src, want
+def _rows_used(picks, first, count):
+    """Tokens with at least one of their ``picks`` on experts ``first ..
+    first + count``."""
+    held = (picks >= first) & (picks < first + count)
+    return jnp.sum(jnp.any(held, axis=1), dtype=jnp.int32)
 
 
 def _take_rows(x, idx):
@@ -1416,18 +1391,21 @@ class MoELayer(_SeqLayer):
     experts are spread over, ``expert_count / chips`` a chip, their
     tensors sharded on their leading axis (``leading_axes``). Where the
     bound mesh (``bind_mesh``) gives that axis more than one chip, each
-    chip routes its own tokens over all ``nexpert``, sends each pick's row
-    to the chip that holds its expert (``jax.lax.all_to_all`` inside
-    ``shard_map``, scope ``exchange``), runs ``dispatch_plan`` and the
-    grouped kernels over what it received, and sends the results back,
-    where they are weighted and summed into their tokens in float32. A
-    chip exchanges its tokens a part at a time (``part``: ``EXCHANGE_ROWS
-    / (chips * topk)`` tokens, a ``lax.map``, each part made again in the
-    backward pass), and its send buffer holds every pick of a part for
-    each destination (``capacity``), so no pick is dropped however uneven
-    the routing (a plan's ``dropped`` stays counted). The state's
-    ``exchange`` then carries ``[rows sent off-chip, fewest rows a chip
-    received, most]``.
+    chip routes its own tokens over all ``nexpert`` and sends each token's
+    row once to every chip of the axis, with its picks and their weights
+    (``jax.lax.all_gather`` inside ``shard_map``, scope ``exchange``). A
+    chip runs ``dispatch_plan`` and the grouped kernels over the picks
+    that land on its experts, each weighted and summed into its token in
+    float32, and one ``jax.lax.all_to_all`` sends every token's partial
+    sum back to its own chip, where the chips' sums are added in float32.
+    A chip exchanges its tokens a part at a time (``part``:
+    ``EXCHANGE_ROWS / (chips * topk)`` tokens, a ``lax.map``, each part
+    made again in the backward pass). A chip receives ``chips * part``
+    rows an exchange (``capacity``) whatever the routing, so no pick can
+    be dropped (``dropped`` stays counted). The state's ``exchange``
+    carries ``[token rows sent off-chip, fewest picks a chip's experts
+    received, most, rows received that carry a pick for the chip's
+    experts]``.
     """
 
     sub_scopes = ("route", "dispatch", "exchange", "experts", "combine",
@@ -1532,7 +1510,7 @@ class MoELayer(_SeqLayer):
                "picks_held": jnp.int32(0), "dropped": jnp.int32(0),
                "grouped": jnp.int32(0)}
         if self.expert_axis:
-            out["exchange"] = jnp.zeros((3,), jnp.int32)
+            out["exchange"] = jnp.zeros((4,), jnp.int32)
         return out
 
     def bind_mesh(self, mesh) -> None:
@@ -1567,9 +1545,9 @@ class MoELayer(_SeqLayer):
                     if tokens % c == 0)
 
     def capacity(self, tokens: int) -> int:
-        """Rows of a chip's send buffer a destination chip, for a chip's
-        ``tokens``: every pick of a ``part``."""
-        return self.part(tokens) * self.topk
+        """Token rows a chip receives in one exchange, for a chip's
+        ``tokens``: a ``part`` from every chip."""
+        return self.chips() * self.part(tokens)
 
     def budget(self, tokens: int) -> int:
         """Blocks of rows the grouped kernels' buffers hold for a step
@@ -1637,92 +1615,78 @@ class MoELayer(_SeqLayer):
                          dropped=held - jnp.sum(tok < b * t),
                          grouped=state["grouped"] + took)
         if "exchange" in state:     # an axis of one chip: nothing travels
-            new_state["exchange"] = jnp.stack([jnp.int32(0), held, held])
+            new_state["exchange"] = jnp.stack(
+                [jnp.int32(0), held, held,
+                 _rows_used(picks, self.first, self.count)])
         return [out], new_state
 
     def _forward_exchange(self, params, state, x, picks, w):
         """The layer over an expert axis of ``chips()`` chips (the class
-        doc). Inside ``shard_map`` a chip has its own tokens and picks and
-        its ``expert_count / chips`` experts, and exchanges them a part at
-        a time; the routing and the weighted sum stay outside, where the
-        partitioner keeps them with the tokens."""
+        doc). Inside ``shard_map`` a chip has its own tokens, their picks
+        and weights, and its ``expert_count / chips`` experts, and
+        exchanges them a part at a time; the routing stays outside, where
+        the partitioner keeps it with the tokens."""
         cd, axis, chips = self.cd, self.expert_axis, self.chips()
         b, t, d = x.shape
         k, per, block = self.topk, self.count // chips, self.block
         part = self.part(b * t // chips)
-        cap = part * k
-        rows = chips * cap
-        # a buffer for every row a chip can receive: the kernels always
-        # apply where the widths tile
-        budget = -(-rows // block) + per if self.grouped else 0
+        rows = self.capacity(b * t // chips)
+        # a buffer for every pick that can land on a chip: the kernels
+        # always apply where the widths tile
+        budget = -(-(rows * k) // block) + per if self.grouped else 0
 
-        def local(xt, picks, wgate, wup, wdown):
-            me = jax.lax.axis_index(axis)
+        def local(xt, picks, w, wgate, wup, wdown):
+            first = self.first + jax.lax.axis_index(axis) * per
 
             # a part made again in the backward pass: what the kernels
             # and the exchange leave for it is a part's rows, and a map
             # would keep them for every part
             @jax.checkpoint
             def one(args):
-                xt, picks = args
-                with jax.named_scope("dispatch"):
-                    slot, src, want = exchange_plan(picks, self.first, per,
-                                                    chips, cap)
-                    flat = picks.reshape(-1) - self.first
-                    held = jnp.take(flat % per, src, mode="fill",
-                                    fill_value=per)
                 with jax.named_scope("exchange"):
-                    # a row a pick, in the destination's block: the
-                    # tokens' rows gathered (an empty row reads zeros)
-                    # and sent
-                    got = jax.lax.all_to_all(
-                        _take_rows(xt, src // k).reshape(chips, cap, d),
-                        axis, 0, 0).reshape(rows, d)
-                    held = jax.lax.all_to_all(held.reshape(chips, cap),
-                                              axis, 0, 0).reshape(rows)
+                    # every chip's rows of the part, each once, with their
+                    # picks and weights
+                    got, picks, w = (jax.lax.all_gather(a, axis, tiled=True)
+                                     for a in args)
                 with jax.named_scope("dispatch"):
-                    # every received row is a token with one pick, of
-                    # weight 1 (the pick's weight is applied where it
-                    # came from)
                     tok, cw, expert, nb, load = dispatch_plan(
-                        held[:, None], jnp.ones((rows, 1), _F32), 0, per,
-                        block)
+                        picks, w, first, per, block)
                 with jax.named_scope("experts"):
                     y = grouped_swiglu(got, wgate, wup, wdown, cw, tok,
                                        expert, nb, block, budget)
                 with jax.named_scope("exchange"):
-                    back = jax.lax.all_to_all(
-                        y.astype(xt.dtype).reshape(chips, cap, d), axis, 0,
-                        0).reshape(rows, d)
-                    y = _take_rows(back, slot).reshape(-1, k, d)
-                took = jnp.minimum(want, cap)
-                stats = jnp.stack([jnp.sum(took) - took[me],
-                                   jnp.sum(held < per),
-                                   jnp.sum(want) - jnp.sum(took)])
+                    # each token's partial sum back to its own chip, where
+                    # the chips' sums are added
+                    y = jnp.sum(jax.lax.all_to_all(
+                        y.astype(cd).reshape(chips, part, d), axis, 0,
+                        0).astype(_F32), axis=0)
+                stats = jnp.stack([jnp.sum(load) - jnp.sum(tok < rows),
+                                   _rows_used(picks, first, per)])
                 return y, load, stats
 
-            y, load, stats = jax.lax.map(
-                one, (xt.reshape(-1, part, d), picks.reshape(-1, part, k)))
-            return (y.reshape(-1, k, d), jnp.sum(load, axis=0)[None],
+            y, load, stats = jax.lax.map(one, (
+                xt.reshape(-1, part, d), picks.reshape(-1, part, k),
+                w.reshape(-1, part, k)))
+            return (y.reshape(-1, d), jnp.sum(load, axis=0)[None],
                     jnp.sum(stats, axis=0)[None])
 
         spec = jax.sharding.PartitionSpec(axis)
         y, load, stats = jax.shard_map(
-            local, mesh=self.mesh, in_specs=(spec,) * 5,
+            local, mesh=self.mesh, in_specs=(spec,) * 6,
             out_specs=(spec, spec, spec), check_vma=False)(
-                x.reshape(b * t, d).astype(cd), picks,
+                x.reshape(b * t, d).astype(cd), picks, w,
                 params["egate"].astype(cd), params["eup"].astype(cd),
                 params["edown"].astype(cd))
         with jax.named_scope("combine"):
-            y = jnp.sum(w[..., None] * y.astype(_F32), axis=1)
             out = y.astype(x.dtype).reshape(b, t, d)
-        received = stats[:, 1]
+        received = jnp.sum(load, axis=1)
         new_state = dict(
             state, load=load.reshape(self.count),
-            picks_held=jnp.sum(received), dropped=jnp.sum(stats[:, 2]),
+            picks_held=jnp.sum(received), dropped=jnp.sum(stats[:, 0]),
             grouped=state["grouped"] + (1 if budget else 0),
-            exchange=jnp.stack([jnp.sum(stats[:, 0]), jnp.min(received),
-                                jnp.max(received)]))
+            exchange=jnp.stack([jnp.int32(b * t * (chips - 1)),
+                                jnp.min(received), jnp.max(received),
+                                jnp.sum(stats[:, 1])]))
         return [out], new_state
 
     def flops_per_example(self) -> float:

@@ -245,8 +245,13 @@ OPTIONAL: Dict[str, tuple] = {
                "short_conv_layers", "head_tied", "expert_axis_size"),
     # the share of the dispatch's passes through an expert layer that
     # did (forward; the other passes took the loop a block at a time);
-    # on an expert axis, the worst layer's busiest chip's received rows
-    # over the mean chip's (each layer's own counters are in ``layers``)
+    # on an expert axis, the worst layer's busiest chip's received picks
+    # over the mean chip's. Each layer's own counters are in ``layers``;
+    # on an expert axis they add sent_offchip (token rows sent to another
+    # chip), received_min / _mean / _max (picks a chip's experts
+    # received), capacity (token rows a chip receives in one exchange)
+    # and exchange_used_share (of the rows received, the share carrying
+    # a pick for the receiving chip's experts)
     "moe": ("grouped_share", "exchange_max_over_mean"),
     # an imgrec source's decode stage over the round (io/iter_imgrec.py):
     # chunks handed out, how many of them the pool had finished when

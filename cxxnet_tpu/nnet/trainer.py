@@ -1732,17 +1732,21 @@ class NetTrainer:
                 "held_share": float(st["picks_held"]) / picks,
                 "dropped": int(st["dropped"])}
             if "exchange" in st:
-                # on an expert axis: rows sent off their chip, the rows
-                # the chips received (fewest, mean, most) and how many
-                # one chip receives in one exchange
-                sent, fewest, most = (int(v) for v in np.asarray(  # cxxlint: disable=CXL003 -- monitor-gated fetch of three counters after the loss
+                # on an expert axis: token rows sent off their chip, the
+                # picks the chips' experts received (fewest, mean, most),
+                # the token rows one chip receives in one exchange and the
+                # share of the rows received that carry a pick for the
+                # receiving chip's experts (a chip receives every token)
+                sent, fewest, most, used = (int(v) for v in np.asarray(  # cxxlint: disable=CXL003 -- monitor-gated fetch of four counters after the loss
                     st["exchange"]))
                 chips = layer.chips()
+                tokens = rows * layer.in_shapes[0].y
                 layers[lkey].update(
                     sent_offchip=sent, received_min=fewest,
                     received_mean=float(st["picks_held"]) / chips,
-                    received_max=most, capacity=chips * layer.capacity(
-                        rows * layer.in_shapes[0].y // chips))
+                    received_max=most,
+                    capacity=layer.capacity(tokens // chips),
+                    exchange_used_share=used / float(chips * tokens))
             passes = int(st["grouped"])
             took += passes - self._moe_grouped[lkey]
             self._moe_grouped[lkey] = passes

@@ -595,11 +595,15 @@ def test_mellum2_layers_compile_for_four_v5e_at_the_cells_shapes(topo, kind):
     positions, two sequences a chip, hidden 2,304, bfloat16), forward and
     backward over a described v5e:2x2. The expert layer on an expert axis
     of four: its 64 experts 16 a chip, a chip's 16,384 tokens exchanged
-    in parts of 4,096 by all-to-alls (three forward, two for a part made
-    again and two backward), the grouped kernels at hidden 2,304 and
+    in parts of 4,096, each token's row gathered once to every chip (an
+    all-gather of 16,384 rows) and the partial sums returned by an
+    all-to-all of four blocks of 4,096, no block of a row a pick (32,768
+    rows) on any collective; the grouped kernels at hidden 2,304 and
     width 896 (their rows' conversion back from the float32 slabs in
     blocks that fit the default scoped VMEM, which 512 rows at 2,304 do
-    not), no all-reduce of an expert's gradient. The full attention layer
+    not), no all-reduce or gather of an expert's tensor, and temporaries
+    well under the 5.28 GiB that blocks of a row a pick held. The full
+    attention layer
     with YaRN's tables: the fused core a chip's rows at a time inside
     ``shard_map`` (the partitioner cannot split a Mosaic kernel)."""
     import re
@@ -641,12 +645,19 @@ def test_mellum2_layers_compile_for_four_v5e_at_the_cells_shapes(topo, kind):
     assert "tpu_custom_call" in text
     if kind == "moe":
         assert layer.grouped and layer.chips() == 4
-        assert layer.part(16384) == 4096 and layer.capacity(16384) == 32768
-        assert len(re.findall(r"all-to-all\(", text)) == 7
-        reduces = [ln for ln in text.splitlines()
-                   if re.search(r"all-reduce(-start)?\(", ln)]
+        assert layer.part(16384) == 4096 and layer.capacity(16384) == 16384
+        collectives = [ln for ln in text.splitlines() if re.search(
+            r"(all-to-all|all-gather|all-reduce|reduce-scatter|"
+            r"collective-permute)(-start)?\(", ln)]
+        assert not any("32768" in ln for ln in collectives)
+        assert any(re.search(r"= bf16\[16384,2304\]\S* all-gather(-start)?\(",
+                             ln) for ln in collectives)
+        assert any(re.search(r"= bf16\[4,4096,2304\]\S* all-to-all\(", ln)
+                   for ln in collectives)
         assert not any(re.search(r"\[(64|16),(2304,896|896,2304)\]", ln)
-                       for ln in reduces)
+                       and re.search(r"all-(reduce|gather)(-start)?\(", ln)
+                       for ln in collectives)
+        assert compiled.memory_analysis().temp_size_in_bytes < 4.5 * 2 ** 30
         assert " conditional(" not in text
     else:
         assert layer.fused_core and layer.yarn() is not None

@@ -1,11 +1,13 @@
-"""The expert axis (layers/sequence.py: ``MoELayer`` with ``expert_axis``,
-``exchange_plan``; parallel/__init__.py: ``param_sharding``'s leading
-axes; nnet/trainer.py: a sequence net's experts spread over the mesh's
-data axis) on four virtual devices against the uncut layer on one: the
+"""The expert axis (layers/sequence.py: ``MoELayer`` with ``expert_axis``:
+a token's row sent once to every chip, the partial sums back;
+parallel/__init__.py: ``param_sharding``'s leading axes;
+nnet/trainer.py: a sequence net's experts spread over the mesh's data
+axis) on four virtual devices against the uncut layer on one: the
 forward pass and the gradients, one sequence a chip and two in parts,
-routing made as uneven as it can be with nothing dropped, where the trainer places the
-expert tensors and their Adam moments, which gradients it reduces, and
-that one device lowers no all-to-all.
+routing made as uneven as it can be with nothing dropped, the rows that
+carry a pick counted on a routing made by hand, where the trainer places
+the expert tensors and their Adam moments, which gradients it reduces,
+and that one device lowers no all-to-all.
 """
 
 import re
@@ -18,7 +20,6 @@ import pytest
 from cxxnet_tpu.io.data import DataBatch
 from cxxnet_tpu.layers import create_layer, seq_shape
 from cxxnet_tpu.layers import sequence
-from cxxnet_tpu.layers.sequence import exchange_plan
 from cxxnet_tpu.models import mellum2_tiny
 from cxxnet_tpu.nnet.trainer import NetTrainer
 from cxxnet_tpu.parallel import make_mesh
@@ -34,8 +35,8 @@ def _close(a, b, tol=1e-5):
         np.abs(a - b).max()
 
 
-def _layer(chips, score="softmax", block=4, d=D, w=W):
-    cfg = [("nexpert", E), ("topk", K), ("nhidden", w), ("nshared", 0),
+def _layer(chips, score="softmax", block=4, d=D, w=W, e=E, k=K):
+    cfg = [("nexpert", e), ("topk", k), ("nhidden", w), ("nshared", 0),
            ("score_func", score), ("expert_block", block)]
     if chips:
         cfg += [("expert_axis", "data")]
@@ -46,8 +47,8 @@ def _layer(chips, score="softmax", block=4, d=D, w=W):
     return layer
 
 
-def _params(seed=0, d=D, w=W):
-    layer = _layer(0, d=d, w=w)
+def _params(seed=0, d=D, w=W, e=E, k=K):
+    layer = _layer(0, d=d, w=w, e=e, k=k)
     return layer.init_params(jax.random.PRNGKey(seed)), layer.init_state()
 
 
@@ -64,11 +65,19 @@ def _run(layer, params, state, x, wt):
     return y, st, g
 
 
-def _uneven_state(state, toward):
-    """Routing made uneven on purpose: a bias on three experts, which the
-    picks follow (the weights stay the scores')."""
-    bias = jnp.zeros((E,)).at[jnp.array(toward)].set(10.0)
+def _uneven_state(state, toward, e=E):
+    """Routing made uneven on purpose: a bias on ``topk`` experts, which
+    the picks follow (the weights stay the scores')."""
+    bias = jnp.zeros((e,)).at[jnp.array(toward)].set(10.0)
     return dict(state, bias=bias)
+
+
+def _same(a, b):
+    """Two runs' outputs and every gradient to 1e-5 (float32)."""
+    _close(a[0], b[0], 1e-5)
+    for x, y in zip(jax.tree_util.tree_leaves(a[2]),
+                    jax.tree_util.tree_leaves(b[2])):
+        _close(x, y, 1e-5)
 
 
 @pytest.mark.parametrize("uneven", [False, True])
@@ -78,88 +87,123 @@ def test_four_chips_give_the_uncut_layers_values_and_gradients(uneven):
     layer's on one; so do the loads each expert got, and every pick
     arrives. With the routing made uneven (every token picks experts 0,
     2, 4: a pick a token on each of chips 0, 1, 2 and none on chip 3) too.
-    """
+    Every chip sends each of its tokens' rows to the three others, and
+    receives every chip's."""
     params, state = _params()
     if uneven:
         state = _uneven_state(state, (0, 2, 4))
     x = jax.random.normal(jax.random.PRNGKey(1), (B, T, D))
     wt = jax.random.normal(jax.random.PRNGKey(2), (B, T, D))
-    y1, st1, g1 = _run(_layer(0), params, state, x, wt)
+    one = _run(_layer(0), params, state, x, wt)
     four = _layer(4)
-    y4, st4, g4 = _run(four, params, state, x, wt)
-    _close(y4, y1, 1e-5)
-    for a, b in zip(jax.tree_util.tree_leaves(g4),
-                    jax.tree_util.tree_leaves(g1)):
-        _close(a, b, 1e-5)
+    assert four.capacity(T) == 4 * T
+    got = _run(four, params, state, x, wt)
+    _same(got, one)
+    st1, st4 = one[1], got[1]
     assert np.array_equal(st4["load"], st1["load"])
     assert int(st4["dropped"]) == 0
     assert int(st4["picks_held"]) == B * T * K == int(st1["picks_held"])
-    sent, fewest, most = (int(v) for v in st4["exchange"])
+    sent, fewest, most, used = (int(v) for v in st4["exchange"])
+    assert sent == B * T * 3
     assert fewest + most <= B * T * K and most >= B * T * K // 4
     if uneven:
-        # chips 0, 1, 2 each take one pick a token of all 64, chip 3 none
+        # chips 0, 1, 2 each take one pick a token, chip 3 none: of the
+        # 4 x B x T rows received, chip 3's B x T carry nothing
         assert (fewest, most) == (0, B * T)
         assert [int(st4["load"][e]) for e in (0, 2, 4)] == [B * T] * 3
-    # each chip keeps the picks for its own experts: the rest cross
-    assert 0 < sent < B * T * K
+        assert used == 3 * B * T
+    else:
+        assert B * T <= used <= 4 * B * T
 
 
 def test_two_sequences_a_chip_in_parts_give_the_uncut_layers_values(
         monkeypatch):
     """Eight sequences on four chips, a chip's 32 tokens exchanged in four
-    parts of 8 (``EXCHANGE_ROWS`` cut to 96 rows: 8 tokens x 3 picks from
-    each of four chips), each made again in the backward pass: the output,
-    every gradient, the loads and the counters are the uncut layer's."""
+    parts of 8 (``EXCHANGE_ROWS`` cut to 96 picks: 8 tokens x 3 picks from
+    each of four chips; a chip receives 4 x 8 token rows a part), each
+    made again in the backward pass: the output, every gradient, the
+    loads and the counters are the uncut layer's."""
     monkeypatch.setattr(sequence, "EXCHANGE_ROWS", 4 * 8 * K)
     params, state = _params()
     x = jax.random.normal(jax.random.PRNGKey(1), (2 * B, T, D))
     wt = jax.random.normal(jax.random.PRNGKey(2), (2 * B, T, D))
-    y1, st1, g1 = _run(_layer(0), params, state, x, wt)
+    one = _run(_layer(0), params, state, x, wt)
     four = _layer(4)
-    assert four.part(2 * T) == 8 and four.capacity(2 * T) == 8 * K
-    y4, st4, g4 = _run(four, params, state, x, wt)
-    _close(y4, y1, 1e-5)
-    for a, b in zip(jax.tree_util.tree_leaves(g4),
-                    jax.tree_util.tree_leaves(g1)):
-        _close(a, b, 1e-5)
+    assert four.part(2 * T) == 8 and four.capacity(2 * T) == 4 * 8
+    got = _run(four, params, state, x, wt)
+    _same(got, one)
+    st1, st4 = one[1], got[1]
     assert np.array_equal(st4["load"], st1["load"])
     assert int(st4["dropped"]) == 0
     assert int(st4["picks_held"]) == 2 * B * T * K
-    sent, fewest, most = (int(v) for v in st4["exchange"])
-    assert fewest <= 2 * B * T * K // 4 <= most and 0 < sent < 2 * B * T * K
+    sent, fewest, most, used = (int(v) for v in st4["exchange"])
+    assert fewest <= 2 * B * T * K // 4 <= most
+    assert sent == 2 * B * T * 3 and 2 * B * T <= used <= 4 * 2 * B * T
 
 
 def test_the_most_uneven_routing_drops_no_pick():
-    """A destination's block holds every pick of a part: every token
-    picking experts 0, 1, 2 sends two of its three picks to chip 0, 32 of
-    a chip's 48, and each lands (the plan would drop past a smaller
-    block: ``test_the_exchange_plan_fills_each_destination_in_order``)."""
+    """Every token picking experts 0, 1, 2 sends two of its three picks to
+    chip 0 and one to chip 1: chip 0's experts take 32 of a chip's 48
+    picks, and each lands."""
     params, state = _params()
     state = _uneven_state(state, (0, 1, 2))
     x = jax.random.normal(jax.random.PRNGKey(1), (B, T, D))
-    layer = _layer(4)
-    assert layer.capacity(T) == T * K
-    y, st, _ = _run(layer, params, state, x, jnp.ones((B, T, D)))
+    ones = jnp.ones((B, T, D))
+    got = _run(_layer(4), params, state, x, ones)
+    st = got[1]
     assert int(st["dropped"]) == 0
     assert int(st["picks_held"]) == B * T * K
-    sent, fewest, most = (int(v) for v in st["exchange"])
-    assert most == 2 * B * T and fewest == 0
+    sent, fewest, most, used = (int(v) for v in st["exchange"])
+    assert most == 2 * B * T and fewest == 0 and used == 2 * B * T
     assert [int(st["load"][e]) for e in (0, 1, 2)] == [B * T] * 3
-    y1, _, _ = _run(_layer(0), params, state, x, jnp.ones((B, T, D)))
-    _close(y, y1, 1e-5)
+    _same(got, _run(_layer(0), params, state, x, ones))
 
 
-def test_the_exchange_plan_fills_each_destination_in_order():
-    picks = jnp.array([[0, 3], [2, 1], [3, 0], [1, 2]])   # 2 experts a chip
-    slot, src, want = exchange_plan(picks, 0, 2, 2, 3)
-    # destination 0 takes experts 0, 1: picks 0, 3, 5, 6 in that order,
-    # the fourth past its 3 rows
-    assert want.tolist() == [4, 4]
-    assert slot.tolist() == [0, 3, 4, 1, 5, 2, 6, 6]
-    assert src.tolist() == [0, 3, 5, 1, 2, 4]
-    # experts off the axis (a program holding 2 .. 5 of them) go nowhere
-    slot, _, want = exchange_plan(picks, 2, 1, 2, 3)
-    assert want.tolist() == [2, 2] and slot.tolist()[0] == 6
+def test_every_pick_on_one_chip_drops_nothing():
+    """Top-4 of 16 experts, 4 a chip, every token's four picks on chip 0's
+    experts: chip 0 takes every pick of every chip (the case a block of one
+    row a pick had to be four times the even share for), the others none;
+    nothing is dropped, and the output and every gradient are the uncut
+    layer's."""
+    e, k = 16, 4
+    params, state = _params(e=e, k=k)
+    state = _uneven_state(state, (0, 1, 2, 3), e=e)
+    x = jax.random.normal(jax.random.PRNGKey(1), (B, T, D))
+    wt = jax.random.normal(jax.random.PRNGKey(2), (B, T, D))
+    got = _run(_layer(4, e=e, k=k), params, state, x, wt)
+    _same(got, _run(_layer(0, e=e, k=k), params, state, x, wt))
+    st = got[1]
+    assert int(st["dropped"]) == 0
+    assert int(st["picks_held"]) == B * T * k
+    assert [int(v) for v in st["load"]] == [B * T] * 4 + [0] * 12
+    sent, fewest, most, used = (int(v) for v in st["exchange"])
+    assert (sent, fewest, most, used) == (B * T * 3, 0, B * T * k, B * T)
+
+
+def test_the_rows_that_carry_a_pick_are_counted_exactly():
+    """A routing made by hand: the router reads features 0 .. 7 as the
+    experts' scores, and half the tokens pick experts 0, 1, 2 (chips 0 and
+    1), the other half 1, 3, 7 (chips 0, 1 and 3). Of the 4 x B x T token
+    rows the chips receive, 2.5 x B x T carry a pick for the receiving
+    chip: a share of 0.625, which the trainer reports as
+    ``exchange_used_share``."""
+    params, state = _params()
+    params = dict(params, router=10.0 * jnp.eye(D, E))
+    half = jnp.arange(B * T).reshape(B, T) % 2 == 0
+    x = jnp.where(half[..., None],
+                  jnp.zeros(D).at[jnp.array([0, 1, 2])].set(1.0),
+                  jnp.zeros(D).at[jnp.array([1, 3, 7])].set(1.0))
+    wt = jax.random.normal(jax.random.PRNGKey(2), (B, T, D))
+    got = _run(_layer(4), params, state, x, wt)
+    _same(got, _run(_layer(0), params, state, x, wt))
+    st = got[1]
+    assert [int(v) for v in st["load"]] == \
+        [B * T // 2, B * T, B * T // 2, B * T // 2, 0, 0, 0, B * T // 2]
+    sent, fewest, most, used = (int(v) for v in st["exchange"])
+    assert used == 5 * B * T // 2
+    assert used / (4 * B * T) == 0.625
+    # per chip: chip 0 and 1 every row, chip 3 half, chip 2 none
+    assert (fewest, most) == (0, 3 * B * T // 2)
 
 
 def test_the_grouped_kernels_run_on_the_received_rows():

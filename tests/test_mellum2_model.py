@@ -169,8 +169,9 @@ def test_the_seeded_weights_are_the_same_on_one_device_and_on_four():
 def test_the_records_count_the_expert_axis_and_the_exchange():
     """The ``layout`` record's expert axis, and a ``moe`` record a
     dispatch whose layers say what crossed: every pick received (held
-    share 1), none dropped, rows sent off-chip and received a chip, the
-    buffers' capacity."""
+    share 1), none dropped, each token's row sent once to each other
+    chip, picks received a chip, the token rows a chip receives in one
+    exchange and the share of them that carry a pick for its experts."""
     t = _trainer("bfloat16", 4)
     sink = MemorySink()
     t.set_monitor(Monitor(sink))
@@ -186,9 +187,10 @@ def test_the_records_count_the_expert_axis_and_the_exchange():
     for lk, v in moe["layers"].items():
         assert v["received_mean"] * 4 == picks
         assert v["received_min"] <= v["received_mean"] <= v["received_max"]
-        assert 0 < v["sent_offchip"] <= picks
-        # every pick of a chip's 16 tokens, from each of the four chips
-        assert v["capacity"] == 4 * 16 * 3
+        assert v["sent_offchip"] == 4 * T * (4 - 1)
+        # a chip's 16 tokens from each of the four chips
+        assert v["capacity"] == 4 * 16
+        assert 0 < v["exchange_used_share"] <= 1
     # one device: the axis has one chip and nothing travels
     one = _trainer("bfloat16", 1)
     sink = MemorySink()
@@ -197,7 +199,8 @@ def test_the_records_count_the_expert_axis_and_the_exchange():
     (layout,) = [r for r in sink.records if r["event"] == "layout"]
     assert layout["expert_axis_size"] == 1
     (moe,) = [r for r in sink.records if r["event"] == "moe"]
-    assert all(v["sent_offchip"] == 0 for v in moe["layers"].values())
+    assert all(v["sent_offchip"] == 0 and v["exchange_used_share"] == 1.0
+               for v in moe["layers"].values())
     assert moe["exchange_max_over_mean"] == 1.0
 
 
