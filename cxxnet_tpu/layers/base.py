@@ -83,12 +83,21 @@ def seq_shape(time: int, features: int) -> SeqShape:
 # kernel's three outputs (layers/pallas_kernels.py:_gated_delta_scan_fwd:
 # ``o``, a state a chunk and ``u``, which the backward kernel reads; 134
 # + 268 + 134 MB a layer there), so that it runs once a step: 3.43 ->
-# 3.50 sequences a second in the cell (PERF.md, PR 37). A name outside
-# a ``jax.checkpoint`` is the identity and lowers to nothing.
+# 3.50 sequences a second in the cell (PERF.md, PR 37). The expert layer
+# names its routing (layers/sequence.py: ``MoELayer.route``,
+# ``dispatch_plan``): the router's float32 logits, the picks and the
+# integer plan of which row holds which pick, so that the recomputed
+# forward runs no router product, ``top_k``, one-hot count or scatter
+# for it (33.5 MB of logits and about 3 MB of integers a layer at
+# Qwen3-Next's 512 experts and 2 x 8,192 tokens). A name outside a
+# ``jax.checkpoint`` is the identity and lowers to nothing.
 ATTENTION_KEEPS = ("attention_o", "attention_lse")
 DELTA_KEEPS = ("delta_solve",)
 DELTA_SCAN_KEEPS = ("delta_o", "delta_state", "delta_u")
-BLOCK_REMAT_KEEPS = ATTENTION_KEEPS + DELTA_KEEPS + DELTA_SCAN_KEEPS
+MOE_KEEPS = ("moe_logits", "moe_picks", "moe_src", "moe_dest", "moe_tok",
+             "moe_expert", "moe_load")
+BLOCK_REMAT_KEEPS = ATTENTION_KEEPS + DELTA_KEEPS + DELTA_SCAN_KEEPS \
+    + MOE_KEEPS
 
 
 def array_shape(batch: int, s: Shape3) -> Tuple[int, ...]:

@@ -1136,6 +1136,29 @@ class GatedDeltaLayer(_SeqLayer):
 # -- the expert layer ---------------------------------------------------------
 
 
+@jax.custom_vjp
+def _rows_of_picks(w, src, dest):
+    """``w[src]``: a pick's value in each row that holds it, 0 where
+    ``src`` is past the picks (padding). ``dest`` is the inverse index,
+    the row of each pick (past the rows for a pick that no row holds):
+    each pick lands in one row at most, so the cotangent gathered
+    through it is the gradient a scatter of ``w`` into the rows would
+    have, without the scatter (or the ids a scatter's gradient scatters
+    and gathers back to find which of its writes held)."""
+    return jnp.take(w, src, mode="fill", fill_value=0)
+
+
+def _rows_of_picks_fwd(w, src, dest):
+    return _rows_of_picks(w, src, dest), dest
+
+
+def _rows_of_picks_bwd(dest, g):
+    return jnp.take(g, dest, mode="fill", fill_value=0), None, None
+
+
+_rows_of_picks.defvjp(_rows_of_picks_fwd, _rows_of_picks_bwd)
+
+
 def dispatch_plan(picks, weights, first: int, count: int, block: int):
     """Where each pick that lands on a held expert goes.
 
@@ -1151,6 +1174,15 @@ def dispatch_plan(picks, weights, first: int, count: int, block: int):
     expert (blocks,) int32 the held expert (0-based) of each block
     nb     () int32       blocks in use: the loops run this far
     load   (count,) int32 picks each held expert got
+
+    The plan is integers: ``dest``, the row of each pick (``rows`` for a
+    pick off the held experts), and ``src``, the pick in each row (``tokens
+    x topk`` in padding), one scatter of indices that has no derivative;
+    the weights move into the rows by a gather through ``src`` whose
+    gradient is a gather through ``dest`` (``_rows_of_picks``). The plan
+    carries the names of ``MOE_KEEPS`` (layers/base.py), so that a
+    ``remat = block`` segment keeps it and its recomputed forward makes
+    none of it again.
     """
     n, k = picks.shape
     rows = (-(-n * k // block) + count) * block
@@ -1158,18 +1190,22 @@ def dispatch_plan(picks, weights, first: int, count: int, block: int):
     held = (flat >= 0) & (flat < count)
     onehot = (flat[:, None] == jnp.arange(count)[None, :]).astype(jnp.int32)
     rank = jnp.sum((jnp.cumsum(onehot, axis=0) - 1) * onehot, axis=1)
-    load = jnp.sum(onehot, axis=0)
+    load = checkpoint_name(jnp.sum(onehot, axis=0), "moe_load")
     nblk = (load + block - 1) // block
     ends = jnp.cumsum(nblk)
     start = (ends - nblk) * block
-    dest = jnp.where(held, start[jnp.clip(flat, 0, count - 1)] + rank, rows)
-    tok = jnp.full((rows,), n, jnp.int32).at[dest].set(
-        jnp.arange(n * k, dtype=jnp.int32) // k, mode="drop")
-    cw = jnp.zeros((rows,), _F32).at[dest].set(
-        weights.reshape(-1).astype(_F32), mode="drop")
-    expert = jnp.clip(jnp.searchsorted(
+    dest = checkpoint_name(
+        jnp.where(held, start[jnp.clip(flat, 0, count - 1)] + rank, rows),
+        "moe_dest")
+    src = checkpoint_name(jnp.full((rows,), n * k, jnp.int32).at[dest].set(
+        jnp.arange(n * k, dtype=jnp.int32), mode="drop"), "moe_src")
+    # padding's ``n * k`` gives ``n``
+    tok = checkpoint_name(src // k, "moe_tok")
+    cw = _rows_of_picks(weights.reshape(-1).astype(_F32), src, dest)
+    expert = checkpoint_name(jnp.clip(jnp.searchsorted(
         ends, jnp.arange(rows // block), side="right"), 0, count - 1)
-    return tok, cw, expert.astype(jnp.int32), ends[-1].astype(jnp.int32), load
+        .astype(jnp.int32), "moe_expert")
+    return tok, cw, expert, ends[-1].astype(jnp.int32), load
 
 
 # Picks that can land on a chip's experts in one exchange on an expert
@@ -1569,12 +1605,16 @@ class MoELayer(_SeqLayer):
     def route(self, xt, router, bias):
         """(picks, weights) of each token: float32 throughout, the
         product at full precision (a bf16 pass would move near-tied
-        picks)."""
-        s = jnp.dot(xt.astype(_F32), router.astype(_F32),
-                    precision=jax.lax.Precision.HIGHEST)
+        picks). The logits and the picks carry names of ``MOE_KEEPS``
+        (layers/base.py): a ``remat = block`` segment keeps them, so that
+        its recomputed forward makes only the scores again, elementwise."""
+        s = checkpoint_name(jnp.dot(xt.astype(_F32), router.astype(_F32),
+                                    precision=jax.lax.Precision.HIGHEST),
+                            "moe_logits")
         s = jax.nn.sigmoid(s) if self.score_func == "sigmoid" \
             else jax.nn.softmax(s, axis=-1)
-        _, picks = jax.lax.top_k(s + bias[None, :], self.topk)
+        picks = checkpoint_name(
+            jax.lax.top_k(s + bias[None, :], self.topk)[1], "moe_picks")
         w = jnp.take_along_axis(s, picks, axis=1)
         if self.norm_topk:
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)

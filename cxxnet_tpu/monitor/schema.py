@@ -229,7 +229,8 @@ OPTIONAL: Dict[str, tuple] = {
     # segment keeps the core's outputs for the backward pass, and how
     # many see a window of keys; moe layers, and how many of them run
     # their experts as the grouped kernels while a step's routing fits
-    # the kernels' row buffers; linear-attention layers (gated_delta),
+    # the kernels' row buffers, and of how many a remat = block segment
+    # keeps the routing; linear-attention layers (gated_delta),
     # the positions a chunk of their scan along time holds, how many of
     # them run that scan as the fused kernels and how many their short
     # convolution as the fused kernel; gated
@@ -238,7 +239,7 @@ OPTIONAL: Dict[str, tuple] = {
     # and the chips an expert layer's experts are spread over
     "layout": ("attention_layers", "attention_fused_layers",
                "attention_saved_layers", "attention_window_layers",
-               "moe_layers", "moe_grouped_layers",
+               "moe_layers", "moe_grouped_layers", "moe_plan_saved_layers",
                "linear_attention_layers", "linear_attention_chunk",
                "linear_attention_fused_layers",
                "linear_attention_fused_conv_layers",

@@ -1628,6 +1628,11 @@ class NetTrainer:
                            if getattr(layer, "window", 0) > 0),
                        moe_layers=len(grouped),
                        moe_grouped_layers=sum(grouped),
+                       # those whose routing (logits, picks, the integer
+                       # plan) a remat = block segment keeps, so that
+                       # each is routed once a step (MOE_KEEPS)
+                       moe_plan_saved_layers=(
+                           len(grouped) if self.remat == "block" else 0),
                        expert_axis_size=max(spread, default=1),
                        linear_attention_layers=len(chunks),
                        linear_attention_chunk=max(chunks, default=0),

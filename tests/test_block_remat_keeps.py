@@ -18,7 +18,8 @@ import numpy as np
 import pytest
 
 from cxxnet_tpu.layers import pallas_kernels as pk
-from cxxnet_tpu.layers.base import ATTENTION_KEEPS, BLOCK_REMAT_KEEPS
+from cxxnet_tpu.layers.base import (ATTENTION_KEEPS, BLOCK_REMAT_KEEPS,
+                                   MOE_KEEPS)
 from cxxnet_tpu.models.kimi_vl import decoder_lm
 from cxxnet_tpu.models.trinity import afmoe_lm
 from cxxnet_tpu.monitor import MemorySink, Monitor
@@ -164,14 +165,18 @@ def _shape_of_program(t):
 @pytest.mark.parametrize("kind", ["mla", "gqa"])
 def test_the_xla_core_names_nothing(kind, monkeypatch):
     """A length no tile divides: the XLA core, whose segments hold no
-    name, so the grad jaxpr is the policy-free segment's, equation for
-    equation."""
+    name of attention's (the expert layer's routing carries the only
+    names, ``MOE_KEEPS``), so the grad jaxpr is that of a segment that
+    keeps the routing alone, equation for equation."""
     t = _trainer(kind, seq_len=192)
     assert not any(layer.fused_core for layer in t.net.layer_objs
                    if hasattr(layer, "fused_core"))
     got = _shape_of_program(t)
-    assert not {"pallas_call", "name"} & {name for name, _ in got}
-    monkeypatch.setattr(net_mod, "BLOCK_REMAT_KEEPS", ())
+    assert "pallas_call" not in {name for name, _ in got}
+    names = {e.params["name"] for e in _eqns(jax.make_jaxpr(
+        _loss_and_grads(t))(t.params).jaxpr) if e.primitive.name == "name"}
+    assert names == set(MOE_KEEPS)
+    monkeypatch.setattr(net_mod, "BLOCK_REMAT_KEEPS", MOE_KEEPS)
     assert got == _shape_of_program(t)
 
 

@@ -102,6 +102,75 @@ def test_grouped_kernels_match_the_block_loop(routing, dtype, tol):
                   _value_and_grads(args, plan, g, 0), tol)
 
 
+def _scatter_plan(picks, weights, first, count, block):
+    """``dispatch_plan`` as it was before its plan became integers: the
+    token ids and the combine weights scattered into the rows."""
+    n, k = picks.shape
+    rows = (-(-n * k // block) + count) * block
+    flat = picks.reshape(-1) - first
+    held = (flat >= 0) & (flat < count)
+    onehot = (flat[:, None] == jnp.arange(count)[None, :]).astype(jnp.int32)
+    rank = jnp.sum((jnp.cumsum(onehot, axis=0) - 1) * onehot, axis=1)
+    load = jnp.sum(onehot, axis=0)
+    nblk = (load + block - 1) // block
+    ends = jnp.cumsum(nblk)
+    start = (ends - nblk) * block
+    dest = jnp.where(held, start[jnp.clip(flat, 0, count - 1)] + rank, rows)
+    tok = jnp.full((rows,), n, jnp.int32).at[dest].set(
+        jnp.arange(n * k, dtype=jnp.int32) // k, mode="drop")
+    cw = jnp.zeros((rows,), jnp.float32).at[dest].set(
+        weights.reshape(-1).astype(jnp.float32), mode="drop")
+    expert = jnp.clip(jnp.searchsorted(
+        ends, jnp.arange(rows // block), side="right"), 0, count - 1)
+    return tok, cw, expert.astype(jnp.int32), ends[-1].astype(jnp.int32), load
+
+
+def _routed(routing):
+    """(picks, weights, first) of a routing case; ``HELD`` experts from
+    ``first``. ``ties``: top-k of scores with three values, so that most
+    picks are decided by the lower index, and their weights tie too."""
+    rng = np.random.RandomState(9)
+    weights = jnp.asarray(rng.uniform(size=(TOKENS, TOPK)), jnp.float32)
+    if routing == "ties":
+        scores = jnp.asarray(rng.randint(0, 3, (TOKENS, NEXPERT)) / 4.0,
+                             jnp.float32)
+        weights, picks = jax.lax.top_k(scores, TOPK)
+        return picks, weights, 0
+    if routing == "off_held":
+        return _picks("even") % (NEXPERT - HELD) + HELD, weights, 0
+    if routing == "first":
+        return _picks("even"), weights, 5
+    return _picks(routing), weights, 0
+
+
+@pytest.mark.parametrize("routing", ["even", "one_expert", "off_held",
+                                     "first", "ties"])
+def test_the_integer_plan_is_the_scatters_to_the_bit(routing):
+    """The plan of a row's pick and a pick's row gives the scatter form's
+    token ids, combine weights, blocks, count and loads exactly, and the
+    gradient of ``sum(cw * r)`` in the weights is the scatter's own."""
+    picks, weights, first = _routed(routing)
+    got = dispatch_plan(picks, weights, first, HELD, BLOCK)
+    want = _scatter_plan(picks, weights, first, HELD, BLOCK)
+    load = np.asarray(want[4])
+    assert {"even": load.min() > 0, "one_expert": load[0] == TOKENS,
+            "off_held": load.sum() == 0, "first": load.min() > 0,
+            "ties": load.sum() > TOKENS}[routing], load
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    r = jax.random.normal(jax.random.PRNGKey(3), got[1].shape)
+
+    def grad(plan):
+        return jax.grad(lambda w: jnp.sum(plan(picks, w, first, HELD,
+                                               BLOCK)[1] * r))(weights)
+
+    g = grad(dispatch_plan)
+    np.testing.assert_array_equal(np.asarray(g), np.asarray(
+        grad(_scatter_plan)))
+    assert (routing == "off_held") == (not np.asarray(g).any())
+
+
 def test_the_whole_plan_as_budget_needs_no_branch():
     """A budget of all the plan's blocks (a layer that holds every
     expert) runs the kernels alone, to the same values: the only loops

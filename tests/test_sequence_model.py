@@ -237,6 +237,55 @@ def test_remat_block_and_loss_chunks_change_no_value():
         float(chunked.loss_value(logit, label, None)), rel=1e-6)
 
 
+# -- routing once a step ----------------------------------------------------
+
+
+def _routing_ops(t):
+    """(``top_k`` calls, router products) in the jaxpr of the gradient of
+    the trainer's loss: the router's is the one product at HIGHEST
+    precision (forward, a recomputed forward, two transposes)."""
+    from test_block_remat_keeps import _eqns
+    data, lab = _batch()
+    eqns = _eqns(jax.make_jaxpr(jax.grad(
+        lambda p: t.net.loss_fn(p, t.net_state, jnp.asarray(data),
+                                jnp.asarray(lab), None)[0]))(t.params).jaxpr)
+    return (sum(e.primitive.name == "top_k" for e in eqns),
+            sum(e.primitive.name == "dot_general"
+                and "HIGHEST" in str(e.params["precision"]) for e in eqns))
+
+
+def test_remat_block_routes_each_expert_layer_once(monkeypatch):
+    """A ``remat = block`` segment keeps the expert layer's logits, picks
+    and integer plan (layers/base.py: ``MOE_KEEPS``), so the step's
+    gradient holds one ``top_k`` a layer, where a segment that keeps none
+    of them makes it twice, and one router product fewer a layer."""
+    from cxxnet_tpu.layers.base import BLOCK_REMAT_KEEPS, MOE_KEEPS
+    from cxxnet_tpu.nnet import net as net_mod
+    t = _trainer()
+    assert t.remat == "block" and t.net.block_remat
+    moe = sum(hasattr(layer, "grouped") for layer in t.net.layer_objs)
+    assert moe == 2
+    tops, products = _routing_ops(t)
+    monkeypatch.setattr(net_mod, "BLOCK_REMAT_KEEPS", tuple(
+        n for n in BLOCK_REMAT_KEEPS if n not in MOE_KEEPS))
+    assert (tops, products) == (moe, _routing_ops(t)[1] - moe)
+    assert _routing_ops(t)[0] == 2 * moe
+
+
+@pytest.mark.parametrize("remat,saved", [("block", 2), ("none", 0)])
+def test_the_layout_record_counts_the_layers_whose_routing_is_kept(
+        remat, saved):
+    from cxxnet_tpu.monitor import MemorySink, Monitor
+    from cxxnet_tpu.monitor.schema import OPTIONAL, validate_record
+    t = _trainer(extra=[("remat", remat)])
+    sink = MemorySink()
+    t.set_monitor(Monitor(sink))
+    (rec,) = [r for r in sink.records if r["event"] == "layout"]
+    assert not validate_record(rec)
+    assert "moe_plan_saved_layers" in OPTIONAL["layout"]
+    assert (rec["moe_layers"], rec["moe_plan_saved_layers"]) == (2, saved)
+
+
 # -- a chip's share of an expert layer ----------------------------------------
 
 
