@@ -13,16 +13,13 @@ file and ``key=value`` overrides), at AlexNet's published width
 3. pred     ``task = pred`` from that snapshot over the same archive
 4. serve    ``task = export`` seals a bundle, ``task = serve`` boots FROM
             the bundle and answers a few hundred closed-loop requests
-5. kernels  each Pallas kernel of ``layers/pallas_kernels.py`` compiled by
-            Mosaic at a real width of a zoo model, forward and VJP,
-            against a plain ``jax.numpy`` reference
 
 Every check reads what came out (telemetry records, files, arrays), not
 what was printed. Any failed check raises, so the script exits non-zero;
 nothing is caught to let a run finish. It is a smoke: it prints wall
 and compile times per phase, and no rate under a metric's name.
 
-    python chip_smoke.py            # one chip, all five phases
+    python chip_smoke.py            # one chip, all four phases
     python chip_smoke.py --chips 4  # ONLY data-parallel training over the
                                     # four chips of one host, against the
                                     # same steps on a one-device mesh
@@ -344,236 +341,6 @@ def phase_serve(conf: str, out: str, size: Size, platform: str,
             "compile_events": summ["compile_events"]}
 
 
-# -- phase 5: the Pallas kernels, compiled ----------------------------------
-
-
-class KernelCase(NamedTuple):
-    name: str
-    shape: str
-    fn: Callable             # the Pallas path
-    ref: Callable            # plain jax.numpy, same semantics
-    args: tuple
-    grad_argnums: tuple      # () = forward only
-    tol: float               # max|a-b| <= tol * max|b|, per output
-    why: str                 # why the tolerance is what it is
-
-
-def kernel_cases(real: bool) -> List[KernelCase]:
-    """Each kernel at a real width of a model in the zoo (``real``), or
-    at a toy size for the CPU rehearsal."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from cxxnet_tpu.layers import pallas_kernels as pk
-
-    bf16, f32 = jnp.bfloat16, jnp.float32
-    keys = iter(jax.random.split(jax.random.PRNGKey(0), 32))
-
-    def randn(shape, dtype, scale=1.0):
-        return (jax.random.normal(next(keys), shape, f32)
-                * scale).astype(dtype)
-
-    def uniform(shape, lo, hi):
-        return jax.random.uniform(next(keys), shape, f32, lo, hi)
-
-    def signs(shape):
-        return jnp.where(jax.random.bernoulli(next(keys), 0.5, shape),
-                         1.0, -1.0)
-
-    def off_zero(shape, dtype):
-        """|x| in [1, 2): with scale in [0.5, 1.5) and |shift| small,
-        x * scale + shift keeps the sign of x whichever way a kernel
-        rounds — a relu mask that flips on a last-bit difference near
-        zero would fail the comparison for no fault of the kernel."""
-        return (signs(shape) * uniform(shape, 1.0, 2.0)).astype(dtype)
-
-    def affine_ref(out_dtype):
-        def ref(x, s, t, relu):
-            y = x.astype(f32) * s + t
-            return (jnp.maximum(y, 0) if relu else y).astype(out_dtype)
-        return ref
-
-    def window(x, k, op, init):
-        return jax.lax.reduce_window(x, init, op, (1, k, k, 1),
-                                     (1, 1, 1, 1), "VALID")
-
-    def pool_concat_ref(mode):
-        def ref(branches, pool_pos, k):
-            p = k // 2
-            xs = list(branches)
-            xp = jnp.pad(xs[pool_pos], ((0, 0), (p, p), (p, p), (0, 0)))
-            if mode == "max":
-                xs[pool_pos] = window(xp, k, jax.lax.max, -jnp.inf)
-            else:
-                xs[pool_pos] = (window(xp.astype(f32), k, jax.lax.add,
-                                       0.0) / (k * k)).astype(xp.dtype)
-            return jnp.concatenate(xs, axis=-1)
-        return ref
-
-    # sizes: AlexNet fc6; Inception-BN 3a/3b maps; kaiming's stem pool
-    m, kdim, n = (256, 9216, 4096) if real else (16, 600, 24)
-    b, hw = (128, 28) if real else (2, 8)
-    c_bn = 192 if real else 16
-    branches_c = (64, 64, 96, 192) if real else (8, 8, 16, 24)
-    pb, phw, pc = (128, 109, 64) if real else (2, 12, 8)
-
-    branches = tuple(randn((b, hw, hw, c), bf16) for c in branches_c)
-    # relu_max_pool credits EVERY tied max (the reference's unpool
-    # semantics), XLA's select-and-scatter only the first: make the
-    # positive values of any 3x3 window distinct (one magnitude per
-    # (h mod 3, w mod 3) residue, exact in bf16) so the two agree
-    hh, ww = np.meshgrid(np.arange(phw), np.arange(phw), indexing="ij")
-    mag = jnp.asarray(1.0 + ((hh % 3) * 3 + (ww % 3)) / 16.0, f32)
-    pool_x = (mag[None, :, :, None]
-              * signs((pb, phw, phw, pc))).astype(bf16)
-
-    one_ulp = "outputs round to bf16 once per op in the kernel and " \
-              "once per fusion in XLA: up to two bf16 ulps (2^-7 each)"
-    return [
-        KernelCase(
-            "matmul", "%dx%d . %dx%d bf16" % (m, kdim, kdim, n),
-            pk.matmul,
-            lambda x, w: jnp.dot(x, w, preferred_element_type=f32),
-            (randn((m, kdim), bf16), randn((kdim, n), bf16, 0.02)),
-            (0, 1), 2e-2,
-            "f32 accumulation on both sides, in another order; the "
-            "gradients round to bf16, and XLA rounds the f32 "
-            "cotangent to bf16 before its backward products"),
-        KernelCase(
-            "bn_apply", "%dx%dx%dx%d bf16, relu" % (b, hw, hw, c_bn),
-            lambda x, s, t: pk.bn_apply(x, s, t, True),
-            lambda x, s, t: affine_ref(bf16)(
-                x, s.astype(bf16).astype(f32),
-                t.astype(bf16).astype(f32), True),
-            (off_zero((b, hw, hw, c_bn), bf16),
-             uniform((c_bn,), 0.5, 1.5), randn((c_bn,), f32, 0.03)),
-            (0, 1, 2), 2e-2, one_ulp),
-        KernelCase(
-            "conv_epilogue[f32]",
-            "%dx%dx%dx%d f32 -> bf16, relu" % (b, hw, hw, c_bn),
-            lambda x, s, t: pk.conv_epilogue(x, s, t, True, bf16),
-            lambda x, s, t: affine_ref(bf16)(x, s, t, True),
-            (off_zero((b, hw, hw, c_bn), f32),
-             uniform((c_bn,), 0.5, 1.5), randn((c_bn,), f32, 0.03)),
-            (0, 1, 2), 1e-2, "f32 arithmetic, one rounding to bf16"),
-        KernelCase(
-            "conv_epilogue[int32]",
-            "%dx%dx%dx%d int32 -> f32" % (b, hw, hw, c_bn),
-            lambda x, s, t: pk.conv_epilogue(x, s, t, False, f32),
-            lambda x, s, t: affine_ref(f32)(x, s, t, False),
-            (jax.random.randint(next(keys), (b, hw, hw, c_bn),
-                                -2 ** 20, 2 ** 20, jnp.int32),
-             uniform((c_bn,), 0.0, 1e-4), randn((c_bn,), f32, 0.1)),
-            (), 1e-5, "f32 multiply-add of exactly converted int32"),
-        KernelCase(
-            "pool_concat[avg]",
-            "%dx%dx%d, branches %s, avg 3x3"
-            % (b, hw, hw, "/".join(map(str, branches_c))),
-            lambda *xs: pk.pool_concat(xs, 3, 3, "avg"),
-            lambda *xs: pool_concat_ref("avg")(xs, 3, 3),
-            branches, (0, 1, 2, 3), 2e-2, one_ulp),
-        KernelCase(
-            "pool_concat[max]",
-            "%dx%dx%d, branches %s, max 3x3"
-            % (b, hw, hw, "/".join(map(str, branches_c))),
-            lambda *xs: pk.pool_concat(xs, 3, 3, "max"),
-            lambda *xs: pool_concat_ref("max")(xs, 3, 3),
-            branches, (), 0.0, "copies and maxima are exact"),
-        KernelCase(
-            "relu_max_pool", "%dx%dx%dx%d bf16, 3x3" % (pb, phw, phw, pc),
-            lambda x: pk.relu_max_pool(x, 3),
-            lambda x: window(jnp.maximum(x, 0), 3, jax.lax.max,
-                             -jnp.inf),
-            (pool_x,), (0,), 2e-2,
-            "forward exact; the backward sums up to nine bf16 "
-            "cotangents, in f32 in the kernel and in bf16 in XLA: "
-            "up to two bf16 ulps of the largest sum"),
-    ]
-
-
-def _max_err(got, want) -> float:
-    """max|got - want| / max|want| (0/0 = 0)."""
-    import jax.numpy as jnp
-    g, w = got.astype(jnp.float32), want.astype(jnp.float32)
-    err = float(jnp.max(jnp.abs(g - w)))
-    scale = float(jnp.max(jnp.abs(w)))
-    return err / scale if scale > 0 else err
-
-
-def kernel_programs(case: KernelCase):
-    """``(pallas, reference)`` as jitted ``f(dy, *args) -> (out,
-    *grads)``: the forward, and the VJP at cotangent ``dy`` with
-    respect to ``case.grad_argnums``."""
-    import jax
-
-    diff = case.grad_argnums
-
-    def with_vjp(f):
-        def run(dy, *args):
-            if not diff:
-                return (f(*args),)
-
-            def g(*d):
-                full = list(args)
-                for i, v in zip(diff, d):
-                    full[i] = v
-                return f(*full)
-            out, pull = jax.vjp(g, *[args[i] for i in diff])
-            return (out,) + tuple(pull(dy))
-        return run
-
-    return jax.jit(with_vjp(case.fn)), jax.jit(with_vjp(case.ref))
-
-
-def run_kernel_case(case: KernelCase, compiled: bool) -> Dict:
-    """Forward and VJP of one kernel against its reference. With
-    ``compiled`` the lowering must hold a Mosaic call: the kernel was
-    compiled for the chip, not interpreted."""
-    import jax
-    import jax.numpy as jnp
-
-    fn, ref = kernel_programs(case)
-    out_s = jax.eval_shape(case.ref, *case.args)
-    dy = jax.random.normal(jax.random.PRNGKey(1), out_s.shape,
-                           jnp.float32).astype(out_s.dtype)
-    if compiled:
-        check("tpu_custom_call" in fn.lower(dy, *case.args).as_text(),
-              "%s: no Mosaic call in the lowering" % case.name)
-    t0 = time.perf_counter()
-    got = jax.block_until_ready(fn(dy, *case.args))
-    want = jax.block_until_ready(ref(dy, *case.args))
-    errs = []
-    for i, (g, w) in enumerate(zip(got, want)):
-        check(g.shape == w.shape and g.dtype == w.dtype,
-              "%s output %d: %s %s vs reference %s %s"
-              % (case.name, i, g.shape, g.dtype, w.shape, w.dtype))
-        check(bool(jnp.all(jnp.isfinite(g.astype(jnp.float32)))),
-              "%s output %d is not finite" % (case.name, i))
-        e = _max_err(g, w)
-        check(e <= case.tol, "%s output %d: error %.3g over tolerance "
-              "%.3g (%s)" % (case.name, i, e, case.tol, case.why))
-        errs.append(e)
-    return {"kernel": case.name, "shape": case.shape,
-            "compiled": compiled,
-            "vjp": bool(case.grad_argnums),
-            # forward first, then one per differentiated argument
-            "errs": [float("%.3g" % e) for e in errs], "tol": case.tol,
-            "wall_s": round(time.perf_counter() - t0, 3)}
-
-
-def phase_kernels(real: bool, compiled: bool) -> Dict:
-    from cxxnet_tpu.layers import pallas_kernels as pk
-    from cxxnet_tpu.nnet.quantize import backend_native
-    check(pk.interpret() is (not compiled),
-          "pallas_kernels.interpret() is %r" % pk.interpret())
-    results = [run_kernel_case(c, compiled) for c in kernel_cases(real)]
-    return {"kernels": results,
-            # the int8 serving path's probe (nnet/quantize.py), so a
-            # chip run says which contraction path it would take
-            "int8_native": {"dot": backend_native("int8", "dot"),
-                            "conv": backend_native("int8", "conv")}}
-
-
 # -- the four-chip path (--chips 4) ------------------------------------------
 
 
@@ -766,8 +533,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             conf, OUT_DIR, REAL, "tpu", train["snapshot"]), meter)
         run_phase("serve", lambda: phase_serve(
             conf, OUT_DIR, REAL, "tpu", train["snapshot"]), meter)
-        run_phase("kernels", lambda: phase_kernels(
-            real=True, compiled=True), meter)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(devices)}}), flush=True)

@@ -27,7 +27,6 @@ from .conv import (BatchNormLayer, ConvolutionLayer, InsanityPoolingLayer,
                    LRNLayer, PoolingLayer)
 from .loss import LossLayer, LpLossLayer, MultiLogisticLayer, SoftmaxLayer
 from .pairtest import PairTestLayer
-from .pallas_kernels import PallasFullConnectLayer
 from .sequence import (AddLayer, EmbedLayer, GatedConvLayer,
                        GatedDeltaLayer, GQAAttentionLayer, MLAAttentionLayer,
                        MoELayer, RMSNormLayer, SwiGLULayer)
@@ -35,7 +34,6 @@ from .torch_adapter import TorchLayer
 
 _FACTORY: Dict[str, Callable[..., Layer]] = {
     "fullc": lambda cfg, **kw: FullConnectLayer(cfg),
-    "pallas_fullc": lambda cfg, **kw: PallasFullConnectLayer(cfg),
     "fixconn": lambda cfg, **kw: FixConnectLayer(cfg),
     "bias": lambda cfg, **kw: BiasLayer(cfg),
     "softmax": lambda cfg, **kw: SoftmaxLayer(cfg),
@@ -51,8 +49,6 @@ _FACTORY: Dict[str, Callable[..., Layer]] = {
     "avg_pooling": lambda cfg, **kw: PoolingLayer("avg", cfg),
     "relu_max_pooling": lambda cfg, **kw: PoolingLayer("max", cfg,
                                                        pre_relu=True),
-    "pallas_relu_max_pooling": lambda cfg, **kw: PoolingLayer(
-        "max", cfg, pre_relu=True, use_pallas=True),
     "lrn": lambda cfg, **kw: LRNLayer(cfg),
     "concat": lambda cfg, **kw: ConcatLayer(3, cfg),
     "ch_concat": lambda cfg, **kw: ConcatLayer(1, cfg),
@@ -67,11 +63,6 @@ _FACTORY: Dict[str, Callable[..., Layer]] = {
     "prelu": lambda cfg, **kw: PReluLayer(cfg),
     "batch_norm": lambda cfg, **kw: BatchNormLayer(True, cfg),
     "batch_norm_no_ma": lambda cfg, **kw: BatchNormLayer(False, cfg),
-    # fused-epilogue variant: the folded scale/shift(+relu) runs as one
-    # Pallas pass (pallas_kernels.bn_apply); numerically identical to
-    # batch_norm with bn_fold_affine — pairtest-validated
-    "pallas_batch_norm": lambda cfg, **kw: BatchNormLayer(
-        True, cfg, use_pallas=True),
     # cross-framework oracle (the caffe adapter equivalent): a torch-
     # backed fullc/conv for pairtest-conv-torch style in-net A/B checks
     "torch": lambda cfg, **kw: TorchLayer(cfg),

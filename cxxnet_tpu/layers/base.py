@@ -149,22 +149,12 @@ class LayerParam:
     # bf16 with f32 accumulation (MXU-native); weights/state stay f32.
     # New knob, no reference equivalent (2015-era f32-only).
     compute_dtype: str = "float32"
-    # perf toggles (measurements in doc/perf_profile.md round 4):
-    # conv_1x1_matmul lowers pointwise convs to dot_general (measured
-    # neutral; off). bn_fold_affine folds BN's normalize+affine into
-    # one per-channel scale/shift so the full-tensor math stays in the
-    # compute dtype (+2.5% Inception-BN; DEFAULT — same math as the
-    # eval path's folded form, reassociation-level rounding only)
-    conv_1x1_matmul: int = 0
+    # perf toggle (measurements in doc/perf_profile.md round 4):
+    # bn_fold_affine folds BN's normalize+affine into one per-channel
+    # scale/shift so the full-tensor math stays in the compute dtype
+    # (+2.5% Inception-BN; DEFAULT — same math as the eval path's
+    # folded form, reassociation-level rounding only)
     bn_fold_affine: int = 1
-    # route relu_max_pooling through the fused Pallas kernel where
-    # applicable (stride-1 VALID square max pools that fit VMEM)
-    pallas_pool: int = 0
-    # run the conv's per-channel epilogue (bn_fold_eval scale/shift +
-    # relu, and the quantized path's dequant) as ONE Pallas pass
-    # (pallas_kernels.conv_epilogue) instead of folding the scale into
-    # the weights — same math, reassociation-level rounding only
-    conv_pallas_epilogue: int = 0
 
     def set_param(self, name: str, val: str) -> None:
         if name == "init_sigma":
@@ -214,14 +204,8 @@ class LayerParam:
             if val not in ("float32", "bfloat16"):
                 raise ValueError("dtype must be float32 or bfloat16")
             self.compute_dtype = val
-        if name == "conv_1x1_matmul":
-            self.conv_1x1_matmul = int(val)
         if name == "bn_fold_affine":
             self.bn_fold_affine = int(val)
-        if name == "pallas_pool":
-            self.pallas_pool = int(val)
-        if name == "conv_pallas_epilogue":
-            self.conv_pallas_epilogue = int(val)
 
     def rand_init_weight(self, key: jax.Array, shape: Tuple[int, ...],
                          in_num: int, out_num: int) -> jnp.ndarray:

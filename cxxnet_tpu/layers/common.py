@@ -390,15 +390,6 @@ class ConcatLayer(Layer):
             if self.dim != 3:
                 raise ValueError("ch_concat on matrix nodes is unsupported")
             return [jnp.concatenate(inputs, axis=1)], state
-        # Inception tower tail fusion (net-level pool_concat_pallas
-        # pass, nnet/net.py): the pool-branch input arrives UN-pooled
-        # and one Pallas pass reduces its window while writing every
-        # branch into its channel segment
-        fused = getattr(self, "_fused_pool", None)
-        if fused is not None and self.dim == 1:
-            from .pallas_kernels import pool_concat
-            pos, k, mode = fused
-            return [pool_concat(tuple(inputs), pos, k, mode)], state
         axis = {1: 3, 2: 1, 3: 2}[self.dim]   # NCHW dim -> NHWC axis
         return [jnp.concatenate(inputs, axis=axis)], state
 

@@ -166,14 +166,9 @@ class ConvolutionLayer(Layer):
         # _fold_shift (from the BN's running stats) and the downstream
         # BN runs as identity — w*scale folds into the (small) weight
         # tensor, deleting the per-layer elementwise pass entirely.
-        # With conv_pallas_epilogue the factor instead applies to the
-        # conv OUTPUT inside the fused scale+shift(+relu) Pallas pass
-        # (reassociation-level rounding only, same as the weight fold)
         fold_scale = params.get("_fold_scale")
         out_pad = getattr(self, "_out_pad", 0)
-        fold_in_epilogue = (fold_scale is not None and not quant
-                            and p.conv_pallas_epilogue and not out_pad)
-        if fold_scale is not None and not fold_in_epilogue:
+        if fold_scale is not None:
             w = w * fold_scale          # f32, per out channel (HWIO)
         # channel-alignment annotations (nnet/layout.py): zero weight
         # rows absorb a padded input's dead channels, zero weight
@@ -227,30 +222,16 @@ class ConvolutionLayer(Layer):
         else:
             b = None
         relu = fold_scale is not None and "_fold_relu" in params
-        ep_scale = q.dequant_vec() if quant \
-            else (fold_scale if fold_in_epilogue else None)
-        if ep_scale is not None:
-            # one fused per-channel scale+shift(+relu) pass: the
-            # quantized dequant or the output-side BN fold — through
-            # the Pallas kernel when configured and applicable
-            shift = b if b is not None else jnp.zeros_like(ep_scale)
-            # bf16 covers BOTH the training compute_dtype knob and
-            # serve_dtype=bfloat16 — a bf16-served graph must emit bf16
-            # from the fused epilogue or the ladder's halved activation
-            # bytes are lost mid-graph
+        if quant:
+            # the quantized dequant as one per-channel scale+shift
+            # (+relu) pass that emits the compute dtype
+            dq = q.dequant_vec()
+            shift = b if b is not None else jnp.zeros_like(dq)
             out_dtype = jnp.bfloat16 if bf16 else jnp.float32
-            from .pallas_kernels import (conv_epilogue,
-                                         conv_epilogue_applicable)
-            if p.conv_pallas_epilogue \
-                    and conv_epilogue_applicable(y.shape):
-                y = conv_epilogue(y, ep_scale.astype(jnp.float32),
-                                  shift.astype(jnp.float32), relu,
-                                  out_dtype)
-            else:
-                yf = y.astype(jnp.float32) * ep_scale + shift
-                if relu:
-                    yf = jax.nn.relu(yf)
-                y = yf.astype(out_dtype)
+            yf = y.astype(jnp.float32) * dq + shift
+            if relu:
+                yf = jax.nn.relu(yf)
+            y = yf.astype(out_dtype)
         else:
             if b is not None:
                 if out_pad:               # padded channels stay zero
@@ -293,18 +274,10 @@ class ConvolutionLayer(Layer):
             bf16 = (p.compute_dtype == "bfloat16"
                     or q.dtype == "bfloat16")
             out_dtype = jnp.bfloat16 if bf16 else jnp.float32
-            from .pallas_kernels import (conv_epilogue,
-                                         conv_epilogue_applicable)
-            if p.conv_pallas_epilogue \
-                    and conv_epilogue_applicable(y.shape):
-                y = conv_epilogue(y, dq.astype(jnp.float32),
-                                  shift.astype(jnp.float32), relu,
-                                  out_dtype)
-            else:
-                yf = y.astype(jnp.float32) * dq + shift
-                if relu:
-                    yf = jax.nn.relu(yf)
-                y = yf.astype(out_dtype)
+            yf = y.astype(jnp.float32) * dq + shift
+            if relu:
+                yf = jax.nn.relu(yf)
+            y = yf.astype(out_dtype)
         else:
             # pre-folded (and possibly pre-cast) float weights
             bf16 = (p.compute_dtype == "bfloat16"
@@ -320,18 +293,10 @@ class ConvolutionLayer(Layer):
         return [y], state
 
     def _float_conv(self, x, w, bf16):
-        """The three float conv lowerings (pointwise-as-matmul,
-        space-to-depth entry rewrite, general NHWC/HWIO conv)."""
+        """The two float conv lowerings (space-to-depth entry rewrite,
+        general NHWC/HWIO conv)."""
         p = self.param
-        if (p.conv_1x1_matmul and p.kernel_height == 1
-                and p.kernel_width == 1 and p.stride == 1
-                and p.num_group == 1 and p.pad_y == 0 and p.pad_x == 0):
-            # pointwise conv as an explicit (B*H*W, Cin) @ (Cin, Cout)
-            # matmul — experiment toggle, see doc/perf_profile.md
-            b, h, wd, c = x.shape
-            y = jnp.dot(x.reshape(b * h * wd, c), w.reshape(c, -1))
-            y = y.reshape(b, h, wd, -1)
-        elif (p.stride > 1 and p.num_group == 1 and x.shape[-1] <= 8
+        if (p.stride > 1 and p.num_group == 1 and x.shape[-1] <= 8
                 and p.kernel_height == p.kernel_width):
             # padded entry convs (Inception stem 7x7 s2 p3) zero-pad
             # explicitly, then the same VALID space-to-depth rewrite
@@ -358,11 +323,9 @@ class PoolingLayer(Layer):
     (the reference's relu_max_pooling, layer_impl-inl.hpp:55-56).
     """
 
-    def __init__(self, mode: str, cfg=(), pre_relu: bool = False,
-                 use_pallas: bool = False):
+    def __init__(self, mode: str, cfg=(), pre_relu: bool = False):
         self.mode = mode
         self.pre_relu = pre_relu
-        self.use_pallas = use_pallas
         super().__init__(cfg)
 
     def infer_shape(self, in_shapes: List[Shape3]) -> List[Shape3]:
@@ -411,12 +374,6 @@ class PoolingLayer(Layer):
     def forward(self, params, state, inputs, is_train, rng):
         x = inputs[0]
         if self.pre_relu:
-            p = self.param
-            if ((self.use_pallas or p.pallas_pool) and self.mode == "max"):
-                from .pallas_kernels import (relu_max_pool,
-                                             relu_max_pool_applicable)
-                if relu_max_pool_applicable(x.shape, p):
-                    return [relu_max_pool(x, p.kernel_height)], state
             x = jax.nn.relu(x)
         return [self._pool(x)], state
 
@@ -542,14 +499,13 @@ class BatchNormLayer(Layer):
 
     needs_mask = True
 
-    def __init__(self, moving_avg: bool, cfg=(), use_pallas: bool = False):
+    def __init__(self, moving_avg: bool, cfg=()):
         self.moving_avg = moving_avg
         self.init_slope = 1.0
         self.init_bias = 0.0
         self.eps = 1e-10
         self.bn_momentum = 0.9
         self.channel = 0
-        self.use_pallas = use_pallas
         # set by the net-level bn_fuse_relu pass (nnet/net.py): the
         # relu consuming this BN's output runs inside this layer and
         # the relu connection becomes identity — same math, one pass
@@ -566,8 +522,6 @@ class BatchNormLayer(Layer):
             self.eps = float(val)
         if name == "bn_momentum":
             self.bn_momentum = float(val)
-        if name == "bn_pallas":
-            self.use_pallas = bool(int(val))
 
     def infer_shape(self, in_shapes: List[Shape3]) -> List[Shape3]:
         s = self._expect_one(in_shapes)
@@ -620,17 +574,6 @@ class BatchNormLayer(Layer):
         var = jnp.maximum(s2 / n - mean * mean, 0.0)
         return mean, var
 
-    def _apply(self, x, scale, shift):
-        """The folded per-channel epilogue (+ fused relu), through the
-        Pallas kernel when configured — scale/shift in f32, applied in
-        the compute dtype (identical arithmetic on both paths, pinned
-        by pairtest-batch_norm-pallas_batch_norm)."""
-        if self.use_pallas:
-            from .pallas_kernels import bn_apply
-            return bn_apply(x, scale, shift, self.fuse_relu)
-        out = x * scale.astype(x.dtype) + shift.astype(x.dtype)
-        return jax.nn.relu(out) if self.fuse_relu else out
-
     def forward(self, params, state, inputs, is_train, rng, mask=None):
         x = inputs[0]
         slope, bias = params["wmat"], params["bias"]
@@ -658,12 +601,12 @@ class BatchNormLayer(Layer):
                 # test_inception_gate.py)
                 scale = slope * jax.lax.rsqrt(var + self.eps)
                 shift = bias - mean * scale
-                out = self._apply(x, scale, shift)
+                out = x * scale.astype(x.dtype) + shift.astype(x.dtype)
             else:
                 xhat = (x - mean) * jax.lax.rsqrt(var + self.eps)
                 out = (xhat * slope + bias).astype(x.dtype)
-                if self.fuse_relu:
-                    out = jax.nn.relu(out)
+            if self.fuse_relu:
+                out = jax.nn.relu(out)
             if self.moving_avg:
                 m = self.bn_momentum
                 if layout is not None:    # state stays logical

@@ -1,18 +1,21 @@
-"""Pallas TPU kernels + layers using them.
+"""Pallas TPU kernels of the language models' layers.
 
-The reference demonstrated extending its codegen with a hand-written
-CUDA expression Plan (insanity_pooling_layer-inl.hpp:12-220) and
-validated hand kernels against library implementations via pairtest
-(SURVEY.md §4.1). Same roles here: Pallas kernels with custom VJPs,
-validated with ``pairtest-pallas_fullc-fullc`` (tests/test_pallas.py),
-runnable in interpret mode on CPU test meshes.
+Four families, each with a custom VJP and a shape gate (its
+``*_applicable``) that the calling layer asks; where the gate says no,
+the layer runs its XLA form, which the kernels' tests pair them with
+(in interpret mode on the CPU). No key turns a kernel on or off: the
+shapes decide.
 
-Kernel: tiled matmul on the MXU — (bm, bk) blocks of x and (bk, bn)
-blocks of w meet in VMEM, ``jnp.dot`` drives the systolic array and
-the f32 output block accumulates over the K grid axis. The backward pass reuses the same kernel for both
-gradient GEMMs (dx = dy·wᵀ, dw = xᵀ·dy), exactly the two products the
-reference's hand-written fullc backprop computed
-(fullc_layer-inl.hpp:108-130).
+- :func:`causal_attention`: the causal softmax core of ``mla_attention``
+  and ``gqa_attention`` (grouped key/value heads, a window of keys).
+- :func:`gated_delta_scan`: ``gated_delta``'s chunked delta-rule scan.
+- :func:`gated_delta_conv`: ``gated_delta``'s short convolution with its
+  SiLU and the per-head unit norm of ``q`` and ``k``.
+- :func:`experts_forward` / :func:`experts_backward`: a ``moe`` layer's
+  routed experts as grouped products over blocks of rows.
+
+:func:`interpret` says whether they are built for the Pallas
+interpreter or compiled by Mosaic.
 """
 
 from __future__ import annotations
@@ -25,8 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
-from .base import ATTENTION_KEEPS, DELTA_SCAN_KEEPS, Shape3
-from .common import FullConnectLayer
+from .base import ATTENTION_KEEPS, DELTA_SCAN_KEEPS
 
 
 # The explicit interpret-mode choice (None = none made). The CPU tests
@@ -67,533 +69,8 @@ def _build_interpret() -> bool:
     return mode
 
 
-def _matmul_kernel(x_ref, w_ref, o_ref):
-    """One (i, j, k) grid step: the f32 output block stays resident in
-    VMEM across the K axis (its index map ignores k) and accumulates
-    one (bm, bk) x (bk, bn) MXU product per step."""
-    from jax.experimental import pallas as pl
-
-    @pl.when(pl.program_id(2) == 0)
-    def _():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    o_ref[...] += jnp.dot(x_ref[...], w_ref[...],
-                          preferred_element_type=jnp.float32)
-
-
 def _pad_to(v: int, m: int) -> int:
     return (v + m - 1) // m * m
-
-
-@partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
-def _matmul_pallas_raw(x: jnp.ndarray, w: jnp.ndarray,
-                       bm: int = 256, bn: int = 256, bk: int = 512,
-                       interpret: bool = False) -> jnp.ndarray:
-    """Tiled x @ w with f32 accumulation. K is a grid axis: a whole-K
-    block (the first version) ran out of VMEM at AlexNet's fc6 width
-    (256x9216 . 9216x4096) on the v5e; with (bm, bk) / (bk, bn) blocks
-    the working set is three 256 KB-class tiles, double-buffered,
-    whatever K is. A K that fits one block keeps the single-step
-    shape (block = full, 8-aligned K)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    m, k = x.shape
-    k2, n = w.shape
-    assert k == k2
-    if k <= bk:
-        bk = _pad_to(k, 8)
-    mp, np_, kp = _pad_to(m, bm), _pad_to(n, bn), _pad_to(k, bk)
-    xp = jnp.pad(x, ((0, mp - m), (0, kp - k)))
-    wp = jnp.pad(w, ((0, kp - k), (0, np_ - n)))
-    out = pl.pallas_call(
-        _matmul_kernel,
-        grid=(mp // bm, np_ // bn, kp // bk),
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(xp, wp)
-    return out[:m, :n]
-
-
-@jax.custom_vjp
-def matmul(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
-    """x @ w through the Pallas kernel, differentiable."""
-    return _matmul_pallas_raw(x, w, interpret=_build_interpret())
-
-
-def _matmul_fwd(x, w):
-    return _matmul_pallas_raw(x, w, interpret=_build_interpret()), (x, w)
-
-
-def _matmul_bwd(res, dy):
-    x, w = res
-    dx = _matmul_pallas_raw(dy, w.T, interpret=_build_interpret())
-    dw = _matmul_pallas_raw(x.T, dy, interpret=_build_interpret())
-    return dx.astype(x.dtype), dw.astype(w.dtype)
-
-
-matmul.defvjp(_matmul_fwd, _matmul_bwd)
-
-
-# ---------------------------------------------------- fused relu+maxpool
-
-def _relu_pool_fwd_kernel(k: int, x_ref, y_ref):
-    """One batch item: y = max-pool(relu(x)) over a k*k stride-1 VALID
-    window — relu applied in-register, no materialized relu tensor."""
-    x = x_ref[0]
-    r = jnp.maximum(x, 0)
-    oh = x.shape[0] - k + 1
-    ow = x.shape[1] - k + 1
-    y = r[0:oh, 0:ow, :]
-    for di in range(k):
-        for dj in range(k):
-            if di == 0 and dj == 0:
-                continue
-            y = jnp.maximum(y, r[di:di + oh, dj:dj + ow, :])
-    y_ref[0] = y
-
-
-def _relu_pool_bwd_kernel(k: int, x_ref, y_ref, dy_ref, dx_ref, acc_ref):
-    """dx in one pass: every input equal to its window max receives the
-    window's cotangent (the reference's exact unpool tie semantics,
-    mshadow unpool — XLA's select-and-scatter credits only the first
-    max), then the relu mask. f32 accumulation in VMEM scratch."""
-    x = x_ref[0]
-    # compares run in f32 (bf16 vector compare is unsupported on some
-    # Mosaic targets); bf16->f32 is exact so tie semantics are unchanged
-    r = jnp.maximum(x, 0).astype(jnp.float32)
-    y = y_ref[0].astype(jnp.float32)
-    dy = dy_ref[0].astype(jnp.float32)
-    oh, ow = y.shape[0], y.shape[1]
-    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
-    for di in range(k):
-        for dj in range(k):
-            contrib = jnp.where(r[di:di + oh, dj:dj + ow, :] == y,
-                                dy, 0.0)
-            acc_ref[di:di + oh, dj:dj + ow, :] = (
-                acc_ref[di:di + oh, dj:dj + ow, :] + contrib)
-    dx_ref[0] = jnp.where(x.astype(jnp.float32) > 0, acc_ref[...],
-                          0.0).astype(x.dtype)
-
-
-def _chunk_rows(h: int, w: int, c: int, k: int, itemsize: int) -> int:
-    """Output rows per pallas call so the scoped-VMEM working set stays
-    well under the 16MB limit. Mosaic pads the (W, C) tile dims (W to
-    the sublane multiple, C to 128 lanes); the unrolled k*k slice maxes
-    plus in/out double-buffering keep roughly a dozen row-sized buffers
-    live (the un-chunked 109x109x64 bf16 stem measured 29.3MB scoped)."""
-    padded_row = _pad_to(w, 32 // itemsize) * _pad_to(c, 128) * itemsize
-    rows = (5 * 1024 * 1024) // (padded_row * 12)
-    return max(8, min(h - k + 1, rows))
-
-
-def _relu_pool_call_fwd(x: jnp.ndarray, k: int) -> jnp.ndarray:
-    from jax.experimental import pallas as pl
-
-    b, h, w, c = x.shape
-    oh, ow = h - k + 1, w - k + 1
-    return pl.pallas_call(
-        partial(_relu_pool_fwd_kernel, k),
-        grid=(b,),
-        in_specs=[pl.BlockSpec((1, h, w, c), lambda i: (i, 0, 0, 0))],
-        out_specs=pl.BlockSpec((1, oh, ow, c), lambda i: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, oh, ow, c), x.dtype),
-        interpret=_build_interpret(),
-    )(x)
-
-
-def _relu_pool_pallas_fwd(x: jnp.ndarray, k: int) -> jnp.ndarray:
-    b, h, w, c = x.shape
-    oh = h - k + 1
-    rows = _chunk_rows(h, w, c, k, x.dtype.itemsize)
-    if rows >= oh:
-        return _relu_pool_call_fwd(x, k)
-    ys = []
-    for o in range(0, oh, rows):
-        r = min(rows, oh - o)
-        xi = jax.lax.slice_in_dim(x, o, o + r + k - 1, axis=1)
-        ys.append(_relu_pool_call_fwd(xi, k))
-    return jnp.concatenate(ys, axis=1)
-
-
-def _relu_pool_call_bwd(x: jnp.ndarray, y: jnp.ndarray,
-                        dy: jnp.ndarray, k: int) -> jnp.ndarray:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    b, h, w, c = x.shape
-    oh, ow = y.shape[1], y.shape[2]
-    return pl.pallas_call(
-        partial(_relu_pool_bwd_kernel, k),
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, h, w, c), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((1, oh, ow, c), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((1, oh, ow, c), lambda i: (i, 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, h, w, c), lambda i: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, w, c), x.dtype),
-        scratch_shapes=[pltpu.VMEM((h, w, c), jnp.float32)],
-        interpret=_build_interpret(),
-    )(x, y, dy)
-
-
-def _relu_pool_pallas_bwd(x: jnp.ndarray, y: jnp.ndarray,
-                          dy: jnp.ndarray, k: int) -> jnp.ndarray:
-    b, h, w, c = x.shape
-    oh = y.shape[1]
-    rows = _chunk_rows(h, w, c, k, x.dtype.itemsize)
-    if rows >= oh:
-        return _relu_pool_call_bwd(x, y, dy, k)
-    # chunk along H with a k-1 halo; dx chunks overlap by the halo, so
-    # accumulate into the full-size cotangent
-    dx = jnp.zeros_like(x)
-    for o in range(0, oh, rows):
-        r = min(rows, oh - o)
-        xi = jax.lax.slice_in_dim(x, o, o + r + k - 1, axis=1)
-        yi = jax.lax.slice_in_dim(y, o, o + r, axis=1)
-        dyi = jax.lax.slice_in_dim(dy, o, o + r, axis=1)
-        dxi = _relu_pool_call_bwd(xi, yi, dyi, k)
-        dx = dx.at[:, o:o + r + k - 1].add(dxi)
-    return dx
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(1,))
-def relu_max_pool(x: jnp.ndarray, k: int) -> jnp.ndarray:
-    """Fused relu + k*k stride-1 VALID max pool (NHWC) as one Pallas
-    kernel per direction — the hand-kernel answer to kaiming's stem
-    pool, whose select-and-scatter backward profiled at 28% of the
-    step (doc/perf_profile.md). The CUDA precedent is the reference's
-    hand-written pooling Plan (insanity_pooling_layer-inl.hpp:12-220).
-    """
-    return _relu_pool_pallas_fwd(x, k)
-
-
-def _relu_pool_vjp_fwd(x, k):
-    y = _relu_pool_pallas_fwd(x, k)
-    return y, (x, y)
-
-
-def _relu_pool_vjp_bwd(k, res, dy):
-    x, y = res
-    return (_relu_pool_pallas_bwd(x, y, dy, k),)
-
-
-relu_max_pool.defvjp(_relu_pool_vjp_fwd, _relu_pool_vjp_bwd)
-
-
-def relu_max_pool_applicable(shape, param) -> bool:
-    """Config gate for the fused kernel: stride-1 VALID square max
-    pools with a real window (H is chunked internally, so any extent
-    fits VMEM; a single ROW must — true for every conv feature map)."""
-    return (param.stride == 1 and param.pad_y == 0 and param.pad_x == 0
-            and param.kernel_height == param.kernel_width
-            and param.kernel_height > 1)
-
-
-# --------------------------------------------------- fused BN epilogue
-
-def _bn_apply_kernel(relu: bool, x_ref, s_ref, t_ref, o_ref):
-    """One block: y = x * scale + shift (+ relu), scale/shift per
-    channel applied in the block's compute dtype — the same arithmetic
-    as the bn_fold_affine jnp path, so pairtest divergence is zero."""
-    x = x_ref[...]
-    y = x * s_ref[...].astype(x.dtype) + t_ref[...].astype(x.dtype)
-    if relu:
-        y = jnp.maximum(y, 0)
-    o_ref[...] = y
-
-
-def _bn_rows(h: int, w: int, c: int, itemsize: int) -> int:
-    """Rows per block so in+out blocks stay well inside scoped VMEM
-    (Mosaic pads W to the sublane multiple and C to 128 lanes)."""
-    padded_row = _pad_to(w, 32 // itemsize) * _pad_to(c, 128) * itemsize
-    rows = max(1, (4 * 1024 * 1024) // (padded_row * 4))
-    while h % rows:                       # blocks must tile H exactly
-        rows -= 1
-    return rows
-
-
-def _bn_apply_call(x: jnp.ndarray, scale: jnp.ndarray,
-                   shift: jnp.ndarray, relu: bool) -> jnp.ndarray:
-    from jax.experimental import pallas as pl
-
-    mat = x.ndim == 2
-    x4 = x[:, None, None, :] if mat else x
-    b, h, w, c = x4.shape
-    rows = _bn_rows(h, w, c, x4.dtype.itemsize)
-    # per-channel params as (1, c) blocks: 2-D tiles keep Mosaic on its
-    # native (sublane, lane) layout
-    y = pl.pallas_call(
-        partial(_bn_apply_kernel, relu),
-        grid=(b, h // rows),
-        in_specs=[
-            pl.BlockSpec((1, rows, w, c), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, c), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, c), lambda i, j: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, rows, w, c),
-                               lambda i, j: (i, j, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, w, c), x4.dtype),
-        interpret=_build_interpret(),
-    )(x4, scale[None, :], shift[None, :])
-    return y[:, 0, 0, :] if mat else y
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(3,))
-def bn_apply(x: jnp.ndarray, scale: jnp.ndarray, shift: jnp.ndarray,
-             relu: bool = False) -> jnp.ndarray:
-    """Fused BN epilogue: ``relu?(x * scale + shift)`` per channel as
-    ONE Pallas pass (NHWC or matrix nodes) — the hand-kernel answer to
-    Inception's ~30 per-layer BN+relu elementwise chains. scale/shift
-    are the already-folded per-channel factors (bn_fold_affine form);
-    the moments stay outside so autodiff composes through them."""
-    return _bn_apply_call(x, scale, shift, relu)
-
-
-def _bn_apply_vjp_fwd(x, scale, shift, relu):
-    y = _bn_apply_call(x, scale, shift, relu)
-    return y, (x, scale, y)
-
-
-def _bn_apply_vjp_bwd(relu, res, dy):
-    x, scale, y = res
-    dym = jnp.where(y > 0, dy, jnp.zeros_like(dy)) if relu else dy
-    # dx reuses the forward kernel (shift=0): one fused pass; the two
-    # channel reductions fuse in XLA and accumulate in f32
-    dx = _bn_apply_call(dym, scale, jnp.zeros_like(scale), False)
-    axes = tuple(range(x.ndim - 1))
-    dscale = jnp.sum((dym * x).astype(jnp.float32), axis=axes)
-    dshift = jnp.sum(dym.astype(jnp.float32), axis=axes)
-    return (dx, dscale.astype(scale.dtype), dshift.astype(scale.dtype))
-
-
-bn_apply.defvjp(_bn_apply_vjp_fwd, _bn_apply_vjp_bwd)
-
-
-# ------------------------------------------------ conv epilogue fusion
-
-def _conv_epilogue_kernel(relu: bool, out_dtype, x_ref, s_ref, t_ref,
-                          o_ref):
-    """One block: o = relu?(x * scale + shift) with the arithmetic in
-    f32 — the conv/quantized-conv epilogue. Unlike the BN kernel the
-    input may be an int32 accumulator (native int8 conv) whose
-    per-channel dequant IS the scale, so x upcasts to f32 first and
-    the output dtype is explicit."""
-    x = x_ref[...].astype(jnp.float32)
-    y = x * s_ref[...] + t_ref[...]
-    if relu:
-        y = jnp.maximum(y, 0)
-    o_ref[...] = y.astype(out_dtype)
-
-
-def _conv_epilogue_call(x: jnp.ndarray, scale: jnp.ndarray,
-                        shift: jnp.ndarray, relu: bool,
-                        out_dtype) -> jnp.ndarray:
-    from jax.experimental import pallas as pl
-
-    mat = x.ndim == 2
-    x4 = x[:, None, None, :] if mat else x
-    b, h, w, c = x4.shape
-    rows = _bn_rows(h, w, c, max(x4.dtype.itemsize, 4))
-    y = pl.pallas_call(
-        partial(_conv_epilogue_kernel, relu, out_dtype),
-        grid=(b, h // rows),
-        in_specs=[
-            pl.BlockSpec((1, rows, w, c), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, c), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, c), lambda i, j: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, rows, w, c),
-                               lambda i, j: (i, j, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, w, c), out_dtype),
-        interpret=_build_interpret(),
-    )(x4, scale.astype(jnp.float32)[None, :],
-      shift.astype(jnp.float32)[None, :])
-    return y[:, 0, 0, :] if mat else y
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def conv_epilogue(x: jnp.ndarray, scale: jnp.ndarray,
-                  shift: jnp.ndarray, relu: bool,
-                  out_dtype=jnp.float32) -> jnp.ndarray:
-    """Fused conv epilogue: ``relu?(x * scale + shift)`` per out
-    channel as ONE Pallas pass (NHWC or matrix nodes). Two callers:
-    the eval ``bn_fold_eval`` path (scale = the BN running-stats
-    factor, applied to the conv output instead of pre-folded into the
-    weights — reassociation-level rounding only) and the quantized
-    path, where ``x`` is the raw int8-conv accumulator and ``scale``
-    carries the per-channel dequant (x_scale * w_scale) folded with
-    the BN factor. Differentiable in the float case for training
-    reuse; the int32 accumulator only ever flows on the eval path."""
-    return _conv_epilogue_call(x, scale, shift, relu, out_dtype)
-
-
-def _conv_epilogue_vjp_fwd(x, scale, shift, relu, out_dtype):
-    y = _conv_epilogue_call(x, scale, shift, relu, out_dtype)
-    return y, (x, scale, y)
-
-
-def _conv_epilogue_vjp_bwd(relu, out_dtype, res, dy):
-    x, scale, y = res
-    dym = jnp.where(y > 0, dy, jnp.zeros_like(dy)) if relu else dy
-    dx = _conv_epilogue_call(dym, scale, jnp.zeros_like(scale), False,
-                             x.dtype)
-    axes = tuple(range(x.ndim - 1))
-    dscale = jnp.sum((dym.astype(jnp.float32)
-                      * x.astype(jnp.float32)), axis=axes)
-    dshift = jnp.sum(dym.astype(jnp.float32), axis=axes)
-    return (dx, dscale.astype(scale.dtype), dshift.astype(scale.dtype))
-
-
-conv_epilogue.defvjp(_conv_epilogue_vjp_fwd, _conv_epilogue_vjp_bwd)
-
-
-def conv_epilogue_applicable(shape) -> bool:
-    """Config gate for the fused epilogue: NHWC or matrix nodes whose
-    single (1, rows, w, c) block tiles VMEM (guaranteed by the _bn_rows
-    chunking for any row that fits — true for every conv feature map)."""
-    return len(shape) in (2, 4) and shape[-1] > 0
-
-
-# -------------------------------------- fused pool+concat (Inception)
-
-def _pool_concat_kernel(k: int, mode: str, pool_pos: int, segs, *refs):
-    """One batch item: write every branch into its channel segment of
-    the concat output; the ``pool_pos`` input arrives pre-padded (zero
-    pad, the reference base-pad semantics) and its k*k stride-1 window
-    reduction happens in-register on the way into its segment — the
-    pooled intermediate is never materialized in HBM."""
-    o_ref = refs[-1]
-    for idx, (x_ref, (off, c)) in enumerate(zip(refs[:-1], segs)):
-        x = x_ref[0]
-        if idx != pool_pos:
-            o_ref[0, :, :, off:off + c] = x
-            continue
-        oh = x.shape[0] - k + 1
-        ow = x.shape[1] - k + 1
-        y = x[0:oh, 0:ow, :]
-        for di in range(k):
-            for dj in range(k):
-                if di == 0 and dj == 0:
-                    continue
-                sl = x[di:di + oh, dj:dj + ow, :]
-                y = jnp.maximum(y, sl) if mode == "max" else y + sl
-        if mode == "avg":
-            y = y * (1.0 / (k * k))
-        o_ref[0, :, :, off:off + c] = y
-
-
-def _pool_concat_call(branches, pool_pos: int, k: int,
-                      mode: str) -> jnp.ndarray:
-    from jax.experimental import pallas as pl
-
-    p = k // 2
-    xs = list(branches)
-    b, h, w, _ = xs[0].shape
-    dtype = xs[0].dtype
-    # zero pad OUTSIDE the kernel (XLA fuses it into the transfer);
-    # the kernel then runs a plain VALID stride-1 window
-    xs[pool_pos] = jnp.pad(xs[pool_pos].astype(dtype),
-                           ((0, 0), (p, p), (p, p), (0, 0)))
-    segs, off = [], 0
-    for x in branches:
-        segs.append((off, x.shape[-1]))
-        off += x.shape[-1]
-    in_specs = [pl.BlockSpec((1,) + x.shape[1:],
-                             lambda i: (i, 0, 0, 0)) for x in xs]
-    return pl.pallas_call(
-        partial(_pool_concat_kernel, k, mode, pool_pos, tuple(segs)),
-        grid=(b,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h, w, off), lambda i: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, w, off), dtype),
-        interpret=_build_interpret(),
-    )(*[x.astype(dtype) for x in xs])
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
-def pool_concat(branches, pool_pos: int, k: int,
-                mode: str) -> jnp.ndarray:
-    """Fused Inception tower tail: ``ch_concat(branches)`` where the
-    branch at ``pool_pos`` is the UN-pooled input of a k*k stride-1
-    SAME (pad = k//2) max/avg pool — one Pallas pass writes every
-    branch into its channel segment and reduces the pool window on the
-    way, deleting both the pooled intermediate and the separate concat
-    copy (the remaining device-step gap in the Inception modules after
-    channel alignment). Zero-pad semantics match the reference pooling
-    layer exactly (mshadow ``pad()`` is a zero pad; avg divides by
-    k*k unconditionally). Differentiable: the backward credits every
-    input equal to its window max (reference unpool tie semantics) /
-    redistributes uniformly for avg."""
-    return _pool_concat_call(branches, pool_pos, k, mode)
-
-
-def _pool_concat_vjp_fwd(branches, pool_pos, k, mode):
-    out = _pool_concat_call(branches, pool_pos, k, mode)
-    segs, off = [], 0
-    for x in branches:
-        segs.append((off, x.shape[-1]))
-        off += x.shape[-1]
-    o, c = segs[pool_pos]
-    y_pool = out[..., o:o + c] if mode == "max" else None
-    return out, (tuple(branches), y_pool)
-
-
-def _pool_concat_vjp_bwd(pool_pos, k, mode, res, dy):
-    branches, y_pool = res
-    p = k // 2
-    grads, off = [], 0
-    for i, x in enumerate(branches):
-        c = x.shape[-1]
-        seg = dy[..., off:off + c]
-        off += c
-        if i != pool_pos:
-            grads.append(seg.astype(x.dtype))
-            continue
-        h, w = x.shape[1], x.shape[2]
-        dyf = seg.astype(jnp.float32)
-        accp = jnp.zeros((x.shape[0], h + 2 * p, w + 2 * p, c),
-                         jnp.float32)
-        if mode == "max":
-            xp = jnp.pad(x.astype(jnp.float32),
-                         ((0, 0), (p, p), (p, p), (0, 0)))
-            yf = y_pool.astype(jnp.float32)
-        for di in range(k):
-            for dj in range(k):
-                if mode == "max":
-                    # every input equal to its window max receives the
-                    # window's cotangent (reference unpool ties)
-                    contrib = jnp.where(
-                        xp[:, di:di + h, dj:dj + w, :] == yf, dyf, 0.0)
-                else:
-                    contrib = dyf * (1.0 / (k * k))
-                accp = accp.at[:, di:di + h, dj:dj + w, :].add(contrib)
-        grads.append(accp[:, p:p + h, p:p + w, :].astype(x.dtype))
-    return (tuple(grads),)
-
-
-pool_concat.defvjp(_pool_concat_vjp_fwd, _pool_concat_vjp_bwd)
-
-
-def pool_concat_applicable(h: int, w: int, total_ch: int, k: int,
-                           itemsize: int) -> bool:
-    """Fusion gate: the whole (H, W, Ctotal) item (inputs + output +
-    the pool halo) must sit comfortably inside scoped VMEM — true for
-    every Inception tower map (<= 28x28 x ~1k ch), false for stem-sized
-    maps, which keep the unfused path."""
-    if k <= 1 or k % 2 == 0:
-        return False
-    per_item = (h + 2 * (k // 2)) * (w + 2 * (k // 2)) \
-        * _pad_to(total_ch, 128) * itemsize
-    return 3 * per_item <= 6 * 1024 * 1024
 
 
 # ------------------------------------------- fused causal attention
@@ -1880,22 +1357,3 @@ def grouped_experts_applicable(d: int, w: int, block: int, dtype) -> bool:
     vmem = 6 * d * w * size + block * (4 * d * size + 8 * d + 40 * w)
     return (min(d, w, block) > 0 and d % _LANES == 0 and w % _LANES == 0
             and block % _LANES == 0 and vmem <= _EXPERT_VMEM)
-
-
-class PallasFullConnectLayer(FullConnectLayer):
-    """fullc with the matmul lowered through the Pallas kernel
-    (config name ``pallas_fullc``); numerically identical to ``fullc``
-    — pairtest-pallas_fullc-fullc must report zero divergence."""
-
-    def forward(self, params, state, inputs, is_train, rng):
-        x = inputs[0]
-        w = params["wmat"]
-        if self.param.compute_dtype == "bfloat16":
-            # honor the global dtype knob so pairtest against fullc
-            # stays divergence-free under mixed precision
-            x = x.astype(jnp.bfloat16)
-            w = w.astype(jnp.bfloat16)
-        y = matmul(x, w)
-        if self.param.no_bias == 0:
-            y = y + params["bias"]
-        return [y], state

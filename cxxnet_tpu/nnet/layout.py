@@ -98,9 +98,8 @@ def take_valid(x: jnp.ndarray, layout: Layout) -> jnp.ndarray:
 # layer types that preserve the zero-channel invariant and operate
 # per-channel, so a padded input passes through untouched
 _PROPAGATE = ("relu", "max_pooling", "avg_pooling", "sum_pooling",
-              "relu_max_pooling", "pallas_relu_max_pooling", "dropout",
-              "split")
-_BN = ("batch_norm", "batch_norm_no_ma", "pallas_batch_norm")
+              "relu_max_pooling", "dropout", "split")
+_BN = ("batch_norm", "batch_norm_no_ma")
 
 
 def _round_up(c: int, q: int) -> int:

@@ -106,8 +106,6 @@ class FuncNet:
 
     # -- graph-level fusion passes ---------------------------------------
 
-    _BN_TYPES = ("batch_norm", "pallas_batch_norm")
-
     def _net_flag(self, name: str, default: int = 0) -> int:
         """Net-level knob from the global (default) layer config."""
         val = default
@@ -120,9 +118,9 @@ class FuncNet:
         """Epilogue fusion over the built graph.
 
         ``bn_fuse_relu = 1``: a relu that is the SOLE consumer of a
-        batch-norm output runs inside the BN layer (one fused epilogue
-        — and one Pallas pass under bn_pallas) and the relu connection
-        becomes identity. Same math, exactly: relu(bn(x)).
+        batch-norm output runs inside the BN layer (one fused epilogue)
+        and the relu connection becomes identity. Same math, exactly:
+        relu(bn(x)).
 
         ``bn_fold_eval = 1``: on the eval/pred path, a moving-average
         batch_norm that solely consumes a conv's output folds its
@@ -132,17 +130,6 @@ class FuncNet:
         from batch moments. Parity is pinned by tests (reassociation-
         level rounding only: the scale multiplies the weight before
         the contraction instead of the output after it).
-
-        ``pool_concat_pallas = 1``: an Inception-tower ``ch_concat``
-        whose pool branch is a k*k stride-1 SAME (pad = k//2) max/avg
-        pool consumed ONLY by the concat fuses into one Pallas pass
-        (pallas_kernels.pool_concat): the pool layer passes its input
-        through unpooled and the concat reduces the window while
-        writing the channel segments — the pooled intermediate and the
-        concat copy both disappear. Gated by the VMEM applicability
-        probe and off under ``channel_pad`` (the alignment pass owns
-        concat layout there). Same math both directions (custom VJP
-        with reference unpool tie semantics).
 
         Both fusions change what INTERIOR nodes hold (the BN output
         node carries the post-relu value; at eval the conv output node
@@ -164,7 +151,7 @@ class FuncNet:
                                if info.type == "share")
         if self._net_flag("bn_fuse_relu"):
             for li, info in enumerate(g.layers):
-                if info.type not in self._BN_TYPES + ("batch_norm_no_ma",):
+                if info.type not in ("batch_norm", "batch_norm_no_ma"):
                     continue
                 if li in shared_primaries:
                     continue
@@ -185,62 +172,10 @@ class FuncNet:
                 if len(cons) != 1:
                     continue
                 lj = cons[0]
-                if (g.layers[lj].type in self._BN_TYPES
+                if (g.layers[lj].type == "batch_norm"
                         and self.layer_objs[lj].moving_avg):
                     self._fold_pairs[li] = lj
                     self._fold_bns.add(lj)
-        self._pool_passthrough = set()    # pools fused into their concat
-        self._pool_concat = {}            # concat li -> (pos, k, mode)
-        if (self._net_flag("pool_concat_pallas")
-                and not self._net_flag("channel_pad")):
-            self._plan_pool_concat(consumers, shared_primaries)
-
-    def _plan_pool_concat(self, consumers, shared_primaries) -> None:
-        """Mark Inception-tower ch_concat layers whose pool branch can
-        fuse (see _fusion_passes docstring for the conditions)."""
-        from ..layers.conv import InsanityPoolingLayer, PoolingLayer
-        from ..layers.pallas_kernels import pool_concat_applicable
-        g = self.graph
-        producers = {}
-        for li, info in enumerate(g.layers):
-            for ni in info.nindex_out:
-                producers.setdefault(ni, li)
-        itemsize = 2 if any(n == "dtype" and v == "bfloat16"
-                            for n, v in g.defcfg) else 4
-        for li, info in enumerate(g.layers):
-            if info.type != "ch_concat" or li in shared_primaries:
-                continue
-            out_shape = self.node_shapes[info.nindex_out[0]]
-            for pos, ni in enumerate(info.nindex_in):
-                pli = producers.get(ni)
-                if pli is None or g.layers[pli].type not in (
-                        "max_pooling", "avg_pooling"):
-                    continue
-                pool = self.layer_objs[pli]
-                if (not isinstance(pool, PoolingLayer)
-                        or isinstance(pool, InsanityPoolingLayer)
-                        or pool.pre_relu):
-                    continue
-                pp = pool.param
-                k = pp.kernel_height
-                if (pp.stride != 1 or k != pp.kernel_width or k <= 1
-                        or k % 2 == 0 or pp.pad_y != k // 2
-                        or pp.pad_x != k // 2):
-                    continue
-                if consumers.get(ni, []) != [li]:
-                    continue
-                ins = self.node_shapes[g.layers[pli].nindex_in[0]]
-                outs = self.node_shapes[ni]
-                if (ins.y, ins.x) != (outs.y, outs.x):
-                    continue              # not a SAME-size pool
-                if not pool_concat_applicable(out_shape.y, out_shape.x,
-                                              out_shape.ch, k,
-                                              itemsize):
-                    continue
-                self._pool_concat[li] = (pos, k, pool.mode)
-                self.layer_objs[li]._fused_pool = (pos, k, pool.mode)
-                self._pool_passthrough.add(pli)
-                break                     # one fused branch per concat
 
     def _fold_entries(self, params: Params, state: NetState,
                       conv_li: int):
@@ -341,11 +276,9 @@ class FuncNet:
         for li in range(lo, hi):
             info = g.layers[li]
             if li in self._identity_layers \
-                    or li in self._pool_passthrough \
                     or (fold_eval and li in self._fold_bns):
                 # epilogue already ran fused inside the producer (relu
-                # inside BN / BN inside the folded conv / pool inside
-                # the fused concat): pass through
+                # inside BN / BN inside the folded conv): pass through
                 v = nodes[info.nindex_in[0]]
                 for ni in info.nindex_out:
                     nodes[ni] = v
@@ -575,7 +508,7 @@ class FuncNet:
                 total += (2 * p.kernel_height * p.kernel_width
                           * (p.num_input_channel // p.num_group)
                           * out.ch * out.y * out.x)
-            elif t in ("fullc", "pallas_fullc", "fixconn"):
+            elif t in ("fullc", "fixconn"):
                 p = layer.param
                 s = layer.in_shapes[0]
                 total += 2 * p.num_input_node * p.num_hidden \

@@ -24,7 +24,7 @@ snapshot and a servable quantized graph:
   :class:`QuantSpec` on each quantizable layer object; the eval
   forward then quantizes operands on device, contracts in the low
   dtype (int32 / f32 accumulation), and folds the per-channel dequant
-  into the conv epilogue (``layers/pallas_kernels.conv_epilogue``).
+  into the conv epilogue (``layers/conv.py``).
   Training forwards never consult the spec.
 
 One fallback is part of the contract: a backend that rejects native
@@ -52,8 +52,8 @@ SERVE_DTYPES = ("float32", "bfloat16", "int8", "fp8")
 
 QUANT_PREFIX = "quant/"
 
-# graph layer types whose contraction quantizes (pallas_fullc keeps its
-# own kernel path; the torch oracle layer is a test fixture)
+# graph layer types whose contraction quantizes (the torch oracle layer
+# is a test fixture)
 _QUANT_TYPES = {"conv": "conv", "fullc": "dot"}
 
 # amax floor: a dead channel (all-zero weights/activations) must not

@@ -897,24 +897,17 @@ class NetTrainer:
             quant = q is not None and q.is_affine
             bf16 = (layer.param.compute_dtype == "bfloat16"
                     or (q is not None and q.dtype == "bfloat16"))
-            fold = (info.type == "conv" and net._bn_fold_eval
-                    and li in net._fold_pairs)
-            # with conv_pallas_epilogue the fold factor applies to the
-            # conv OUTPUT (no per-dispatch weight work exists): only
-            # the scale/shift vectors precompute, the weight stays raw
-            epifold = (fold and not quant
-                       and bool(layer.param.conv_pallas_epilogue))
-            prefold = fold and not epifold
-            if not (quant or bf16 or prefold or epifold):
+            prefold = (info.type == "conv" and net._bn_fold_eval
+                       and li in net._fold_pairs)
+            if not (quant or bf16 or prefold):
                 continue
             relu = False
-            if fold:
+            if prefold:
                 relu = bool(net.layer_objs[net._fold_pairs[li]]
                             .fuse_relu)
             plan.append({"li": li, "lkey": lkey, "kind": info.type,
                          "q": q, "quant": quant, "bf16": bf16,
-                         "prefold": prefold, "epifold": epifold,
-                         "relu": relu,
+                         "prefold": prefold, "relu": relu,
                          "has_bias": layer.param.no_bias == 0})
         return plan
 
@@ -939,21 +932,12 @@ class NetTrainer:
                 w = p["wmat"]
                 b = p.get("bias") if item["has_bias"] else None
                 eff = None
-                if item["prefold"] or item["epifold"]:
+                if item["prefold"]:
                     fe = net._fold_entries(params, net_state,
                                            item["li"])
                     scale, shift = fe["_fold_scale"], fe["_fold_shift"]
-                    if item["prefold"]:
-                        w = w * scale
-                        eff = shift if b is None else shift + b * scale
-                    else:
-                        new["_fold_scale"] = scale
-                        new["_fold_shift"] = shift
-                        if item["relu"]:
-                            # value never read — key presence is the
-                            # (static) relu flag, as on the legacy path
-                            new["_fold_relu"] = jnp.ones((),
-                                                         jnp.float32)
+                    w = w * scale
+                    eff = shift if b is None else shift + b * scale
                 if item["quant"]:
                     q = item["q"]
                     w = q.quantize_w(w)
@@ -970,8 +954,7 @@ class NetTrainer:
                         else "_r_shift"] = eff
                 if item["bf16"] and not item["quant"]:
                     w = w.astype(jnp.bfloat16)
-                if item["quant"] or item["prefold"] or item["bf16"]:
-                    new["wmat"] = w
+                new["wmat"] = w
                 out[item["lkey"]] = new
             return out
 
@@ -1609,7 +1592,6 @@ class NetTrainer:
                        input_layout=self.input_layout_effective,
                        bn_fuse_relu=len(net._identity_layers),
                        bn_fold_eval_pairs=len(net._fold_pairs),
-                       pool_concat_fused=len(net._pool_concat),
                        # how any Pallas kernel of this process is built
                        # (layers/pallas_kernels.interpret)
                        pallas_interpret=_pallas.interpret(),

@@ -25,11 +25,6 @@ TINY = cs.Size(batch=8, image=67, src_image=72, n_images=32,
                dispatch_period=2, rounds=2, serve_buckets="2,4",
                serve_clients=2, serve_requests=4, serve_request_rows=2)
 
-KERNELS = ["matmul", "bn_apply", "conv_epilogue[f32]",
-           "conv_epilogue[int32]", "pool_concat[avg]",
-           "pool_concat[max]", "relu_max_pool"]
-
-
 @pytest.fixture(scope="module")
 def meter():
     return cs.CompileMeter()
@@ -81,34 +76,6 @@ def test_export_and_serve_from_bundle_phase(cli):
     assert line["artifact_rebuilds"] == 0
     assert line["compile_events"] == 0
     assert line["requests"] == 8 and line["rows"] == 16
-
-
-def test_kernel_case_names():
-    assert [c.name for c in cs.kernel_cases(real=False)] == KERNELS
-
-
-@pytest.mark.parametrize("name", KERNELS)
-def test_kernel_case_interpreted(name):
-    (case,) = [c for c in cs.kernel_cases(real=False) if c.name == name]
-    line = cs.run_kernel_case(case, compiled=False)
-    assert len(line["errs"]) == 1 + len(case.grad_argnums)
-    assert max(line["errs"]) <= case.tol
-    assert line["vjp"] == bool(case.grad_argnums)
-
-
-def test_kernel_case_fails_against_a_wrong_reference():
-    (case,) = [c for c in cs.kernel_cases(real=False)
-               if c.name == "matmul"]
-    wrong = case._replace(ref=lambda x, w: 1.05 * case.ref(x, w))
-    with pytest.raises(cs.SmokeFailure, match="over tolerance"):
-        cs.run_kernel_case(wrong, compiled=False)
-
-
-def test_kernel_phase_wants_compiled_kernels_on_the_chip():
-    """phase_kernels(compiled=True) is what the chip runs: under the
-    tests' interpret mode it must refuse, not run interpreted."""
-    with pytest.raises(cs.SmokeFailure, match="interpret"):
-        cs.phase_kernels(real=False, compiled=True)
 
 
 def test_data_parallel_phase_on_four_virtual_devices():

@@ -52,6 +52,35 @@ def test_fullc_forward_and_grad(rng):
     np.testing.assert_allclose(np.asarray(gx), dout @ w.T, rtol=1e-4)
 
 
+@pytest.mark.parametrize("nin,nout", [(40, 24), (77, 130)])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_fullc_matches_x_w_plus_b(rng, nin, nout, dtype):
+    """fullc against ``x @ W + b`` forward and gradient, at widths that
+    are and are not multiples of 8 or 128, in both compute dtypes (bf16
+    operands, f32 accumulation: within one bf16 rounding a product)."""
+    x = rng.randn(12, nin).astype(np.float32)
+    layer, params, _, outs, _ = run_layer(
+        "fullc", [("nhidden", str(nout)), ("init_bias", "0.3"),
+                  ("dtype", dtype)], [(1, 1, nin)], [x])
+    w, b = np.asarray(params["wmat"]), np.asarray(params["bias"])
+    tol = dict(rtol=1e-4, atol=1e-4) if dtype == "float32" \
+        else dict(rtol=2e-2, atol=2e-2 * np.abs(x).max())
+    assert outs[0].dtype == jnp.dtype(dtype)
+    y = np.asarray(outs[0], np.float32)
+    np.testing.assert_allclose(y, x @ w + b, **tol)
+    g = rng.randn(12, nout).astype(np.float32)
+
+    def f(p, xx):
+        o, _ = layer.forward(p, {}, [xx], True, None)
+        return jnp.sum(o[0].astype(jnp.float32) * g)
+
+    gp, gx = jax.grad(f, argnums=(0, 1))(params, jnp.asarray(x))
+    np.testing.assert_allclose(np.asarray(gp["wmat"], np.float32), x.T @ g,
+                               **tol)
+    np.testing.assert_allclose(np.asarray(gp["bias"]), g.sum(0), **tol)
+    np.testing.assert_allclose(np.asarray(gx, np.float32), g @ w.T, **tol)
+
+
 def test_fullc_no_bias():
     _, params, _, _, _ = run_layer(
         "fullc", [("nhidden", "3"), ("no_bias", "1")], [(1, 1, 8)],
@@ -145,13 +174,66 @@ def test_pooling_matches_reference_semantics(rng, mode, k, stride, pad,
                                atol=1e-6)
 
 
-def test_relu_max_pooling(rng):
-    x = rng.randn(2, 8, 8, 3).astype(np.float32)
-    _, _, _, outs, _ = run_layer(
-        "relu_max_pooling", [("kernel_size", "2"), ("stride", "2")],
-        [(3, 8, 8)], [x])
-    ref = _ref_pool(np.maximum(x, 0), 2, 2, 0, "max")
-    np.testing.assert_allclose(np.asarray(outs[0]), ref, rtol=1e-5)
+def _untied(rng, b, h, w, c):
+    """(b, h, w, c) with no two equal values in any (image, channel)
+    plane, exactly representable in bfloat16: a max pool's backward
+    then has one winner a window in either dtype."""
+    grid = np.concatenate([(128 + np.arange(128)) * 2.0 ** e
+                           for e in range(-8, -3)])
+    vals = np.concatenate([grid, -grid])
+    x = np.empty((b, c, h * w), np.float32)
+    for i in range(b):
+        for j in range(c):
+            x[i, j] = rng.choice(vals, h * w, replace=False)
+    return x.reshape(b, c, h, w).transpose(0, 2, 3, 1)
+
+
+def _ref_max_pool_grad(x, g, k, stride):
+    """NumPy backward of relu -> max pool (pad 0, truncated windows):
+    each window's cotangent goes to its one largest input, if that is
+    positive (relu's gradient)."""
+    b, h, w, c = x.shape
+    dx = np.zeros_like(x)
+    for i in range(g.shape[1]):
+        for j in range(g.shape[2]):
+            ys, xs = i * stride, j * stride
+            win = x[:, ys:min(ys + k, h), xs:min(xs + k, w)]
+            flat = win.reshape(b, -1, c)
+            arg = flat.argmax(1)
+            for bi in range(b):
+                for ci in range(c):
+                    if flat[bi, arg[bi, ci], ci] > 0:
+                        dy, dxx = divmod(arg[bi, ci], win.shape[2])
+                        dx[bi, ys + dy, xs + dxx, ci] += g[bi, i, j, ci]
+    return dx
+
+
+@pytest.mark.parametrize("k,stride,h,w", [
+    (2, 2, 8, 8), (3, 2, 9, 9), (3, 1, 9, 9), (3, 1, 30, 13),
+    (3, 2, 30, 13)])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_relu_max_pooling(rng, k, stride, h, w, dtype):
+    """relu_max_pooling forward and gradient against NumPy relu then max
+    pool, square windows of 2 and 3, strides 2 and 1, maps of 8 to 30
+    rows. Values are exact in both dtypes and the cotangents small
+    integers, so every comparison is exact."""
+    x = _untied(rng, 2, h, w, 8)
+    xd = jnp.asarray(x, dtype)
+    layer, params, state, outs, _ = run_layer(
+        "relu_max_pooling", [("kernel_size", str(k)),
+                             ("stride", str(stride))],
+        [(8, h, w)], [xd])
+    ref = _ref_pool(np.maximum(x, 0), k, stride, 0, "max")
+    assert outs[0].dtype == jnp.dtype(dtype)
+    np.testing.assert_array_equal(np.asarray(outs[0], np.float32), ref)
+    g = rng.randint(1, 8, ref.shape).astype(np.float32)
+
+    def f(xx):
+        o, _ = layer.forward(params, state, [xx], True, None)
+        return jnp.sum(o[0].astype(jnp.float32) * g)
+
+    np.testing.assert_array_equal(np.asarray(jax.grad(f)(xd), np.float32),
+                                  _ref_max_pool_grad(x, g, k, stride))
 
 
 # ---------------------------------------------------------------- lrn
@@ -196,6 +278,37 @@ def test_batch_norm_train_and_running(rng):
     ref2 = (x - rexp) / np.sqrt(rvar + 1e-10)
     np.testing.assert_allclose(np.asarray(outs2[0]), ref2, rtol=1e-3,
                                atol=1e-4)
+
+
+@pytest.mark.parametrize("fuse_relu", [False, True])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_batch_norm_training_matches_numpy(rng, fuse_relu, dtype):
+    """A training step of batch_norm (the folded scale/shift, with the
+    relu that the net's bn_fuse_relu pass moves into it) against a NumPy
+    batch norm with a learned slope and bias; the running statistics
+    move by the batch's moments."""
+    x = (rng.randn(6, 5, 5, 8) * 2.0 + 0.5).astype(np.float32)
+    layer = create_layer("batch_norm", [])
+    layer.infer_shape([Shape3(8, 5, 5)])
+    layer.fuse_relu = fuse_relu
+    params = {"wmat": jnp.asarray(rng.rand(8).astype(np.float32) + 0.5),
+              "bias": jnp.asarray(rng.randn(8).astype(np.float32))}
+    outs, new_state = layer.forward(params, layer.init_state(),
+                                    [jnp.asarray(x, dtype)], True, None)
+    xr = np.asarray(jnp.asarray(x, dtype), np.float64)
+    mean, var = xr.mean(axis=(0, 1, 2)), xr.var(axis=(0, 1, 2))
+    ref = (xr - mean) / np.sqrt(var + 1e-10) * np.asarray(params["wmat"]) \
+        + np.asarray(params["bias"])
+    if fuse_relu:
+        ref = np.maximum(ref, 0)
+    assert outs[0].dtype == jnp.dtype(dtype)
+    atol = 1e-4 if dtype == "float32" else 0.06
+    np.testing.assert_allclose(np.asarray(outs[0], np.float32), ref,
+                               atol=atol)
+    np.testing.assert_allclose(np.asarray(new_state["running_exp"]),
+                               0.1 * mean, rtol=1e-4, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(new_state["running_var"]),
+                               0.1 * var, rtol=1e-4, atol=1e-6)
 
 
 @pytest.mark.parametrize("mode", ["train", "eval"])
@@ -336,6 +449,57 @@ def test_concat_and_ch_concat(rng):
     assert layer.out_shapes[0] == Shape3(5, 4, 4)
     np.testing.assert_allclose(np.asarray(outs[0]),
                                np.concatenate([xa, xb], -1))
+
+
+def _pool_concat_ref(branches, pos, k, mode):
+    """A SAME stride-1 k x k pool of branch ``pos`` (zero padding, as
+    mshadow's pad()), then the channel concat of every branch."""
+    p = k // 2
+    xs = list(branches)
+    pad = jnp.pad(xs[pos], ((0, 0), (p, p), (p, p), (0, 0)))
+    if mode == "max":
+        y = jax.lax.reduce_window(pad, -jnp.inf, jax.lax.max,
+                                  (1, k, k, 1), (1, 1, 1, 1), "VALID")
+    else:
+        y = jax.lax.reduce_window(pad, 0.0, jax.lax.add,
+                                  (1, k, k, 1), (1, 1, 1, 1),
+                                  "VALID") * (1.0 / (k * k))
+    xs[pos] = y
+    return jnp.concatenate(xs, axis=3)
+
+
+@pytest.mark.parametrize("pos", [0, 1, 2])
+@pytest.mark.parametrize("mode", ["max", "avg"])
+def test_inception_tower_tail_pool_into_ch_concat(rng, mode, pos):
+    """An Inception tower's tail: a 3 x 3 stride-1 SAME pool branch into
+    ch_concat with two other branches, at each position, forward and
+    gradient against the padded reduce_window + concatenate. Random
+    float32 data has no ties for the max backward to split."""
+    widths = (8, 16, 8)
+    bs = [jnp.asarray(rng.randn(2, 8, 8, c).astype(np.float32))
+          for c in widths]
+    pool = create_layer("%s_pooling" % mode, [("kernel_size", "3"),
+                                              ("stride", "1"),
+                                              ("pad", "1")])
+    pool.infer_shape([Shape3(widths[pos], 8, 8)])
+    concat = create_layer("ch_concat", [])
+    concat.infer_shape([Shape3(c, 8, 8) for c in widths])
+
+    def tail(*branches):
+        xs = list(branches)
+        xs[pos] = pool.forward({}, {}, [xs[pos]], True, None)[0][0]
+        return concat.forward({}, {}, xs, True, None)[0][0]
+
+    np.testing.assert_allclose(np.asarray(tail(*bs)),
+                               np.asarray(_pool_concat_ref(bs, pos, 3,
+                                                           mode)),
+                               atol=1e-6)
+    args = (0, 1, 2)
+    g = jax.grad(lambda *a: jnp.sum(tail(*a) ** 2), argnums=args)(*bs)
+    gr = jax.grad(lambda *a: jnp.sum(_pool_concat_ref(a, pos, 3, mode)
+                                     ** 2), argnums=args)(*bs)
+    for a, b in zip(g, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
 
 
 def test_split_grad_sums(rng):

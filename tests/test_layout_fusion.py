@@ -9,8 +9,6 @@
   function composition (bit-exact).
 - bn_fold_eval: BN running-stats scale/shift folded into the conv
   weights for eval/pred — reassociation-level rounding only.
-- pallas_batch_norm (pallas_kernels.bn_apply): zero pairtest
-  divergence against the jnp folded path.
 - run_steps with update_period > 1: the scanned dispatch equals the
   per-batch dispatch path across accumulation windows.
 - the uint32 epoch: exact past 2^24 where the old f32 hyper slot
@@ -23,7 +21,6 @@ import numpy as np
 import pytest
 
 from cxxnet_tpu.io.data import DataBatch
-from cxxnet_tpu.layers import Shape3, create_layer
 from cxxnet_tpu.nnet.trainer import NetTrainer
 from cxxnet_tpu.utils.config import parse_config
 
@@ -248,38 +245,6 @@ def test_bn_fold_eval_with_fuse_relu_and_pad():
     fb = opt.extract_feature(b, "r2")
     assert fa.shape == fb.shape
     np.testing.assert_allclose(fa, fb, rtol=1e-4, atol=5e-5)
-
-
-def test_pairtest_pallas_batch_norm_divergence_at_fma_level(rng):
-    """The Pallas fused BN epilogue against the jnp folded path inside
-    one pairtest connection: same formula, same operands — divergence
-    bounded at the FMA-contraction level (the XLA fusion may contract
-    x*scale+shift into an fma where the interpret-mode kernel keeps
-    separate mul/add; one rounding of an O(1) normalized tensor)."""
-    layer = create_layer("pairtest-batch_norm-pallas_batch_norm", [])
-    layer.infer_shape([Shape3(5, 6, 6)])
-    params = layer.init_params(jax.random.PRNGKey(0))
-    state = layer.init_state()
-    x = jnp.asarray(rng.randn(4, 6, 6, 5).astype(np.float32))
-    outs, new_state = layer.forward(params, state, [x], True, None,
-                                    mask=None)
-    assert float(new_state["pairtest:max_diff"]) < 1e-6
-
-    def f(p):
-        o, _ = layer.forward(p, state, [x], True, None, mask=None)
-        return jnp.sum(o[0] ** 2)
-
-    g = jax.grad(f)(params)
-    np.testing.assert_allclose(np.asarray(g["wmat"]),
-                               np.asarray(g["slave:wmat"]), atol=1e-4)
-    np.testing.assert_allclose(np.asarray(g["bias"]),
-                               np.asarray(g["slave:bias"]), atol=1e-4)
-
-
-def test_pallas_bn_training_matches_jnp():
-    base = _train(CHAIN_CONF, [])
-    pl = _train(CHAIN_CONF, [("bn_pallas", "1"), ("bn_fuse_relu", "1")])
-    _assert_params(base, pl, exact=False, rtol=1e-3, atol=1e-5)
 
 
 def test_run_steps_update_period_matches_per_batch():

@@ -308,14 +308,13 @@ def test_backend_native_probe_is_cached_and_boolean(dt):
 
 
 def test_bf16_serve_epilogue_keeps_bf16_activations():
-    """serve_dtype=bfloat16 with conv_pallas_epilogue=1: the fused
-    fold epilogue must emit bf16 (regression: out_dtype keyed off the
+    """serve_dtype=bfloat16: the folded conv+BN(+relu) eval path must
+    emit bf16 (regression: the epilogue's out_dtype keyed off the
     training compute_dtype only, silently upcasting the whole ladder's
     activations back to f32 mid-graph)."""
     import jax.numpy as jnp
     t = NetTrainer(parse_config(CONV_CONF)
-                   + [("serve_dtype", "bfloat16"),
-                      ("conv_pallas_epilogue", "1")])
+                   + [("serve_dtype", "bfloat16")])
     t.init_model()
     for i in range(2):
         t.update(_batch(seed=i))
@@ -324,3 +323,79 @@ def test_bf16_serve_epilogue_keeps_bf16_activations():
                                 is_train=False)
     # node 1 = the folded conv+BN(+relu) output on the eval path
     assert nodes[1].dtype == jnp.bfloat16, nodes[1].dtype
+
+
+@pytest.mark.parametrize("relu", [False, True])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_int8_conv_dequant_epilogue_matches_float(relu, dtype):
+    """The quantized conv's epilogue over a resident serve tree: the
+    accumulator (int32 where the backend contracts int8 natively) times
+    ``_r_dequant`` plus the shift (+relu), emitted in the compute dtype,
+    against the same integer contraction done in float."""
+    import jax
+    import jax.numpy as jnp
+    from cxxnet_tpu.layers import Shape3, create_layer
+    from cxxnet_tpu.nnet.quantize import QuantSpec
+    rng = np.random.RandomState(5)
+    layer = create_layer("conv", [("nchannel", "24"), ("kernel_size", "3"),
+                                  ("pad", "1"), ("dtype", dtype)])
+    layer.infer_shape([Shape3(8, 6, 10)])
+    x = rng.randn(2, 6, 10, 8).astype(np.float32)
+    w = rng.randn(3, 3, 8, 24).astype(np.float32)
+    xs = float(np.abs(x).max() / 127)
+    ws = np.abs(w).max(axis=(0, 1, 2)) / 127
+    q = QuantSpec("int8", xs, jnp.asarray(ws),
+                  backend_native("int8", "conv"))
+    layer._quant = q
+    shift = rng.randn(24).astype(np.float32)
+    params = {"wmat": q.quantize_w(jnp.asarray(w)),
+              "_r_dequant": q.dequant_vec(),
+              "_r_shift_relu" if relu else "_r_shift": jnp.asarray(shift)}
+    (y,), _ = layer.forward(params, {}, [jnp.asarray(x)], False, None)
+    assert y.dtype == jnp.dtype(dtype)
+    xq = np.clip(np.round(x / xs), -127, 127)
+    wq = np.clip(np.round(w / ws), -127, 127)
+    # integer products and sums below 2**24: exact in float32
+    acc = jax.lax.conv_general_dilated(
+        xq, wq, (1, 1), [(1, 1), (1, 1)],
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=jax.lax.Precision.HIGHEST)
+    ref = np.asarray(acc, np.float64) * (xs * ws) + shift
+    if relu:
+        ref = np.maximum(ref, 0)
+    tol = 1e-5 if dtype == "float32" else 1e-2
+    np.testing.assert_allclose(np.asarray(y, np.float32), ref, rtol=tol,
+                               atol=tol * np.abs(ref).max())
+
+
+@pytest.mark.parametrize("relu", [0, 1])
+@pytest.mark.parametrize("serve_dtype", ["float32", "bfloat16"])
+def test_bn_fold_eval_resident_tree_matches_the_unfolded_net(relu,
+                                                            serve_dtype):
+    """freeze_serve_weights under bn_fold_eval: the conv's weight arrives
+    folded (times the BN's scale, in the serve dtype) with the shift
+    beside it, keyed by whether the relu rides along, and the eval output
+    matches conv -> batch_norm -> relu on the masters."""
+    import jax.numpy as jnp
+    t = _trained_trainer([("bn_fuse_relu", str(relu)),
+                          ("serve_dtype", serve_dtype)])
+    res = t.freeze_serve_weights()
+    tree = res.tree["c1"]
+    assert ("_r_shift_relu" if relu else "_r_shift") in tree
+    assert tree["wmat"].dtype == jnp.dtype(serve_dtype)
+    ref = NetTrainer(parse_config(CONV_CONF.replace("bn_fold_eval = 1",
+                                                    "bn_fold_eval = 0")))
+    ref.init_model()
+    for lk, pt in t.params.items():
+        for tag in pt:
+            ref.set_weight(lk, tag, t.get_weight(lk, tag))
+    for lk, st in t.net_state.items():
+        ref.net_state[lk] = dict(st)
+    data = jnp.asarray(_rows(8, seed=9))
+    top = t.graph.num_nodes - 1
+    got = t.net.forward(res.tree, t.net_state, data, is_train=False)[0][top]
+    want = ref.net.forward(ref.params, ref.net_state, data,
+                           is_train=False)[0][top]
+    tol = 1e-5 if serve_dtype == "float32" else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=tol)
